@@ -9,6 +9,9 @@ from __future__ import annotations
 
 __version__ = "0.1.0"
 
+from .framework.device import configure_compile_cache as _configure_cache
+_configure_cache()
+
 # core
 from .framework import (  # noqa: F401
     CPUPlace, CUDAPinnedPlace, CUDAPlace, CustomPlace, Parameter, Place,

@@ -132,7 +132,6 @@ def all_reduce(tensor, op=ReduceOp.SUM, group=None, sync_op=True):
         return tensor
     arr = unwrap(tensor)
     mesh = mesh_mod.get_mesh()
-    from jax.experimental.shard_map import shard_map
     spec = [None] * arr.ndim
     spec[dim] = axis
     in_spec = PartitionSpec(*spec)
@@ -140,8 +139,9 @@ def all_reduce(tensor, op=ReduceOp.SUM, group=None, sync_op=True):
     def body(a):
         return _reduce_fn(op)(a, axis)
 
-    fn = jax.jit(shard_map(body, mesh=mesh, in_specs=(in_spec,),
-                           out_specs=PartitionSpec(*([None] * arr.ndim))))
+    fn = jax.jit(jax.shard_map(
+        body, mesh=mesh, in_specs=(in_spec,),
+        out_specs=PartitionSpec(*([None] * arr.ndim))))
     out = fn(arr)
     tensor._data = out
     return tensor
@@ -295,7 +295,7 @@ def batch_isend_irecv(p2p_op_list):
 
 
 def barrier(group=None):
-    jax.effects_barrier() if hasattr(jax, "effects_barrier") else None
+    jax.effects_barrier()
     for d in jax.devices():
         pass
     return None

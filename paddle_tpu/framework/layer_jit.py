@@ -37,6 +37,7 @@ import jax.numpy as jnp
 
 from . import autograd
 from . import random as _random
+from .op import _trace_clean
 
 _UNSAFE = "unsafe"
 
@@ -67,13 +68,6 @@ def mark_unsafe(layer) -> None:
     leak, and fall back anyway — opting out up front skips the wasted
     trace AND keeps the leak from ever poisoning the attribute state."""
     _cache[layer] = {"execs": {}, "all": _UNSAFE}
-
-
-def _trace_clean() -> bool:
-    try:
-        return jax.core.trace_state_clean()
-    except AttributeError:  # older/newer jax layouts
-        return True
 
 
 def _flatten(obj):
@@ -183,11 +177,13 @@ class _LayerExec:
         self.with_grad = with_grad
         self.in_tree = in_tree
         self.kwargs = dict(kwargs_tuple)
-        named = list(layer.named_parameters())
-        self.diff_params = [p for _, p in named if not p.stop_gradient]
-        self.nd_params = [p for _, p in named if p.stop_gradient]
-        self.buffers = [b for _, b in layer.named_buffers()
-                        if b is not None]
+        # The exec holds NO parameter or buffer: jax keeps every jitted
+        # function — here the bound ``_fwd_impl``, hence this object —
+        # alive in its own cache, so a strong reference from here would
+        # pin a dead model's weights in device memory for the life of
+        # the process. ``call`` reads them off the live layer and lends
+        # them to the trace for the duration of the call.
+        self._live = None   # (diff_params, nd_params, buffers) in a call
         # Host-side trees are PER TRACE: the same jit can hold several
         # traced programs (aval changes retrace silently, and a retrace
         # may take a different Python path — e.g. a model flag toggled
@@ -213,14 +209,15 @@ class _LayerExec:
         under no_grad with chained RNG, collect outs + new buffers."""
         from .tensor import Tensor
         layer = self.layer
-        saved_d = [p._data for p in self.diff_params]
-        saved_n = [p._data for p in self.nd_params]
-        saved_b = [b._data for b in self.buffers]
-        for p, a in zip(self.diff_params, diff_arrays):
+        diff_params, nd_params, buffers = self._live
+        saved_d = [p._data for p in diff_params]
+        saved_n = [p._data for p in nd_params]
+        saved_b = [b._data for b in buffers]
+        for p, a in zip(diff_params, diff_arrays):
             p._data = a
-        for p, a in zip(self.nd_params, nd_arrays):
+        for p, a in zip(nd_params, nd_arrays):
             p._data = a
-        for b, a in zip(self.buffers, buf_arrays):
+        for b, a in zip(buffers, buf_arrays):
             b._data = a
         try:
             args = _unflatten(self.in_tree, list(in_leaves),
@@ -228,7 +225,7 @@ class _LayerExec:
             with autograd.no_grad(), _random.chain_scope(key) as chain:
                 out = layer.forward(*args, **self.kwargs)
                 new_key = chain.current()  # before scope restore
-            new_bufs = tuple(b._data for b in self.buffers)
+            new_bufs = tuple(b._data for b in buffers)
             out_leaves, out_tree, out_objs = _flatten(out)
             self._trace_out_tree = out_tree
             # integer/bool outputs (indices, masks) cannot ride the tape;
@@ -244,11 +241,11 @@ class _LayerExec:
             self._trace_leak = leak
             return tuple(out_leaves), (new_bufs, new_key)
         finally:
-            for p, a in zip(self.diff_params, saved_d):
+            for p, a in zip(diff_params, saved_d):
                 p._data = a
-            for p, a in zip(self.nd_params, saved_n):
+            for p, a in zip(nd_params, saved_n):
                 p._data = a
-            for b, a in zip(self.buffers, saved_b):
+            for b, a in zip(buffers, saved_b):
                 b._data = a
 
     def _fwd_impl(self, diff_arrays, in_leaves, nd_arrays, buf_arrays,
@@ -293,11 +290,17 @@ class _LayerExec:
         return bwd
 
     # -- entry --------------------------------------------------------------
-    def call(self, in_leaves, in_objs):
+    def call(self, in_leaves, in_objs, named):
+        """``named``: the layer's ``named_parameters()`` as of this call
+        (the walk ``try_call`` already made for the signature)."""
         from .tensor import Tensor
-        diff_arrays = tuple(p.data for p in self.diff_params)
-        nd_arrays = tuple(p.data for p in self.nd_params)
-        buf_arrays = tuple(b.data for b in self.buffers)
+        layer = self.layer
+        diff_params = [p for _, p in named if not p.stop_gradient]
+        nd_params = [p for _, p in named if p.stop_gradient]
+        buffers = [b for _, b in layer.named_buffers() if b is not None]
+        diff_arrays = tuple(p.data for p in diff_params)
+        nd_arrays = tuple(p.data for p in nd_params)
+        buf_arrays = tuple(b.data for b in buffers)
         key = _random.get_rng_state()
         # Any call may trace (first call, or a silent jax retrace on an
         # aval change), and a trace runs the Python forward, which may
@@ -307,8 +310,9 @@ class _LayerExec:
         # eager re-run (a stale tracer left in an attribute poisons
         # later eager ops).
         snap = [(sub, dict(vars(sub)))
-                for sub in _walk_layers(self.layer)]
+                for sub in _walk_layers(layer)]
         _state.active = True
+        self._live = (diff_params, nd_params, buffers)
         try:
             outs, (new_bufs, new_key), res = self._fwd(
                 diff_arrays, tuple(in_leaves), nd_arrays, buf_arrays,
@@ -318,6 +322,7 @@ class _LayerExec:
             raise
         finally:
             _state.active = False
+            self._live = None
         info = self._trees.get((len(outs), len(res)))
         if info is None or info[2] is not None:
             _restore_snapshot(snap)
@@ -325,7 +330,7 @@ class _LayerExec:
                                  "trace bookkeeping mismatch")
         out_tree, res_tree, _, diffable = info
         _random.set_rng_state(new_key)
-        for b, a in zip(self.buffers, new_bufs):
+        for b, a in zip(buffers, new_bufs):
             b._data = a
 
         grad_on = self.with_grad
@@ -340,7 +345,7 @@ class _LayerExec:
         result = _unflatten(out_tree, list(outs), wrap)
         node_outs = [t for t, d in out_tensors if d]
         if grad_on and node_outs:
-            node_inputs = list(self.diff_params) + list(in_objs)
+            node_inputs = list(diff_params) + list(in_objs)
             bwd = self._bwd_for(res_tree, len(res), diffable)
 
             def node_vjp(cot):
@@ -366,7 +371,7 @@ def _walk_info(layer):
     return hooks, tuple(training)
 
 
-def _signature(layer, in_leaves, in_objs, kwargs_tuple, with_grad,
+def _signature(named, in_leaves, in_objs, kwargs_tuple, with_grad,
                in_tree, training):
     from ..flags import flags_version
     parts = [with_grad, training,
@@ -374,7 +379,7 @@ def _signature(layer, in_leaves, in_objs, kwargs_tuple, with_grad,
     for a, o in zip(in_leaves, in_objs):
         parts.append((tuple(a.shape), str(a.dtype),
                       o.stop_gradient if o is not None else True))
-    for _, p in layer.named_parameters():
+    for _, p in named:
         parts.append((tuple(p.shape), str(p.dtype), p.stop_gradient))
     return tuple(parts)
 
@@ -427,14 +432,15 @@ def try_call(layer, inputs, kwargs):
     if any(isinstance(a, jax.core.Tracer) for a in in_leaves):
         return False, None
 
+    named = list(layer.named_parameters())
     with_grad = autograd.tape_enabled() and (
-        any(not p.stop_gradient for p in layer.parameters())
+        any(not p.stop_gradient for _, p in named)
         or any(o is not None and not o.stop_gradient for o in in_objs))
 
     if entry is None:
         entry = {"execs": {}}
         _cache[layer] = entry
-    sig = _signature(layer, in_leaves, in_objs, kwargs_tuple, with_grad,
+    sig = _signature(named, in_leaves, in_objs, kwargs_tuple, with_grad,
                      in_tree, training)
     exec_ = entry["execs"].get(sig)
     if exec_ is _UNSAFE:
@@ -443,7 +449,7 @@ def try_call(layer, inputs, kwargs):
         exec_ = _LayerExec(layer, with_grad, in_tree, kwargs_tuple)
         entry["execs"][sig] = exec_
     try:
-        return True, exec_.call(in_leaves, in_objs)
+        return True, exec_.call(in_leaves, in_objs, named)
     except _CaptureUnsafe:
         entry["execs"].pop(sig, None)
         entry["all"] = _UNSAFE

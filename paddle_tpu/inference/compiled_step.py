@@ -72,7 +72,6 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from ..framework.tensor import Tensor
 from ..nn.functional.attention import _sdpa_jnp
@@ -287,11 +286,11 @@ class CompiledStepRunner:
         pool_specs = [_POOL_SPEC] * nl
         scale_specs = [_SCALE_SPEC] * nl if meta["quantized"] else []
         ops_spec = jax.tree_util.tree_map(lambda _: P(), ops)
-        smap = shard_map(
+        smap = jax.shard_map(
             body, mesh=self.mesh,
             in_specs=(pool_specs, scale_specs, self._wspecs, ops_spec),
             out_specs=(P(), pool_specs, scale_specs),
-            check_rep=False)
+            check_vma=False)
         psums = _count_psums(smap, (pools_g, scales_g, self._weights,
                                     ops))
         fn = jax.jit(smap, donate_argnums=(0, 1))

@@ -1838,10 +1838,12 @@ class PagedKVCache:
         mid-chunked-prefill) present as all-trash so a fused decode
         step cannot write into their half-built pages."""
         if self._bt_cached is None:
-            tbl = self.block_tables
+            # always a COPY: jax may alias the host buffer it is handed,
+            # and block_tables mutates in place while calls that ride
+            # this tensor are still executing asynchronously
+            tbl = self.block_tables.copy()
             if self._decode_masked is not None and \
                     self._decode_masked.any():
-                tbl = tbl.copy()
                 tbl[self._decode_masked] = 0
             self._bt_cached = Tensor(jnp.asarray(tbl, jnp.int32))
         return self._bt_cached
@@ -1852,8 +1854,8 @@ class PagedKVCache:
         invalidated with the full table."""
         t = self._bt_rows_cached.get(slot)
         if t is None:
-            t = Tensor(jnp.asarray(self.block_tables[slot:slot + 1],
-                                   jnp.int32))
+            t = Tensor(jnp.asarray(
+                self.block_tables[slot:slot + 1].copy(), jnp.int32))
             self._bt_rows_cached[slot] = t
         return t
 

@@ -139,7 +139,9 @@ def build_model_from_spec(spec: dict):
     # hide inside a bit-identity assertion, a walking one cannot.
     roll = int(spec.get("head_roll", 0))
     head = (np.roll(embed, -roll, axis=0).T.copy() if roll else None)
-    return TokenServingModel(core, embed, lm_head=head)
+    tsm = TokenServingModel(core, embed, lm_head=head)
+    mp = int(spec.get("mp", 1))
+    return tsm.shard(mp) if mp > 1 else tsm
 
 
 def build_server_from_spec(spec: dict) -> RecoverableServer:
@@ -151,7 +153,8 @@ def build_server_from_spec(spec: dict) -> RecoverableServer:
     Keys (defaults in parens): model dims ``d_model`` (32), ``heads``
     (4), ``ffn`` (64), ``layers`` (2), ``vocab`` (50), seeds
     ``model_seed`` (0) / ``embed_seed`` (1234), ``head_roll`` (0 —
-    see the note at the readout below); engine knobs ``k``
+    see the note at the readout below), ``mp`` (1 — tensor-parallel
+    shards, ``TokenServingModel.shard``); engine knobs ``k``
     (0), ``max_batch`` (2), ``block_size`` (4), ``num_blocks`` (60),
     ``max_blocks_per_seq`` (10), ``prefix_cache`` (True),
     ``chunk_tokens``, ``prefill_token_budget``, ``kv_dtype``,

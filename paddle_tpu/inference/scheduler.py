@@ -2018,7 +2018,11 @@ class PagedServingEngine:
             # launch per layer on the kernel path
             out = self._flush_ragged_plan(x=x)
         else:
-            t = Tensor(np.asarray(self.lens, np.int32))
+            # a private COPY: jax may alias a host buffer it is handed
+            # (zero-copy on CPU, an in-flight transfer elsewhere), and
+            # self.lens advances in place below while the layers of
+            # this call are still executing asynchronously
+            t = Tensor(np.array(self.lens, np.int32))
             with no_grad():
                 out, _ = self.model(x, caches=self.cache.views,
                                     time_step=t)
@@ -2173,7 +2177,11 @@ class PagedServingEngine:
             # packed into ONE ragged model call
             out = self._flush_ragged_plan(x=x, L=L)
         else:
-            t = Tensor(np.asarray(self.lens, np.int32))
+            # a private COPY: jax may alias a host buffer it is handed
+            # (zero-copy on CPU, an in-flight transfer elsewhere), and
+            # self.lens advances in place below while the layers of
+            # this call are still executing asynchronously
+            t = Tensor(np.array(self.lens, np.int32))
             with no_grad():
                 out, _ = self.model(x, caches=self.cache.views,
                                     time_step=t)

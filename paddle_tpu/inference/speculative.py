@@ -1128,7 +1128,8 @@ class SpeculativeEngine:
                         x[s, 0] = self.draft.embed(cur[s])
                         self.draft_cache.ensure(
                             s, int(self._draft_lens[s]) + 1)
-                    t = Tensor(np.asarray(self._draft_lens, np.int32))
+                    # a copy: _draft_lens advances in place below
+                    t = Tensor(np.array(self._draft_lens, np.int32))
                     with no_grad():
                         out, _ = self.draft.core(
                             paddle.to_tensor(x),
@@ -1187,7 +1188,8 @@ class SpeculativeEngine:
                             self._seqs[s].toks[-1])
                         self.draft_cache.ensure(
                             s, int(self._draft_lens[s]) + 1)
-                    t = Tensor(np.asarray(self._draft_lens, np.int32))
+                    # a copy: _draft_lens advances in place below
+                    t = Tensor(np.array(self._draft_lens, np.int32))
                     with no_grad():
                         self.draft.core(paddle.to_tensor(x),
                                         caches=self.draft_cache.views,

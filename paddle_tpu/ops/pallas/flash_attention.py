@@ -23,11 +23,7 @@ import math
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-try:
-    from jax.experimental.pallas import tpu as pltpu
-except ImportError:  # pragma: no cover
-    pltpu = None
+from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 
@@ -228,12 +224,10 @@ def _flash_fwd_pallas(q, k, v, causal=False, sm_scale=None, block_q=128,
             pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, d), jnp.float32),
-        ] if pltpu is not None else [],
+        ],
         interpret=interpret,
-        compiler_params=(pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"))
-            if (pltpu is not None and not interpret
-                and hasattr(pltpu, "CompilerParams")) else None),
+        compiler_params=None if interpret else pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
     )(q, k, v, *extra)
     out, lse = outs if return_lse else (outs, None)
     if t_q_pad != t_q:
@@ -416,10 +410,8 @@ def _flash_bwd_pallas(q, k, v, o, lse, do, causal, sm_scale, block_q=128,
     if dropout_rate > 0.0:
         seed_extra = (jnp.full((8, 128), jnp.asarray(seed, jnp.int32)),)
         seed_spec = [pl.BlockSpec((8, 128), lambda b, i, j: (0, 0))]
-    cparams = (pltpu.CompilerParams(
+    cparams = None if interpret else pltpu.CompilerParams(
         dimension_semantics=("parallel", "parallel", "arbitrary"))
-        if (pltpu is not None and not interpret
-            and hasattr(pltpu, "CompilerParams")) else None)
 
     grid_dq = (bh, t_q_pad // block_q, t_k_pad // block_k)
     dq_base = functools.partial(_flash_bwd_dq_kernel, kv_steps=grid_dq[2],
@@ -444,8 +436,7 @@ def _flash_bwd_pallas(q, k, v, o, lse, do, causal, sm_scale, block_q=128,
         ] + seed_spec,
         out_specs=pl.BlockSpec((1, block_q, d), lambda b, qi, ki: (b, qi, 0)),
         out_shape=jax.ShapeDtypeStruct((bh, t_q_pad, d), q.dtype),
-        scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)]
-        if pltpu is not None else [],
+        scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
         interpret=interpret,
         compiler_params=cparams,
     )(q, k, v, do, lse, delta, *seed_extra)
@@ -478,8 +469,7 @@ def _flash_bwd_pallas(q, k, v, o, lse, do, causal, sm_scale, block_q=128,
         out_shape=[jax.ShapeDtypeStruct((bh, t_k_pad, d), k.dtype),
                    jax.ShapeDtypeStruct((bh, t_k_pad, d), v.dtype)],
         scratch_shapes=[pltpu.VMEM((block_k, d), jnp.float32),
-                        pltpu.VMEM((block_k, d), jnp.float32)]
-        if pltpu is not None else [],
+                        pltpu.VMEM((block_k, d), jnp.float32)],
         interpret=interpret,
         compiler_params=cparams,
     )(q, k, v, do, lse, delta, *seed_extra)
@@ -596,29 +586,14 @@ def flash_attention_blhd(q, k, v, causal=False, sm_scale=None,
             jnp.asarray(seed if seed is not None else 0, jnp.int32),
             causal, sm_scale, dropout_rate)
     else:
-        try:
-            from jax.experimental.pallas.ops.tpu.flash_attention import (
-                flash_attention as jax_flash)
-            out = jax_flash(qh, kh, vh, causal=causal, sm_scale=sm_scale,
-                            block_sizes=_tuned_block_sizes(
-                                qh.shape[2], kh.shape[2]))
-        except Exception as e:
-            global _warned_fallback
-            if not _warned_fallback:
-                import warnings
-                warnings.warn(
-                    "jax tuned TPU flash attention unavailable "
-                    f"({type(e).__name__}: {e}); falling back to the native "
-                    "pallas forward + AD backward (slower backward). Set "
-                    "FLAGS_tpu_flash_impl=native to silence.",
-                    stacklevel=2)
-                _warned_fallback = True
-            out = _native_flash_bhtd(qh, kh, vh, jnp.int32(0), causal,
-                                     sm_scale, 0.0)
+        # no fallback: a Mosaic refusal of the tuned kernel must surface,
+        # not silently cost the trainer its backward kernel
+        from jax.experimental.pallas.ops.tpu.flash_attention import (
+            flash_attention as jax_flash)
+        out = jax_flash(qh, kh, vh, causal=causal, sm_scale=sm_scale,
+                        block_sizes=_tuned_block_sizes(
+                            qh.shape[2], kh.shape[2]))
     return jnp.moveaxis(out, 1, 2)
-
-
-_warned_fallback = False
 
 
 def _tuned_block_sizes(t_q, t_k):

@@ -74,13 +74,17 @@ def serving_shard_devices(mp: int):
       2. ``jax.devices()`` when there are at least ``mp`` of them
          (e.g. ``XLA_FLAGS=--xla_force_host_platform_device_count=N``
          CPU meshes with no mesh installed yet);
-      3. otherwise the available devices CYCLED — LOGICAL shards:
-         several shards share one physical device. Numerics and the
-         collective schedule are identical to a real mesh (the
-         per-shard executables don't know their neighbors), only the
-         placement is degenerate — this is how the tier-1 in-process
-         bit-identity tests run mp=2 on a single-device CI host.
+      3. otherwise, OFF the TPU only, the available devices CYCLED —
+         LOGICAL shards: several shards share one physical device.
+         Numerics and the collective schedule are identical to a real
+         mesh (the per-shard executables don't know their neighbors),
+         only the placement is degenerate — this is how the tier-1
+         in-process bit-identity tests run mp=2 on a single-device CI
+         host. On a TPU, asking for more shards than chips is an
+         error: logical shards there would quietly serve the
+         host-staged loop while the caller believes the pool is spread.
     """
+    from ..framework.device import on_tpu
     mp = int(mp)
     if mp < 1:
         raise ValueError(f"mp must be >= 1, got {mp}")
@@ -91,6 +95,10 @@ def serving_shard_devices(mp: int):
         # and take the first row's leading mp devices
         arr = np.asarray(m.devices).reshape(-1, m.shape["mp"])
         return [arr[0, i] for i in range(mp)]
+    if mp > len(devs) and on_tpu():
+        raise ValueError(
+            f"mp={mp} serving shards need {mp} TPU devices, only "
+            f"{len(devs)} present")
     return [devs[i % len(devs)] for i in range(mp)]
 
 
@@ -132,23 +140,15 @@ def _current_mesh():
     """The mesh to annotate against: inside a shard_map/use_mesh trace this
     is the context's AbstractMesh (whose axis_types mark manual axes);
     otherwise the concrete global mesh."""
-    try:
-        from jax._src import mesh as _jm
-        am = _jm.get_abstract_mesh()
-        if am is not None and am.axis_names:
-            return am
-    except Exception:
-        pass
+    am = jax.sharding.get_abstract_mesh()
+    if am.axis_names:
+        return am
     return get_mesh()
 
 
 def _manual_axes(m):
-    try:
-        from jax.sharding import AxisType
-        return {n for n, t in zip(m.axis_names, m.axis_types)
-                if t == AxisType.Manual}
-    except Exception:
-        return set()
+    return {n for n, t in zip(m.axis_names, m.axis_types)
+            if t == jax.sharding.AxisType.Manual}
 
 
 def constraint(x, *spec):
@@ -184,24 +184,9 @@ def constraint(x, *spec):
         return x
 
 
-def current_axis_names():
-    """Axis names bound inside the current shard_map/xmap trace, if any."""
-    try:
-        from jax._src.core import get_axis_env  # jax>=0.5 internal
-        return set(get_axis_env().axis_sizes.keys())
-    except Exception:
-        try:
-            import jax.core as jc
-            frame = jc.thread_local_state.trace_state.axis_env  # older jax
-            return {f.name for f in frame}
-        except Exception:
-            return set()
-
-
 def inside_spmd_region(axis: str) -> bool:
     try:
-        import jax
-        jax.lax.axis_index(axis)  # raises if axis not bound
+        jax.lax.axis_index(axis)
         return True
-    except Exception:
+    except NameError:    # "unbound axis name": not under a shard_map on it
         return False

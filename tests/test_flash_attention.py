@@ -87,11 +87,16 @@ class TestFlashDropout:
         return fa, mk(), mk(), mk()
 
     def test_rate_zero_matches_reference(self):
+        from paddle_tpu.flags import set_flags
         fa, q, k, v = self._qkv()
         fa._FORCE_INTERPRET = True
+        # the native kernel carries dropout; at rate 0 the default impl is
+        # jax's tuned kernel, which only Mosaic can compile
+        set_flags({"FLAGS_tpu_flash_impl": "native"})
         try:
             out = fa.flash_attention_blhd(q, k, v, dropout_rate=0.0)
         finally:
+            set_flags({"FLAGS_tpu_flash_impl": "jax"})
             fa._FORCE_INTERPRET = False
         ref = jnp.moveaxis(fa._mha_jnp(
             jnp.moveaxis(q, 1, 2), jnp.moveaxis(k, 1, 2),

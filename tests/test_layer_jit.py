@@ -233,3 +233,32 @@ def test_data_dependent_control_flow_falls_back():
     assert out.shape == [2, 4]
     entry = layer_jit._cache.get(net)
     assert any(v is layer_jit._UNSAFE for v in entry["execs"].values())
+
+
+def test_capture_and_op_cache_decline_inside_a_trace():
+    """Inside someone's trace neither the layer capture nor the eager
+    op-executable cache may engage — the guard was dead code while it
+    asked jax for a function that no longer exists. The inputs are
+    CONCRETE on purpose: only the trace state can tell."""
+    import jax
+    from paddle_tpu.framework import op
+    lin = nn.Linear(4, 4)
+    x = paddle.to_tensor(np.ones((2, 4), np.float32))
+
+    def impl(a):
+        return a + 1
+
+    assert op._trace_clean()
+    assert layer_jit.try_call(lin, (x,), {})[0]
+    assert op._op_exec_for(impl, {}, (x.data,), False) is not None
+    seen = {}
+
+    @jax.jit
+    def traced(a):
+        seen["clean"] = op._trace_clean()
+        seen["captured"] = layer_jit.try_call(lin, (x,), {})[0]
+        seen["exec"] = op._op_exec_for(impl, {}, (x.data,), False)
+        return a
+
+    traced(1.0)
+    assert seen == {"clean": False, "captured": False, "exec": None}

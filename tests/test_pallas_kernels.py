@@ -496,6 +496,36 @@ class TestPagedAttentionRagged:
                         jnp.float32)
         return q, pool, bt, q_lens, kv_lens
 
+    def test_scalar_prefetch_kernels_interpreted(self, monkeypatch):
+        """The kernels the CHIP runs — ``_kernel_ragged_prefetch`` and
+        its int8-page twin, block table and tile maps riding as scalar
+        prefetch — interpreted on CPU. The default CPU branch
+        pre-gathers pages and runs a different ``pallas_call`` with
+        different BlockSpecs, so without this the chip's kernel bodies
+        and index maps execute nowhere in tier-1."""
+        import importlib
+        from paddle_tpu.inference.paged_cache import _quant_rows
+        pa = importlib.import_module(
+            "paddle_tpu.ops.pallas.paged_attention")
+        real = pa.pl.pallas_call
+        monkeypatch.setattr(pa, "on_tpu", lambda: True)
+        monkeypatch.setattr(
+            pa.pl, "pallas_call",
+            lambda *a, **kw: real(*a, interpret=True, **kw))
+        q, pool, bt, q_lens, kv_lens = self._mixed()
+        out = pa.paged_attention_ragged(q, pool, bt, q_lens, kv_lens)
+        ref = paged_attention_ragged_reference(q, pool, bt, q_lens,
+                                               kv_lens)
+        np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                                   atol=2e-5)
+        pool_q, scales = _quant_rows(pool)
+        out = pa.paged_attention_ragged(q, pool_q, bt, q_lens, kv_lens,
+                                        kv_scales=scales)
+        ref = paged_attention_ragged_reference(
+            q, pool_q, bt, q_lens, kv_lens, kv_scales=scales)
+        np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                                   atol=2e-5)
+
     def test_mixed_matches_shared_reference(self):
         q, pool, bt, q_lens, kv_lens = self._mixed()
         for tq in (None, 4):
@@ -808,7 +838,10 @@ class TestW8A16Matmul:
     def test_matches_float_matmul(self):
         from paddle_tpu.ops.pallas.int8_matmul import w8a16_matmul
         r = np.random.default_rng(0)
-        for M, K, N in [(1, 256, 128), (8, 512, 256), (5, 384, 128)]:
+        # the last shape has no lane-aligned divisor of N (a 50257-token
+        # vocabulary in small): the final column block is partial
+        for M, K, N in [(1, 256, 128), (8, 512, 256), (5, 384, 128),
+                        (8, 512, 1000)]:
             x = jnp.asarray(r.standard_normal((M, K)), jnp.bfloat16)
             w = jnp.asarray(r.integers(-127, 128, (K, N)), jnp.int8)
             out = w8a16_matmul(x, w)

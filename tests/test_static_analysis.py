@@ -319,16 +319,16 @@ class TestRealTree:
                     floor = 2 if c.name == "_GroupTable" else 5
                     assert len(keys) >= floor, (c.name, sorted(keys))
         # the compiled-step purity pass really engages the compiled
-        # runner and the serving hand-off: the real tree's two
-        # legitimate host hops (legacy _allreduce device_put +
-        # _uncommitted's fallback pull) surface as SUPPRESSED
-        # findings, never silently out of scope
+        # runner and the serving hand-off: the real tree's one
+        # legitimate host hop (the legacy _allreduce device_put)
+        # surfaces as a SUPPRESSED finding, never silently out of
+        # scope
         kept, supp, problems, _ = cs.run_passes(
             INF, ["compiled-step-purity"])
         assert not problems and kept == []
         assert {os.path.basename(f.path) for f in supp} == \
             {"serving.py"}
-        assert len(supp) == 2
+        assert len(supp) == 1
         assert any("compiled_step.py" == sf.base for sf in files)
 
     def test_allowlist_entries_all_load_bearing(self):
