@@ -58,6 +58,7 @@ from typing import Dict, List, Optional
 
 import numpy as np
 
+from . import telemetry
 from .resilience import RequestOutcome  # noqa: F401  (re-export surface)
 from .speculative import SpeculativeEngine
 
@@ -433,6 +434,10 @@ class RecoverableServer:
         self._snap_seq = 0          # journal.seq at the last snapshot
         self._snap_step = 0         # engine step at the last snapshot
         self._closed = False
+        # the collector THIS server installed for a profile session
+        # (see _session_switch); None outside one and whenever the
+        # caller passed a collector of their own
+        self._session_col = None
         engine.registry.attach("journal", self._journal_gauges)
         engine.registry.attach("snapshot", self._snapshot_gauges)
         if _fresh:
@@ -452,6 +457,30 @@ class RecoverableServer:
 
     def _snapshot_gauges(self) -> dict:
         return {"age_steps": self._engine_step() - self._snap_step}
+
+    # -- telemetry: the profile session is the switch -----------------
+    def _session_switch(self):
+        """Top of every round and submit: while a ``jax.profiler``
+        trace is recording and the engine has no collector, install
+        one (``telemetry.open_session_collector``); at the first top
+        after the trace stopped, remove it again — it stays readable
+        through ``telemetry.last_session_collector()``. One flag read
+        and no clock read outside a session; a collector the caller
+        passed is never touched. A collector installed in mid-flight
+        meets requests it never saw submitted and ignores them
+        (``TraceCollector._req``). Returns the collector in force,
+        stamped with the coming round's number."""
+        eng = self.engine.engine
+        col = eng.collector
+        if telemetry.profile_recording():
+            if col is None:
+                col = eng.collector = self._session_col = \
+                    telemetry.open_session_collector()
+        elif col is not None and col is self._session_col:
+            col = eng.collector = self._session_col = None
+        if col is not None:
+            col.round_no = self.rounds + 1
+        return col
 
     # -- persistence --------------------------------------------------
     def _flush_drains(self) -> None:
@@ -497,16 +526,63 @@ class RecoverableServer:
                 "journal replay; use deadline_steps on a "
                 "RecoverableServer (bare engines still accept "
                 "deadline_s)")
-        self._flush_drains()
-        toks = [int(t) for t in np.asarray(token_ids).reshape(-1)]
-        self.journal.append("submit", {"tokens": toks,
-                                       "kw": dict(kw)})
-        return self.engine.submit(toks, **kw)
+        col = self._session_switch()
+        # traced: ``submit`` spans the whole call, ``submit.journal``
+        # the token list and the append; the engines add
+        # ``submit.embed`` / ``submit.hash`` / ``submit.admit``
+        depth = col.span_depth if col is not None else 0
+        if col is not None:
+            col.span_begin("submit")
+            col.span_begin("submit.journal")
+        try:
+            self._flush_drains()
+            toks = [int(t) for t in np.asarray(token_ids).reshape(-1)]
+            self.journal.append("submit", {"tokens": toks,
+                                           "kw": dict(kw)})
+            if col is not None:
+                col.span_end(tokens=len(toks))
+            rid = self.engine.submit(toks, **kw)
+        except BaseException:
+            if col is not None:
+                col.span_unwind(depth, aborted=True)
+            raise
+        if col is not None:
+            col.span_end(rid=rid)
+        return rid
 
     def step(self) -> Dict[int, List[int]]:
-        self._flush_drains()
+        col = self._session_switch()
+        if col is None:
+            return self._round(None)
+        # ``round`` is the parent of every span of the round; what no
+        # named child covers is its self time
+        depth = col.span_depth
+        col.span_begin("round")
+        try:
+            emitted = self._round(col)
+        except BaseException:
+            col.span_unwind(depth, aborted=True)
+            raise
+        if self._session_col is not None and \
+                not telemetry.profile_recording():
+            # the trace stopped inside this round: part of it is not
+            # in the profile, so the readers leave it out
+            col.span_end(partial=True)
+        else:
+            col.span_end()
+        return emitted
+
+    def _round(self, col) -> Dict[int, List[int]]:
+        if col is not None and self._pending_drain:
+            # the drain flush is a journal append like the round's own
+            col.span_begin("journal", record="outcomes")
+            try:
+                self._flush_drains()
+            finally:
+                col.span_end()
+        else:
+            self._flush_drains()
         inj = self.injector
-        col = self.engine.collector
         if inj is not None:
             inj.begin_round()           # live-round crash clock
         emitted = self.engine.step()

@@ -924,9 +924,33 @@ class PagedServingEngine:
         if n > 1:
             self.groups.create(req.rid, n)
             self.parallel_stats.groups += 1
+        col = self.collector
+        if self.prefix_cache:
+            # the prompt's chain hashes, memoized on the request: the
+            # admission pass (now or rounds later) probes the prefix
+            # index with them. Computed here so that their cost — a
+            # hash over every prompt row — is the submit's, and has a
+            # span of its own
+            if col is not None:
+                col.span_begin("submit.hash", rid=req.rid)
+            try:
+                req.block_hashes(self.cache.block_size)
+            finally:
+                if col is not None:
+                    col.span_end()
         self._bump_vtime(ten.tid)
-        self._enqueue(req)
-        self._try_admit()
+        depth = col.span_depth if col is not None else 0
+        if col is not None:
+            col.span_begin("submit.admit", rid=req.rid)
+        try:
+            self._enqueue(req)
+            self._try_admit()
+        except BaseException:
+            if col is not None:
+                col.span_unwind(depth, aborted=True)
+            raise
+        if col is not None:
+            col.span_unwind(depth)
         return req.rid
 
     def _admission_health(self, req: PagedRequest,
@@ -1299,9 +1323,7 @@ class PagedServingEngine:
             T = len(req)
             c = _chunk_len(T, st["pos"], self.chunk_tokens,
                            budget=budget)
-            if not self._grow_or_shed(slot, req, st["pos"] + c,
-                                      start_block=st["n_cached"],
-                                      write_from=st["pos"]):
+            if not self._grow_chunk(slot, req, st, c):
                 continue  # the slot was evicted (or shed) growing
             pos, h = chunked_prefill(
                 self.model, self.cache, slot, req.history,
@@ -1320,6 +1342,23 @@ class PagedServingEngine:
         if ran:
             self.prefill_stats.prefill_steps += 1
         return ran, fresh
+
+    def _grow_chunk(self, slot: int, req: PagedRequest, st: dict,
+                    c: int) -> bool:
+        """Cover the next ``c`` prompt rows of a streaming prefill
+        (``_grow_or_shed`` under a ``grow`` span: the chunk's page
+        growth is the paged-cache manager's time, like the decode
+        rows')."""
+        col = self.collector
+        if col is not None:
+            col.span_begin("grow", rid=req.rid)
+        try:
+            return self._grow_or_shed(slot, req, st["pos"] + c,
+                                      start_block=st["n_cached"],
+                                      write_from=st["pos"])
+        finally:
+            if col is not None:
+                col.span_end()
 
     def _plan_prefills(self) -> Tuple[bool, List[int]]:
         """RAGGED token-budget mode: spend the prefill budget exactly
@@ -1356,9 +1395,7 @@ class PagedServingEngine:
             T = len(req)
             c = _chunk_len(T, st["pos"], self.chunk_tokens,
                            budget=budget)
-            if not self._grow_or_shed(slot, req, st["pos"] + c,
-                                      start_block=st["n_cached"],
-                                      write_from=st["pos"]):
+            if not self._grow_chunk(slot, req, st, c):
                 continue  # the slot was evicted (or shed) growing
             seg = plan[-1] if plan and plan[-1]["slot"] == slot \
                 else None
@@ -1424,8 +1461,17 @@ class PagedServingEngine:
              s["ws"]) for s in segs]
         if x is not None:
             desc.append(("decode", self.lens.copy(), L))
-        views = self.cache.ragged_views(desc, tile_q=self.tile_q,
-                                        tile_kv=self.tile_kv)
+        col = self.collector
+        if col is not None:
+            # the step's block tables and tile layout, built on the
+            # host and uploaded: paged-cache manager time too
+            col.span_begin("grow", what="layout")
+        try:
+            views = self.cache.ragged_views(desc, tile_q=self.tile_q,
+                                            tile_kv=self.tile_kv)
+        finally:
+            if col is not None:
+                col.span_end()
         import jax.numpy as jnp
         parts = [jnp.asarray(np.ascontiguousarray(
             s["req"].history[s["from"]:s["to"]], np.float32))
@@ -1978,10 +2024,16 @@ class PagedServingEngine:
         #    Oldest first: under pressure the young yield to the old.
         order = sorted(np.flatnonzero(stepping),
                        key=lambda s: self._requests[s].admit_seq)
-        for slot in order:
-            slot = int(slot)
-            self._grow_or_shed(slot, self._requests[slot],
-                               int(self.lens[slot]) + 1)
+        if col is not None:
+            col.span_begin("grow")
+        try:
+            for slot in order:
+                slot = int(slot)
+                self._grow_or_shed(slot, self._requests[slot],
+                                   int(self.lens[slot]) + 1)
+        finally:
+            if col is not None:
+                col.span_end()
         stepping &= self.active     # growth may have evicted some
         if not stepping.any():
             if plan:
@@ -2150,11 +2202,17 @@ class PagedServingEngine:
         # grow pages to cover the whole write range, oldest first
         order = sorted(np.flatnonzero(stepping),
                        key=lambda s: self._requests[s].admit_seq)
-        for slot in order:
-            slot = int(slot)
-            self._grow_or_shed(slot, self._requests[slot],
-                               int(self.lens[slot]) + L,
-                               write_from=int(self.lens[slot]))
+        if col is not None:
+            col.span_begin("grow")
+        try:
+            for slot in order:
+                slot = int(slot)
+                self._grow_or_shed(slot, self._requests[slot],
+                                   int(self.lens[slot]) + L,
+                                   write_from=int(self.lens[slot]))
+        finally:
+            if col is not None:
+                col.span_end()
         stepping &= self.active     # growth may have evicted some
         if not stepping.any():
             if plan:

@@ -232,7 +232,7 @@ class TokenServingModel:
                temperature: float = 1.0, top_k: Optional[int] = None,
                rng: Optional[np.random.RandomState] = None,
                rng_rows: Optional[list] = None,
-               logit_mask=None
+               logit_mask=None, collector=None
                ) -> Tuple[np.ndarray, Optional[np.ndarray]]:
         """logits [..., vocab] Tensor -> (token ids int64 [...], probs
         float32 [..., vocab] or None). Greedy is a pure on-device
@@ -255,19 +255,33 @@ class TokenServingModel:
         stochastic distribution renormalizes over the language — and
         the rejection-sampling residual max(p - q, 0) stays
         in-language because BOTH p and q were masked. None skips the
-        add entirely (bit-identical to before)."""
+        add entirely (bit-identical to before).
+
+        ``collector`` (TraceCollector): the one blocking
+        device-to-host read below is recorded as ``device_wait`` — the
+        host waiting for the device, apart from the host work around
+        it."""
         import paddle_tpu as paddle
         if logit_mask is not None:
             neg = np.where(np.asarray(logit_mask, bool), 0.0,
                            -1e30).astype(np.float32)
             logits = logits + paddle.to_tensor(neg)
         if mode == "greedy":
-            toks = np.asarray(paddle.argmax(logits, axis=-1).numpy())
-            return toks.astype(np.int64), None
-        if mode not in ("sample", "top_k", "temperature"):
+            out = paddle.argmax(logits, axis=-1)
+        elif mode in ("sample", "top_k", "temperature"):
+            out = self.probs(logits, temperature, top_k)
+        else:
             raise ValueError(f"unknown sampling mode {mode!r}")
-        p = np.asarray(self.probs(logits, temperature, top_k).numpy(),
-                       np.float32)
+        if collector is not None:
+            collector.span_begin("device_wait")
+        try:
+            host = np.asarray(out.numpy())
+        finally:
+            if collector is not None:
+                collector.span_end()
+        if mode == "greedy":
+            return host.astype(np.int64), None
+        p = host.astype(np.float32, copy=False)
         if rng is None:
             rng = np.random
         flat = p.reshape(-1, p.shape[-1]).astype(np.float64)
@@ -554,7 +568,15 @@ class SpeculativeEngine:
         if logit_mask is not None:
             logit_mask_fn(logit_mask)   # fail unknown names loudly now
         prefix = toks[:-1] if resume else toks
-        rid = self.engine.submit(self.target.embed(prefix),
+        col = self.engine.collector
+        if col is not None:
+            col.span_begin("submit.embed")
+        try:
+            rows = self.target.embed(prefix)
+        finally:
+            if col is not None:
+                col.span_end(tokens=len(prefix))
+        rid = self.engine.submit(rows,
                                  max_preemptions=max_preemptions,
                                  deadline_steps=deadline_steps,
                                  deadline_s=deadline_s,
@@ -734,7 +756,8 @@ class SpeculativeEngine:
         return model.sample(logits, mode=self.sampling,
                             temperature=self.temperature,
                             top_k=self.top_k, rng=self._rng,
-                            rng_rows=rng_rows, logit_mask=logit_mask)
+                            rng_rows=rng_rows, logit_mask=logit_mask,
+                            collector=self.engine.collector)
 
     def _lane_rows(self, slots, L: int) -> Optional[list]:
         """Per-flat-row RNG lanes for a [max_batch, L]-row sample: row
@@ -1228,12 +1251,19 @@ class SpeculativeEngine:
         if self.injector is not None:
             self.injector.crash_point("mid_spec_round")
         d_t = self.target.d_model
-        x = np.zeros((B, L, d_t), np.float32)
         pre_lens = {s: int(eng.lens[s]) for s in slots}
+        # the [B, L, d_model] input: gathered on the host, handed to
+        # the device
+        if col is not None:
+            col.span_begin("embed")
+        x = np.zeros((B, L, d_t), np.float32)
         for s in slots:
             x[s] = self.target.embed([self._seqs[s].toks[-1]]
                                      + drafts[s])
-        out = eng.step_multi(paddle.to_tensor(x))
+        x = paddle.to_tensor(x)
+        if col is not None:
+            col.span_end()
+        out = eng.step_multi(x)
         if out is None:
             # every slot fell out mid-step (deadline/shed storm): the
             # outcomes carry the verdicts; nothing was scored

@@ -35,9 +35,21 @@ that subsystem for the paged serving stack:
     - per-STEP timeline: ``begin_step``/``phase``/``end_step`` bracket
       each engine step's phases (admission, prefill, model,
       bookkeeping), ``span_begin``/``span_end`` nest free-form spans
-      around them (spec rounds, journal appends, snapshots), and
-      ``end_step`` samples gauges (pool tiers, queue depth, per-tenant
-      charge) from engine ground truth;
+      around and inside them (the server's ``round`` and ``submit``,
+      spec rounds, ``embed``, ``grow``, ``device_wait``, journal
+      appends, snapshots), and ``end_step`` samples gauges (pool
+      tiers, queue depth, per-tenant charge) from engine ground
+      truth. Every span records its round number and the name of the
+      span that encloses it (``args["round"]`` / ``args["parent"]``),
+      so self time — duration less children — can be computed; the
+      timeline is a ring of the newest ``max_events``;
+    - the PROFILER'S CLOCK: every span and phase is also a
+      ``jax.profiler.TraceAnnotation`` named ``pt.<name>`` for its
+      lifetime, so a profile taken while the collector is installed
+      carries the program's spans on the ``/host:CPU`` plane of the
+      same ``.xplane.pb`` as the device planes
+      (``paddle_tpu.profiler.idle_gaps_by_span`` books the device's
+      idle gaps on them);
     - export: ``chrome_trace()`` emits the ``trace_events`` JSON
       format (loadable in Perfetto / chrome://tracing) with the
       request records and summaries riding ``metadata``;
@@ -49,6 +61,16 @@ that subsystem for the paged serving stack:
       engines perform no clock reads and no telemetry allocations —
       every hook site is behind ``if self.collector is not None``,
       the same pattern as ``FaultInjector``.
+    - ONE SWITCH, THE PROFILE SESSION: a ``RecoverableServer`` built
+      without a collector reads one flag (``profile_recording()``) at
+      the top of each round and each submit; while a
+      ``jax.profiler`` trace is recording it installs a collector of
+      its own (``open_session_collector``) and removes it at the
+      first round top after the trace stopped. The removed collector
+      stays reachable through ``last_session_collector()`` until the
+      next session replaces it. Outside a session such a server
+      allocates no telemetry object and reads no clock; a collector
+      the caller passed is never removed.
     - PASSIVE: the collector only ever observes; token streams and
       terminal outcomes are bit-identical with tracing on vs off
       across plain / prefix-cached / speculative / recoverable
@@ -72,14 +94,19 @@ zero-overhead contract.
 """
 from __future__ import annotations
 
+import collections
 import json
 import time
+import weakref
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
+import jax.monitoring
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 __all__ = ["StatsBase", "MetricsRegistry", "NetStats",
-           "TraceCollector", "percentiles"]
+           "TraceCollector", "percentiles", "profile_recording",
+           "open_session_collector", "last_session_collector"]
 
 
 # ---------------------------------------------------------------------
@@ -409,38 +436,72 @@ class TraceCollector:
         self._t0 = self._clock()
         self.max_events = int(max_events)
         self.max_requests = int(max_requests)
-        self.dropped = 0
+        self.dropped = 0                   # events the ring overwrote
         self.evicted_requests = 0
-        self.events: List[dict] = []       # timeline (chrome-ish dicts,
-                                           # ts in relative seconds)
+        # timeline (chrome-ish dicts, ts in relative seconds): a ring
+        # of the NEWEST max_events — a collector switched on and off on
+        # a live server must end a session holding its last rounds
+        self.events: collections.deque = collections.deque(
+            maxlen=self.max_events)
         self.requests: Dict[int, _ReqTrace] = {}
         self.registry = MetricsRegistry()
         self.steps = 0
         self.replayed_steps = 0
+        # the server round (RecoverableServer sets it at the top of
+        # each round and submit); None on a bare engine
+        self.round_no: Optional[int] = None
         self._replay = False
-        self._step: Optional[tuple] = None     # (t, step_id, kind)
-        self._phase: Optional[tuple] = None    # (t, name)
-        self._spans: List[tuple] = []          # (t, name, args)
+        # open step: (t, step_id, kind, span depth at open, parent,
+        # annotation); open phase: (t, name, annotation); open spans:
+        # (t, name, args, parent, annotation)
+        self._step: Optional[tuple] = None
+        self._phase: Optional[tuple] = None
+        self._spans: List[tuple] = []
+        _watch_compiles(self)
 
     def now(self) -> float:
         return self._clock() - self._t0
 
     # -- low-level emit -----------------------------------------------
     def _emit(self, ev: dict) -> None:
-        if len(self.events) >= self.max_events:
-            self.dropped += 1
-            return
         if self._replay and ev.get("ph") != "C":
             # counter events' args IS the {series: value} map — a
             # replay flag there would chart as a bogus series
             ev.setdefault("args", {})["replay"] = True
+        if len(self.events) == self.max_events:
+            self.dropped += 1              # the ring drops its oldest
         self.events.append(ev)
 
+    def _annotate(self, name: str, args: Optional[dict] = None):
+        """Open ``pt.<name>`` on the profiler's clock, with the round
+        number and (spans about one request) the rid as metadata."""
+        meta = {}
+        if self.round_no is not None:
+            meta["round"] = self.round_no
+        if args and "rid" in args:
+            meta["rid"] = args["rid"]
+        ann = TraceAnnotation("pt." + name, **meta)
+        ann.__enter__()
+        return ann
+
+    def _innermost(self) -> Optional[str]:
+        """Name of the innermost open span or phase (the parent of
+        whatever opens next)."""
+        if self._step is not None and len(self._spans) <= self._step[3]:
+            return self._phase[1] if self._phase else self._step[2]
+        return self._spans[-1][1] if self._spans else None
+
     def _span_event(self, name: str, t0: float, t1: float,
-                    args: Optional[dict] = None) -> None:
+                    args: Optional[dict] = None,
+                    parent: Optional[str] = None) -> None:
+        args = dict(args) if args else {}
+        if self.round_no is not None:
+            args["round"] = self.round_no
+        if parent is not None:
+            args["parent"] = parent
         ev = {"name": name, "ph": "X", "ts": t0, "dur": t1 - t0}
         if args:
-            ev["args"] = dict(args)
+            ev["args"] = args
         self._emit(ev)
         # every span duration also lands in a windowed registry
         # histogram (``span.<name>``): percentiles_since over these is
@@ -457,8 +518,10 @@ class TraceCollector:
         t = self.now()
         if self._step is not None:
             self._close_step(t, aborted=True)
-        self._step = (t, int(step), kind)
-        self._phase = (t, "bookkeeping")
+        parent = self._innermost()
+        self._step = (t, int(step), kind, len(self._spans), parent,
+                      self._annotate(kind))
+        self._phase = (t, "bookkeeping", self._annotate("bookkeeping"))
 
     def phase(self, name: str) -> None:
         """Close the current phase span, open the next. No-op outside
@@ -466,10 +529,17 @@ class TraceCollector:
         if self._step is None:
             return
         t = self.now()
-        if self._phase is not None:
-            self._span_event(self._phase[1], self._phase[0], t,
-                             {"step": self._step[1]})
-        self._phase = (t, name)
+        self._close_phase(t)
+        self._phase = (t, name, self._annotate(name))
+
+    def _close_phase(self, t: float) -> None:
+        if self._phase is None:
+            return
+        t0, name, ann = self._phase
+        ann.__exit__(None, None, None)
+        self._span_event(name, t0, t, {"step": self._step[1]},
+                         parent=self._step[2])
+        self._phase = None
 
     def end_step(self, gauges: Optional[dict] = None,
                  aborted: bool = False) -> None:
@@ -493,15 +563,13 @@ class TraceCollector:
                     self.registry.gauge(f"{track}.{k}", v)
 
     def _close_step(self, t: float, aborted: bool = False) -> None:
-        t0, step, kind = self._step
-        if self._phase is not None:
-            self._span_event(self._phase[1], self._phase[0], t,
-                             {"step": step})
-            self._phase = None
+        self._close_phase(t)
+        t0, step, kind, _, parent, ann = self._step
+        ann.__exit__(None, None, None)
         args = {"step": step}
         if aborted:
             args["aborted"] = True
-        self._span_event(kind, t0, t, args)
+        self._span_event(kind, t0, t, args, parent=parent)
         self._step = None
         if aborted:
             # a torn step is not a completed step: it either replays
@@ -514,21 +582,25 @@ class TraceCollector:
             self.steps += 1
             self.registry.count("steps.live")
 
-    # -- free-form spans (spec rounds, journal, snapshots) ------------
+    # -- free-form spans (rounds, submits, journal, snapshots, ...) ---
     @property
     def span_depth(self) -> int:
         return len(self._spans)
 
     def span_begin(self, name: str, **args) -> None:
-        self._spans.append((self.now(), name, args))
+        parent = self._innermost()
+        self._spans.append((self.now(), name, args, parent,
+                            self._annotate(name, args)))
 
     def span_end(self, **extra) -> None:
         if not self._spans:
             return
-        t0, name, args = self._spans.pop()
+        t0, name, args, parent, ann = self._spans.pop()
+        ann.__exit__(None, None, None)
         if extra:
             args = dict(args, **extra)
-        self._span_event(name, t0, self.now(), args or None)
+        self._span_event(name, t0, self.now(), args or None,
+                         parent=parent)
 
     def span_unwind(self, depth: int, aborted: bool = False) -> None:
         """Close every span above ``depth``. ``aborted=True`` is for
@@ -544,13 +616,24 @@ class TraceCollector:
 
     def on_event(self, name: str, args: Optional[dict] = None) -> None:
         """Instant event on the engine track (OOM/shed occupancy
-        dumps ride this)."""
+        dumps and ``compile`` ride this), stamped with the round it
+        fell in."""
         ev = {"name": name, "ph": "i", "ts": self.now(), "s": "t"}
-        if args:
-            ev["args"] = dict(args)
+        if args or self.round_no is not None:
+            ev["args"] = dict(args or {})
+            if self.round_no is not None:
+                ev["args"]["round"] = self.round_no
         self._emit(ev)
         if not self._replay:       # replayed instants are flagged in
             self.registry.count(f"events.{name}")   # the timeline only
+
+    def on_compile(self, seconds: float) -> None:
+        """A backend compile jax's monitoring reported (the one
+        process-wide listener below calls this): an instant event
+        ``compile`` on the round it fell in. Compiles that fall
+        outside every span of this collector are somebody else's."""
+        if self._spans or self._step is not None:
+            self.on_event("compile", {"seconds": float(seconds)})
 
     # -- request lifecycle --------------------------------------------
     def _rec_event(self, rec: _ReqTrace, ts: float, name: str,
@@ -820,3 +903,59 @@ def _json_default(o):
     if isinstance(o, np.ndarray):
         return o.tolist()
     raise TypeError(f"not JSON serializable: {type(o).__name__}")
+
+
+# ---------------------------------------------------------------------
+# compile events: one process-wide listener
+# ---------------------------------------------------------------------
+
+_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+_collectors: "weakref.WeakSet[TraceCollector]" = weakref.WeakSet()
+_listening = False
+
+
+def _on_jax_duration(event: str, seconds: float, **_kw) -> None:
+    if event == _COMPILE_EVENT:
+        for col in list(_collectors):
+            col.on_compile(seconds)
+
+
+def _watch_compiles(col: TraceCollector) -> None:
+    """jax's monitoring listeners cannot be removed, so there is ONE
+    for the process, registered with the first collector; it hands
+    each backend compile to the collectors that are alive."""
+    global _listening
+    if not _listening:
+        jax.monitoring.register_event_duration_secs_listener(
+            _on_jax_duration)
+        _listening = True
+    _collectors.add(col)
+
+
+# ---------------------------------------------------------------------
+# the profile session switch (RecoverableServer, inference/recovery.py)
+# ---------------------------------------------------------------------
+
+_session: Optional[TraceCollector] = None
+
+
+def profile_recording() -> bool:
+    """Whether a ``jax.profiler`` trace is recording now: one flag
+    read, no clock read."""
+    return TraceAnnotation.is_enabled()
+
+
+def open_session_collector() -> TraceCollector:
+    """A fresh collector for the profile session that is recording;
+    it replaces the last session's as ``last_session_collector()``."""
+    global _session
+    _session = TraceCollector()
+    return _session
+
+
+def last_session_collector() -> Optional[TraceCollector]:
+    """The collector a ``RecoverableServer`` installed for the running
+    or the last profile session (None before the first): what a
+    reader run after ``server.close()`` reads, and what an operator
+    dumps with ``save_chrome_trace``."""
+    return _session

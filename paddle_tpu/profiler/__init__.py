@@ -17,9 +17,11 @@ import threading
 import time
 from typing import Callable, Iterable, Optional
 
+from .idle_gaps import idle_gaps_by_span
+
 __all__ = ["Profiler", "ProfilerTarget", "ProfilerState", "RecordEvent",
            "make_scheduler", "export_chrome_tracing", "load_profiler_result",
-           "SortedKeys", "SummaryView"]
+           "SortedKeys", "SummaryView", "idle_gaps_by_span"]
 
 
 class ProfilerTarget(enum.Enum):

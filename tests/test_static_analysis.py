@@ -499,26 +499,20 @@ class TestMutations:
         assert "_begin_step" in kept[0].msg
 
     def test_deleted_span_bracket(self, tmp_path):
+        """The server's ``round`` span is closed on every path by the
+        unwinding except in ``step()`` (which is also what makes the
+        brackets inside ``_round`` safe): take it away and the
+        ``span_begin`` is flagged."""
         old = ("        try:\n"
-               "            self.journal.append(\"round\", {\n"
-               "                \"emitted\": {int(r): [int(t) "
-               "for t in toks]\n"
-               "                            for r, toks in "
-               "emitted.items()}})\n"
-               "        finally:\n"
-               "            if col is not None:\n"
-               "                col.span_end()")
-        new = ("        self.journal.append(\"round\", {\n"
-               "            \"emitted\": {int(r): [int(t) "
-               "for t in toks]\n"
-               "                        for r, toks in "
-               "emitted.items()}})\n"
-               "        if col is not None:\n"
-               "            col.span_end()")
+               "            emitted = self._round(col)\n"
+               "        except BaseException:\n"
+               "            col.span_unwind(depth, aborted=True)\n"
+               "            raise\n")
+        new = "        emitted = self._round(col)\n"
         root, path = _mutate(tmp_path, "recovery.py", old, new)
         kept, _ = run(root, ["span-safety"])
         assert [(f.path, f.line) for f in kept] == \
-            [(path, lineno(path, 'col.span_begin("journal")'))]
+            [(path, lineno(path, 'col.span_begin("round")'))]
 
     def test_host_pull_in_compiled_dispatch(self, tmp_path):
         """The compiled-collectives acceptance: a host pull sneaking

@@ -920,3 +920,393 @@ class TestChromeTraceAndReport:
         with open(p, "w") as f:
             json.dump({"traceEvents": []}, f)
         assert tile_report.main([p]) == 1
+
+
+# ---------------------------------------------------------------------
+# the profile session is the switch (RecoverableServer), spans on the
+# profiler's clock, the ring, and idle gaps by program span
+# ---------------------------------------------------------------------
+
+ROUND_CHILDREN = {"spec_round", "draft_roll", "embed", "verify", "grow",
+                  "prefill", "bookkeeping", "model", "admission",
+                  "sample_verify", "device_wait", "journal", "snapshot"}
+SUBMIT_CHILDREN = {"submit.journal", "submit.embed", "submit.hash",
+                   "submit.admit"}
+
+
+def _spec_server(tmp_path, name="s", **spec):
+    from paddle_tpu.inference.router import build_server_from_spec
+    base = dict(d_model=D, heads=HEADS, ffn=FFN, layers=LAYERS,
+                vocab=VOCAB, head_roll=1, max_batch=3, block_size=4,
+                num_blocks=80, max_blocks_per_seq=12,
+                prefill_token_budget=8, snapshot_every=4,
+                journal_path=str(tmp_path / f"{name}.wal"),
+                snapshot_path=str(tmp_path / f"{name}.snap"))
+    base.update(spec)
+    return build_server_from_spec(base)
+
+
+class _Profile:
+    """A jax.profiler trace without the Python tracer (as the
+    benchmark takes it)."""
+
+    def __init__(self, outdir):
+        self.outdir = str(outdir)
+
+    def __enter__(self):
+        import jax
+        opts = jax.profiler.ProfileOptions()
+        opts.python_tracer_level = 0
+        opts.host_tracer_level = 2
+        jax.profiler.start_trace(self.outdir, profiler_options=opts)
+        return self
+
+    def __exit__(self, *exc):
+        import jax
+        jax.profiler.stop_trace()
+        return False
+
+    def xplane(self):
+        import glob
+        found = glob.glob(os.path.join(self.outdir, "**", "*.xplane.pb"),
+                          recursive=True)
+        assert len(found) == 1
+        return found[0]
+
+
+def _serve_closed_loop(srv, prompts, n_gen, rounds, on_round=None):
+    """``len(prompts)`` clients; each submits its next prompt when its
+    last has ``n_gen`` tokens. Returns {rid: tokens} and the drained
+    (rid, status) pairs."""
+    pending = list(prompts)
+    live, streams, outcomes = [], {}, []
+    for i in range(rounds):
+        if on_round is not None:
+            on_round(i)
+        while pending and len(live) < 3:
+            live.append(srv.submit(pending.pop(0)))
+        srv.step()
+        for rid in list(live):
+            if len(srv.generated(rid)) >= n_gen:
+                streams[rid] = srv.generated(rid)[:n_gen]
+                srv.release(rid)
+                live.remove(rid)
+        outcomes += [(oc.rid, oc.status) for oc in srv.drain_outcomes()]
+    return streams, outcomes
+
+
+@pytest.fixture
+def fresh_session(monkeypatch):
+    """No last session on entry; whatever the test installs is gone
+    on exit."""
+    from paddle_tpu.inference import telemetry
+    monkeypatch.setattr(telemetry, "_session", None)
+    return telemetry
+
+
+class TestProfileSessionSwitch:
+    def test_no_session_no_collector_no_clock_reads(
+            self, tmp_path, counting_clock, fresh_session):
+        # (an engine snapshot reads the monotonic clock once, for the
+        # wall-clock deadlines it rebases: behavioral state, and here
+        # only snapshot 0 of the constructor)
+        srv = _spec_server(tmp_path, snapshot_every=0)
+        built = counting_clock.calls
+        streams, _ = _serve_closed_loop(srv, _prompts(5, n=5), 4, 14)
+        srv.close()
+        assert len(streams) == 5
+        assert counting_clock.calls == built <= 1
+        assert srv.engine.collector is None
+        assert srv._session_col is None
+        assert fresh_session.last_session_collector() is None
+
+    def test_session_records_the_round_and_the_submit(
+            self, tmp_path, fresh_session):
+        srv = _spec_server(tmp_path)
+        prof = _Profile(tmp_path / "prof")
+
+        def switch(i):
+            if i == 3:               # rounds 1-3 were dark
+                assert srv.engine.collector is None
+                prof.__enter__()
+        _serve_closed_loop(srv, _prompts(6, n=8, lo=9, hi=14), 4, 12,
+                           on_round=switch)
+        col = srv.engine.collector
+        assert col is fresh_session.last_session_collector()
+        prof.__exit__()
+        srv.step()                   # first round top after the stop
+        assert srv.engine.collector is None
+        assert fresh_session.last_session_collector() is col
+        srv.close()
+
+        spans = [ev for ev in col.events if ev["ph"] == "X"]
+        names = {ev["name"] for ev in spans}
+        assert {"round", "submit"} | ROUND_CHILDREN | SUBMIT_CHILDREN \
+            <= names
+        rounds = [ev for ev in spans if ev["name"] == "round"]
+        assert len(rounds) == 9 and col.steps == 9
+        assert [ev["args"]["round"] for ev in rounds] == \
+            list(range(srv.rounds - 9, srv.rounds))
+        # every span of a round names a parent that is open around it,
+        # and the spans with one parent tile it without overlap
+        eps = 1e-9
+        for top in rounds:
+            r, t0, t1 = top["args"]["round"], top["ts"], \
+                top["ts"] + top["dur"]
+            inside = [ev for ev in spans if ev["args"].get("round") == r
+                      and t0 - eps <= ev["ts"] and ev is not top
+                      and ev["ts"] + ev["dur"] <= t1 + eps]
+            assert {ev["name"] for ev in inside} >= \
+                {"spec_round", "embed", "verify", "model", "grow",
+                 "sample_verify", "device_wait", "journal"}
+            by_parent = {}
+            for ev in inside:
+                assert ev["name"] in ROUND_CHILDREN
+                by_parent.setdefault(ev["args"]["parent"], []).append(ev)
+            assert set(by_parent) <= ROUND_CHILDREN | {"round"}
+            for parent, kids in by_parent.items():
+                kids.sort(key=lambda ev: ev["ts"])
+                for a, b in zip(kids, kids[1:]):
+                    assert a["ts"] + a["dur"] <= b["ts"] + eps, \
+                        (parent, a["name"], b["name"])
+            direct = sum(ev["dur"] for ev in by_parent["round"])
+            assert 0 <= top["dur"] - direct <= top["dur"]
+        # the submit and its four children, in order, inside it
+        for top in (ev for ev in spans if ev["name"] == "submit"):
+            kids = [ev for ev in spans
+                    if ev["args"].get("parent") == "submit"
+                    and top["ts"] - eps <= ev["ts"]
+                    and ev["ts"] + ev["dur"]
+                    <= top["ts"] + top["dur"] + eps]
+            assert [ev["name"] for ev in kids] == \
+                ["submit.journal", "submit.embed", "submit.hash",
+                 "submit.admit"]
+            assert isinstance(top["args"]["rid"], int)
+        # requests submitted before the session are not synthesized
+        assert col.requests and min(col.requests) == 3
+        assert all(rec.queue_wait_s is not None and rec.queue_wait_s >= 0
+                   for rec in col.requests.values())
+
+        # ... and the same spans are in the profile, on the host plane
+        import jax
+        data = jax.profiler.ProfileData.from_file(prof.xplane())
+        host = next(p for p in data.planes if p.name == "/host:CPU")
+        pt = [(e.name, e.start_ns, e.start_ns + e.duration_ns,
+               dict(e.stats)) for line in host.lines for e in line.events
+              if e.name.startswith("pt.")]
+        tops = [e for e in pt if e[0] == "pt.round"]
+        assert len(tops) == 9
+        assert [e[3]["round"] for e in tops] == \
+            [ev["args"]["round"] for ev in rounds]
+        for name in ("pt.model", "pt.device_wait"):
+            kids = [e for e in pt if e[0] == name]
+            assert len(kids) >= 9
+            assert all(any(t[1] <= k[1] and k[2] <= t[2] for t in tops)
+                       for k in kids), name
+        # spans about one request carry its rid (the submit learns
+        # its rid when it ends: host-side only)
+        admits = [e for e in pt if e[0] == "pt.submit.admit"]
+        assert admits and sorted(e[3]["rid"] for e in admits) == \
+            sorted(col.requests)
+        assert any(e[0] == "pt.submit" for e in pt)
+
+    def test_streams_are_bit_identical_with_a_session_in_mid_flight(
+            self, tmp_path, fresh_session):
+        prompts = _prompts(8, n=7, lo=7, hi=13)
+        off = _spec_server(tmp_path, "off")
+        want = _serve_closed_loop(off, prompts, 5, 20)
+        off.close()
+
+        on = _spec_server(tmp_path, "on")
+        prof = _Profile(tmp_path / "prof")
+
+        def switch(i):
+            if i == 4:
+                prof.__enter__()       # requests 0-2 are in flight
+            if i == 13:
+                prof.__exit__()        # ... and so are later ones here
+        got = _serve_closed_loop(on, prompts, 5, 20, on_round=switch)
+        on.close()
+        assert got == want and len(want[0]) == 7
+        col = fresh_session.last_session_collector()
+        assert col is not None and on.engine.collector is None
+        assert col.steps == 9
+        # it met requests it never saw submitted, and ignored them
+        assert min(col.requests) >= 3
+        assert all(rec.tokens >= 0 for rec in col.requests.values())
+
+    def test_a_passed_collector_is_never_removed(self, tmp_path,
+                                                 fresh_session):
+        mine = TraceCollector()
+        eng = SpeculativeEngine(_tsm(), None, k=0, max_batch=2,
+                                block_size=4, num_blocks=60,
+                                max_blocks_per_seq=10, collector=mine)
+        srv = RecoverableServer(eng, journal_path=str(tmp_path / "j"),
+                                snapshot_path=str(tmp_path / "s"))
+        srv.submit(_prompts(9, n=1)[0])
+        srv.step()
+        with _Profile(tmp_path / "prof"):
+            srv.step()
+        srv.step()
+        srv.close()
+        assert srv.engine.collector is mine
+        assert fresh_session.last_session_collector() is None
+        rounds = [ev for ev in mine.events if ev["name"] == "round"]
+        assert [ev["args"]["round"] for ev in rounds] == [1, 2, 3]
+        assert not any(ev["args"].get("partial") for ev in rounds)
+
+    def test_a_round_the_trace_stopped_in_is_flagged(self, tmp_path,
+                                                     fresh_session,
+                                                     monkeypatch):
+        srv = _spec_server(tmp_path)
+        srv.submit(_prompts(10, n=1)[0])
+        flags = iter([True, True, True, False])   # submit, round 1 top
+        monkeypatch.setattr(fresh_session, "profile_recording",  # and
+                            lambda: next(flags, False))   # end, round 2
+        srv.step()
+        srv.step()
+        srv.step()
+        srv.close()
+        col = fresh_session.last_session_collector()
+        rounds = [ev for ev in col.events if ev["name"] == "round"]
+        assert [bool(ev["args"].get("partial")) for ev in rounds] == \
+            [False, True]
+        assert srv.engine.collector is None
+
+
+class TestRingAndCompileEvents:
+    def test_the_ring_keeps_the_newest_and_counts_the_overwritten(self):
+        col = TraceCollector(max_events=4)
+        for i in range(10):
+            col.span_begin(f"s{i}")
+            col.span_end()
+        assert [ev["name"] for ev in col.events] == \
+            ["s6", "s7", "s8", "s9"]
+        assert col.dropped == 6
+        assert col.as_dict()["timeline_events"] == 4
+        assert col.chrome_trace()["metadata"]["dropped_events"] == 6
+
+    def test_spans_record_parent_and_round(self):
+        col = TraceCollector()
+        col.round_no = 7
+        col.span_begin("round")
+        col.begin_step(3, "verify")
+        col.phase("prefill")
+        col.span_begin("grow", rid=5)
+        col.span_end()
+        col.phase("model")
+        col.end_step()
+        col.span_begin("journal")
+        col.span_end()
+        col.span_end()
+        got = {ev["name"]: ev["args"] for ev in col.events}
+        assert got["grow"] == {"rid": 5, "round": 7, "parent": "prefill"}
+        assert got["model"]["parent"] == "verify"
+        assert got["verify"]["parent"] == "round"
+        assert got["journal"]["parent"] == "round"
+        assert got["round"] == {"round": 7}
+        # a bare engine's collector has no round and no parent on top
+        bare = TraceCollector()
+        bare.span_begin("spec_round")
+        bare.span_end()
+        assert "args" not in bare.events[0]
+
+    def test_a_compile_is_an_instant_on_the_round_it_fell_in(self):
+        import jax
+        import jax.numpy as jnp
+        idle, busy = TraceCollector(), TraceCollector()
+        busy.round_no = 12
+        x = jnp.ones(5)
+        busy.span_begin("round")
+        jax.jit(lambda x: x * 3 + 1)(x).block_until_ready()
+        busy.span_end()
+        jax.jit(lambda x: x * 5 + 2)(x).block_until_ready()
+        got = [ev for ev in busy.events if ev["name"] == "compile"]
+        assert len(got) == 1 and got[0]["ph"] == "i"
+        assert got[0]["args"]["round"] == 12
+        assert got[0]["args"]["seconds"] > 0
+        assert not idle.events      # open nowhere: somebody else's
+
+
+class TestIdleGapsBySpan:
+    def test_a_gap_is_booked_on_the_innermost_span(self):
+        from paddle_tpu.profiler import idle_gaps as ig
+        spans = [("pt.round", 0, 100), ("pt.model", 10, 40),
+                 ("pt.grow", 20, 25), ("pt.device_wait", 60, 90),
+                 ("pt.round", 120, 200)]
+        segs = ig.innermost_segments(spans)
+        assert segs == [
+            ("pt.round", 0, 10), ("pt.model", 10, 20),
+            ("pt.grow", 20, 25), ("pt.model", 25, 40),
+            ("pt.round", 40, 60), ("pt.device_wait", 60, 90),
+            ("pt.round", 90, 100), ("pt.round", 120, 200)]
+        got = list(ig.book_gaps([(22, 30), (95, 130)], segs))
+        assert got == [("pt.grow", 22, 25), ("pt.model", 25, 30),
+                       ("pt.round", 95, 100), (ig.NO_SPAN, 100, 120),
+                       ("pt.round", 120, 130)]
+        assert ig.merge_intervals([(3, 10), (0, 5), (20, 30)]) == \
+            [[0, 10], [20, 30]]
+
+    def test_a_planted_clock_offset_is_recovered(self):
+        from paddle_tpu.profiler import idle_gaps as ig
+        rng = np.random.default_rng(0)
+        planted, busy, waits, t = 1_250_000.0, [], [], 0.0
+        for _ in range(40):
+            busy.append([t, t + 85e6])            # the round's programs
+            # the read returns 30-60 us after the last op, on a host
+            # clock that runs ``planted`` ns ahead of the device's
+            waits.append(t + 85e6 + planted + rng.uniform(30e3, 60e3))
+            # the next round's first upload, nearer to the read's end
+            # than the end that released it: not the pair
+            busy.append([t + 85e6 + planted + 200e3,
+                         t + 85e6 + planted + 250e3])
+            t += 96e6
+        waits.append(t + 500e6)                   # an empty round
+        off, n = ig.estimate_clock_offset(busy, waits)
+        assert n == 40
+        assert planted + 30e3 <= off <= planted + 60e3
+        assert ig.estimate_clock_offset(busy, []) == (0.0, 0)
+
+
+class TestTraceReportKnowsTheRound:
+    def test_round_self_time_is_printed_and_old_traces_load(
+            self, tmp_path, capsys):
+        from tools import trace_report
+        col = TraceCollector()
+        col.round_no = 1
+        col.span_begin("round")
+        col.span_begin("embed")
+        col.span_end()
+        col.begin_step(1, "verify")
+        col.phase("model")
+        col.end_step()
+        col.span_end()
+        path = str(tmp_path / "new.json")
+        col.save_chrome_trace(path)
+        assert trace_report.main([path]) == 0
+        out = capsys.readouterr().out
+        line = next(ln for ln in out.splitlines()
+                    if ln.strip().startswith("round: 1 x"))
+        assert "self" in line
+        rep = trace_report.machine_report(col.chrome_trace())
+        r = rep["spans"]["round"]
+        assert 0 <= r["self_s"] <= r["total_s"]
+        assert rep["spans"]["embed"]["self_s"] == \
+            rep["spans"]["embed"]["total_s"]
+        # a trace written before spans named their parents
+        old = {"traceEvents": [
+            {"name": "model", "ph": "X", "ts": 0.0, "dur": 5.0,
+             "args": {"step": 1}, "pid": 1, "tid": 0},
+            {"name": "step", "ph": "X", "ts": 0.0, "dur": 9.0,
+             "args": {"step": 1}, "pid": 1, "tid": 0}]}
+        path = str(tmp_path / "old.json")
+        with open(path, "w") as f:
+            json.dump(old, f)
+        assert trace_report.main([path]) == 0
+        out = capsys.readouterr().out
+        assert "model: 1 x" in out and ", self " not in out
+        # one input, not both and not neither
+        with pytest.raises(SystemExit):
+            trace_report.main([])
+        assert trace_report.main(
+            ["--xplane", str(tmp_path / "missing.xplane.pb")]) == 2

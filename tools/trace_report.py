@@ -10,6 +10,17 @@ doctor.
 Usage:
   python tools/trace_report.py TRACE.json [--tenant TID] [--requests]
                                           [--slo TARGETS.json]
+  python tools/trace_report.py --xplane FILE.xplane.pb [--outer bench.]
+                                          [--offset-us US]
+
+``--xplane`` reads a ``jax.profiler`` trace taken while a collector
+was installed (on a ``RecoverableServer`` that is: any profile) and
+prints the device's idle seconds by the innermost ``pt.*`` program
+span that overlaps each gap, after estimating the host-device clock
+offset from the trace itself and applying it
+(``paddle_tpu.profiler.idle_gaps_by_span``). ``--outer PREFIX`` splits
+the figures by a second family of host spans (a harness's own);
+``--offset-us`` overrides the estimate (0: the planes as stamped).
 
 ``--slo`` evaluates per-tenant SLO compliance against the trace's
 request records (the offline twin of the live ``SloTracker``) so CI
@@ -45,8 +56,16 @@ except ImportError:      # run as a script: tools/ is sys.path[0]
         os.path.abspath(__file__))))
     from tools._report import envelope, emit_json
 
-# span names that belong to one engine step (phases) vs wrappers
+# span names that belong to one engine step (phases), to one server
+# round around and inside the step, and to one submit; anything else
+# prints under "spans" (a trace written before these existed has none
+# of the last two families and no parents: it loads and prints as it
+# always did)
 _PHASES = ("admission", "prefill", "model", "bookkeeping")
+_ROUND = ("round", "spec_round", "draft_roll", "embed", "verify", "step",
+          "grow", "sample_verify", "device_wait", "journal", "snapshot")
+_SUBMIT = ("submit", "submit.journal", "submit.embed", "submit.hash",
+           "submit.admit")
 
 
 def _fmt_s(us: float) -> str:
@@ -105,11 +124,14 @@ def _rollup(evs):
     human renderer (``summarize``) and the machine one
     (``machine_report``) so the two can never drift: returns
     (spans {name: (total, count, max)}, counter-track names,
-    instant tallies, replay-flagged span count)."""
+    instant tallies, replay-flagged span count, children {name: total
+    duration of the spans that name it as their parent} — a span's
+    self time is its total less its children's)."""
     spans = {}
     counters = set()
     insts = {}
     replayed = 0
+    children = {}
     for ev in evs:
         ph = ev.get("ph")
         if ph == "X":
@@ -117,36 +139,44 @@ def _rollup(evs):
             tot, n, mx = spans.get(name, (0.0, 0, 0.0))
             d = float(ev.get("dur", 0))
             spans[name] = (tot + d, n + 1, max(mx, d))
-            if (ev.get("args") or {}).get("replay"):
+            args = ev.get("args") or {}
+            if args.get("replay"):
                 replayed += 1
+            if args.get("parent") is not None:
+                children[args["parent"]] = \
+                    children.get(args["parent"], 0.0) + d
         elif ph == "C":
             counters.add(ev["name"])
         elif ph == "i":
             insts[ev["name"]] = insts.get(ev["name"], 0) + 1
-    return spans, counters, insts, replayed
+    return spans, counters, insts, replayed, children
 
 
 def summarize(trace: dict, tenant: str = None,
               show_requests: bool = False) -> str:
     evs = trace["traceEvents"]
     lines = []
-    spans, counters, insts, replayed = _rollup(evs)
+    spans, counters, insts, replayed, children = _rollup(evs)
     lines.append(f"timeline: {len(evs)} event(s), "
                  f"{sum(n for _, n, _ in spans.values())} span(s)"
                  + (f" ({replayed} replay-flagged)" if replayed
                     else ""))
     order = sorted(spans, key=lambda n: -spans[n][0])
-    phase_names = [n for n in order if n in _PHASES]
-    other_names = [n for n in order if n not in _PHASES]
-    for title, names in (("step phases", phase_names),
-                         ("spans", other_names)):
+    known = _PHASES + _ROUND + _SUBMIT
+    for title, names in (
+            ("step phases", [n for n in order if n in _PHASES]),
+            ("round spans", [n for n in order if n in _ROUND]),
+            ("submit spans", [n for n in order if n in _SUBMIT]),
+            ("spans", [n for n in order if n not in known])):
         if not names:
             continue
         lines.append(f"  {title}:")
         for name in names:
             tot, n, mx = spans[name]
             lines.append(f"    {name}: {n} x, total {_fmt_s(tot)}, "
-                         f"mean {_fmt_s(tot / n)}, max {_fmt_s(mx)}")
+                         f"mean {_fmt_s(tot / n)}, max {_fmt_s(mx)}"
+                         + (f", self {_fmt_s(tot - children[name])}"
+                            if name in children else ""))
     if counters:
         lines.append(f"  gauge tracks: {sorted(counters)}")
     if insts:
@@ -205,13 +235,16 @@ def machine_report(trace: dict) -> dict:
     instant/counter tallies and the collector metadata summary — the
     same facts ``summarize`` renders (same ``_rollup`` pass), as
     data."""
-    spans, counters, insts, replayed = _rollup(trace["traceEvents"])
+    spans, counters, insts, replayed, children = \
+        _rollup(trace["traceEvents"])
     meta = trace.get("metadata")
     out = {
         "events": len(trace["traceEvents"]),
         "spans": {name: {"count": n,
                          "total_s": round(tot / 1e6, 6),
-                         "max_s": round(mx / 1e6, 6)}
+                         "max_s": round(mx / 1e6, 6),
+                         "self_s": round(
+                             (tot - children.get(name, 0.0)) / 1e6, 6)}
                   for name, (tot, n, mx) in sorted(spans.items())},
         "replayed_spans": replayed,
         "instants": dict(sorted(insts.items())),
@@ -278,10 +311,50 @@ def slo_check(trace: dict, targets: dict):
     return lines, ok
 
 
+def xplane_report(path: str, outer: str = None,
+                  offset_us: float = None) -> str:
+    """The ``--xplane`` rendering: device idle seconds by innermost
+    program span, with the clock offset that was applied."""
+    from paddle_tpu.profiler import idle_gaps_by_span
+    got = idle_gaps_by_span(
+        path, outer=outer,
+        offset_ns=None if offset_us is None else offset_us * 1e3)
+    lines = [
+        f"host clock less device clock: "
+        f"{got['estimated_offset_ns'] / 1e3:.1f} us estimated from "
+        f"{got['offset_pairs']} device_wait end(s); applied "
+        f"{got['offset_ns'] / 1e3:.1f} us",
+        f"window {got['window_s']:.6f} s on {got['devices']} device "
+        f"plane(s): busy {got['busy_s']:.6f} s, idle "
+        f"{got['idle_s']:.6f} s "
+        f"({100 * got['idle_s'] / got['window_s']:.2f} %)",
+        "idle seconds by innermost program span:"]
+
+    def table(d, indent):
+        for name, v in sorted(d.items(), key=lambda kv: -kv[1]):
+            lines.append(f"{indent}{name}: {v:.6f}")
+
+    table(got["gap_seconds"], "  ")
+    for oname, d in sorted(got.get("by_outer", {}).items(),
+                           key=lambda kv: -sum(kv[1].values())):
+        lines.append(f"inside {oname}: {sum(d.values()):.6f}")
+        table(d, "    ")
+    return "\n".join(lines)
+
+
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(
         description="summarize a serving Chrome-trace JSON offline")
-    ap.add_argument("trace")
+    ap.add_argument("trace", nargs="?")
+    ap.add_argument("--xplane", default=None, metavar="FILE.xplane.pb",
+                    help="a jax.profiler trace: device idle seconds "
+                         "by innermost pt.* program span")
+    ap.add_argument("--outer", default=None, metavar="PREFIX",
+                    help="with --xplane: also split by the host spans "
+                         "of this prefix (e.g. bench.)")
+    ap.add_argument("--offset-us", type=float, default=None,
+                    help="with --xplane: host-less-device clock offset "
+                         "to apply instead of the estimated one")
     ap.add_argument("--tenant", default=None,
                     help="show only this tenant's latency section")
     ap.add_argument("--requests", action="store_true",
@@ -294,6 +367,18 @@ def main(argv=None) -> int:
                          "(paddle_tpu.report.v1, shared with "
                          "health_report/cost_report)")
     args = ap.parse_args(argv)
+    if (args.trace is None) == (args.xplane is None):
+        ap.error("give TRACE.json or --xplane FILE, one of the two")
+    if args.xplane is not None:
+        if not os.path.isfile(args.xplane):
+            print(f"UNREADABLE: no file {args.xplane!r}")
+            return 2
+        try:
+            print(xplane_report(args.xplane, args.outer, args.offset_us))
+        except (ValueError, RuntimeError) as e:   # no planes, not a trace
+            print(f"UNREADABLE: {e}")
+            return 2
+        return 0
 
     try:
         with open(args.trace) as f:
