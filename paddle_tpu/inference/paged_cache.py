@@ -810,7 +810,7 @@ class _RaggedLayout:
 
     __slots__ = ("segs", "q_lens", "blk", "off", "kv_lens", "bt_all",
                  "tile_q", "tile_kv", "total_rows", "blk_np",
-                 "off_np")
+                 "off_np", "_cache")
 
     def __init__(self, cache: "PagedKVCache", segments, tile_q=None,
                  tile_kv=None):
@@ -895,6 +895,23 @@ class _RaggedLayout:
         self.bt_all = Tensor(jnp.asarray(np.stack(bt_rows), jnp.int32))
         self.tile_q = tile_q
         self.tile_kv = tile_kv
+        self._cache = cache
+
+    def launch_plan(self):
+        """The paged-attention launch this layout makes on the chip, a
+        layer and a shard (``launch_plan`` of the kernel module, at one
+        query head a kv head as the serving cores have): host
+        arithmetic over shapes, for the collector's ``paged_attn``
+        gauge."""
+        from ..ops.pallas.paged_attention import (launch_plan,
+                                                  resolve_tile_q)
+        c = self._cache
+        tile_q = resolve_tile_q(self.q_lens, self.tile_q)
+        return launch_plan(
+            sum(-(-ql // tile_q) for ql in self.q_lens),
+            c.heads_per_shard, tile_q, c.max_blocks_per_seq,
+            c.block_size, c.head_dim, c.pools[0].data.dtype.itemsize,
+            quantized=c.quantized, tile_kv=self.tile_kv)
 
 
 class PagedRaggedView:

@@ -1469,6 +1469,13 @@ class PagedServingEngine:
         try:
             views = self.cache.ragged_views(desc, tile_q=self.tile_q,
                                             tile_kv=self.tile_kv)
+            if col is not None:
+                # what each layer's launch will cost the kernel's grid
+                plan = views[0]._layout.launch_plan()
+                col.gauge("paged_attn", {
+                    "grid_steps": plan.grid_steps,
+                    "pages_per_step": plan.pages,
+                    "heads_per_step": plan.heads})
         finally:
             if col is not None:
                 col.span_end()

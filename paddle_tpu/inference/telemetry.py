@@ -555,12 +555,18 @@ class TraceCollector:
         self._close_step(t, aborted=aborted)
         if aborted:
             return
-        if gauges:
-            for track, series in gauges.items():
-                self._emit({"name": track, "ph": "C", "ts": t,
-                            "args": dict(series)})
-                for k, v in series.items():
-                    self.registry.gauge(f"{track}.{k}", v)
+        for track, series in (gauges or {}).items():
+            self.gauge(track, series, ts=t)
+
+    def gauge(self, track: str, series: dict,
+              ts: Optional[float] = None) -> None:
+        """One sample of a gauge track ({series: value}): a Chrome
+        counter event, mirrored into the registry."""
+        self._emit({"name": track, "ph": "C",
+                    "ts": self.now() if ts is None else ts,
+                    "args": dict(series)})
+        for k, v in series.items():
+            self.registry.gauge(f"{track}.{k}", v)
 
     def _close_step(self, t: float, aborted: bool = False) -> None:
         self._close_phase(t)

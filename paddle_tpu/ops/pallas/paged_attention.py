@@ -29,32 +29,47 @@ instead of one per phase per slot (inference/paged_cache.py
 ``ragged_views`` builds the batch; inference/scheduler.py launches it).
 
 Grid layout: each sequence's queries are cut into tiles of ``tile_q``
-rows; the grid is (total_tiles * nkv_heads, kv_steps) and a page whose
-first position lies past a tile's LAST query is skipped outright (the
-causal frontier — prefill work is O(tokens written), not O(page
-capacity); a decode tile skips everything past its one position).
-On real TPU the block table, the tile->sequence map and the per-tile
-base positions ride as SCALAR-PREFETCH arguments
-(pltpu.PrefetchScalarGridSpec): the pool BlockSpec index_map reads
-``bt[tile_seq[t], j]`` so each page is DMA'd HBM->VMEM directly from
-its pool row — the gathered [B, S, H, D] view never materializes. On
-CPU the same kernel body runs in interpret mode over pre-gathered
-pages (a branch written when interpret mode had no scalar-prefetch
-index maps — under jax 0.9.0 it has them, see ROADMAP D3 and
-tests/test_pallas_kernels.py, which interprets the chip's kernels);
-the model-level CPU fallback in inference/paged_cache.py uses a
-pure-jnp gather instead so tier-1 serving tests exercise the full
+rows, and a page whose first position lies past a tile's LAST query is
+skipped outright (the causal frontier — prefill work is O(tokens
+written), not O(page capacity); a decode tile skips everything past
+its one position). On real TPU the block table, the tile->sequence map
+and the per-tile base positions ride as SCALAR-PREFETCH arguments
+(pltpu.PrefetchScalarGridSpec) and the grid is
+(total_tiles * nkv_heads / Hb, ceil(MB / P)): one grid step carries
+``Hb`` kv heads of ``P`` pages. The q and output blocks are
+(1, Hb, rows, hd), the m / l / acc scratch (Hb, rows, ..), and both
+products are batched over the head axis. A sequence's pages are not
+contiguous in the pool, so the pool is handed to the call ``P`` times;
+operand p's BlockSpec (1, 2, Hb, block_s, hd) index_map reads
+``bt[tile_seq[t], j * P + p]``, so each page is DMA'd HBM->VMEM
+directly from its pool row, every head at once — the gathered
+[B, S, H, D] view never materializes — and the body joins the ``P``
+pages into one (Hb, P * block_s, hd) kv tile. Past a tile's frontier
+each operand's index is held at its last real page (the per-tile last
+position is prefetched), so a skipped step re-names the block the
+pipeline already holds and no copy is issued for it. ``Hb`` and ``P``
+come from ``launch_plan``: the largest divisor of nkv, then the most
+pages up to one 128-position score tile, whose double-buffered blocks,
+scratch and float32 working set fit VMEM_BUDGET_BYTES. At the serving
+cells' shapes (32 kv heads of 128, bf16 pages of 16, 128 table
+entries) that is Hb 32 and P 8: 2 MB a step and 512 grid steps a
+decode launch, where one head of one page a step was 8 KB and 131 072
+(PERF.md, PR 27, has the sweep over P and Hb on the chip). On
+CPU the same body runs in interpret mode, one head a grid step, over
+pre-gathered pages (a branch written when interpret mode had no
+scalar-prefetch index maps — under jax 0.9.0 it has them, see ROADMAP
+D3 and tests/test_pallas_kernels.py, which interprets the chip's
+kernel); the model-level CPU fallback in inference/paged_cache.py uses
+a pure-jnp gather instead so tier-1 serving tests exercise the full
 protocol without Mosaic.
 
 Tile knobs (the README "Ragged paged attention" section carries the
 default table): ``tile_q`` is the query rows per grid step — more rows
 amortize each page DMA across queries but pad decode segments;
-``tile_kv`` is the PAGES per kv grid step — honored on the
-pre-gathered (interpret / jnp-reference) layout, clamped to 1 on the
-scalar-prefetch path because pool pages are non-contiguous (one DMA
-per page is the indirection's price; tile over q to amortize it).
-``tools/tile_report.py`` sizes both from recorded ``span.model``
-step-phase timings (PR 8/9) so real-TPU tuning is data-driven.
+``tile_kv`` is the PAGES per kv grid step, on both layouts. ``None``,
+which is what the engine passes, means derived: the longest segment up
+to DEFAULT_TILE_Q_CAP rows, and ``launch_plan``'s ``P`` on the chip
+(one page a step on the pre-gathered CPU layout).
 
 TENSOR-PARALLEL DISPATCH (sharded pools, inference/paged_cache.py
 ``mp`` > 1): the kernel itself is shard-oblivious — attention is
@@ -78,11 +93,13 @@ table with a per-page scale array [num_blocks, 2, nkv, block_size]
 inference/paged_cache.py for why scales are per row, not one scalar
 per block: row granularity is what keeps the quantized payload a pure
 function of the token stream, so prefix adoption stays exact). On the
-scalar-prefetch path the scale page is DMA'd next to its int8 page
-through the same ``bt[tile_seq[t], j]`` lookup and the kernel
-dequantizes in-register, folding the scales into its score and
-probability tiles (int8 page bytes plus the page's scales over the
-wire instead of bf16 — the HBM win). In interpret / jnp-
+scalar-prefetch path each scale page is DMA'd next to its int8 page
+through the same ``bt[tile_seq[t], j * P + p]`` lookup as a
+(1, 2, Hb, block_s) block — the step's heads on the sublane axis,
+which is why a quantized launch keeps ``Hb`` whole or a multiple of 8
+— and the kernel dequantizes in-register, folding the scales into its
+score and probability tiles (int8 page bytes plus the page's scales
+over the wire instead of bf16 — the HBM win). In interpret / jnp-
 reference mode the pre-gathered pages are dequantized before the
 kernel body, which then runs unchanged in float32.
 """
@@ -90,6 +107,7 @@ from __future__ import annotations
 
 import functools
 import math
+from typing import NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -107,6 +125,15 @@ NEG_INF = -1e30
 # (one page sweep scores every position), prefill wants wide tiles up
 # to this cap so a long chunk never holds every row in VMEM at once.
 DEFAULT_TILE_Q_CAP = 64
+
+# what one grid step of the scalar-prefetch launch may hold in VMEM
+# (launch_plan sizes Hb and P against it) and what Mosaic is told it
+# may use, which leaves the compiler room for its own temporaries; a
+# v5e core has 128 MiB. KV_STEP_POSITIONS caps the kv positions of a
+# step at one 128-lane score tile.
+VMEM_BUDGET_BYTES = 24 * 2 ** 20
+VMEM_LIMIT_BYTES = 2 * VMEM_BUDGET_BYTES
+KV_STEP_POSITIONS = 128
 
 # launch accounting for the dispatch-count acceptance tests and the
 # kernel microbench: every ``paged_attention_ragged`` entry (kernel,
@@ -142,30 +169,110 @@ def head_slice(x, shard: int, mp: int, axis: int = -2):
     return x[tuple(idx)]
 
 
+class LaunchPlan(NamedTuple):
+    """What one scalar-prefetch launch does, from its shapes alone."""
+    heads: int            # Hb: kv heads a grid step carries
+    pages: int            # P: pool pages a kv grid step carries
+    grid: Tuple[int, int]
+    bytes_per_step: int   # K/V (and scale) bytes a full step DMAs
+
+    @property
+    def grid_steps(self) -> int:
+        return self.grid[0] * self.grid[1]
+
+
+def _vmem_bytes(shape, itemsize: int) -> int:
+    """Bytes a buffer of ``shape`` takes in VMEM: the last dim padded
+    to 128 lanes, the one before it to the dtype's sublane tile (8
+    rows of 32 bits, 16 of bf16, 32 of int8)."""
+    *lead, sub, lane = shape
+    tile = 32 // itemsize
+    return (math.prod(lead) * -(-sub // tile) * tile
+            * -(-lane // 128) * 128 * itemsize)
+
+
+def launch_plan(T: int, nkv: int, rows: int, MB: int, block_s: int,
+                hd: int, kv_itemsize: int, *, q_itemsize: int = 4,
+                quantized: bool = False,
+                tile_kv: Optional[int] = None) -> LaunchPlan:
+    """The scalar-prefetch launch for a shape — pure host arithmetic,
+    shared by the kernel wrapper and the telemetry gauge. ``Hb`` is
+    the largest divisor of ``nkv`` whose grid step fits
+    VMEM_BUDGET_BYTES at one page a step: the pool (and scale) blocks
+    and the q / out blocks, all double-buffered by the pipeline, the
+    m / l / acc scratch, and the float32 working set the body makes of
+    them (the K and V tiles, the score and probability tiles). ``P``
+    is ``tile_kv`` where the caller passes one, else the most pages
+    that still fit, up to one KV_STEP_POSITIONS score tile (and never
+    more than the table has). A quantized launch keeps ``Hb`` whole
+    or a multiple of 8: its (2, Hb, block_s) scale block has the heads
+    on the sublane axis."""
+    def fits(hb, p):
+        n = p * block_s
+        blocks = p * _vmem_bytes((2 * hb, block_s, hd), kv_itemsize) \
+            + 2 * _vmem_bytes((hb, rows, hd), q_itemsize)
+        if quantized:
+            blocks += p * _vmem_bytes((2, hb, block_s), 4)
+        scratch = 2 * _vmem_bytes((hb, rows, 1), 4) \
+            + _vmem_bytes((hb, rows, hd), 4)
+        work = 2 * _vmem_bytes((hb, n, hd), 4) \
+            + 2 * _vmem_bytes((hb, rows, n), 4)
+        return 2 * blocks + scratch + work <= VMEM_BUDGET_BYTES
+
+    cands = [h for h in range(nkv, 0, -1) if nkv % h == 0
+             and (not quantized or h == nkv or h % 8 == 0)]
+    hb = next((h for h in cands if fits(h, 1)), cands[-1])
+    if tile_kv is not None:
+        p = min(max(1, int(tile_kv)), MB)
+    else:
+        cap = max(1, min(MB, KV_STEP_POSITIONS // block_s))
+        p = next((n for n in range(cap, 1, -1) if fits(hb, n)), 1)
+    grid = (T * (nkv // hb), -(-MB // p))
+    step = p * 2 * hb * block_s * (hd * kv_itemsize
+                                   + (4 if quantized else 0))
+    return LaunchPlan(hb, p, grid, step)
+
+
+def _heads_dot(a, b, b_axis: int):
+    """a's last axis against ``b``'s axis ``b_axis`` (counted from the
+    [.., rows, cols] pair), accumulated in float32, batched over the
+    leading head axis where there is one."""
+    lead = a.ndim - 2
+    batch = tuple(range(lead))
+    return jax.lax.dot_general(
+        a, b, (((a.ndim - 1,), (lead + b_axis,)), (batch, batch)),
+        preferred_element_type=jnp.float32)
+
+
 def _ragged_body(pos0, pos_last, k, v, q_ref, o_ref, m_scr, l_scr,
                  acc_scr, *, block_s, n_blocks, sm_scale, tile_q, g,
                  k_scale=None, v_scale=None):
-    """Online-softmax update for one (tile*kv-head, kv-step) grid step —
-    THE paged-attention body, shared by every phase. ``pos0`` is this
-    tile's first query's absolute position and ``pos_last`` its LAST
-    REAL query's (both read out of SMEM/prefetch by the wrapper; a
-    partial tail tile's pos_last excludes the padding rows, so a
-    decode row padded into a wide mixed-batch tile still skips
-    everything past its single position). Row r of the q block is
+    """Online-softmax update for one (tile x head-group, kv-step) grid
+    step — THE paged-attention body, shared by every phase. ``pos0``
+    is this tile's first query's absolute position and ``pos_last``
+    its LAST REAL query's (both read out of SMEM/prefetch by the
+    wrapper; a partial tail tile's pos_last excludes the padding rows,
+    so a decode row padded into a wide mixed-batch tile still skips
+    everything past its single position). ``q_ref`` / ``o_ref`` view
+    the step's (Hb, rows, hd) of their blocks: row r of head h is
     query r // g of the tile, at position pos0 + r // g, masked
-    causally per row. k/v hold this step's kv tile as (block_s, hd)
-    float32 — one pool page on the scalar-prefetch path, ``tile_kv``
-    pages pre-gathered in interpret mode. A kv step whose first
-    position lies past pos_last is fully masked for every real row
-    and skipped outright (the causal frontier: decode pages above a
-    prefill chunk don't exist yet — this is both the old prefill
-    kernel's page skip and the old decode kernel's length skip,
-    unified; padding rows lose those pages too, but their outputs are
-    dropped on unpack). ``k_scale`` / ``v_scale`` (int8 pages): the
-    page's per-position dequantization scales as (1, block_s) ROW
-    vectors. Dequantization folds into the two products — q.(s_k k)^T
-    = (q.k^T) s_k and p.(s_v v) = (p s_v).v — so the scales multiply
-    the [rows, block_s] score/probability tiles along their lane axis
+    causally per row. k/v hold this step's kv tile as
+    (Hb, block_s, hd) float32 — ``P`` pool pages of ``Hb`` heads on
+    the scalar-prefetch path — and both products are batched over the
+    head axis; the CPU branch's grid step is one head and passes
+    everything without that axis ((rows, hd) views, a (block_s, hd)
+    tile of ``tile_kv`` pre-gathered pages, 2-D scratch), which keeps
+    its products the plain 2-D ones. A kv step whose first position
+    lies past pos_last is fully masked for every real row and skipped
+    outright (the causal frontier: decode pages above a prefill chunk
+    don't exist yet — this is both the old prefill kernel's page skip
+    and the old decode kernel's length skip, unified; padding rows
+    lose those pages too, but their outputs are dropped on unpack).
+    ``k_scale`` / ``v_scale`` (int8 pages): the tile's per-position
+    dequantization scales as (Hb, 1, block_s) ROW vectors.
+    Dequantization folds into the two products — q.(s_k k)^T =
+    (q.k^T) s_k and p.(s_v v) = (p s_v).v — so the scales multiply the
+    [Hb, rows, block_s] score/probability tiles along their lane axis
     and never have to be turned into a column."""
     j = pl.program_id(1)
 
@@ -175,80 +282,61 @@ def _ragged_body(pos0, pos_last, k, v, q_ref, o_ref, m_scr, l_scr,
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    q = q_ref[0, 0].astype(jnp.float32)         # [tile_q * g, hd]
+    q = q_ref[...].astype(jnp.float32)          # [Hb, tile_q * g, hd]
 
     @pl.when(j * block_s <= pos_last)
     def _update():
-        scores = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * sm_scale
+        scores = _heads_dot(q, k, 1) * sm_scale
         if k_scale is not None:
             scores = scores * k_scale
         kpos = j * block_s + jax.lax.broadcasted_iota(
-            jnp.int32, scores.shape, 1)
+            jnp.int32, scores.shape, scores.ndim - 1)
         qpos = pos0 + jax.lax.broadcasted_iota(
-            jnp.int32, scores.shape, 0) // g
+            jnp.int32, scores.shape, scores.ndim - 2) // g
         valid = kpos <= qpos                    # implies kpos < kv_len
         scores = jnp.where(valid, scores, NEG_INF)
 
-        m_prev = m_scr[...]                     # [tile_q * g, 1]
+        m_prev = m_scr[...]                     # [Hb, tile_q * g, 1]
         m_new = jnp.maximum(m_prev, scores.max(axis=-1, keepdims=True))
         corr = jnp.exp(m_prev - m_new)
         # mask the probabilities too: a fully-masked row would
         # otherwise turn exp(NEG_INF - NEG_INF) into ones
         p = jnp.exp(scores - m_new) * valid
         l_scr[...] = l_scr[...] * corr + p.sum(axis=-1, keepdims=True)
-        acc_scr[...] = acc_scr[...] * corr + jax.lax.dot_general(
-            p if v_scale is None else p * v_scale, v,
-            (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+        acc_scr[...] = acc_scr[...] * corr + _heads_dot(
+            p if v_scale is None else p * v_scale, v, 0)
         m_scr[...] = m_new
 
     @pl.when(j == n_blocks - 1)
     def _done():
         l = l_scr[...]
         # rows with no valid key (length-0 sequences) emit zeros
-        o_ref[0, 0] = (acc_scr[...] /
-                       jnp.where(l == 0.0, 1.0, l)).astype(o_ref.dtype)
+        o_ref[...] = (acc_scr[...] /
+                      jnp.where(l == 0.0, 1.0, l)).astype(o_ref.dtype)
 
 
-def _kernel_ragged_prefetch(bt_ref, tseq_ref, pos_ref, q_ref,
-                            pool_ref, o_ref, m_scr, l_scr, acc_scr, *,
-                            nkv, **kw):
-    # bt/tseq feed the index maps only; pos is a prefetched [T, 2]
-    # (first, last) query-position table
+def _kernel_ragged_prefetch(bt_ref, tseq_ref, pos_ref, q_ref, *refs,
+                            n_hb, pages, quantized, **kw):
+    """The chip's kernel. ``refs``: the pool handed in ``pages`` times
+    (one (1, 2, Hb, block_s, hd) page block each, see the index map),
+    for int8 pages the scale array as often ((1, 2, Hb, block_s): the
+    block already carries the step's heads), then the output block
+    and the m / l / acc scratch. bt/tseq feed the index maps only; pos
+    is a prefetched [T, 2] (first, last) query-position table."""
     del bt_ref, tseq_ref
-    hd = q_ref.shape[-1]
-    t = pl.program_id(0) // nkv
-    kv = pool_ref[...].reshape(2, kw["block_s"], hd)
-    _ragged_body(pos_ref[t, 0], pos_ref[t, 1],
-                 kv[0].astype(jnp.float32), kv[1].astype(jnp.float32),
-                 q_ref, o_ref, m_scr, l_scr, acc_scr, **kw)
-
-
-def _kernel_ragged_prefetch_quant(bt_ref, tseq_ref, pos_ref, q_ref,
-                                  pool_ref, scale_ref, o_ref, m_scr,
-                                  l_scr, acc_scr, *, nkv, **kw):
-    # int8 pages: the page's scales ride the same block-table index map
-    # as a (1, 2, nkv, block_s) block — ALL kv heads, because Mosaic
-    # wants the last two block dims whole (or multiples of (8, 128)) and
-    # a one-head (1, block_s) slice is neither. This grid step's head
-    # row is picked out with a one-hot sum over the head (sublane) axis,
-    # which leaves the (1, block_s) row vectors the body multiplies into
-    # its score and probability tiles (dequantization in-register).
-    del bt_ref, tseq_ref
-    hd = q_ref.shape[-1]
-    block_s = kw["block_s"]
-    t = pl.program_id(0) // nkv
-    h = pl.program_id(0) % nkv
-    kv = pool_ref[...].reshape(2, block_s, hd)
-    sc = scale_ref[0]                                 # [2, nkv, block_s]
-    mine = jax.lax.broadcasted_iota(jnp.int32, sc.shape, 1) == h
-    sc = jnp.sum(jnp.where(mine, sc, 0.0), axis=1)    # [2, block_s]
-    _ragged_body(pos_ref[t, 0], pos_ref[t, 1],
-                 kv[0].astype(jnp.float32), kv[1].astype(jnp.float32),
-                 q_ref, o_ref, m_scr, l_scr, acc_scr,
-                 k_scale=sc[0:1, :], v_scale=sc[1:2, :], **kw)
+    pool_refs, refs = refs[:pages], refs[pages:]
+    t = pl.program_id(0) // n_hb
+    kv = [r[0].astype(jnp.float32) for r in pool_refs]
+    k = jnp.concatenate([x[0] for x in kv], axis=1)   # [Hb, P*bs, hd]
+    v = jnp.concatenate([x[1] for x in kv], axis=1)
+    if quantized:
+        sc_refs, refs = refs[:pages], refs[pages:]
+        # [2, Hb, P*bs] -> the (Hb, 1, P*bs) row vectors of the body
+        sc = jnp.concatenate([r[0] for r in sc_refs], axis=-1)
+        kw.update(k_scale=sc[0][:, None, :], v_scale=sc[1][:, None, :])
+    o_ref, *scratch = refs
+    _ragged_body(pos_ref[t, 0], pos_ref[t, 1], k, v, q_ref.at[0],
+                 o_ref.at[0], *scratch, **kw)
 
 
 def _kernel_ragged_interpret(pos_ref, q_ref, pg_ref, o_ref, m_scr,
@@ -260,7 +348,16 @@ def _kernel_ragged_interpret(pos_ref, q_ref, pg_ref, o_ref, m_scr,
         2, kw["block_s"], hd)
     _ragged_body(pos_ref[i, 0], pos_ref[i, 1],
                  kv[0].astype(jnp.float32), kv[1].astype(jnp.float32),
-                 q_ref, o_ref, m_scr, l_scr, acc_scr, **kw)
+                 q_ref.at[0, 0], o_ref.at[0, 0], m_scr, l_scr, acc_scr,
+                 **kw)
+
+
+def resolve_tile_q(q_lens, tile_q=None) -> int:
+    """Query rows a tile: the caller's, else the longest segment up to
+    DEFAULT_TILE_Q_CAP."""
+    if tile_q is None:
+        tile_q = min(DEFAULT_TILE_Q_CAP, max(q_lens))
+    return max(1, int(tile_q))
 
 
 def _tile_layout(q_lens, tile_q):
@@ -345,9 +442,7 @@ def paged_attention_ragged(q, kv_pool, block_tables, q_lens, kv_lens,
             f"head_slice(q, shard, mp), one launch per shard)")
     g = nh // nkv
     scale = sm_scale if sm_scale is not None else 1.0 / math.sqrt(hd)
-    if tile_q is None:
-        tile_q = min(DEFAULT_TILE_Q_CAP, max(q_lens))
-    tile_q = max(1, int(tile_q))
+    tile_q = resolve_tile_q(q_lens, tile_q)
     tile_seq, tile_off, tile_n, pad_idx, out_idx = \
         _tile_layout(q_lens, tile_q)
     T = tile_seq.shape[0]
@@ -371,22 +466,24 @@ def paged_attention_ragged(q, kv_pool, block_tables, q_lens, kv_lens,
     qp = jnp.transpose(qp.reshape(T, tile_q, nkv, g, hd),
                        (0, 2, 1, 3, 4)).reshape(T, nkv, rows, hd)
 
-    scratch = [pltpu.VMEM((rows, 1), jnp.float32),
-               pltpu.VMEM((rows, 1), jnp.float32),
-               pltpu.VMEM((rows, hd), jnp.float32)]
     out_shape = jax.ShapeDtypeStruct((T, nkv, rows, hd), q.dtype)
+
+    def scratch(*heads):
+        return [pltpu.VMEM(heads + (rows, 1), jnp.float32),
+                pltpu.VMEM(heads + (rows, 1), jnp.float32),
+                pltpu.VMEM(heads + (rows, hd), jnp.float32)]
 
     if not on_tpu():
         # the CPU branch: pre-gather each tile's pages instead of riding
         # the block table as scalar prefetch (the online-softmax body is
-        # shared; the pallas_call and its BlockSpecs are not).
+        # shared, one head a grid step; the pallas_call and its
+        # BlockSpecs are not).
         # The gather is per TILE, so a sequence tiled into k query
         # tiles duplicates its pages k-fold here — acceptable because
         # tests run small shapes and the default tile_q covers whole
         # chunks (k == 1); the scalar-prefetch path never gathers at
-        # all (one DMA per page straight off the pool row).
-        # tile_kv is honored here — the gathered layout is contiguous,
-        # so a kv grid step can cover several pages at once.
+        # all (the pages DMA straight off their pool rows).
+        # tile_kv None means one page a kv step here.
         tkv = max(1, int(tile_kv)) if tile_kv is not None else 1
         MBp = -(-MB // tkv) * tkv
         if MBp != MB:
@@ -425,53 +522,68 @@ def paged_attention_ragged(q, kv_pool, block_tables, q_lens, kv_lens,
                                    lambda i, j: (i // nkv, i % nkv,
                                                  0, 0)),
             out_shape=out_shape,
-            scratch_shapes=scratch,
+            scratch_shapes=scratch(),
             interpret=True,
         )(pos_r, qp, pg)
     else:
-        # scalar-prefetch path: tile_kv stays 1 — pool pages are
-        # non-contiguous, so each kv step DMAs exactly the page the
-        # block table names (tile over q to amortize the DMA instead)
-        kw = dict(block_s=block_s, n_blocks=MB, sm_scale=scale,
-                  tile_q=tile_q, g=g)
-        in_specs = [
-            pl.BlockSpec((1, 1, rows, hd),
-                         lambda i, j, bt_, ts_, p_:
-                         (i // nkv, i % nkv, 0, 0)),
-            # one page per step, straight out of the pool row named
-            # by the block table — the whole paged-attention trick
-            pl.BlockSpec((1, 2, 1, block_s, hd),
-                         lambda i, j, bt_, ts_, p_:
-                         (bt_[ts_[i // nkv], j], 0, i % nkv,
-                          0, 0)),
-        ]
-        operands = [bt, tseq, pos, qp, kv_pool]
-        if kv_scales is None:
-            kernel = functools.partial(_kernel_ragged_prefetch,
-                                       nkv=nkv, **kw)
-        else:
-            # the scale page rides the SAME block-table lookup as its
-            # int8 page, all heads at once (see the kernel)
-            in_specs.append(
-                pl.BlockSpec((1, 2, nkv, block_s),
-                             lambda i, j, bt_, ts_, p_:
-                             (bt_[ts_[i // nkv], j], 0, 0, 0)))
-            operands.append(jnp.asarray(kv_scales))
-            kernel = functools.partial(_kernel_ragged_prefetch_quant,
-                                       nkv=nkv, **kw)
+        # scalar-prefetch path: a grid step carries Hb heads of P
+        # pages (launch_plan). A sequence's pages are not contiguous
+        # in the pool, so the pool is handed to the call P times and
+        # operand p's index map names table entry j * P + p
+        plan = launch_plan(T, nkv, rows, MB, block_s, hd,
+                           kv_pool.dtype.itemsize,
+                           q_itemsize=q.dtype.itemsize,
+                           quantized=kv_scales is not None,
+                           tile_kv=tile_kv)
+        Hb, P = plan.heads, plan.pages
+        n_hb = nkv // Hb
+        kw = dict(block_s=block_s * P, n_blocks=plan.grid[1],
+                  sm_scale=scale, tile_q=tile_q, g=g)
+
+        def q_map(i, j, bt_, ts_, pos_):
+            return (i // n_hb, i % n_hb, 0, 0)
+
+        def page_map(p, tail):
+            # operand p's page of kv step j is table entry j * P + p —
+            # held at its LAST REAL page (the per-tile frontier,
+            # pos[t, 1]) for every step past it: a skipped step
+            # re-names the block the pipeline already holds, and no
+            # copy is issued past the frontier. An operand whose first
+            # page is already past it holds entry p throughout.
+            def index(i, j, bt_, ts_, pos_):
+                t = i // n_hb
+                last = jnp.maximum(pos_[t, 1], 0) // block_s
+                jj = jnp.minimum(j, jnp.maximum(last - p, 0) // P)
+                return (bt_[ts_[t], jj * P + p], 0, i % n_hb) + tail
+            return index
+
+        # the pages straight out of the pool rows the block table
+        # names — the whole paged-attention trick
+        in_specs = [pl.BlockSpec((1, Hb, rows, hd), q_map)] + [
+            pl.BlockSpec((1, 2, Hb, block_s, hd), page_map(p, (0, 0)))
+            for p in range(P)]
+        operands = [bt, tseq, pos, qp] + [kv_pool] * P
+        if kv_scales is not None:
+            # each scale page rides the SAME lookup as its int8 page
+            in_specs += [
+                pl.BlockSpec((1, 2, Hb, block_s), page_map(p, (0,)))
+                for p in range(P)]
+            operands += [jnp.asarray(kv_scales)] * P
         grid_spec = pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,   # bt + tile->seq map + pos (SMEM)
-            grid=(T * nkv, MB),
+            grid=plan.grid,
             in_specs=in_specs,
-            out_specs=pl.BlockSpec((1, 1, rows, hd),
-                                   lambda i, j, bt_, ts_, p_:
-                                   (i // nkv, i % nkv, 0, 0)),
-            scratch_shapes=scratch,
+            out_specs=pl.BlockSpec((1, Hb, rows, hd), q_map),
+            scratch_shapes=scratch(Hb),
         )
         out = pl.pallas_call(
-            kernel,
+            functools.partial(_kernel_ragged_prefetch, n_hb=n_hb,
+                              pages=P, quantized=kv_scales is not None,
+                              **kw),
             grid_spec=grid_spec,
             out_shape=out_shape,
+            compiler_params=pltpu.CompilerParams(
+                vmem_limit_bytes=VMEM_LIMIT_BYTES),
         )(*operands)
 
     # unfold + unpad back to the packed row order

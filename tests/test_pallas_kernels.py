@@ -496,22 +496,16 @@ class TestPagedAttentionRagged:
                         jnp.float32)
         return q, pool, bt, q_lens, kv_lens
 
-    def test_scalar_prefetch_kernels_interpreted(self, monkeypatch):
-        """The kernels the CHIP runs — ``_kernel_ragged_prefetch`` and
-        its int8-page twin, block table and tile maps riding as scalar
-        prefetch — interpreted on CPU. The default CPU branch
-        pre-gathers pages and runs a different ``pallas_call`` with
-        different BlockSpecs, so without this the chip's kernel bodies
-        and index maps execute nowhere in tier-1."""
-        import importlib
+    def test_scalar_prefetch_kernels_interpreted(self, chip_pa):
+        """The kernel the CHIP runs — ``_kernel_ragged_prefetch``, over
+        bf16/float pages and over int8 pages with their scales, block
+        table and tile maps riding as scalar prefetch — interpreted on
+        CPU. The default CPU branch pre-gathers pages and runs a
+        different ``pallas_call`` with different BlockSpecs, so without
+        this (and TestChipKernelInterpreted below) the chip's kernel
+        body and index maps execute nowhere in tier-1."""
         from paddle_tpu.inference.paged_cache import _quant_rows
-        pa = importlib.import_module(
-            "paddle_tpu.ops.pallas.paged_attention")
-        real = pa.pl.pallas_call
-        monkeypatch.setattr(pa, "on_tpu", lambda: True)
-        monkeypatch.setattr(
-            pa.pl, "pallas_call",
-            lambda *a, **kw: real(*a, interpret=True, **kw))
+        pa = chip_pa
         q, pool, bt, q_lens, kv_lens = self._mixed()
         out = pa.paged_attention_ragged(q, pool, bt, q_lens, kv_lens)
         ref = paged_attention_ragged_reference(q, pool, bt, q_lens,
@@ -679,6 +673,163 @@ class TestPagedAttentionRagged:
         out = np.asarray(paged_attention_ragged(
             q, pool, bt, (1, 1), jnp.asarray([0, 7], jnp.int32)))
         assert np.all(out[0] == 0.0) and np.isfinite(out).all()
+
+
+@pytest.fixture
+def chip_pa(monkeypatch):
+    """``paged_attention`` as the CHIP runs it — the scalar-prefetch
+    branch, every ``pallas_call`` interpreted (the default CPU branch
+    pre-gathers pages and is a different call)."""
+    import importlib
+    pa = importlib.import_module("paddle_tpu.ops.pallas.paged_attention")
+    real = pa.pl.pallas_call
+    monkeypatch.setattr(pa, "on_tpu", lambda: True)
+    monkeypatch.setattr(
+        pa.pl, "pallas_call",
+        lambda *a, **kw: real(*a, interpret=True, **kw))
+    return pa
+
+
+def _budget_for_heads(pa, monkeypatch, heads, *shape, **kw):
+    """Shrink the module's VMEM budget until the plan for ``shape``
+    carries ``heads`` kv heads a grid step."""
+    for kib in range(4096, 0, -8):
+        monkeypatch.setattr(pa, "VMEM_BUDGET_BYTES", kib * 1024)
+        if pa.launch_plan(*shape, **kw).heads == heads:
+            return
+    raise AssertionError(f"no budget gives {heads} heads a step")
+
+
+class TestChipKernelInterpreted:
+    """The chip's launch at its new grid — a step carries ``Hb`` kv
+    heads of ``P`` pages (``launch_plan``), each page operand held at
+    its last real page past the tile's frontier — against the shared
+    jnp reference, interpreted on the CPU."""
+
+    # name: (q_lens, kv_lens, tile_q, tile_kv, nkv, g, MB)
+    CASES = {
+        # rows == 1: one M = 1 product a head; P = MB, one kv step
+        "decode": ((1,) * 5, (17, 1, 40, 33, 8), 1, None, 4, 1, 5),
+        # derived P = 16 of MB = 20: two kv steps, the last one short
+        "decode_pages_not_dividing": ((1,) * 3, (150, 129, 5), 1, None,
+                                      4, 1, 20),
+        "verify": ((3,) * 4, (9, 3, 24, 40), 3, 2, 4, 1, 5),
+        "prefill": ((11,), (27,), 4, 2, 4, 1, 5),
+        "mixed": ((1, 3, 7, 1, 10, 0), (17, 9, 12, 33, 10, 0), None, 2,
+                  4, 1, 5),
+        "mixed_one_step": ((1, 3, 7, 1, 10), (17, 9, 12, 33, 10), None,
+                           None, 4, 1, 5),
+        "tile_kv_not_dividing": ((1, 6, 1), (40, 30, 8), 4, 3, 4, 1, 5),
+        # lengths exactly on a page boundary (8, 16, 40 at 8 a page)
+        "page_boundary": ((1, 4, 1, 8), (8, 16, 40, 8), 4, 2, 4, 1, 5),
+        # kv_len 0 under a live query row: zeros, never NaN
+        "length_zero_rows": ((1, 1, 2), (0, 7, 0), None, 2, 4, 1, 5),
+        "gqa_decode": ((1,) * 3, (17, 40, 8), 1, 2, 2, 2, 5),
+        "gqa_mixed": ((1, 5, 1), (23, 13, 40), 4, 2, 2, 2, 5),
+    }
+
+    def _inputs(self, q_lens, kv_lens, nkv, g, MB, hd=16, bs=8,
+                seed=0):
+        r = np.random.default_rng(seed)
+        NB = 2 * MB + 4
+        pool = jnp.asarray(r.standard_normal((NB, 2, nkv, bs, hd)),
+                           jnp.float32)
+        bt = jnp.asarray(r.integers(1, NB, (len(q_lens), MB)),
+                         jnp.int32)
+        q = jnp.asarray(r.standard_normal((sum(q_lens), nkv * g, hd)),
+                        jnp.float32)
+        return q, pool, bt, jnp.asarray(kv_lens, jnp.int32)
+
+    def _check(self, pa, q, pool, bt, q_lens, kv_lens, kv_scales=None,
+               **tiles):
+        out = np.asarray(pa.paged_attention_ragged(
+            q, pool, bt, q_lens, kv_lens, kv_scales=kv_scales, **tiles))
+        ref = np.asarray(paged_attention_ragged_reference(
+            q, pool, bt, q_lens, kv_lens, kv_scales=kv_scales))
+        assert np.isfinite(out).all()
+        np.testing.assert_allclose(out, ref, atol=2e-5, rtol=2e-5)
+        return out
+
+    @pytest.mark.parametrize("case", sorted(CASES))
+    def test_launch_matches_reference(self, chip_pa, case):
+        q_lens, kv_lens, tq, tkv, nkv, g, MB = self.CASES[case]
+        q, pool, bt, kvl = self._inputs(q_lens, kv_lens, nkv, g, MB)
+        out = self._check(chip_pa, q, pool, bt, q_lens, kvl, tile_q=tq,
+                          tile_kv=tkv)
+        if case == "length_zero_rows":
+            assert np.all(out[0] == 0.0) and np.all(out[2:] == 0.0)
+
+    @pytest.mark.parametrize("case", ["decode", "mixed", "verify"])
+    def test_head_groups_under_a_small_budget(self, chip_pa,
+                                              monkeypatch, case):
+        """A budget that holds two of the four kv heads: the grid's
+        first axis walks (tile, head group) and the blocks carry
+        ``Hb < nkv`` heads."""
+        q_lens, kv_lens, tq, tkv, nkv, g, MB = self.CASES[case]
+        q, pool, bt, kvl = self._inputs(q_lens, kv_lens, nkv, g, MB)
+        rows = (tq or max(q_lens)) * g
+        _budget_for_heads(chip_pa, monkeypatch, 2, 1, nkv, rows, MB, 8,
+                          16, 4)
+        self._check(chip_pa, q, pool, bt, q_lens, kvl, tile_q=tq,
+                    tile_kv=tkv)
+
+    @pytest.mark.parametrize("case,heads", [("mixed", None),
+                                            ("decode", None),
+                                            ("page_boundary", None),
+                                            ("mixed", 8)])
+    def test_int8_pages(self, chip_pa, monkeypatch, case, heads):
+        """int8 pages: each scale page rides its page's lookup as a
+        (1, 2, Hb, block_s) block; with 16 kv heads and a small budget
+        a step carries 8 of them (the scale block's sublane tile)."""
+        from paddle_tpu.inference.paged_cache import _quant_rows
+        q_lens, kv_lens, tq, tkv, nkv, g, MB = self.CASES[case]
+        if heads is not None:
+            nkv = 16
+        q, pool, bt, kvl = self._inputs(q_lens, kv_lens, nkv, g, MB)
+        if heads is not None:
+            rows = (tq or max(q_lens)) * g
+            _budget_for_heads(chip_pa, monkeypatch, heads, 1, nkv, rows,
+                              MB, 8, 16, 1, quantized=True)
+        pool_q, scales = _quant_rows(pool)
+        self._check(chip_pa, q, pool_q, bt, q_lens, kvl,
+                    kv_scales=scales, tile_q=tq, tile_kv=tkv)
+
+    @pytest.mark.parametrize("tile_q", [1, 4])
+    def test_segment_independence(self, chip_pa, tile_q):
+        """Element-exact: a sequence's rows of a packed launch equal
+        the same sequence launched alone at the same tile_q."""
+        q_lens, kv_lens = (1, 3, 7, 1, 10), (17, 9, 12, 33, 10)
+        q, pool, bt, kvl = self._inputs(q_lens, kv_lens, 4, 1, 5)
+        out = np.asarray(chip_pa.paged_attention_ragged(
+            q, pool, bt, q_lens, kvl, tile_q=tile_q))
+        r0 = 0
+        for s, ql in enumerate(q_lens):
+            solo = np.asarray(chip_pa.paged_attention_ragged(
+                q[r0:r0 + ql], pool, bt[s:s + 1], (ql,), kvl[s:s + 1],
+                tile_q=tile_q))
+            np.testing.assert_array_equal(out[r0:r0 + ql], solo)
+            r0 += ql
+
+    def test_launch_plan_at_the_cells_shapes(self, chip_pa):
+        """The serving cells' launches (32 kv heads of 128, bf16 pages
+        of 16, 128 table entries): every head of a page in one grid
+        step, so at most 4 096 steps of at least 256 KB where the
+        one-head one-page grid had 131 072 of 8 KB."""
+        plan = chip_pa.launch_plan(32, 32, 1, 128, 16, 128, 2)
+        assert plan.heads == 32 and plan.grid[0] == 32
+        assert plan.grid_steps == plan.grid[0] * plan.grid[1] <= 4096
+        assert plan.bytes_per_step >= 256 * 1024
+        assert plan.grid[1] == -(-128 // plan.pages)
+        mixed = chip_pa.launch_plan(36, 32, 64, 128, 16, 128, 2)
+        assert mixed.heads == 32 and mixed.grid_steps <= 4608
+        # tile_kv, where a caller passes one, is the pages a step
+        assert chip_pa.launch_plan(36, 32, 64, 128, 16, 128, 2,
+                                   tile_kv=1).grid == (36, 128)
+        # grouped shards (mp 4) and an int8 pool keep whole blocks
+        assert chip_pa.launch_plan(32, 8, 1, 128, 16, 128, 2).heads == 8
+        q8 = chip_pa.launch_plan(36, 32, 64, 128, 16, 128, 1,
+                                 quantized=True)
+        assert q8.heads % 8 == 0 and 32 % q8.heads == 0
 
 
 class TestDecodeAttention:

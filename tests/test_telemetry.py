@@ -701,6 +701,66 @@ class TestTimelineAndLifecycle:
         assert any(ev.get("ph") == "X" and ev["name"] == "prefill"
                    for ev in col.events)
 
+    def test_paged_attn_gauge_rides_the_ragged_layout(self, tmp_path,
+                                                       capsys):
+        """Token-budget mode, packed steps: every launch samples the kernel's
+        launch plan (grid steps, pages and heads a grid step) on the
+        ``paged_attn`` gauge track, inside the ``grow`` span that
+        builds the layout — host arithmetic over shapes — and the
+        trace report prints the series. Without a budget there is no
+        ragged layout and no such track (the test above)."""
+        import importlib
+        from tools import trace_report
+        pa = importlib.import_module(
+            "paddle_tpu.ops.pallas.paged_attention")
+        col = TraceCollector()
+        eng = PagedServingEngine(_model(), max_batch=2, block_size=4,
+                                 num_blocks=60, max_blocks_per_seq=10,
+                                 prefill_token_budget=8,
+                                 ragged_step="force", collector=col)
+        rng = np.random.RandomState(6)
+        for T in (13, 11, 10):
+            eng.submit(paddle.to_tensor(
+                rng.randn(T, D).astype(np.float32)))
+        x = np.zeros((2, 1, D), np.float32)
+        for _ in range(12):
+            out = eng.step(paddle.to_tensor(x))
+            for _, slot, h in eng.admitted:
+                x[slot, 0] = np.asarray(h.numpy())[0]
+            eng.admitted.clear()
+            if out is not None:
+                x = np.asarray(out.numpy())[:, :1].copy()
+        samples = [ev for ev in col.events
+                   if ev.get("ph") == "C" and ev["name"] == "paged_attn"]
+        layouts = [ev for ev in col.events if ev.get("ph") == "X"
+                   and ev["name"] == "grow"
+                   and (ev.get("args") or {}).get("what") == "layout"]
+        assert samples and len(samples) == len(layouts)
+        for ev in samples:
+            a = ev["args"]
+            assert set(a) == {"grid_steps", "pages_per_step",
+                              "heads_per_step"}
+            # 10 table entries a sequence, HEADS kv heads, one shard
+            assert a["heads_per_step"] == HEADS
+            assert a["grid_steps"] % -(-10 // a["pages_per_step"]) == 0
+        # a decode-only packed launch: 2 slots, one tile each
+        plan = pa.launch_plan(2, HEADS, 1, 10, 4, D // HEADS, 4)
+        assert plan.grid_steps in {ev["args"]["grid_steps"]
+                                   for ev in samples}
+        assert col.registry.gauges["paged_attn.grid_steps"] == \
+            samples[-1]["args"]["grid_steps"]
+        path = str(tmp_path / "g.trace.json")
+        col.save_chrome_trace(path)
+        assert trace_report.main([path]) == 0
+        out = capsys.readouterr().out
+        assert "paged_attn.grid_steps:" in out
+        assert "paged_attn.pages_per_step:" in out
+        assert trace_report.main([path, "--json"]) == 0
+        rep = json.loads(capsys.readouterr().out)
+        assert rep["data"]["gauges"]["paged_attn.grid_steps"][
+            "samples"] == \
+            len(samples)
+
     @pytest.mark.spec
     def test_rollback_events_ride_the_spec_engine(self):
         """An adversarial draft (noise logits) forces rejections:

@@ -123,12 +123,13 @@ def _rollup(evs):
     """ONE aggregation pass over the timeline events, shared by the
     human renderer (``summarize``) and the machine one
     (``machine_report``) so the two can never drift: returns
-    (spans {name: (total, count, max)}, counter-track names,
-    instant tallies, replay-flagged span count, children {name: total
-    duration of the spans that name it as their parent} — a span's
-    self time is its total less its children's)."""
+    (spans {name: (total, count, max)}, gauge tracks {track: {series:
+    [samples, sum, min, max, last]}}, instant tallies, replay-flagged
+    span count, children {name: total duration of the spans that name
+    it as their parent} — a span's self time is its total less its
+    children's)."""
     spans = {}
-    counters = set()
+    counters = {}
     insts = {}
     replayed = 0
     children = {}
@@ -146,7 +147,13 @@ def _rollup(evs):
                 children[args["parent"]] = \
                     children.get(args["parent"], 0.0) + d
         elif ph == "C":
-            counters.add(ev["name"])
+            track = counters.setdefault(ev["name"], {})
+            for k, v in (ev.get("args") or {}).items():
+                if isinstance(v, bool) or not isinstance(v, (int, float)):
+                    continue
+                st = track.setdefault(k, [0, 0.0, v, v, v])
+                track[k] = [st[0] + 1, st[1] + v, min(st[2], v),
+                            max(st[3], v), v]
         elif ph == "i":
             insts[ev["name"]] = insts.get(ev["name"], 0) + 1
     return spans, counters, insts, replayed, children
@@ -179,6 +186,12 @@ def summarize(trace: dict, tenant: str = None,
                             if name in children else ""))
     if counters:
         lines.append(f"  gauge tracks: {sorted(counters)}")
+        for track in sorted(counters):
+            for k, (n, tot, lo, hi, last) in sorted(
+                    counters[track].items(), key=lambda kv: str(kv[0])):
+                lines.append(f"    {track}.{k}: {n} sample(s), mean "
+                             f"{tot / n:g}, min {lo:g}, max {hi:g}, "
+                             f"last {last:g}")
     if insts:
         lines.append(f"  instants: "
                      + ", ".join(f"{k} x{v}"
@@ -249,6 +262,11 @@ def machine_report(trace: dict) -> dict:
         "replayed_spans": replayed,
         "instants": dict(sorted(insts.items())),
         "gauge_tracks": sorted(counters),
+        "gauges": {f"{track}.{k}": {"samples": n, "mean": tot / n,
+                                    "min": lo, "max": hi, "last": last}
+                   for track in sorted(counters)
+                   for k, (n, tot, lo, hi, last)
+                   in counters[track].items()},
     }
     if isinstance(meta, dict) and "summary" in meta:
         out["steps"] = meta.get("steps")
