@@ -888,6 +888,138 @@ def trainer_four_chip_phase(*, hidden=4096, inter=11008, heads=32,
 
 # ---------------------------------------------------------------------------
 
+# DECODER_LOGITS_TOL — the config-driven decoder core (arch "afmoe") against
+# its plain float32 reference, as relative L2 per probed position. Weights
+# are bfloat16 (the reference takes the same rounded weights), products
+# accumulate in float32 and hand bfloat16 on, K/V pages are bfloat16 and
+# the kernel's dot is one bf16 pass: 6e-3 to 7e-3 through five sandwich
+# layers (the benchmark's probe, PR 28, my chip runs); a reference with
+# its matrices rounded to 3 mantissa bits reads 8e-2. A dropped window, RoPE on a full layer or a capacity drop moves
+# the logits by tens of percent; routing that disagrees with the reference
+# outside its margin fails the probe by itself.
+DECODER_LOGITS_TOL = 2e-2
+
+
+def decoder_phase(*, hidden=3072, heads=48, kv_heads=8, head_dim=128,
+                  window=4096, dense_width=12288, experts=256, held=32,
+                  top_k=4, expert_width=3072, vocab=25024, prompt=4608,
+                  chunk=512, block_size=16, max_batch=8,
+                  weight_dtype="bfloat16", kv_dtype="bfloat16",
+                  expect_kernel=True, logits_tol=DECODER_LOGITS_TOL,
+                  tol=KERNEL_TOL, seed=0, meter=None) -> dict:
+    """The ``afmoe`` core at published widths, one layer of each type: a
+    windowed ragged launch at ``heads`` / ``kv_heads`` past ``window``
+    positions and the dropless grouped GEMM at ``held`` experts against
+    their jnp references, then a two-layer server (a sliding dense layer,
+    a full expert layer) through ``build_server_from_spec`` whose probe
+    (a prompt past the window in chunks, four decode rows) is held to the
+    plain reference's logits."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    import tempfile
+    from benchmark.jobs import serve_arch
+    gg, pa = _kernel_module("grouped_gemm"), _kernel_module("paged_attention")
+
+    t_phase = time.perf_counter()
+    rng = np.random.default_rng(seed)
+    rows = []
+
+    # -- the windowed launch: a chunk and decode rows past the window ------
+    max_blocks = -(-(prompt + 64) // block_size)
+    q_lens = (chunk,) + (1,) * max_batch
+    kv_lens = [prompt] + list(rng.integers(2, prompt, size=max_batch - 1)) \
+        + [window + block_size + 3]
+    num_blocks = 1 + sum(-(-int(n) // block_size) for n in kv_lens)
+    _, _, bt, kvl = _paged_inputs(
+        rng, q_lens, kv_lens, heads=1, head_dim=1, block_size=block_size,
+        num_blocks=num_blocks, max_blocks=max_blocks, pool_dtype="float32")
+    q = jnp.asarray(rng.standard_normal((sum(q_lens), heads, head_dim)),
+                    jnp.float32)
+    pool = jnp.asarray(rng.standard_normal(
+        (num_blocks, 2, kv_heads, block_size, head_dim)), kv_dtype)
+    for w in (window, None):
+        rows.append(_run_check(
+            f"paged_ragged/window={w}",
+            f"R={sum(q_lens)} nh={heads} nkv={kv_heads} hd={head_dim}",
+            lambda q_, p_, bt_, kl_, w=w: pa.paged_attention_ragged(
+                q_, p_, bt_, q_lens, kl_, window=w),
+            lambda q_, p_, bt_, kl_, w=w: pa.paged_attention_ragged_reference(
+                q_, p_, bt_, q_lens, kl_, window=w),
+            (q, pool, bt, kvl), expect_kernel, tol))
+
+    # -- the dropless grouped GEMM at the experts held here ----------------
+    block_m = 16
+    counts = rng.integers(0, 3 * block_m, size=held)
+    counts[rng.integers(0, held)] = 0            # an expert without a row
+    used = int((-(-counts // block_m)).sum())
+    blocks = used + held                         # a worst-case tail
+    be = np.repeat(np.arange(held), -(-counts // block_m))
+    be = np.concatenate([be, np.full(blocks - used, be[-1])]).astype(np.int32)
+    dt = jnp.dtype(weight_dtype)
+    lhs = jnp.asarray(rng.standard_normal((blocks * block_m, hidden)), dt)
+    rhs = jnp.asarray(rng.standard_normal((held, hidden, 2 * expert_width))
+                      * hidden ** -0.5, dt)
+    live = used * block_m
+
+    def gmm_ref(l, r, b, n):
+        out = jnp.einsum("bmk,bkn->bmn",
+                         l.reshape(-1, block_m, l.shape[-1])
+                         .astype(jnp.float32), r[b].astype(jnp.float32))
+        return out.reshape(l.shape[0], -1)[:live]
+    rows.append(_run_check(
+        "gmm/blocks_used", f"M={blocks * block_m} K={hidden} "
+        f"N={2 * expert_width} E={held} {weight_dtype}",
+        lambda l, r, b, n: gg.gmm(l, r, b, block_m=block_m, block_n=512,
+                                  block_k=hidden, blocks_used=n)[:live],
+        gmm_ref, (lhs, rhs, jnp.asarray(be), jnp.asarray([used], jnp.int32)),
+        expect_kernel, tol))
+
+    # -- two layers through the server against the plain reference ---------
+    config = {
+        "model_type": "afmoe", "reference": "afmoe", "hidden_size": hidden,
+        "num_attention_heads": heads, "num_key_value_heads": kv_heads,
+        "head_dim": head_dim, "sliding_window": window,
+        "intermediate_size": dense_width, "num_experts": held,
+        "num_experts_per_tok": top_k, "num_shared_experts": 1,
+        "moe_intermediate_size": expert_width, "route_norm": True,
+        "route_scale": 2.448, "rope_theta": 10000, "rms_norm_eps": 1e-5,
+        "mup_enabled": True, "vocab_size": vocab,
+        "weight_dtype": weight_dtype, "num_dense_layers": 1,
+        "layer_types": ["sliding_attention", "full_attention"],
+        "layers_run": [0, 1],
+        "deployment_cut": {"num_experts_published": experts,
+                           "expert_offset": held},
+        "engine": {"mp": 1, "k": 0, "max_batch": max_batch,
+                   "block_size": block_size,
+                   "num_blocks": 2 * max_blocks + 8,
+                   "max_blocks_per_seq": max_blocks, "prefix_cache": True,
+                   "prefill_token_budget": chunk, "kv_dtype": kv_dtype},
+    }
+    stats = {}
+    with tempfile.TemporaryDirectory(prefix="chip_smoke_") as workdir:
+        server = serve_arch.build_server(config, seed, workdir)
+        try:
+            err = serve_arch.check_probe(
+                server, config, {"table": [[prompt, 8]]}, seed,
+                tol=logits_tol, stats=stats)
+            core = server.engine.target.core
+            moe = core.moe_metrics()
+        finally:
+            server.close()
+    log(f"[decoder] probe of {prompt} tokens past a window of {window}: "
+        f"rel. L2 {err:.2e}, {stats.get('route_ties_taken')} of "
+        f"{stats.get('route_rows')} routed rows tied; experts "
+        f"{moe['mixed']['rows_routed_here']} rows routed here in "
+        f"{moe['mixed']['layer_calls']} mixed layer calls")
+    if moe["mixed"]["rows_routed_here"] == 0:
+        raise AssertionError("no row was routed to the experts held here")
+    if meter is not None:
+        _phase_line("decoder", time.perf_counter() - t_phase, meter.take())
+    return {"kernels": rows, "probe_rel_l2": err, "route": stats,
+            "moe": moe}
+
+
 def main() -> None:
     t_start = time.perf_counter()
     device = require_tpu()
@@ -903,6 +1035,8 @@ def main() -> None:
     _free_device_memory("serving")
     kernel_phase(meter=meter)
     _free_device_memory("kernels")
+    decoder_phase(meter=meter)
+    _free_device_memory("decoder")
     trainer_phase(meter=meter)
     _free_device_memory("trainer")
 

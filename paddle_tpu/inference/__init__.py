@@ -58,6 +58,7 @@ from .speculative import (SpeculativeEngine,  # noqa: F401
                           logit_mask_fn, register_logit_mask)
 from .moe_serving import (MoeServingCore,  # noqa: F401
                           moe_capacity)
+from .decoder import DecoderConfig, DecoderCore  # noqa: F401
 from .recovery import (SNAPSHOT_VERSION,  # noqa: F401
                        RecoverableServer, RecoveryError,
                        RequestJournal, SnapshotVersionError,
@@ -79,6 +80,7 @@ __all__ = ["Config", "Predictor", "create_predictor", "PrecisionType",
            "HealthMonitor", "HealthReport", "SeriesBuffer",
            "SloPolicy", "SloTracker",
            "MetricsRegistry", "MoeServingCore", "moe_capacity",
+           "DecoderConfig", "DecoderCore",
            "PagedKVCache",
            "PagedLayerCache", "PagedPrefillView", "PagedRequest",
            "PagedServingEngine", "ParallelStats", "PrefillStats",

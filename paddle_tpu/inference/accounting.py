@@ -125,7 +125,18 @@ class WorkModel:
     """Analytic FLOPs / HBM-bytes of one FusedMultiTransformer-protocol
     core (see the module docstring for the formulas). All outputs are
     exact python ints — additive over rows and therefore exactly
-    subtractable on rollback."""
+    subtractable on rollback.
+
+    The closed forms hold for the GPT-3 block ONLY (``4 d^2`` of
+    attention projections at as many KV heads as heads, a two-matmul
+    FFN, biases, GShard capacity routing). The config-driven core
+    (``inference/decoder.py``: grouped KV heads, an output gate, SwiGLU,
+    a shared expert, experts held by share) is not priced here:
+    ``for_model`` refuses it rather than misprice it. Its bytes and
+    FLOPs are reckoned from shapes where they are read
+    (``DecoderCore.weight_bytes``, ``PagedKVCache.kv_bytes_per_token``,
+    the benchmark's ``layer_metrics/gmm_roofline.py`` and
+    ``paged_attn_window_roofline.py``)."""
 
     __slots__ = ("num_layers", "d_model", "ffn_dim", "itemsize",
                  "weight_itemsize", "kv_token_bytes", "weight_bytes",
@@ -199,6 +210,11 @@ class WorkModel:
         TokenServingModel wrapping one). MoE cores advertise their
         routing spec via ``moe_spec`` (they have no dense ffn1)."""
         core = getattr(model, "core", model)
+        if not hasattr(core, "layers"):
+            raise ValueError(
+                f"WorkModel's closed forms hold for the GPT-3 block only; "
+                f"{type(core).__name__} is not priced by them (see the "
+                f"class docstring)")
         spec = getattr(core, "moe_spec", None)
         if spec is not None:
             return cls(core.num_layers, core.embed_dim,

@@ -468,3 +468,124 @@ class MoeServingCore(FusedMultiTransformer):
                                              self._ep_devices[0])
                 out = contrib if out is None else out + contrib
         return out
+
+
+# ---------------------------------------------------------------------
+# Dropless sigmoid routing with a selection bias and a shared expert:
+# the expert layer of inference/decoder.py (pure functions of arrays).
+# ---------------------------------------------------------------------
+
+# weight columns a grouped-GEMM step of the dropless layer streams (with
+# the whole of K: 3 MB of bf16 at a width of 3072, read once an expert)
+GMM_BLOCK_N = 512
+
+
+def expert_row_block(rows: int, top_k: int, num_experts: int) -> int:
+    """Rows an m-block of the grouped GEMM carries, from the call's row
+    count: half again what an expert expects (rows * top_k / experts),
+    rounded up to a power of two, between one bf16 sublane tile and
+    128. A 32-row decode step gets 16, a 2 080-row mixed step 64, so a
+    group is one block almost always and the padding stays small."""
+    want = max(1, -(-3 * rows * top_k // (2 * num_experts)))
+    return min(128, max(16, 1 << (want - 1).bit_length()))
+
+
+def sigmoid_route(m, router, bias, top_k: int, route_norm: bool,
+                  route_scale: float):
+    """Scores over ALL experts in float32: ``s = sigmoid(m @ router)``,
+    choose ``top_k`` of ``s + bias`` (the bias picks, it never weighs),
+    weigh with ``s`` normalised over the chosen (``route_norm``) times
+    ``route_scale``. Returns (expert ids [R, k] int32, weights [R, k]
+    float32, scores [R, E] float32)."""
+    logits = jnp.dot(m, router, preferred_element_type=jnp.float32)
+    s = jax.nn.sigmoid(logits.astype(jnp.float32))
+    _, idx = jax.lax.top_k(s + bias[None, :], top_k)
+    w = jnp.take_along_axis(s, idx, axis=-1)
+    if route_norm:
+        w = w / (jnp.sum(w, -1, keepdims=True) + 1e-20)
+    return idx.astype(jnp.int32), w * route_scale, s
+
+
+def local_groups(idx, expert_offset: int, experts_held: int,
+                 block_m: int):
+    """Lay the assignments that fall on this chip's experts
+    ``[expert_offset, expert_offset + experts_held)`` out for the
+    grouped GEMM, without dropping any: expert e's rows start at a
+    ``block_m`` multiple, in arrival order. Static shapes from the
+    worst case (every assignment local).
+
+    Returns ``dest`` [R, k] int32 (the layout row of each assignment;
+    ``rows_pad`` where it is another chip's), ``block_expert``
+    [rows_pad // block_m], ``blocks_used`` [1], ``counts``
+    [experts_held] (rows each held expert received) and ``rows_pad``."""
+    R, k = idx.shape
+    rows_pad = -(-(R * k + experts_held * (block_m - 1)) // block_m) \
+        * block_m
+    local = idx - expert_offset
+    here = (local >= 0) & (local < experts_held)
+    flat = jnp.where(here, local, experts_held).reshape(-1)     # [R*k]
+    onehot = (flat[:, None] == jnp.arange(experts_held)[None, :]) \
+        .astype(jnp.int32)
+    rank = jnp.sum((jnp.cumsum(onehot, axis=0) - 1) * onehot, -1)
+    counts = jnp.sum(onehot, axis=0)
+    padded = -(-counts // block_m) * block_m
+    ends = jnp.cumsum(padded)
+    starts = ends - padded
+    dest = jnp.where(here.reshape(-1),
+                     starts[jnp.minimum(flat, experts_held - 1)] + rank,
+                     rows_pad).reshape(R, k)
+    blocks_used = ends[-1:] // block_m
+    nb = rows_pad // block_m
+    # block b belongs to the first expert whose padded range ends past
+    # it; the unused tail repeats the last real block's expert
+    b = jnp.minimum(jnp.arange(nb), jnp.maximum(blocks_used[0] - 1, 0))
+    block_expert = jnp.minimum(
+        jnp.searchsorted(ends, b * block_m, side="right"),
+        experts_held - 1)
+    return dest, block_expert.astype(jnp.int32), \
+        blocks_used.astype(jnp.int32), counts, rows_pad
+
+
+def swiglu(x, w_gate_up, w_down):
+    """``(silu(x Wg) * (x Wu)) Wd`` with gate|up fused on the output
+    axis; bf16 (or whatever ``x`` is) between the products, float32
+    accumulation inside them."""
+    gu = jnp.dot(x, w_gate_up,
+                 preferred_element_type=jnp.float32).astype(x.dtype)
+    g, u = jnp.split(gu, 2, axis=-1)
+    h = (jax.nn.silu(g.astype(jnp.float32))
+         * u.astype(jnp.float32)).astype(x.dtype)
+    return jnp.dot(h, w_down, preferred_element_type=jnp.float32)
+
+
+def dropless_experts(m, idx, w, we_gate_up, we_down, expert_offset: int,
+                     block_m: int):
+    """This chip's part of ``sum_e w_e expert_e(m)``: group the local
+    assignments by expert, two grouped GEMMs (gate|up fused, then
+    down), gather each row's results back and weigh them in float32.
+    No capacity, nothing dropped; what the other chips' experts would
+    add is not here. Returns ([R, d] float32, counts [experts_held])."""
+    R, k = idx.shape
+    held = we_gate_up.shape[0]
+    dest, block_expert, used, counts, rows_pad = local_groups(
+        idx, expert_offset, held, block_m)
+    lhs = jnp.zeros((rows_pad, m.shape[-1]), m.dtype)
+    lhs = lhs.at[dest.reshape(-1)].set(jnp.repeat(m, k, axis=0),
+                                       mode="drop")
+
+    def product(x, weights):      # the whole of K a step (``gmm``)
+        return gmm(x, weights, block_expert, block_m=block_m,
+                   block_n=GMM_BLOCK_N, block_k=x.shape[-1],
+                   blocks_used=used)
+    gu = product(lhs, we_gate_up)
+    g, u = jnp.split(gu, 2, axis=-1)
+    h = (jax.nn.silu(g.astype(jnp.float32))
+         * u.astype(jnp.float32)).astype(m.dtype)
+    y = product(h, we_down)
+    here = dest < rows_pad
+    rows = y[jnp.minimum(dest, rows_pad - 1)].astype(jnp.float32)
+    # where, not a product with zero: rows past the used blocks hold
+    # whatever the buffer held
+    out = jnp.sum(jnp.where(here[..., None], rows * w[..., None], 0.0),
+                  axis=1)
+    return out, counts

@@ -87,7 +87,7 @@ from __future__ import annotations
 
 import hashlib
 from collections import OrderedDict
-from typing import Dict, List, Optional
+from typing import Dict, List, Optional, Tuple
 
 import jax.numpy as jnp
 import numpy as np
@@ -427,6 +427,25 @@ def _ragged_append_q(pool, scales, k, v, blk, off):
     return pool, scales
 
 
+def _visible(kpos, qpos, window):
+    """The attention mask every fallback path shares: key ``kpos`` is
+    visible to query ``qpos`` causally and, on a layer with a sliding
+    window W, iff 0 <= qpos - kpos < W."""
+    ok = kpos <= qpos
+    return ok if window is None else ok & (qpos - kpos < window)
+
+
+def _group_heads(k_full, v_full, q_heads: int):
+    """Gathered K/V [B, S, nkv, D] as the masked sdpa wants them: each
+    kv head repeated for the q heads that share it (nothing to do when
+    every query head has its own)."""
+    g = int(q_heads) // int(k_full.shape[2])
+    if g == 1:
+        return k_full, v_full
+    return (Tensor(jnp.repeat(k_full.data, g, axis=2)),
+            Tensor(jnp.repeat(v_full.data, g, axis=2)))
+
+
 def _block_copy(pool, src, dst):
     # copy-on-write split: pool[dst[i]] = pool[src[i]] (shared by the
     # payload pools AND, on quantized pools, the scale arrays — a COW
@@ -501,6 +520,16 @@ class PagedLayerCache:
     def shape(self):
         return self.pool.shape
 
+    @property
+    def window(self) -> Optional[int]:
+        """This layer's sliding window (None: a full layer)."""
+        return self._cache.layer_windows[self._layer]
+
+    def positions(self, t, rows: int):
+        """Absolute positions int32 [B, rows] of the call's query
+        rows: row b's i-th token sits at t[b] + i."""
+        return t.reshape(-1, 1) + jnp.arange(rows, dtype=jnp.int32)
+
     def decode(self, q, k, v, t, use_kernel: bool = False):
         """q/k/v: [B, L, H, D] Tensors (L == 1 is the plain decode
         step; L > 1 is the multi-query speculative-verification step —
@@ -518,6 +547,7 @@ class PagedLayerCache:
         bit-identical when page capacity == dense max_len."""
         import jax as _jax
         c = self._cache
+        W = self.window
         B, L = q.shape[0], q.shape[1]
         if B != c.max_seqs:
             raise ValueError(f"batch {B} != cache max_seqs {c.max_seqs}")
@@ -582,8 +612,8 @@ class PagedLayerCache:
                         from ..ops.pallas.paged_attention import \
                             paged_attention
                         return paged_attention(q_[:, 0], p, bta,
-                                               tv + 1,
-                                               kv_scales=sc)[:, None]
+                                               tv + 1, kv_scales=sc,
+                                               window=W)[:, None]
                     return apply(dec_q, (new_pool, new_sc, q, tt, bt),
                                  op_name="paged_attention_q")
 
@@ -591,7 +621,8 @@ class PagedLayerCache:
                     from ..ops.pallas.paged_attention import \
                         paged_attention_multi
                     return paged_attention_multi(q_, p, bta, tv + L,
-                                                 kv_scales=sc)
+                                                 kv_scales=sc,
+                                                 window=W)
                 return apply(dec_multi_q,
                              (new_pool, new_sc, q, tt, bt),
                              op_name="paged_attention_multi_q")
@@ -599,15 +630,16 @@ class PagedLayerCache:
                 def dec(p, q_, tv, bta):
                     from ..ops.pallas.paged_attention import \
                         paged_attention
-                    return paged_attention(q_[:, 0], p, bta,
-                                           tv + 1)[:, None]
+                    return paged_attention(q_[:, 0], p, bta, tv + 1,
+                                           window=W)[:, None]
                 return apply(dec, (new_pool, q, tt, bt),
                              op_name="paged_attention")
 
             def dec_multi(p, q_, tv, bta):
                 from ..ops.pallas.paged_attention import \
                     paged_attention_multi
-                return paged_attention_multi(q_, p, bta, tv + L)
+                return paged_attention_multi(q_, p, bta, tv + L,
+                                             window=W)
             return apply(dec_multi, (new_pool, q, tt, bt),
                          op_name="paged_attention_multi")
 
@@ -629,12 +661,13 @@ class PagedLayerCache:
             else (new_pool, bt, new_sc)
         k_full, v_full = apply(gather_pages, gargs,
                                op_name="paged_gather")
+        k_full, v_full = _group_heads(k_full, v_full, q.shape[2])
         S = k_full.shape[1]
         if L == 1:
             qpos = (t[:, None, None, None]
                     + jnp.arange(1)[None, None, :, None])
             kpos = jnp.arange(S)[None, None, None, :]
-            mask = Tensor(jnp.where(kpos <= qpos, 0.0, -1e30)
+            mask = Tensor(jnp.where(_visible(kpos, qpos, W), 0.0, -1e30)
                           .astype(jnp.float32))
             return F.scaled_dot_product_attention(q, k_full, v_full,
                                                   attn_mask=mask)
@@ -649,7 +682,7 @@ class PagedLayerCache:
                                           B))
         qpos = tf[:, None, None, None]
         kpos = jnp.arange(S)[None, None, None, :]
-        mask = Tensor(jnp.where(kpos <= qpos, 0.0, -1e30)
+        mask = Tensor(jnp.where(_visible(kpos, qpos, W), 0.0, -1e30)
                       .astype(jnp.float32))
         out = F.scaled_dot_product_attention(qf, kf, vf, attn_mask=mask)
         return apply(lambda a: a.reshape((B, L) + a.shape[2:]),
@@ -709,6 +742,16 @@ class PagedPrefillView:
     def shape(self):
         return self.pool.shape
 
+    @property
+    def window(self) -> Optional[int]:
+        """This layer's sliding window (None: a full layer)."""
+        return self._cache.layer_windows[self._layer]
+
+    def positions(self, t, rows: int):
+        """Absolute positions int32 [B, rows] of the call's query
+        rows: row b's i-th token sits at t[b] + i."""
+        return t.reshape(-1, 1) + jnp.arange(rows, dtype=jnp.int32)
+
     def decode(self, q, k, v, t, use_kernel: bool = False):
         """q/k/v: [1, C, H, D] — one prompt chunk for this view's
         slot, starting at absolute position t[0] (traced int32 [1]).
@@ -719,6 +762,7 @@ class PagedPrefillView:
         every write position covered and COW-split."""
         import jax as _jax
         c = self._cache
+        W = self.window
         B, C = q.shape[0], q.shape[1]
         if B != 1:
             raise ValueError(
@@ -759,14 +803,16 @@ class PagedPrefillView:
                     from ..ops.pallas.paged_attention import \
                         paged_attention_prefill
                     return paged_attention_prefill(q_, p, bta, tv,
-                                                   kv_scales=sc)
+                                                   kv_scales=sc,
+                                                   window=W)
                 return apply(att_q, (new_pool, new_sc, q, tt, bt),
                              op_name="paged_attention_prefill_q")
 
             def att(p, q_, tv, bta):
                 from ..ops.pallas.paged_attention import \
                     paged_attention_prefill
-                return paged_attention_prefill(q_, p, bta, tv)
+                return paged_attention_prefill(q_, p, bta, tv,
+                                               window=W)
             return apply(att, (new_pool, q, tt, bt),
                          op_name="paged_attention_prefill")
 
@@ -779,10 +825,11 @@ class PagedPrefillView:
             else (new_pool, bt, new_sc)
         k_full, v_full = apply(gather_pages, gargs,
                                op_name="paged_gather")
+        k_full, v_full = _group_heads(k_full, v_full, q.shape[2])
         S = k_full.shape[1]
         qpos = t[0] + jnp.arange(C)[:, None]
         kpos = jnp.arange(S)[None, :]
-        mask = Tensor(jnp.where(kpos <= qpos, 0.0, -1e30)
+        mask = Tensor(jnp.where(_visible(kpos, qpos, W), 0.0, -1e30)
                       .astype(jnp.float32))
         return F.scaled_dot_product_attention(q, k_full, v_full,
                                               attn_mask=mask)
@@ -810,7 +857,7 @@ class _RaggedLayout:
 
     __slots__ = ("segs", "q_lens", "blk", "off", "kv_lens", "bt_all",
                  "tile_q", "tile_kv", "total_rows", "blk_np",
-                 "off_np", "_cache")
+                 "off_np", "pos_np", "kv_lens_np", "_pos", "_cache")
 
     def __init__(self, cache: "PagedKVCache", segments, tile_q=None,
                  tile_kv=None):
@@ -827,6 +874,7 @@ class _RaggedLayout:
         bt_rows: List[np.ndarray] = []
         blk: List[np.ndarray] = []
         off: List[np.ndarray] = []
+        rowpos: List[np.ndarray] = []   # every packed row's position
         lo = 0
         for seg in segments:
             kind = seg[0]
@@ -839,6 +887,7 @@ class _RaggedLayout:
                 # shared (same rule as _make_append_chunk)
                 blk.append(np.where(pos >= write_start, b, 0))
                 off.append(pos % bs)
+                rowpos.append(pos)
                 q_lens.append(int(length))
                 kv_lens.append(int(start) + int(length))
                 bt_rows.append(tbl[slot])
@@ -863,6 +912,7 @@ class _RaggedLayout:
                                    np.minimum(lens // bs, cols - 1)]
                     blk.append(b)
                     off.append(lens % bs)
+                    rowpos.append(lens)
                 else:
                     # multi-query verify rows: slot b's L tokens land
                     # at positions lens[b] .. lens[b]+L-1 through the
@@ -873,6 +923,7 @@ class _RaggedLayout:
                                    np.minimum(pos // bs, cols - 1)]
                     blk.append(b.reshape(-1))
                     off.append((pos % bs).reshape(-1))
+                    rowpos.append(pos.reshape(-1))
                 q_lens.extend([L] * B)
                 kv_lens.extend((lens + L).tolist())
                 bt_rows.extend(masked_tbl)
@@ -889,6 +940,9 @@ class _RaggedLayout:
         # the padding never touches device data
         self.blk_np = np.concatenate(blk).astype(np.int32)
         self.off_np = np.concatenate(off).astype(np.int32)
+        self.pos_np = np.concatenate(rowpos).astype(np.int32)
+        self.kv_lens_np = np.asarray(kv_lens, np.int32)
+        self._pos = None
         self.blk = Tensor(jnp.asarray(self.blk_np))
         self.off = Tensor(jnp.asarray(self.off_np))
         self.kv_lens = Tensor(jnp.asarray(kv_lens, jnp.int32))
@@ -896,6 +950,28 @@ class _RaggedLayout:
         self.tile_q = tile_q
         self.tile_kv = tile_kv
         self._cache = cache
+
+    def positions(self):
+        """int32 [1, total_rows] on the device: every packed row's
+        absolute position (uploaded once a layout, on first use — a
+        model without a position encoding never asks)."""
+        if self._pos is None:
+            self._pos = jnp.asarray(self.pos_np[None])
+        return self._pos
+
+    def window_pages(self, window: int) -> Tuple[int, int]:
+        """(pages in the rows' contexts, pages of them wholly behind
+        ``window``), summed over the layout's sequences: what a
+        sliding layer's launch may skip. Host arithmetic over the
+        lengths, for the collector's ``paged_attn`` gauge."""
+        bs = self._cache.block_size
+        kv = self.kv_lens_np.astype(np.int64)
+        q = np.asarray(self.q_lens, np.int64)
+        first = kv - q                                   # first query
+        # a decode row at position 0 is a slot with nothing in it
+        live = (first > 0) | (q > 1)
+        return (int((-(-kv // bs))[live].sum()),
+                int((np.maximum(first - window + 1, 0) // bs)[live].sum()))
 
     def launch_plan(self):
         """The paged-attention launch this layout makes on the chip, a
@@ -909,7 +985,9 @@ class _RaggedLayout:
         tile_q = resolve_tile_q(self.q_lens, self.tile_q)
         return launch_plan(
             sum(-(-ql // tile_q) for ql in self.q_lens),
-            c.heads_per_shard, tile_q, c.max_blocks_per_seq,
+            c.kv_heads_per_shard,
+            tile_q * (c.num_heads // c.num_kv_heads),
+            c.max_blocks_per_seq,
             c.block_size, c.head_dim, c.pools[0].data.dtype.itemsize,
             quantized=c.quantized, tile_kv=self.tile_kv)
 
@@ -968,6 +1046,16 @@ class PagedRaggedView:
     def shape(self):
         return self.pool.shape
 
+    @property
+    def window(self) -> Optional[int]:
+        """This layer's sliding window (None: a full layer)."""
+        return self._cache.layer_windows[self._layer]
+
+    def positions(self, t, rows: int):
+        """Absolute positions int32 [1, R] of the packed rows, from
+        the layout (``t`` says nothing about a packed batch)."""
+        return self._layout.positions()
+
     def decode(self, q, k, v, t, use_kernel: bool = False):
         """q/k/v: [1, R, H, D] — the packed mixed batch. ``t`` is
         ignored: the layout carries every row's absolute position.
@@ -975,6 +1063,7 @@ class PagedRaggedView:
         COW-split (the scheduler's planning pass ensure()s chunk by
         chunk) and the decode mask is set."""
         c = self._cache
+        W = self.window
         lay = self._layout
         if q.shape[0] != 1 or q.shape[1] != lay.total_rows:
             raise ValueError(
@@ -1009,7 +1098,8 @@ class PagedRaggedView:
                         paged_attention_ragged
                     return paged_attention_ragged(
                         q_[0], p, bts, q_lens, kvl, tile_q=tile_q,
-                        tile_kv=tile_kv, kv_scales=sc)[None]
+                        tile_kv=tile_kv, kv_scales=sc,
+                        window=W)[None]
                 return apply(att_q, (new_pool, new_sc, q, lay.kv_lens,
                                      lay.bt_all),
                              op_name="paged_attention_ragged_q")
@@ -1019,7 +1109,7 @@ class PagedRaggedView:
                     paged_attention_ragged
                 return paged_attention_ragged(
                     q_[0], p, bts, q_lens, kvl, tile_q=tile_q,
-                    tile_kv=tile_kv)[None]
+                    tile_kv=tile_kv, window=W)[None]
             return apply(att, (new_pool, q, lay.kv_lens, lay.bt_all),
                          op_name="paged_attention_ragged")
 
@@ -1039,10 +1129,12 @@ class PagedRaggedView:
                     else (new_pool, bt, new_sc)
                 k_full, v_full = apply(gather_pages, gargs,
                                        op_name="paged_gather")
+                k_full, v_full = _group_heads(k_full, v_full,
+                                              q.shape[2])
                 S = k_full.shape[1]
                 qpos = start + jnp.arange(C)[:, None]
                 kpos = jnp.arange(S)[None, :]
-                mask = Tensor(jnp.where(kpos <= qpos, 0.0, -1e30)
+                mask = Tensor(jnp.where(_visible(kpos, qpos, W), 0.0, -1e30)
                               .astype(jnp.float32))
                 out = F.scaled_dot_product_attention(
                     qs, k_full, v_full, attn_mask=mask)
@@ -1054,6 +1146,8 @@ class PagedRaggedView:
                     else (new_pool, bt, new_sc)
                 k_full, v_full = apply(gather_pages, gargs,
                                        op_name="paged_gather")
+                k_full, v_full = _group_heads(k_full, v_full,
+                                              q.shape[2])
                 S = k_full.shape[1]
                 if L == 1:
                     B = hi - lo
@@ -1062,7 +1156,7 @@ class PagedRaggedView:
                     qpos = (tj[:, None, None, None]
                             + jnp.arange(1)[None, None, :, None])
                     kpos = jnp.arange(S)[None, None, None, :]
-                    mask = Tensor(jnp.where(kpos <= qpos, 0.0, -1e30)
+                    mask = Tensor(jnp.where(_visible(kpos, qpos, W), 0.0, -1e30)
                                   .astype(jnp.float32))
                     out = F.scaled_dot_product_attention(
                         qd, k_full, v_full, attn_mask=mask)
@@ -1083,7 +1177,7 @@ class PagedRaggedView:
                                      B))
                     qpos = tf[:, None, None, None]
                     kpos = jnp.arange(S)[None, None, None, :]
-                    mask = Tensor(jnp.where(kpos <= qpos, 0.0, -1e30)
+                    mask = Tensor(jnp.where(_visible(kpos, qpos, W), 0.0, -1e30)
                                   .astype(jnp.float32))
                     out = F.scaled_dot_product_attention(
                         qd, kf, vf, attn_mask=mask)
@@ -1101,10 +1195,36 @@ class PagedKVCache:
                  block_size: int, num_blocks: int, max_seqs: int,
                  max_blocks_per_seq: Optional[int] = None,
                  dtype: str = "float32", prefix_cache: bool = False,
-                 mp: int = 1, shard_devices=None):
+                 mp: int = 1, shard_devices=None,
+                 num_kv_heads: Optional[int] = None,
+                 layer_windows=None):
         import paddle_tpu as paddle
         self.num_layers = int(num_layers)
         self.num_heads = int(num_heads)
+        # GROUPED KV HEADS: the pool stores ``num_kv_heads`` heads a
+        # position (== num_heads unless the model shares each kv head
+        # among num_heads / num_kv_heads query heads). Pools, scales,
+        # append ops, the byte model and the snapshot / slice payloads
+        # size by it; ``num_heads`` stays the query width the views
+        # check incoming q against.
+        self.num_kv_heads = int(num_heads if num_kv_heads is None
+                                else num_kv_heads)
+        if self.num_heads % self.num_kv_heads:
+            raise ValueError(
+                f"num_heads {self.num_heads} is not a multiple of "
+                f"num_kv_heads {self.num_kv_heads}")
+        # SLIDING WINDOWS: ``layer_windows[i]`` is layer i's window W
+        # (query i sees key j iff 0 <= i - j < W) or None for a full
+        # layer. Every view of a layer masks by it (kernel and
+        # fallbacks alike). One block table per slot still serves all
+        # layers: pages behind a window are skipped, not freed.
+        self.layer_windows = tuple(
+            None if w is None else int(w)
+            for w in (layer_windows or (None,) * self.num_layers))
+        if len(self.layer_windows) != self.num_layers:
+            raise ValueError(
+                f"layer_windows has {len(self.layer_windows)} entries "
+                f"for {self.num_layers} layers")
         self.head_dim = int(head_dim)
         self.block_size = int(block_size)
         self.num_blocks = int(num_blocks)
@@ -1129,9 +1249,10 @@ class PagedKVCache:
         self.mp = int(mp)
         if self.mp < 1:
             raise ValueError(f"mp must be >= 1, got {mp}")
-        if self.num_heads % self.mp:
+        if self.num_heads % self.mp or self.num_kv_heads % self.mp:
             raise ValueError(
-                f"num_heads {self.num_heads} must divide evenly over "
+                f"num_heads {self.num_heads} / num_kv_heads "
+                f"{self.num_kv_heads} must divide evenly over "
                 f"mp={self.mp} tensor-parallel shards")
         if self.mp > 1 and shard_devices is None:
             from ..parallel.mesh import serving_shard_devices
@@ -1186,7 +1307,7 @@ class PagedKVCache:
         # keeps every uniform whole-pool pass (COW copy, snapshot
         # pull, deep-audit fingerprint) working unchanged over all
         # layer x shard entries.
-        Hs = self.heads_per_shard
+        Hs = self.kv_heads_per_shard
         self.pools: List[Tensor] = [
             self._place(paddle.zeros(
                 [self.num_blocks, 2, Hs, self.block_size,
@@ -1246,7 +1367,9 @@ class PagedKVCache:
                    max_blocks_per_seq=max_blocks_per_seq, dtype=dtype,
                    prefix_cache=prefix_cache,
                    mp=getattr(model, "mp", 1),
-                   shard_devices=getattr(model, "shard_devices", None))
+                   shard_devices=getattr(model, "shard_devices", None),
+                   num_kv_heads=getattr(model, "num_kv_heads", None),
+                   layer_windows=getattr(model, "layer_windows", None))
 
     def _place(self, t: Tensor, pi: int) -> Tensor:
         """Commit a pool/scale entry to its shard's device (mp > 1);
@@ -1261,9 +1384,14 @@ class PagedKVCache:
     # -- geometry -----------------------------------------------------
     @property
     def heads_per_shard(self) -> int:
-        """Attention heads each mp shard stores (== num_heads at
-        mp 1); shard s holds heads [s*H/mp, (s+1)*H/mp)."""
+        """Query heads each mp shard drives (== num_heads at mp 1);
+        shard s holds heads [s*H/mp, (s+1)*H/mp)."""
         return self.num_heads // self.mp
+
+    @property
+    def kv_heads_per_shard(self) -> int:
+        """K/V heads each mp shard's pool stores."""
+        return self.num_kv_heads // self.mp
 
     def pool_index(self, layer: int, shard: int = 0) -> int:
         """Index of (layer, shard)'s entry in the flat ``pools`` /
@@ -1333,7 +1461,7 @@ class PagedKVCache:
 
     def kv_bytes_per_token(self) -> int:
         """PER-SHARD HBM bytes one token's K/V occupies across every
-        layer (2 x heads/mp x (head_dim x payload itemsize + scale
+        layer (2 x kv heads/mp x (head_dim x payload itemsize + scale
         bytes) x layers) — the KV-traffic unit of the analytic work
         model (inference/accounting.py), per DEVICE: each shard reads
         and writes only its own head slice, so MBU paired against one
@@ -1343,7 +1471,7 @@ class PagedKVCache:
         per_head = self.head_dim * self.pools[0].data.dtype.itemsize
         if self.quantized:
             per_head += self.scales[0].data.dtype.itemsize
-        return int(2 * self.heads_per_shard * per_head
+        return int(2 * self.kv_heads_per_shard * per_head
                    * self.num_layers)
 
     # -- tenant accounting --------------------------------------------
@@ -1626,6 +1754,8 @@ class PagedKVCache:
         geometry = {
             "num_layers": self.num_layers,
             "num_heads": self.num_heads,
+            "num_kv_heads": self.num_kv_heads,
+            "layer_windows": list(self.layer_windows),
             "head_dim": self.head_dim,
             "block_size": self.block_size,
             "num_blocks": self.num_blocks,
@@ -1669,7 +1799,7 @@ class PagedKVCache:
             payload = np.stack([arr[dirty] for arr in arrs],
                                axis=1)                 # [n, L, 2, H, bs, D]
         else:
-            payload = np.zeros((0, self.num_layers, 2, self.num_heads,
+            payload = np.zeros((0, self.num_layers, 2, self.num_kv_heads,
                                 self.block_size, self.head_dim),
                                arrs[0].dtype)
         scale_payload = None
@@ -1689,7 +1819,7 @@ class PagedKVCache:
                                          axis=1)   # [n, L, 2, H, bs]
             else:
                 scale_payload = np.zeros(
-                    (0, self.num_layers, 2, self.num_heads,
+                    (0, self.num_layers, 2, self.num_kv_heads,
                      self.block_size), np.float32)
         return {
             "kind": "paged_kv_cache",
@@ -1761,7 +1891,9 @@ class PagedKVCache:
                     g["block_size"], nb, g["max_seqs"],
                     max_blocks_per_seq=g["max_blocks_per_seq"],
                     dtype=g["dtype"], prefix_cache=g["prefix_cache"],
-                    mp=mp_t, shard_devices=shard_devices)
+                    mp=mp_t, shard_devices=shard_devices,
+                    num_kv_heads=g.get("num_kv_heads"),
+                    layer_windows=g.get("layer_windows"))
         refcount = {int(b): int(n) for b, n in snap["refcount"].items()}
         cached = [int(b) for b in snap["cached_order"]]
         live = sorted(b for b, n in refcount.items() if n > 0)
@@ -1823,7 +1955,7 @@ class PagedKVCache:
             ids = jnp.asarray([remap[int(snap["blocks"][i])]
                                for i in rows], jnp.int32)
             payload = payload[rows]
-            Hs = cache.heads_per_shard
+            Hs = cache.kv_heads_per_shard
             for i in range(cache.num_layers):
                 for s in range(cache.mp):
                     # each target shard takes its head slice of the
@@ -2180,6 +2312,7 @@ class PagedKVCache:
             "geometry": {
                 "num_layers": self.num_layers,
                 "num_heads": self.num_heads,
+                "num_kv_heads": self.num_kv_heads,
                 "head_dim": self.head_dim,
                 "block_size": self.block_size,
                 "dtype": self.dtype,
@@ -2235,8 +2368,12 @@ class PagedKVCache:
         g = slc["geometry"]
         mine = {"num_layers": self.num_layers,
                 "num_heads": self.num_heads,
+                "num_kv_heads": self.num_kv_heads,
                 "head_dim": self.head_dim,
                 "block_size": self.block_size, "dtype": self.dtype}
+        # slices written before grouped kv heads carry no such key:
+        # their pages hold num_heads heads
+        g = dict(g, num_kv_heads=g.get("num_kv_heads", g.get("num_heads")))
         if {k: g.get(k) for k in mine} != mine:
             raise ValueError(
                 f"kv_slice geometry {g} does not match pool {mine}")
@@ -2263,7 +2400,7 @@ class PagedKVCache:
             return 0
         ids = jnp.asarray([b for b, _ in landing], jnp.int32)
         rows = [i for _, i in landing]
-        Hs = self.heads_per_shard
+        Hs = self.kv_heads_per_shard
         for li in range(self.num_layers):
             # ONE fancy-index gather of the layer's canonical
             # full-head pages; each local shard lands a view-slice of

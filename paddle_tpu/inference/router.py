@@ -123,6 +123,11 @@ def build_model_from_spec(spec: dict):
     from ..incubate.nn import FusedMultiTransformer
     from .speculative import TokenServingModel
 
+    arch = spec.get("arch", "gpt3")
+    if arch == "afmoe":
+        return _build_decoder_model(spec)
+    if arch != "gpt3":
+        raise ValueError(f"unknown arch {arch!r} (gpt3 | afmoe)")
     paddle.seed(int(spec.get("model_seed", 0)))
     core = FusedMultiTransformer(
         int(spec.get("d_model", 32)), int(spec.get("heads", 4)),
@@ -144,11 +149,60 @@ def build_model_from_spec(spec: dict):
     return tsm.shard(mp) if mp > 1 else tsm
 
 
+def _build_decoder_model(spec: dict):
+    """``arch: "afmoe"``: the config-driven ``DecoderCore``
+    (inference/decoder.py) behind a ``TokenServingModel`` with a final
+    RMSNorm, an UNTIED head and the embedding multiplier. Core, head
+    and final gains are drawn on the device from ``model_seed`` in the
+    stored ``weight_dtype``; the embedding table stays on the host
+    (``embed_seed``), at the scale that makes the multiplied rows unit
+    variance."""
+    import jax
+    import jax.numpy as jnp
+    from ..framework.tensor import Tensor
+    from .decoder import DecoderConfig, DecoderCore
+    from .speculative import TokenServingModel
+
+    if int(spec.get("mp", 1)) != 1:
+        raise ValueError("arch 'afmoe' serves on one chip (mp 1): its "
+                         "deployment splits experts, not heads")
+    cfg = DecoderConfig.from_spec(spec)
+    seed = int(spec.get("model_seed", 0))
+    core = DecoderCore(cfg, seed=seed)
+    d, vocab = cfg.hidden_size, int(spec["vocab_size"])
+    k_head, k_norm = jax.random.split(jax.random.PRNGKey(seed ^ 0x5EED))
+    head = (jax.random.normal(k_head, (d, vocab), jnp.float32)
+            / np.sqrt(d)).astype(jnp.dtype(cfg.weight_dtype))
+    gain = 1.0 + 0.1 * jax.random.normal(k_norm, (d,), jnp.float32)
+    embed = np.random.RandomState(
+        int(spec.get("embed_seed", 1234))).standard_normal(
+            (vocab, d)).astype(np.float32)
+    embed *= np.float32(1.0 / cfg.input_scale)
+    return TokenServingModel(
+        core, embed, lm_head=Tensor(head), weight_dtype=cfg.weight_dtype,
+        final_norm=gain, norm_eps=cfg.rms_norm_eps,
+        input_scale=cfg.input_scale)
+
+
 def build_server_from_spec(spec: dict) -> RecoverableServer:
     """Construct a worker's ``RecoverableServer`` from a PICKLABLE,
     data-only spec — the one constructor both transports share, so a
     spawned child process builds bit-identical weights from the same
     seeds the parent (or a single-engine baseline) uses.
+
+    ``arch`` (``"gpt3"``) picks the architecture. ``"afmoe"`` builds the
+    config-driven decoder core (inference/decoder.py) and takes the
+    published ``config.json`` keys instead of the GPT-3 dims below:
+    ``hidden_size``, ``num_attention_heads``, ``num_key_value_heads``,
+    ``head_dim``, ``layer_types`` (one entry a layer: its length is the
+    depth), ``sliding_window``, ``num_dense_layers``,
+    ``intermediate_size``, ``num_experts``, ``num_experts_per_tok``,
+    ``num_shared_experts``, ``moe_intermediate_size``, ``route_norm``,
+    ``route_scale``, ``rope_theta``, ``rms_norm_eps``, ``mup_enabled``,
+    ``vocab_size``; ``experts_held`` / ``expert_offset`` (which of the
+    ``num_experts`` this chip holds: it routes over all of them and
+    computes its own experts' part); ``weight_dtype`` (``"bfloat16"``
+    or ``"float32"``). Seeds and every engine and host knob are shared.
 
     Keys (defaults in parens): model dims ``d_model`` (32), ``heads``
     (4), ``ffn`` (64), ``layers`` (2), ``vocab`` (50), seeds

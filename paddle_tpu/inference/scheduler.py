@@ -829,6 +829,24 @@ class PagedServingEngine:
             for tid, t in self.tenants.items()}
 
     # -- admission ----------------------------------------------------
+    @property
+    def collector(self):
+        """The installed ``TraceCollector`` or None. A model core that
+        records spans of its own (``DecoderCore``: ``moe``) is handed
+        whatever is installed here, whoever installs it."""
+        return self._collector
+
+    @collector.setter
+    def collector(self, col) -> None:
+        self._collector = col
+        if hasattr(self.model, "collector"):
+            self.model.collector = col
+        if col is not None and col.registry is not None and \
+                hasattr(self.model, "moe_metrics"):
+            # the expert layer's device-side counters, scraped cold when
+            # the collector is dumped (never on the hot path)
+            col.registry.attach("moe", self.model.moe_metrics)
+
     def submit(self, prompt, *, max_preemptions: Optional[int] = None,
                deadline_steps: Optional[int] = None,
                deadline_s: Optional[float] = None,
@@ -1472,10 +1490,18 @@ class PagedServingEngine:
             if col is not None:
                 # what each layer's launch will cost the kernel's grid
                 plan = views[0]._layout.launch_plan()
-                col.gauge("paged_attn", {
-                    "grid_steps": plan.grid_steps,
-                    "pages_per_step": plan.pages,
-                    "heads_per_step": plan.heads})
+                series = {"grid_steps": plan.grid_steps,
+                          "pages_per_step": plan.pages,
+                          "heads_per_step": plan.heads}
+                windows = [w for w in self.cache.layer_windows if w]
+                if windows:
+                    # what a sliding layer's launch may skip, from the
+                    # layout's lengths (one figure a step: the
+                    # sliding layers share a window)
+                    (series["pages_in_context"],
+                     series["pages_behind_window"]) = \
+                        views[0]._layout.window_pages(min(windows))
+                col.gauge("paged_attn", series)
         finally:
             if col is not None:
                 col.span_end()

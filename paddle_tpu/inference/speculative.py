@@ -104,6 +104,27 @@ def logit_mask_fn(name: str):
             f"before submit") from None
 
 
+def _readout(hidden, head, *gain, eps):
+    import jax
+    import jax.numpy as jnp
+    h = hidden.astype(jnp.float32)
+    if gain:
+        h = h * jax.lax.rsqrt(jnp.mean(h * h, -1, keepdims=True) + eps) \
+            * gain[0]
+    return jnp.dot(h.astype(head.dtype), head,
+                   preferred_element_type=jnp.float32)
+
+
+def apply_readout(hidden, head, gain, eps) -> Tensor:
+    """``RMSNorm(hidden) @ head`` (the norm only where ``gain`` is
+    given): statistics in float32, the product in the head's stored
+    type with float32 accumulation, logits float32."""
+    from ..framework.op import apply
+    args = (hidden, head) + (() if gain is None else (gain,))
+    return apply(_readout, args, {"eps": float(eps)},
+                 differentiable=False, op_name="readout")
+
+
 class TokenServingModel:
     """Token-ID serving surface over a FusedMultiTransformer-protocol
     core: owns the embedding table ([vocab, d_model]) and the readout
@@ -115,9 +136,18 @@ class TokenServingModel:
     host."""
 
     def __init__(self, model, embedding, lm_head=None,
-                 weight_dtype: str = "float32"):
+                 weight_dtype: str = "float32", final_norm=None,
+                 norm_eps: float = 1e-5, input_scale: float = 1.0):
         import jax.numpy as jnp
         self.core = model
+        # ``input_scale`` multiplies the looked-up rows (an embedding
+        # multiplier such as sqrt(d_model)); ``final_norm`` ([d_model]
+        # gains) puts an RMSNorm before the readout. Both default to
+        # what the GPT-3 block serves with: neither.
+        self.input_scale = float(input_scale)
+        self.final_norm = None if final_norm is None else \
+            jnp.asarray(final_norm, jnp.float32)
+        self.norm_eps = float(norm_eps)
         emb = np.asarray(embedding.numpy() if hasattr(embedding, "numpy")
                          else embedding, np.float32)
         if emb.ndim != 2:
@@ -149,10 +179,15 @@ class TokenServingModel:
         # multiply folds into the readout epilogue — ~2x weight HBM
         # vs bf16 (4x vs f32) on the weight-bound decode readout.
         # Off by default: float32 readout is bit-identical to before.
-        if weight_dtype not in ("float32", "int8"):
+        if weight_dtype not in ("float32", "int8", "bfloat16"):
             raise ValueError(f"unsupported weight_dtype "
-                             f"{weight_dtype!r} (float32 | int8)")
+                             f"{weight_dtype!r} (float32 | int8 | "
+                             f"bfloat16)")
         self.weight_dtype = weight_dtype
+        if weight_dtype == "bfloat16" and \
+                self.lm_head.data.dtype != jnp.bfloat16:
+            # stored bf16, multiplied with float32 accumulation
+            self.lm_head = Tensor(self.lm_head.data.astype(jnp.bfloat16))
         self._head_int8: Optional[Tensor] = None
         self._head_scale: Optional[Tensor] = None
         if weight_dtype == "int8":
@@ -173,7 +208,8 @@ class TokenServingModel:
         if self._head_int8 is not None:
             return (int(np.prod(self._head_int8.shape))
                     + 4 * int(self._head_scale.shape[0]))
-        return int(np.prod(self.lm_head.shape)) * 4
+        return int(np.prod(self.lm_head.shape)) \
+            * self.lm_head.data.dtype.itemsize
 
     @property
     def vocab_size(self) -> int:
@@ -190,7 +226,10 @@ class TokenServingModel:
         ids = np.asarray(token_ids, np.int64)
         if ids.size and (ids.min() < 0 or ids.max() >= self.vocab_size):
             raise ValueError("token id out of range")
-        return self._embed_np[ids]
+        rows = self._embed_np[ids]       # a copy: fancy indexing
+        if self.input_scale != 1.0:
+            rows *= np.float32(self.input_scale)   # in place: one pass
+        return rows
 
     def logits(self, hidden) -> Tensor:
         """hidden [..., d_model] Tensor -> logits [..., vocab] Tensor
@@ -198,6 +237,9 @@ class TokenServingModel:
         quantized head and folds the per-channel scale into the
         epilogue — see __init__)."""
         import paddle_tpu as paddle
+        if self.final_norm is not None or self.weight_dtype == "bfloat16":
+            return apply_readout(hidden, self.lm_head, self.final_norm,
+                                 self.norm_eps)
         if self._head_int8 is None:
             return paddle.matmul(hidden, self.lm_head)
         # weight-only int8 GEMM: the w8a16 Pallas kernel behind the
@@ -332,7 +374,8 @@ class TokenServingModel:
                                compiled_step=compiled_step,
                                out_shard=out_shard),
             self._embed_np, self.lm_head,
-            weight_dtype=self.weight_dtype)
+            weight_dtype=self.weight_dtype, final_norm=self.final_norm,
+            norm_eps=self.norm_eps, input_scale=self.input_scale)
 
     # -- draft construction -------------------------------------------
     def truncated_draft(self, num_layers: int) -> "TokenServingModel":

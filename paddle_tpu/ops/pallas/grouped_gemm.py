@@ -30,92 +30,90 @@ from jax.experimental.pallas import tpu as pltpu
 from ...framework.device import on_tpu
 
 
-def _gmm_kernel(block_expert_ref, lhs_ref, rhs_ref, out_ref, acc_ref, *,
-                k_steps):
-    k_i = pl.program_id(2)
+def _gmm_kernel(block_expert_ref, used_ref, lhs_ref, rhs_ref, out_ref,
+                acc_ref, *, k_steps):
+    del block_expert_ref                      # feeds the index maps only
+    m, k_i = pl.program_id(1), pl.program_id(2)
 
-    @pl.when(k_i == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
+    @pl.when(m < used_ref[0])
+    def _product():
+        @pl.when(k_i == 0)
+        def _init():
+            acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    acc_ref[...] += jax.lax.dot_general(
-        lhs_ref[...], rhs_ref[0],
-        (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
+        acc_ref[...] += jax.lax.dot_general(
+            lhs_ref[...], rhs_ref[0],
+            (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
 
-    @pl.when(k_i == k_steps - 1)
-    def _done():
-        out_ref[...] = acc_ref[...].astype(out_ref.dtype)
+        @pl.when(k_i == k_steps - 1)
+        def _done():
+            out_ref[...] = acc_ref[...].astype(out_ref.dtype)
 
 
-def gmm(lhs, rhs, block_expert, block_m=128, block_n=128, block_k=128):
+def gmm(lhs, rhs, block_expert, block_m=128, block_n=128, block_k=128,
+        blocks_used=None):
     """lhs: [M, K] tokens grouped by expert and padded so each block_m
     rows share one expert. rhs: [E, K, N] expert weights. block_expert:
-    int32 [M // block_m] expert id per m-block. Returns [M, N]."""
+    int32 [M // block_m] expert id per m-block. Returns [M, N]
+    (float32 accumulation, ``lhs.dtype`` out).
+
+    ``blocks_used`` (int32 [1], may be traced) is for a serving step
+    whose row count per expert is known only on the device: ``lhs`` is
+    then laid out for the WORST case, only its first ``blocks_used``
+    m-blocks hold rows, and ``block_expert`` repeats the last real
+    block's expert past them. The unused tail costs no copy and no
+    product (its index maps name the blocks the pipeline already holds)
+    and its rows come back unwritten, whatever the buffer held.
+
+    The grid is (N / block_n, M / block_m, K / block_k): with the
+    n-blocks outermost and ``block_k`` the whole of K, consecutive
+    m-blocks of one expert name the same weight block, so every expert
+    that holds a row has its weights read once a launch and an expert
+    without rows never; ``lhs`` is read once an n-block."""
     M, K = lhs.shape
     E, K2, N = rhs.shape
-    assert K == K2 and M % block_m == 0
+    assert K == K2 and M % block_m == 0, (lhs.shape, rhs.shape, block_m)
     block_n = min(block_n, N)
     block_k = min(block_k, K)
     while N % block_n:
         block_n //= 2
     while K % block_k:
         block_k //= 2
-    grid = (M // block_m, N // block_n, K // block_k)
-    k_steps = grid[2]
+    m_blocks, k_steps = M // block_m, K // block_k
+    if blocks_used is None:
+        blocks_used = m_blocks
+    used = jnp.asarray(blocks_used, jnp.int32).reshape(1)
 
-    kernel = functools.partial(_gmm_kernel, k_steps=k_steps)
-    # PrefetchScalarGridSpec passes scalar refs AFTER the grid indices
-    lhs_spec = pl.BlockSpec((block_m, block_k), lambda m, n, k, be: (m, k))
-    rhs_spec = pl.BlockSpec(
-        (1, block_k, block_n), lambda m, n, k, be: (be[m], k, n))
-    out_spec = pl.BlockSpec((block_m, block_n),
-                            lambda m, n, k, be: (m, n))
-    out_shape = jax.ShapeDtypeStruct((M, N), lhs.dtype)
+    # PrefetchScalarGridSpec passes scalar refs AFTER the grid indices.
+    # Past the used blocks every map holds the last block it loaded.
+    def live_m(m, used_):
+        return jnp.minimum(m, jnp.maximum(used_[0] - 1, 0))
 
-    if not on_tpu():
-        # the CPU branch (written when interpret mode had no scalar
-        # prefetch; a different kernel body from _gmm_kernel): emulate
-        # the block->expert indirection by pre-gathering rhs per
-        # m-block (test path only; jnp gather keeps this traceable
-        # under jit)
-        rhs_g = rhs[jnp.asarray(block_expert)]  # [M/bm, K, N]
-        def kern(l_ref, r_ref, o_ref, acc_ref, *, k_steps):
-            k_i = pl.program_id(2)
-            @pl.when(k_i == 0)
-            def _init():
-                acc_ref[...] = jnp.zeros_like(acc_ref)
-            acc_ref[...] += jax.lax.dot_general(
-                l_ref[...], r_ref[0],
-                (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
-            @pl.when(k_i == k_steps - 1)
-            def _done():
-                o_ref[...] = acc_ref[...].astype(o_ref.dtype)
-        return pl.pallas_call(
-            functools.partial(kern, k_steps=k_steps),
-            grid=grid,
-            in_specs=[pl.BlockSpec((block_m, block_k),
-                                   lambda m, n, k: (m, k)),
-                      pl.BlockSpec((1, block_k, block_n),
-                                   lambda m, n, k: (m, k, n))],
-            out_specs=pl.BlockSpec((block_m, block_n),
-                                   lambda m, n, k: (m, n)),
-            out_shape=out_shape,
-            scratch_shapes=[pltpu.VMEM((block_m, block_n), jnp.float32)],
-            interpret=True,
-        )(lhs, rhs_g)
+    def live_k(m, k, used_):
+        return jnp.where(m < used_[0], k, k_steps - 1)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=grid,
-        in_specs=[lhs_spec, rhs_spec],
-        out_specs=out_spec,
+        num_scalar_prefetch=2,
+        grid=(N // block_n, m_blocks, k_steps),
+        in_specs=[
+            pl.BlockSpec((block_m, block_k),
+                         lambda n, m, k, be, u: (live_m(m, u),
+                                                 live_k(m, k, u))),
+            pl.BlockSpec((1, block_k, block_n),
+                         lambda n, m, k, be, u: (be[m], live_k(m, k, u),
+                                                 n))],
+        out_specs=pl.BlockSpec((block_m, block_n),
+                               lambda n, m, k, be, u: (m, n)),
         scratch_shapes=[pltpu.VMEM((block_m, block_n), jnp.float32)],
     )
-    return pl.pallas_call(kernel, grid_spec=grid_spec,
-                          out_shape=out_shape)(
-        jnp.asarray(block_expert, jnp.int32), lhs, rhs)
+    return pl.pallas_call(
+        functools.partial(_gmm_kernel, k_steps=k_steps),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((M, N), lhs.dtype),
+        interpret=not on_tpu(),    # off the chip: the same body, interpreted
+        name="gmm",
+    )(jnp.asarray(block_expert, jnp.int32), used, lhs, rhs)
 
 
 def make_group_metadata(group_sizes, block_m=128):

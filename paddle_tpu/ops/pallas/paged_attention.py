@@ -246,7 +246,7 @@ def _heads_dot(a, b, b_axis: int):
 
 def _ragged_body(pos0, pos_last, k, v, q_ref, o_ref, m_scr, l_scr,
                  acc_scr, *, block_s, n_blocks, sm_scale, tile_q, g,
-                 k_scale=None, v_scale=None):
+                 window=None, k_scale=None, v_scale=None):
     """Online-softmax update for one (tile x head-group, kv-step) grid
     step — THE paged-attention body, shared by every phase. ``pos0``
     is this tile's first query's absolute position and ``pos_last``
@@ -273,7 +273,13 @@ def _ragged_body(pos0, pos_last, k, v, q_ref, o_ref, m_scr, l_scr,
     Dequantization folds into the two products — q.(s_k k)^T =
     (q.k^T) s_k and p.(s_v v) = (p s_v).v — so the scales multiply the
     [Hb, rows, block_s] score/probability tiles along their lane axis
-    and never have to be turned into a column."""
+    and never have to be turned into a column.
+    ``window`` (a sliding layer): key kpos is visible to query qpos
+    iff 0 <= qpos - kpos < window, so beside the causal frontier
+    there is a LOWER one: a kv step whose last position lies behind
+    the tile's first query's window (pos0 - window + 1) is behind
+    every row's and is skipped the same way; the mask inside the
+    boundary steps is per row."""
     j = pl.program_id(1)
 
     @pl.when(j == 0)
@@ -284,7 +290,11 @@ def _ragged_body(pos0, pos_last, k, v, q_ref, o_ref, m_scr, l_scr,
 
     q = q_ref[...].astype(jnp.float32)          # [Hb, tile_q * g, hd]
 
-    @pl.when(j * block_s <= pos_last)
+    live = j * block_s <= pos_last
+    if window is not None:
+        live = live & ((j + 1) * block_s > pos0 - window + 1)
+
+    @pl.when(live)
     def _update():
         scores = _heads_dot(q, k, 1) * sm_scale
         if k_scale is not None:
@@ -294,6 +304,8 @@ def _ragged_body(pos0, pos_last, k, v, q_ref, o_ref, m_scr, l_scr,
         qpos = pos0 + jax.lax.broadcasted_iota(
             jnp.int32, scores.shape, scores.ndim - 2) // g
         valid = kpos <= qpos                    # implies kpos < kv_len
+        if window is not None:
+            valid = valid & (qpos - kpos < window)
         scores = jnp.where(valid, scores, NEG_INF)
 
         m_prev = m_scr[...]                     # [Hb, tile_q * g, 1]
@@ -392,7 +404,7 @@ def _tile_layout(q_lens, tile_q):
 
 def paged_attention_ragged(q, kv_pool, block_tables, q_lens, kv_lens,
                            sm_scale=None, tile_q=None, tile_kv=None,
-                           kv_scales=None):
+                           kv_scales=None, window=None):
     """THE kernel: one launch scores a mixed prefill+decode+verify
     batch. q: [R, nh, hd] — every sequence's query rows packed
     back-to-back (R == sum(q_lens)). q_lens: STATIC per-sequence query
@@ -409,6 +421,10 @@ def paged_attention_ragged(q, kv_pool, block_tables, q_lens, kv_lens,
     ``kv_scales``: per-page dequantization scales
     [num_blocks, 2, nkv, block_size] for an int8 ``kv_pool`` (None =
     the pool holds real values) — see the module docstring.
+    ``window`` (static int or None): a sliding layer — query at
+    position i sees key j iff 0 <= i - j < window; pages wholly behind
+    a tile's first query's window are skipped like pages past the
+    causal frontier (no copy issued on the chip).
     Returns [R, nh, hd] in packed order."""
     q_lens = tuple(int(x) for x in q_lens)
     R, nh, hd = q.shape
@@ -430,7 +446,7 @@ def paged_attention_ragged(q, kv_pool, block_tables, q_lens, kv_lens,
         _DISPATCH["count"] += 1
         return paged_attention_ragged_reference(
             q, kv_pool, block_tables, q_lens, kv_lens,
-            sm_scale=sm_scale, kv_scales=kv_scales)
+            sm_scale=sm_scale, kv_scales=kv_scales, window=window)
     _DISPATCH["count"] += 1
     nkv, block_s = kv_pool.shape[2], kv_pool.shape[3]
     MB = block_tables.shape[1]
@@ -506,7 +522,7 @@ def paged_attention_ragged(q, kv_pool, block_tables, q_lens, kv_lens,
             T * nkv, MBp, 2, block_s, hd)
         pos_r = jnp.repeat(pos, nkv, axis=0)        # [T * nkv, 2]
         kw = dict(block_s=block_s * tkv, n_blocks=n_kv_steps,
-                  sm_scale=scale, tile_q=tile_q, g=g)
+                  sm_scale=scale, tile_q=tile_q, g=g, window=window)
         out = pl.pallas_call(
             functools.partial(_kernel_ragged_interpret, tile_kv=tkv,
                               **kw),
@@ -538,7 +554,7 @@ def paged_attention_ragged(q, kv_pool, block_tables, q_lens, kv_lens,
         Hb, P = plan.heads, plan.pages
         n_hb = nkv // Hb
         kw = dict(block_s=block_s * P, n_blocks=plan.grid[1],
-                  sm_scale=scale, tile_q=tile_q, g=g)
+                  sm_scale=scale, tile_q=tile_q, g=g, window=window)
 
         def q_map(i, j, bt_, ts_, pos_):
             return (i // n_hb, i % n_hb, 0, 0)
@@ -549,11 +565,18 @@ def paged_attention_ragged(q, kv_pool, block_tables, q_lens, kv_lens,
             # pos[t, 1]) for every step past it: a skipped step
             # re-names the block the pipeline already holds, and no
             # copy is issued past the frontier. An operand whose first
-            # page is already past it holds entry p throughout.
+            # page is already past it holds entry p throughout. On a
+            # sliding layer the steps wholly behind the tile's window
+            # hold the first live step's entry the same way.
             def index(i, j, bt_, ts_, pos_):
                 t = i // n_hb
                 last = jnp.maximum(pos_[t, 1], 0) // block_s
                 jj = jnp.minimum(j, jnp.maximum(last - p, 0) // P)
+                if window is not None:
+                    first = jnp.maximum(pos_[t, 0] - window + 1, 0) \
+                        // (block_s * P)
+                    jj = jnp.maximum(jj, jnp.minimum(
+                        first, jnp.maximum(last - p, 0) // P))
                 return (bt_[ts_[t], jj * P + p], 0, i % n_hb) + tail
             return index
 
@@ -595,17 +618,18 @@ def paged_attention_ragged(q, kv_pool, block_tables, q_lens, kv_lens,
 # --- the three phase entry points: thin wrappers over the ragged path -
 
 def paged_attention(q, kv_pool, block_tables, seq_lens, sm_scale=None,
-                    kv_scales=None):
+                    kv_scales=None, window=None):
     """Decode: q [B, nh, hd] (one query per sequence), seq_lens int32
     [B] valid lengths. A ragged launch with q_lens = (1,)*B and
     tile_q = 1 (no padding rows). Returns [B, nh, hd]."""
     return paged_attention_ragged(
         q, kv_pool, block_tables, (1,) * q.shape[0], seq_lens,
-        sm_scale=sm_scale, tile_q=1, kv_scales=kv_scales)
+        sm_scale=sm_scale, tile_q=1, kv_scales=kv_scales,
+        window=window)
 
 
 def paged_attention_multi(q, kv_pool, block_tables, seq_lens,
-                          sm_scale=None, kv_scales=None):
+                          sm_scale=None, kv_scales=None, window=None):
     """Multi-query verify (speculative decode): q [B, n_q, nh, hd],
     query i of row b at position seq_lens[b] - n_q + i, masked
     causally. seq_lens INCLUDE the n_q new tokens. A ragged launch
@@ -616,13 +640,13 @@ def paged_attention_multi(q, kv_pool, block_tables, seq_lens,
     out = paged_attention_ragged(
         q.reshape(B * n_q, nh, hd), kv_pool, block_tables,
         (n_q,) * B, seq_lens, sm_scale=sm_scale, tile_q=n_q,
-        kv_scales=kv_scales)
+        kv_scales=kv_scales, window=window)
     return out.reshape(B, n_q, nh, hd)
 
 
 def paged_attention_prefill(q, kv_pool, block_tables, start_pos,
                             sm_scale=None, tile_q=None,
-                            kv_scales=None):
+                            kv_scales=None, window=None):
     """Chunked prefill: q [B, C, nh, hd] holds one prompt chunk per
     sequence, query i of row b at absolute position start_pos[b] + i.
     A ragged launch with q_lens = (C,)*B, kv_lens = start_pos + C and
@@ -635,7 +659,8 @@ def paged_attention_prefill(q, kv_pool, block_tables, start_pos,
     lens = jnp.asarray(start_pos, jnp.int32) + C
     out = paged_attention_ragged(
         q.reshape(B * C, nh, hd), kv_pool, block_tables, (C,) * B,
-        lens, sm_scale=sm_scale, tile_q=tile_q, kv_scales=kv_scales)
+        lens, sm_scale=sm_scale, tile_q=tile_q, kv_scales=kv_scales,
+        window=window)
     return out.reshape(B, C, nh, hd)
 
 
@@ -665,7 +690,7 @@ def gather_pages(kv_pool, block_tables, kv_scales=None):
 
 def paged_attention_ragged_reference(q, kv_pool, block_tables, q_lens,
                                      kv_lens, sm_scale=None,
-                                     kv_scales=None):
+                                     kv_scales=None, window=None):
     """jnp reference for the ragged kernel — and the ONE place the
     reference semantics live: the per-phase ``*_reference`` functions
     below are thin delegations, so kernel and reference can no longer
@@ -695,6 +720,8 @@ def paged_attention_ragged_reference(q, kv_pool, block_tables, q_lens,
         qpos = (lens[s] - ql) + jnp.arange(ql)[None, :, None]
         kpos = jnp.arange(S)[None, None, :]
         valid = kpos <= qpos
+        if window is not None:
+            valid = valid & (qpos - kpos < window)
         p = jax.nn.softmax(jnp.where(valid, scores, NEG_INF), axis=-1)
         # rows with no valid key (inactive: qpos < 0) -> zeros
         p = jnp.where(valid & (qpos >= 0), p, 0.0)

@@ -245,6 +245,8 @@ SNAPSHOT_ATTR_ALLOW: Dict[str, Dict[str, str]] = {
     "PagedServingEngine": {
         "model": "weights are the caller's problem (restore arg)",
         "collector": "observational — never snapshotted (PR 8)",
+        "_collector": "the collector property's slot (PR 28): "
+                      "observational — never snapshotted",
         "monitor": "derived control-plane state (PR 9)",
         "ledger": "accounting hook — replay-frozen, never snapshotted",
         "registry": "always-on metric surface — reattached on build",

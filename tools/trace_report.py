@@ -66,6 +66,7 @@ _ROUND = ("round", "spec_round", "draft_roll", "embed", "verify", "step",
           "grow", "sample_verify", "device_wait", "journal", "snapshot")
 _SUBMIT = ("submit", "submit.journal", "submit.embed", "submit.hash",
            "submit.admit")
+_MODEL = ("moe", "moe.route", "moe.experts")     # inside the model phase
 
 
 def _fmt_s(us: float) -> str:
@@ -159,6 +160,32 @@ def _rollup(evs):
     return spans, counters, insts, replayed, children
 
 
+def _expert_lines(meta) -> list:
+    """The expert layer's counters (``DecoderCore.moe_metrics``, in the
+    dump's registry under ``moe.``), by kind of model call."""
+    reg = (meta or {}).get("registry") or {} \
+        if isinstance(meta, dict) else {}
+    if "moe.experts_held" not in reg:
+        return []
+    lines = [f"  expert layers: {reg['moe.experts_held']} of "
+             f"{reg['moe.experts']} experts held from "
+             f"{reg['moe.expert_offset']}, top {reg['moe.top_k']}"]
+    for kind in ("decode", "mixed"):
+        calls = reg.get(f"moe.{kind}.layer_calls", 0)
+        if not calls:
+            continue
+        lines.append(
+            f"    {kind}: {reg[f'moe.{kind}.calls']} call(s), "
+            f"{reg[f'moe.{kind}.rows']} row(s); routed here "
+            f"{reg[f'moe.{kind}.rows_routed_here']}; rows an expert a "
+            f"layer call mean "
+            f"{reg[f'moe.{kind}.rows_per_expert_mean']:.2f}, max "
+            f"{reg[f'moe.{kind}.rows_per_expert_max']}; experts that "
+            f"received a row {reg[f'moe.{kind}.experts_hit'] / calls:.1f}"
+            f" a layer call")
+    return lines
+
+
 def summarize(trace: dict, tenant: str = None,
               show_requests: bool = False) -> str:
     evs = trace["traceEvents"]
@@ -169,9 +196,10 @@ def summarize(trace: dict, tenant: str = None,
                  + (f" ({replayed} replay-flagged)" if replayed
                     else ""))
     order = sorted(spans, key=lambda n: -spans[n][0])
-    known = _PHASES + _ROUND + _SUBMIT
+    known = _PHASES + _ROUND + _SUBMIT + _MODEL
     for title, names in (
             ("step phases", [n for n in order if n in _PHASES]),
+            ("model spans", [n for n in order if n in _MODEL]),
             ("round spans", [n for n in order if n in _ROUND]),
             ("submit spans", [n for n in order if n in _SUBMIT]),
             ("spans", [n for n in order if n not in known])):
@@ -192,12 +220,21 @@ def summarize(trace: dict, tenant: str = None,
                 lines.append(f"    {track}.{k}: {n} sample(s), mean "
                              f"{tot / n:g}, min {lo:g}, max {hi:g}, "
                              f"last {last:g}")
+    window = counters.get("paged_attn", {})
+    if "pages_in_context" in window:
+        total = window["pages_in_context"][1]
+        behind = window["pages_behind_window"][1]
+        lines.append(f"  sliding layers: {behind:g} of {total:g} pages in "
+                     f"context behind the window "
+                     f"({100.0 * behind / max(total, 1):.1f} %): skipped "
+                     f"by the launch, not freed")
     if insts:
         lines.append(f"  instants: "
                      + ", ".join(f"{k} x{v}"
                                  for k, v in sorted(insts.items())))
     # -- request summary (our metadata block) -------------------------
     meta = trace.get("metadata")
+    lines.extend(_expert_lines(meta))
     if not isinstance(meta, dict) or "summary" not in meta:
         lines.append("no collector metadata (foreign trace?) — "
                      "request summary skipped")
