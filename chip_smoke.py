@@ -33,9 +33,11 @@ The phases are importable functions that take sizes, so
 """
 from __future__ import annotations
 
+import contextlib
 import gc
 import json
 import os
+import re
 import tempfile
 import time
 
@@ -195,6 +197,57 @@ def _lowers_to_mosaic(fn, *args) -> bool:
 # ---------------------------------------------------------------------------
 # serving phase
 # ---------------------------------------------------------------------------
+
+def _report_largest_op(label: str, summary: dict, pool_shapes) -> tuple:
+    """Log the device operation that took the most time in a traced
+    stretch (``benchmark.xplane.summarize``) and its share of the device's
+    busy time; a copy or transpose of a whole K/V pool among the
+    operations fails the smoke (the row scatter's two layout copies a
+    layer were two thirds of serving's device time before PR 29).
+    Returns (name, share in %)."""
+    ops = summary["op_seconds"]
+    name, secs = max(ops.items(), key=lambda kv: kv[1])
+    share = 100.0 * secs / summary["busy_s"]
+    log(f"[{label}] largest device operation: {name} {secs:.3f} s, "
+        f"{share:.1f} % of {summary['busy_s']:.3f} s busy in a window of "
+        f"{summary['window_s']:.3f} s")
+    for shape in pool_shapes:
+        dims = "_".join(str(int(d)) for d in shape)
+        back = sorted(k for k in ops
+                      if re.fullmatch(rf"(copy|transpose)_\w+_{dims}_", k))
+        if back:
+            raise AssertionError(
+                f"a pool-sized copy is back among the device operations: "
+                f"{[(k, round(ops[k], 4)) for k in back]}")
+    return name, share
+
+
+@contextlib.contextmanager
+def _device_ops_traced(label: str, pool_shapes):
+    """Profile the block on the chip and report its largest device
+    operation. A CPU profile has no device plane to read: the rehearsal
+    runs the block as it is."""
+    import jax
+    if jax.devices()[0].platform != "tpu":
+        yield
+        return
+    from benchmark import xplane
+    with tempfile.TemporaryDirectory(prefix="chip_smoke_trace_") as outdir:
+        tracer = xplane.Tracer(outdir, seconds=0.0)
+        tracer.start()
+        try:
+            with tracer.span(xplane.SPAN_PREFIX + "step"):
+                yield
+        finally:
+            tracer.tick(0, last=True)
+            # the collector the server installed for this profile stays
+            # readable after the server closes and holds the model (its
+            # registry scrapes the core): hand the slot to an empty one,
+            # or the phase's weights outlive the phase
+            from paddle_tpu.inference import telemetry
+            telemetry.open_session_collector()
+        _report_largest_op(label, tracer.summary(), pool_shapes)
+
 
 def _make_prompts(n, lo, hi, shared_prefix, vocab, seed):
     """n seeded prompts of lo..hi tokens; the last two share their first
@@ -386,9 +439,16 @@ def serving_phase(*, d_model=4096, heads=32, ffn=16384, layers=4,
             pa.reset_dispatch_count()
             step_limit = 64 + 4 * (sum(map(len, prompts))
                                    // prefill_token_budget + new_tokens)
-            rids, streams, outcomes, steps, probe_logits = _serve_requests(
-                server, prompts, new_tokens=new_tokens,
-                step_limit=step_limit)
+            # the compiled mp >= 2 step still scatters rows inside its
+            # shard_map and pays the two copies (ROADMAP S6): reported
+            # there, held to nothing
+            with _device_ops_traced(
+                    f"serving mp={mp}",
+                    {tuple(p.shape) for p in cache.pools} if mp == 1
+                    else ()):
+                rids, streams, outcomes, steps, probe_logits = \
+                    _serve_requests(server, prompts, new_tokens=new_tokens,
+                                    step_limit=step_limit)
             jax.block_until_ready(cache.pools[0].data)
             serve_wall = time.perf_counter() - t_phase
 
@@ -1000,9 +1060,12 @@ def decoder_phase(*, hidden=3072, heads=48, kv_heads=8, head_dim=128,
     with tempfile.TemporaryDirectory(prefix="chip_smoke_") as workdir:
         server = serve_arch.build_server(config, seed, workdir)
         try:
-            err = serve_arch.check_probe(
-                server, config, {"table": [[prompt, 8]]}, seed,
-                tol=logits_tol, stats=stats)
+            pools = server.engine.engine.cache.pools
+            with _device_ops_traced("decoder",
+                                    {tuple(p.shape) for p in pools}):
+                err = serve_arch.check_probe(
+                    server, config, {"table": [[prompt, 8]]}, seed,
+                    tol=logits_tol, stats=stats)
             core = server.engine.target.core
             moe = core.moe_metrics()
         finally:

@@ -530,7 +530,10 @@ class CompiledStepRunner:
             return x
 
         def append_rows(pool, sc, k, v, blk, off):
-            # mirror of _ragged_append(_q): k/v [1, R, Hs, hd]
+            # a ROW scatter, where the eager views write whole pages
+            # (paged_cache._write_rows): on the TPU it costs two
+            # whole-pool layout copies a layer, donated or not
+            # (ROADMAP S6). k/v [1, R, Hs, hd]
             if quantized:
                 kq, ks = _quant_rows(k[0])
                 vq, vs = _quant_rows(v[0])
@@ -631,7 +634,8 @@ class CompiledStepRunner:
                 t, ws, nreal = ops["t"], ops["ws"], ops["nreal"]
                 bt = ops["bt"]
                 s = jax.lax.axis_index("mp")
-                # mirror of _make_append_chunk routing, with pad rows
+                # a chunk's row routing (positions below ws to trash),
+                # with pad rows
                 # (>= nreal) ALSO routed to the trash block
                 pos = t[:, None] + jnp.arange(C, dtype=t.dtype)[None, :]
                 blk = jnp.take_along_axis(bt, pos // bs, axis=1)

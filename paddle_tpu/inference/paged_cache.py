@@ -85,14 +85,16 @@ before handing the pool back.
 """
 from __future__ import annotations
 
+import functools
 import hashlib
 from collections import OrderedDict
 from typing import Dict, List, Optional, Tuple
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..framework.op import apply
+from ..framework.op import _trace_clean, apply, unwrap
 from ..framework.tensor import Tensor
 
 __all__ = ["BlockOOM", "BlockAllocator", "PagedKVCache",
@@ -320,111 +322,126 @@ def _quant_rows(x):
     return q, scale
 
 
-# --- per-op impls at module scope: the factory closures carry only ----
-# --- hashable ints, so framework/op.py's executable cache hits --------
+# --- pool writes ----------------------------------------------------
+# Every write into a pool is PAGE-GRANULAR and runs on a DONATED pool:
+# gather the touched pages, set the rows in that small array, scatter
+# the whole pages back. A scatter whose window is a whole page keeps the
+# pool's own layout, and with the pool donated it compiles to one
+# in-place update of the aliased buffer. A ROW scatter into the pool
+# (``pool.at[blk, 0, :, off, :].set(k)``) does not: the TPU compiler
+# gives it a layout of its own and copies the whole pool there and
+# back, two pool-sized copies a layer, donated or not
+# (tests/test_pool_write_hlo.py holds the compiled programs to this).
 
-def _make_append(block_size):
-    def paged_cache_kv(pool, k, v, t, bt):
-        # pool [NB, 2, H, bs, D]; k/v [B, 1, H, D]; t int32 [B]; bt
-        # [B, MB]. Write row b's k/v at position t[b] through its block
-        # table. Inactive rows point at the trash block — duplicate
-        # scatter indices there are fine, nothing reads it unmasked.
-        blk = jnp.take_along_axis(bt, (t // block_size)[:, None],
-                                  axis=1)[:, 0]
-        off = t % block_size
-        pool = pool.at[blk, 0, :, off, :].set(
-            k[:, 0].astype(pool.dtype))
-        return pool.at[blk, 1, :, off, :].set(
-            v[:, 0].astype(pool.dtype))
-    return paged_cache_kv
+def _pages_spanned(n_tokens: int, block_size: int) -> int:
+    """Most pages ``n_tokens`` consecutive positions can touch,
+    wherever they start."""
+    return (n_tokens + block_size - 2) // block_size + 1
 
 
-def _make_append_multi(block_size, n_tokens):
-    def paged_cache_kv_multi(pool, k, v, t, bt):
-        # multi-token append (speculative-decode verification): k/v
-        # [B, L, H, D] land at positions t[b] .. t[b]+L-1 through the
-        # block table. Positions within an active row are distinct, so
-        # the scatter never collides; inactive rows (t == 0, table all
-        # trash) duplicate-write block 0, which nothing reads unmasked.
-        pos = t[:, None] + jnp.arange(n_tokens, dtype=t.dtype)[None, :]
-        blk = jnp.take_along_axis(bt, pos // block_size, axis=1)
-        off = pos % block_size                        # [B, L]
-        pool = pool.at[blk, 0, :, off, :].set(k.astype(pool.dtype))
-        return pool.at[blk, 1, :, off, :].set(v.astype(pool.dtype))
-    return paged_cache_kv_multi
+def _set_rows(arr, pg_ids, slot, off, k, v):
+    # arr [NB, 2, H, bs(, D)]: a pool, or an int8 pool's scale array.
+    # Rows r of k/v [R, H(, D)] land in page pg_ids[slot[r]] at in-page
+    # offset off[r]. pg_ids [P] names every real page once; its other
+    # slots hold the trash block 0 (pad slots, and the slots that rows
+    # routed to trash write), which the write-back may hit in any order.
+    pages = arr[pg_ids]
+    pages = pages.at[slot, 0, :, off].set(k.astype(arr.dtype))
+    pages = pages.at[slot, 1, :, off].set(v.astype(arr.dtype))
+    return arr.at[pg_ids].set(pages)
 
 
-def _make_append_q(block_size):
-    def paged_cache_kv_q(pool, scales, k, v, t, bt):
-        # quantized twin of paged_cache_kv: the int8 payload and the
-        # per-(position, head) scale scatter through the same routing
-        blk = jnp.take_along_axis(bt, (t // block_size)[:, None],
-                                  axis=1)[:, 0]
-        off = t % block_size
-        kq, ks = _quant_rows(k[:, 0])
-        vq, vs = _quant_rows(v[:, 0])
-        pool = pool.at[blk, 0, :, off, :].set(kq)
-        pool = pool.at[blk, 1, :, off, :].set(vq)
-        scales = scales.at[blk, 0, :, off].set(ks)
-        scales = scales.at[blk, 1, :, off].set(vs)
-        return pool, scales
-    return paged_cache_kv_q
+def _write_rows(pool, scales, pg_ids, slot, off, k, v):
+    """THE page-form append: rows k/v [R, H, D] through the routing
+    (pg_ids [P], slot [R], off [R]); int8 pools (``scales`` not None)
+    quantize here and write their scale pages the same way."""
+    if scales is not None:
+        (k, ks), (v, vs) = _quant_rows(k), _quant_rows(v)
+        scales = _set_rows(scales, pg_ids, slot, off, ks, vs)
+    return _set_rows(pool, pg_ids, slot, off, k, v), scales
 
 
-def _make_append_multi_q(block_size, n_tokens):
-    def paged_cache_kv_multi_q(pool, scales, k, v, t, bt):
-        pos = t[:, None] + jnp.arange(n_tokens, dtype=t.dtype)[None, :]
-        blk = jnp.take_along_axis(bt, pos // block_size, axis=1)
-        off = pos % block_size                        # [B, L]
-        kq, ks = _quant_rows(k)                 # [B, L, H, D], [B, L, H]
-        vq, vs = _quant_rows(v)
-        pool = pool.at[blk, 0, :, off, :].set(kq)
-        pool = pool.at[blk, 1, :, off, :].set(vq)
-        scales = scales.at[blk, 0, :, off].set(ks)
-        scales = scales.at[blk, 1, :, off].set(vs)
-        return pool, scales
-    return paged_cache_kv_multi_q
+def _append_rows(block_size, n_tokens, pool, scales, k, v, t, bt,
+                 ws=None):
+    # k/v [B, n_tokens, H, D] land at positions t[b] .. t[b]+n_tokens-1
+    # through the block table bt [B, MB] (the decode step at n_tokens 1,
+    # the speculative verify, a batch-1 prefill chunk). Row b owns the
+    # slots [b*S, (b+1)*S) of the page list: real pages are one row's
+    # alone (the write range is COW-split by precondition), and a
+    # masked or inactive row's table is all trash. With ``ws`` (a
+    # chunk's write start) positions below it (an adopted prefix, whose
+    # pages hold these values already and may be SHARED) go to a trash
+    # slot of their own, and a page wholly below it is not in the list.
+    B = t.shape[0]
+    S = _pages_spanned(n_tokens, block_size)
+    pos = t[:, None] + jnp.arange(n_tokens, dtype=t.dtype)[None, :]
+    first = t // block_size
+    col = first[:, None] + jnp.arange(S, dtype=t.dtype)[None, :]
+    real = col <= ((t + n_tokens - 1) // block_size)[:, None]
+    slot = (jnp.arange(B, dtype=t.dtype)[:, None] * S
+            + pos // block_size - first[:, None])
+    if ws is not None:
+        real = real & ((col + 1) * block_size > ws)
+        slot = jnp.where(pos >= ws, slot, B * S)
+    ids = jnp.take_along_axis(bt, jnp.minimum(col, bt.shape[1] - 1),
+                              axis=1)
+    pg_ids = jnp.where(real, ids, 0).reshape(-1)
+    if ws is not None:
+        pg_ids = jnp.concatenate([pg_ids, jnp.zeros((1,), pg_ids.dtype)])
+    return _write_rows(pool, scales, pg_ids, slot.reshape(-1),
+                       (pos % block_size).reshape(-1),
+                       k.reshape((-1,) + k.shape[2:]),
+                       v.reshape((-1,) + v.shape[2:]))
 
 
-def _make_append_chunk_q(block_size, n_tokens):
-    def paged_prefill_chunk_kv_q(pool, scales, k, v, t, bt, ws):
-        # quantized twin of paged_prefill_chunk_kv: adopted-prefix
-        # positions (< ws) route payload AND scale to the trash block
-        pos = t[:, None] + jnp.arange(n_tokens, dtype=t.dtype)[None, :]
-        blk = jnp.take_along_axis(bt, pos // block_size, axis=1)
-        blk = jnp.where(pos >= ws, blk, 0)
-        off = pos % block_size                        # [1, C]
-        kq, ks = _quant_rows(k)
-        vq, vs = _quant_rows(v)
-        pool = pool.at[blk, 0, :, off, :].set(kq)
-        pool = pool.at[blk, 1, :, off, :].set(vq)
-        scales = scales.at[blk, 0, :, off].set(ks)
-        scales = scales.at[blk, 1, :, off].set(vs)
-        return pool, scales
-    return paged_prefill_chunk_kv_q
+def _ragged_append(pool, scales, k, v, pg_ids, route):
+    # packed mixed-batch append: row r of k/v [1, R, H, D] through the
+    # routing _RaggedLayout built on the host (route [2, R]: each row's
+    # slot in pg_ids, its in-page offset), every segment's writes
+    # (prefill chunks through their slots' tables, decode rows through
+    # the masked batch table) in ONE page-form write.
+    return _write_rows(pool, scales, pg_ids, route[0], route[1], k[0],
+                       v[0])
 
 
-def _make_prefill_scatter_q(start_block, n_blocks, block_size):
-    def paged_prefill_scatter_q(pool, scales, row_cache, blks):
-        lo = start_block * block_size
-        seg = row_cache[:, 0, :, lo:lo + n_blocks * block_size, :]
-        two, H, _, D = seg.shape
-        seg = seg.reshape(two, H, n_blocks, block_size, D)
-        seg = jnp.transpose(seg, (2, 0, 1, 3, 4))  # [n, 2, H, bs, D]
-        q, s = _quant_rows(seg)
-        return pool.at[blks].set(q), scales.at[blks].set(s)
-    return paged_prefill_scatter_q
+def _block_copy(pool, scales, src, dst):
+    # copy-on-write split: pool[dst[i]] = pool[src[i]], and on
+    # quantized pools the page's scales move with its bytes
+    if scales is not None:
+        scales = scales.at[dst].set(scales[src])
+    return pool.at[dst].set(pool[src]), scales
 
 
-def _ragged_append_q(pool, scales, k, v, blk, off):
-    # quantized twin of _ragged_append (packed mixed-batch scatter)
-    kq, ks = _quant_rows(k[0])
-    vq, vs = _quant_rows(v[0])
-    pool = pool.at[blk, 0, :, off, :].set(kq)
-    pool = pool.at[blk, 1, :, off, :].set(vq)
-    scales = scales.at[blk, 0, :, off].set(ks)
-    scales = scales.at[blk, 1, :, off].set(vs)
-    return pool, scales
+def _set_pages(pool, scales, ids, pages, scale_pages):
+    # whole pages [n, 2, H, bs, D] (and their scales) land at block ids
+    if scales is not None:
+        scales = scales.at[ids].set(scale_pages.astype(scales.dtype))
+    return pool.at[ids].set(pages.astype(pool.dtype)), scales
+
+
+def _prefill_scatter(start_block, n_blocks, block_size, pool, scales,
+                     row_cache, blks):
+    # row_cache [2, 1, H, S, D] (dense single-row scratch) -> pages
+    # [start_block, start_block + n_blocks) of this sequence (a
+    # prefix-cache hit skips the shared prefix pages)
+    lo = start_block * block_size
+    seg = row_cache[:, 0, :, lo:lo + n_blocks * block_size, :]
+    two, H, _, D = seg.shape
+    seg = seg.reshape(two, H, n_blocks, block_size, D)
+    seg = jnp.transpose(seg, (2, 0, 1, 3, 4))      # [n, 2, H, bs, D]
+    sseg = None
+    if scales is not None:
+        seg, sseg = _quant_rows(seg)
+    return _set_pages(pool, scales, blks, seg, sseg)
+
+
+@functools.lru_cache(maxsize=None)
+def _pool_program(fn, *static):
+    """The jitted program of one pool write (``fn`` with its leading
+    ``static`` arguments bound), pool and scales DONATED: one a
+    (function, statics) pair, and jax keys the compiled forms by the
+    operands' shapes below that."""
+    return jax.jit(functools.partial(fn, *static), donate_argnums=(0, 1))
 
 
 def _visible(kpos, qpos, window):
@@ -444,44 +461,6 @@ def _group_heads(k_full, v_full, q_heads: int):
         return k_full, v_full
     return (Tensor(jnp.repeat(k_full.data, g, axis=2)),
             Tensor(jnp.repeat(v_full.data, g, axis=2)))
-
-
-def _block_copy(pool, src, dst):
-    # copy-on-write split: pool[dst[i]] = pool[src[i]] (shared by the
-    # payload pools AND, on quantized pools, the scale arrays — a COW
-    # split must move the page's scales with its bytes)
-    return pool.at[dst].set(pool[src])
-
-
-def _make_append_chunk(block_size, n_tokens):
-    def paged_prefill_chunk_kv(pool, k, v, t, bt, ws):
-        # chunked-prefill append: k/v [1, C, H, D] land at positions
-        # t[0] .. t[0]+C-1 through the slot's block-table row bt
-        # [1, MB]. Positions below ws (a prefix-cache hit's adopted
-        # region, whose pages already hold these exact values and may
-        # be SHARED) route to the trash block instead of rewriting —
-        # duplicate trash indices are fine, nothing reads it unmasked.
-        pos = t[:, None] + jnp.arange(n_tokens, dtype=t.dtype)[None, :]
-        blk = jnp.take_along_axis(bt, pos // block_size, axis=1)
-        blk = jnp.where(pos >= ws, blk, 0)
-        off = pos % block_size                        # [1, C]
-        pool = pool.at[blk, 0, :, off, :].set(k.astype(pool.dtype))
-        return pool.at[blk, 1, :, off, :].set(v.astype(pool.dtype))
-    return paged_prefill_chunk_kv
-
-
-def _make_prefill_scatter(start_block, n_blocks, block_size):
-    def paged_prefill_scatter(pool, row_cache, blks):
-        # row_cache [2, 1, H, S, D] (dense single-row scratch) -> pages
-        # [start_block, start_block + n_blocks) of this sequence (a
-        # prefix-cache hit skips the shared prefix pages)
-        lo = start_block * block_size
-        seg = row_cache[:, 0, :, lo:lo + n_blocks * block_size, :]
-        two, H, _, D = seg.shape
-        seg = seg.reshape(two, H, n_blocks, block_size, D)
-        seg = jnp.transpose(seg, (2, 0, 1, 3, 4))  # [n, 2, H, bs, D]
-        return pool.at[blks].set(seg.astype(pool.dtype))
-    return paged_prefill_scatter
 
 
 class PagedLayerCache:
@@ -586,24 +565,9 @@ class PagedLayerCache:
                         f"ensure(row, position+{L}) first")
         bt = c.bt_tensor()
         tt = Tensor(t)
-        new_sc = None
-        if c.quantized:
-            impl = (_make_append_q(c.block_size) if L == 1
-                    else _make_append_multi_q(c.block_size, L))
-            new_pool, new_sc = apply(
-                impl, (self.pool, self.kv_scales, k, v, tt, bt),
-                op_name="paged_cache_kv_q" if L == 1
-                else "paged_cache_kv_multi_q")
-            c.scales[self._pi] = new_sc
-        elif L == 1:
-            new_pool = apply(_make_append(c.block_size),
-                             (self.pool, k, v, tt, bt),
-                             op_name="paged_cache_kv")
-        else:
-            new_pool = apply(_make_append_multi(c.block_size, L),
-                             (self.pool, k, v, tt, bt),
-                             op_name="paged_cache_kv_multi")
-        c.pools[self._pi] = new_pool
+        new_pool, new_sc = c._write_pool(
+            self._pi, _append_rows, (c.block_size, L), k, v, tt, bt,
+            pages=B * _pages_spanned(L, c.block_size), rows=B * L)
 
         if use_kernel:
             if c.quantized:
@@ -721,7 +685,7 @@ class PagedPrefillView:
         self._pi = cache.pool_index(layer, self._shard)
         # positions below write_start are an adopted (possibly shared)
         # prefix whose pages already hold these exact K/V — recomputed
-        # rows there attend but do not write (see _make_append_chunk)
+        # rows there attend but do not write (see _append_rows)
         self._write_start = int(write_start)
 
     def shard(self, s: int) -> "PagedPrefillView":
@@ -784,18 +748,9 @@ class PagedPrefillView:
         bt = c.bt_row_tensor(self._slot)
         tt = Tensor(t)
         ws = Tensor(jnp.asarray([self._write_start], jnp.int32))
-        new_sc = None
-        if c.quantized:
-            new_pool, new_sc = apply(
-                _make_append_chunk_q(c.block_size, C),
-                (self.pool, self.kv_scales, k, v, tt, bt, ws),
-                op_name="paged_prefill_chunk_kv_q")
-            c.scales[self._pi] = new_sc
-        else:
-            new_pool = apply(_make_append_chunk(c.block_size, C),
-                             (self.pool, k, v, tt, bt, ws),
-                             op_name="paged_prefill_chunk_kv")
-        c.pools[self._pi] = new_pool
+        new_pool, new_sc = c._write_pool(
+            self._pi, _append_rows, (c.block_size, C), k, v, tt, bt, ws,
+            pages=_pages_spanned(C, c.block_size) + 1, rows=C)
 
         if use_kernel:
             if c.quantized:
@@ -835,17 +790,6 @@ class PagedPrefillView:
                                               attn_mask=mask)
 
 
-def _ragged_append(pool, k, v, blk, off):
-    # packed mixed-batch append: row r of k/v [1, R, H, D] lands at
-    # pool[blk[r], :, :, off[r], :] — every segment's writes (prefill
-    # chunks through their slots' tables, decode rows through the
-    # masked batch table) in ONE scatter. Rows routed to the trash
-    # block (adopted-prefix positions, masked decode rows) may collide
-    # there; nothing reads it unmasked.
-    pool = pool.at[blk, 0, :, off, :].set(k[0].astype(pool.dtype))
-    return pool.at[blk, 1, :, off, :].set(v[0].astype(pool.dtype))
-
-
 class _RaggedLayout:
     """Host-side descriptors for ONE mixed ragged model call, shared
     by every layer's PagedRaggedView: the packed append routing
@@ -855,9 +799,10 @@ class _RaggedLayout:
     CURRENT tables — the caller must have ensure()d coverage and set
     the decode mask first."""
 
-    __slots__ = ("segs", "q_lens", "blk", "off", "kv_lens", "bt_all",
-                 "tile_q", "tile_kv", "total_rows", "blk_np",
-                 "off_np", "pos_np", "kv_lens_np", "_pos", "_cache")
+    __slots__ = ("segs", "q_lens", "pg_ids", "route", "kv_lens",
+                 "bt_all", "tile_q", "tile_kv", "total_rows", "n_pages",
+                 "blk_np", "off_np", "pos_np", "pg_ids_np", "pg_slot_np",
+                 "kv_lens_np", "_pos", "_cache")
 
     def __init__(self, cache: "PagedKVCache", segments, tile_q=None,
                  tile_kv=None):
@@ -884,7 +829,7 @@ class _RaggedLayout:
                 b = tbl[slot][pos // bs]
                 # adopted shared-prefix positions route to trash: the
                 # pages already hold these exact values and may be
-                # shared (same rule as _make_append_chunk)
+                # shared (same rule as _append_rows)
                 blk.append(np.where(pos >= write_start, b, 0))
                 off.append(pos % bs)
                 rowpos.append(pos)
@@ -943,13 +888,43 @@ class _RaggedLayout:
         self.pos_np = np.concatenate(rowpos).astype(np.int32)
         self.kv_lens_np = np.asarray(kv_lens, np.int32)
         self._pos = None
-        self.blk = Tensor(jnp.asarray(self.blk_np))
-        self.off = Tensor(jnp.asarray(self.off_np))
+        self.pg_ids_np, self.pg_slot_np = self._page_list(bs)
+        self.n_pages = int(self.pg_ids_np.shape[0])
+        self.pg_ids = jnp.asarray(self.pg_ids_np)
+        self.route = jnp.asarray(np.stack([self.pg_slot_np, self.off_np]))
         self.kv_lens = Tensor(jnp.asarray(kv_lens, jnp.int32))
         self.bt_all = Tensor(jnp.asarray(np.stack(bt_rows), jnp.int32))
         self.tile_q = tile_q
         self.tile_kv = tile_kv
         self._cache = cache
+
+    def _page_list(self, bs: int):
+        """The page form of the append routing: (pg_ids [P], slot [R]).
+        Slot 0 is the trash block, where every row routed to trash
+        lands; then each sequence owns ``_pages_spanned(q_len)`` slots,
+        the real pages its rows write in table order and the trash
+        block in the slots it does not need. ``P`` is a function of
+        ``q_lens`` alone, so the append's program is keyed by nothing
+        the attention launch is not keyed by already. No real page may
+        occur twice: its second write-back would undo the first."""
+        spans = [_pages_spanned(ql, bs) for ql in self.q_lens]
+        base = np.concatenate([[1], 1 + np.cumsum(spans)]).astype(np.int64)
+        pg_ids = np.zeros(int(base[-1]), np.int32)
+        seq_of_row = np.repeat(np.arange(len(spans)), self.q_lens)
+        page = self.pos_np.astype(np.int64) // bs
+        first = np.full(len(spans), np.iinfo(np.int64).max)
+        real = self.blk_np != 0
+        np.minimum.at(first, seq_of_row[real], page[real])
+        slot = np.where(real, base[seq_of_row] + page - first[seq_of_row],
+                        0)
+        pg_ids[slot[real]] = self.blk_np[real]
+        ids = pg_ids[pg_ids != 0]
+        if np.unique(ids).shape[0] != ids.shape[0]:
+            raise AssertionError(
+                f"ragged append: a page is written by two sequences of "
+                f"one launch (pages {sorted(ids.tolist())}): the write "
+                f"range must be COW-split first (ensure())")
+        return pg_ids, slot.astype(np.int32)
 
     def positions(self):
         """int32 [1, total_rows] on the device: every packed row's
@@ -998,8 +973,8 @@ class PagedRaggedView:
     several slots AND the fused decode rows packed into one
     [1, total_rows, d] model call. Same duck-typed protocol as
     PagedLayerCache (``is_paged`` + ``decode``): the packed K/V append
-    is ONE scatter through the precomputed routing, and the attention
-    is ONE ``paged_attention_ragged`` launch on the kernel path — the
+    is ONE page-form write through the precomputed routing, and the
+    attention is ONE ``paged_attention_ragged`` launch on the kernel path — the
     dispatch-count collapse this view exists for.
 
     Numerics contract (CPU bit-identity — the folding rules hoisted
@@ -1075,18 +1050,9 @@ class PagedRaggedView:
                 f"head slice ({c.heads_per_shard} heads), got "
                 f"{int(q.shape[2])} — drive a sharded cache through "
                 f"a ShardedServingCore")
-        new_sc = None
-        if c.quantized:
-            new_pool, new_sc = apply(
-                _ragged_append_q,
-                (self.pool, self.kv_scales, k, v, lay.blk, lay.off),
-                op_name="paged_ragged_append_q")
-            c.scales[self._pi] = new_sc
-        else:
-            new_pool = apply(_ragged_append,
-                             (self.pool, k, v, lay.blk, lay.off),
-                             op_name="paged_ragged_append")
-        c.pools[self._pi] = new_pool
+        new_pool, new_sc = c._write_pool(
+            self._pi, _ragged_append, (), k, v, lay.pg_ids, lay.route,
+            pages=lay.n_pages, rows=lay.total_rows)
 
         if use_kernel:
             q_lens, tile_q, tile_kv = (lay.q_lens, lay.tile_q,
@@ -1189,7 +1155,8 @@ class PagedKVCache:
     """Per-layer block pools + one block allocator + per-sequence block
     tables. ``views`` is the list consumed as ``caches=`` by the fused
     decoder; allocation/free/fork are host-side (numpy free list), the
-    pool writes are jnp scatters."""
+    pool writes are page-granular jnp programs on a donated pool
+    (``_write_pool``)."""
 
     def __init__(self, num_layers: int, num_heads: int, head_dim: int,
                  block_size: int, num_blocks: int, max_seqs: int,
@@ -1272,8 +1239,7 @@ class PagedKVCache:
         # scales in ``self.scales`` — allocator metadata that moves
         # with the page through COW copies, snapshots and restores.
         # Quantization happens at page-write time inside the append
-        # ops (_make_append_q and friends); every read path
-        # dequantizes (the ragged kernel in-register via scalar
+        # writes (_write_rows); every read path dequantizes (the ragged kernel in-register via scalar
         # prefetch, the jnp fallbacks inside gather_pages). See the
         # module-level note above _quant_rows for why scales are
         # per-row: it is what keeps the quantized payload a pure
@@ -1337,6 +1303,9 @@ class PagedKVCache:
         # append at lens==0 through them would corrupt position 0)
         self._decode_masked: Optional[np.ndarray] = None
         self.peak_blocks_used = 0
+        # pages / rows the pool writes moved since the last
+        # take_write_stats() (host counts, from the writes' shapes)
+        self._written = np.zeros(2, np.int64)
         # multi-tenant attribution (scheduler.py): which tenant each
         # slot is serving, and the per-tenant block CHARGE. The charge
         # policy is ONE CHARGE PER TABLE REFERENCE — a block shared by
@@ -1397,6 +1366,47 @@ class PagedKVCache:
         """Index of (layer, shard)'s entry in the flat ``pools`` /
         ``scales`` lists."""
         return layer * self.mp + shard
+
+    def _write_pool(self, pi: int, fn, static, *args, pages: int,
+                    rows: int = 0) -> Tuple[Tensor, Optional[Tensor]]:
+        """Run one pool write, ``fn(*static, pool, scales, *args)``, on
+        entry ``pi`` and rebind ``pools[pi]`` (and ``scales[pi]``) to
+        what it returns. The pool and its scales are DONATED: the
+        arrays that were bound are deleted by the call (on the CPU
+        backend too), so whoever reads a pool takes
+        ``cache.pools[i].data`` at the moment it reads. Inside someone
+        else's trace the write composes into their program instead.
+        ``pages`` / ``rows`` are what the write moves, for the
+        ``pool_write`` gauge (``take_write_stats``)."""
+        pool = self.pools[pi].data
+        sc = self.scales[pi].data if self.quantized else None
+        arrays = tuple(unwrap(a) for a in args)
+        if _trace_clean():
+            pool, sc = _pool_program(fn, *static)(pool, sc, *arrays)
+        else:
+            pool, sc = fn(*static, pool, sc, *arrays)
+        new_pool = self.pools[pi] = Tensor(pool)
+        new_sc = None
+        if self.quantized:
+            new_sc = self.scales[pi] = Tensor(sc)
+        self._written[0] += pages
+        self._written[1] += rows
+        return new_pool, new_sc
+
+    def take_write_stats(self) -> dict:
+        """What the pool writes since the last call moved, summed over
+        layers and shards, and reset: pages gathered and written back
+        (trash and pad slots of the page lists included), K/V rows set
+        in them, the bytes of those pages (payload and scales) and,
+        beside them, the bytes of all the pools. One sample a step of
+        the collector's ``pool_write`` gauge."""
+        pages, rows = (int(n) for n in self._written)
+        self._written[:] = 0
+        per_page = (self.kv_bytes_per_token() // self.num_layers
+                    * self.block_size)
+        return {"pages_written": pages, "rows_written": rows,
+                "pool_bytes_written": pages * per_page,
+                "pool_bytes": per_page * self.num_blocks * len(self.pools)}
 
     def rebind_shard_pools(self, layer: int, global_pool,
                            global_scales=None) -> None:
@@ -1956,26 +1966,18 @@ class PagedKVCache:
                                for i in rows], jnp.int32)
             payload = payload[rows]
             Hs = cache.kv_heads_per_shard
+            spay = (np.asarray(snap["scale_payload"])[rows]
+                    if cache.quantized else None)
             for i in range(cache.num_layers):
                 for s in range(cache.mp):
                     # each target shard takes its head slice of the
                     # canonical page (the whole page at mp == 1)
-                    pi = cache.pool_index(i, s)
-                    seg = jnp.asarray(
-                        payload[:, i, :, s * Hs:(s + 1) * Hs])
-                    cache.pools[pi] = Tensor(
-                        cache.pools[pi].data.at[ids].set(
-                            seg.astype(cache.pools[pi].data.dtype)))
-            if cache.quantized:
-                spay = np.asarray(snap["scale_payload"])[rows]
-                for i in range(cache.num_layers):
-                    for s in range(cache.mp):
-                        pi = cache.pool_index(i, s)
-                        cache.scales[pi] = Tensor(
-                            cache.scales[pi].data.at[ids].set(
-                                jnp.asarray(
-                                    spay[:, i, :, s * Hs:(s + 1) * Hs],
-                                    jnp.float32)))
+                    heads = slice(s * Hs, (s + 1) * Hs)
+                    cache._write_pool(
+                        cache.pool_index(i, s), _set_pages, (), ids,
+                        payload[:, i, :, heads],
+                        None if spay is None else spay[:, i, :, heads],
+                        pages=len(rows))
         cache.peak_blocks_used = int(snap["peak_blocks_used"])
         cache._tables_dirty()
         cache.check_invariants(deep=True)
@@ -2178,19 +2180,12 @@ class PagedKVCache:
         old = self.seq_blocks[slot][bpos]
         new = self.allocator.alloc(1)[0]
         if copy:
-            src = Tensor(jnp.asarray([old], jnp.int32))
-            dst = Tensor(jnp.asarray([new], jnp.int32))
-            for i, pool in enumerate(self.pools):
-                self.pools[i] = apply(_block_copy, (pool, src, dst),
-                                      op_name="paged_block_copy")
-            if self.quantized:
-                # the page's scales are part of its content: a COW
-                # split that copied only the int8 payload would
-                # dequantize the private copy through stale scales
-                for i, sc in enumerate(self.scales):
-                    self.scales[i] = apply(
-                        _block_copy, (sc, src, dst),
-                        op_name="paged_block_copy_scales")
+            # the page's scales are part of its content: on quantized
+            # pools they move with its bytes, in the same program
+            src = jnp.asarray([old], jnp.int32)
+            dst = jnp.asarray([new], jnp.int32)
+            for pi in range(len(self.pools)):
+                self._write_pool(pi, _block_copy, (), src, dst, pages=1)
         self.release_to_cache([old])
         self.seq_blocks[slot][bpos] = new
         self.block_tables[slot, bpos] = new
@@ -2408,18 +2403,12 @@ class PagedKVCache:
             seg_full = payload[rows, li]
             sfull = spay[rows, li] if self.quantized else None
             for s in range(self.mp):
-                pi = self.pool_index(li, s)
-                seg = jnp.asarray(
-                    seg_full[:, :, s * Hs:(s + 1) * Hs])
-                self.pools[pi] = Tensor(
-                    self.pools[pi].data.at[ids].set(
-                        seg.astype(self.pools[pi].data.dtype)))
-                if self.quantized:
-                    self.scales[pi] = Tensor(
-                        self.scales[pi].data.at[ids].set(
-                            jnp.asarray(
-                                sfull[:, :, s * Hs:(s + 1) * Hs],
-                                jnp.float32)))
+                heads = slice(s * Hs, (s + 1) * Hs)
+                self._write_pool(
+                    self.pool_index(li, s), _set_pages, (), ids,
+                    seg_full[:, :, heads],
+                    None if sfull is None else sfull[:, :, heads],
+                    pages=len(rows))
         for (b, i) in landing:
             # fresh content: new audit epoch for the fingerprint
             # check, then park cached-free in prefix (oldest-first
@@ -2486,20 +2475,11 @@ class PagedKVCache:
                 "write_prefill_chunk takes full-head K/V; a sharded "
                 "pool's pages are written per shard through the "
                 "prefill views (ShardedServingCore)")
-        tt = Tensor(jnp.asarray([start], jnp.int32))
-        ws = Tensor(jnp.asarray([write_start], jnp.int32))
-        bt = self.bt_row_tensor(slot)
-        if self.quantized:
-            self.pools[pi], self.scales[pi] = apply(
-                _make_append_chunk_q(self.block_size, C),
-                (self.pools[pi], self.scales[pi], k, v, tt, bt,
-                 ws),
-                op_name="paged_prefill_chunk_kv_q")
-        else:
-            self.pools[pi] = apply(
-                _make_append_chunk(self.block_size, C),
-                (self.pools[pi], k, v, tt, bt, ws),
-                op_name="paged_prefill_chunk_kv")
+        self._write_pool(
+            pi, _append_rows, (self.block_size, C), k, v,
+            jnp.asarray([start], jnp.int32), self.bt_row_tensor(slot),
+            jnp.asarray([write_start], jnp.int32),
+            pages=_pages_spanned(C, self.block_size) + 1, rows=C)
 
     def write_prefill(self, slot: int, row_caches, length: int,
                       start_block: int = 0) -> None:
@@ -2526,19 +2506,10 @@ class PagedKVCache:
                 self._copy_block(slot, bpos, copy=False)
         if start_block >= n:
             return  # fully cached prompt: every page already written
-        blks = Tensor(jnp.asarray(self.seq_blocks[slot][start_block:n],
-                                  jnp.int32))
-        if self.quantized:
-            impl_q = _make_prefill_scatter_q(start_block,
-                                             n - start_block,
-                                             self.block_size)
-            for i, rc in enumerate(row_caches):
-                self.pools[i], self.scales[i] = apply(
-                    impl_q, (self.pools[i], self.scales[i], rc, blks),
-                    op_name="paged_prefill_scatter_q")
-            return
-        impl = _make_prefill_scatter(start_block, n - start_block,
-                                     self.block_size)
-        for i, (pool, rc) in enumerate(zip(self.pools, row_caches)):
-            self.pools[i] = apply(impl, (pool, rc, blks),
-                                  op_name="paged_prefill_scatter")
+        blks = jnp.asarray(self.seq_blocks[slot][start_block:n], jnp.int32)
+        for i, rc in enumerate(row_caches):
+            self._write_pool(
+                i, _prefill_scatter,
+                (start_block, n - start_block, self.block_size), rc, blks,
+                pages=n - start_block,
+                rows=(n - start_block) * self.block_size)

@@ -2383,6 +2383,10 @@ class PagedServingEngine:
         the engine is abandoned, so sampling it would diverge the
         series from an uninterrupted run's)."""
         col = self.collector
+        # taken every step, read or not: a sample then holds what was
+        # written since the last step ended, whenever a collector (a
+        # profile session's) is installed
+        writes = self.cache.take_write_stats()
         charges = None
         if not aborted and (col is not None or
                             self.ledger is not None):
@@ -2407,6 +2411,9 @@ class PagedServingEngine:
                     "pool": {"active": occ["active"],
                              "cached_free": occ["cached_free"],
                              "free": occ["free"]},
+                    # what the K/V appends (and COW splits) moved:
+                    # pages of a donated pool, not the pool
+                    "pool_write": writes,
                     "queue": self._queue_gauges(),
                     "tenant_blocks": charges,
                 })

@@ -119,3 +119,30 @@ def test_compile_cache_placement(monkeypatch, tmp_path):
             os.path.join(ROOT, ".jax_cache")
     finally:
         jax.config.update("jax_compilation_cache_dir", before)
+
+
+def _recorded_summary():
+    from benchmark import xplane
+    return xplane.summarize(os.path.join(ROOT, "benchmark", "testdata",
+                                         "small.xplane.pb"))
+
+
+def test_largest_device_operation_is_reported(capsys):
+    """The reduction the serving and decoder phases print after their
+    traced steps, on the recorded trace the benchmark's tests use."""
+    summary = _recorded_summary()
+    name, share = chip_smoke._report_largest_op("serving", summary, [])
+    assert summary["op_seconds"][name] == max(
+        summary["op_seconds"].values())
+    assert 0.0 < share <= 100.0
+    assert f"largest device operation: {name}" in capsys.readouterr().out
+
+
+def test_a_pool_sized_copy_fails_the_smoke():
+    summary = _recorded_summary()
+    summary["op_seconds"]["copy_bf16_3072_2_32_16_128_"] = 1e-3
+    chip_smoke._report_largest_op("serving", summary,
+                                  [(3072, 2, 8, 16, 128)])
+    with pytest.raises(AssertionError, match="pool-sized copy is back"):
+        chip_smoke._report_largest_op("serving", summary,
+                                      [(3072, 2, 32, 16, 128)])

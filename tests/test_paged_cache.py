@@ -1235,3 +1235,234 @@ class TestSnapshotRestore:
         msg = str(ei.value)
         assert f"restore needs {live} live block(s)" in msg
         assert "cached-free" in msg and "blocks per slot" in msg
+
+
+# ---------------------------------------------------------------------
+# page-form pool writes on a donated pool (PR 29)
+# ---------------------------------------------------------------------
+
+_PW = dict(layers=2, heads=2, hd=8, bs=4, nb=24, seqs=4, mb=5)
+
+
+def _pw_cache(dtype, lens=(6, 0, 9, 3), extra=4):
+    """A small pool with random content in every page (so a write that
+    dropped or moved a neighbouring row would show), slots covered to
+    ``lens + extra``."""
+    import jax.numpy as jnp
+    from paddle_tpu.framework.tensor import Tensor
+    g = _PW
+    cache = PagedKVCache(g["layers"], g["heads"], g["hd"], g["bs"],
+                         g["nb"], g["seqs"], max_blocks_per_seq=g["mb"],
+                         dtype=dtype, prefix_cache=True)
+    rng = np.random.RandomState(7)
+    for i, p in enumerate(cache.pools):
+        if cache.quantized:
+            cache.pools[i] = Tensor(jnp.asarray(
+                rng.randint(-127, 128, p.shape).astype(np.int8)))
+            cache.scales[i] = Tensor(jnp.asarray(
+                rng.rand(*cache.scales[i].shape).astype(np.float32)))
+        else:
+            cache.pools[i] = Tensor(jnp.asarray(
+                rng.randn(*p.shape).astype(np.float32)).astype(
+                    p.data.dtype))
+    for slot, n in enumerate(lens):
+        if n:
+            cache.ensure(slot, n + extra, write_from=n)
+    return cache
+
+
+def _pw_kv(rng, b, n):
+    g = _PW
+    mk = lambda: paddle.to_tensor(
+        rng.randn(b, n, g["heads"], g["hd"]).astype(np.float32))
+    return mk(), mk(), mk()
+
+
+def _pw_state(cache):
+    """Host copies of every pool (and scale array), as raw bytes."""
+    # COPIES: a numpy view of a CPU array keeps its buffer alive, and a
+    # buffer someone else holds is copied by the next write, not donated
+    out = [np.array(p.numpy()) for p in cache.pools]
+    if cache.quantized:
+        out += [np.array(s.numpy()) for s in cache.scales]
+    return out
+
+
+def _pw_row_scatter(cache, before, layer, k, v, blk, off):
+    """The row scatter the page-form write replaced, on host copies:
+    row r lands at [blk[r], :, :, off[r]]; rows routed to the trash
+    block 0 are dropped (nothing reads it unmasked)."""
+    import jax.numpy as jnp
+    from paddle_tpu.inference.paged_cache import _quant_rows
+    want = [a.copy() for a in before]
+    pool = want[layer]
+    k = np.asarray(k.numpy()).reshape((-1,) + tuple(k.shape[2:]))
+    v = np.asarray(v.numpy()).reshape((-1,) + tuple(v.shape[2:]))
+    if cache.quantized:
+        import jax
+        (k, ks), (v, vs) = (tuple(np.asarray(a) for a in jax.jit(
+            _quant_rows)(jnp.asarray(x))) for x in (k, v))
+        sc = want[len(cache.pools) + layer]
+    else:
+        k = np.asarray(jnp.asarray(k).astype(cache.pools[0].data.dtype))
+        v = np.asarray(jnp.asarray(v).astype(cache.pools[0].data.dtype))
+    for r, (b, o) in enumerate(zip(blk, off)):
+        if b == 0:
+            continue
+        pool[b, 0, :, o], pool[b, 1, :, o] = k[r], v[r]
+        if cache.quantized:
+            sc[b, 0, :, o], sc[b, 1, :, o] = ks[r], vs[r]
+    return want
+
+
+def _pw_same_bytes(got, want):
+    for i, (a, b) in enumerate(zip(got, want)):
+        # the trash block takes whatever lands there, in any order
+        assert a[1:].tobytes() == b[1:].tobytes(), f"array {i} differs"
+
+
+def _pw_decode(cache, rng, L):
+    lens = np.array([6, 0, 9, 3], np.int32)
+    mask = np.array([False, False, False, True])   # row 3 sits out
+    cache.set_decode_mask(mask)
+    q, k, v = _pw_kv(rng, _PW["seqs"], L)
+    before = _pw_state(cache)
+    pos = lens[:, None] + np.arange(L)[None, :]
+    tbl = cache.block_tables.copy()
+    tbl[mask] = 0
+    blk = tbl[np.arange(4)[:, None], pos // _PW["bs"]].reshape(-1)
+    cache.views[1].decode(q, k, v, np.asarray(lens))
+    return before, 1, k, v, blk, (pos % _PW["bs"]).reshape(-1)
+
+
+def _pw_chunk(cache, rng, via):
+    # positions 5 .. 12 of slot 2, the first two adopted (write_start 7
+    # lies inside page 1): rows below it must not be written
+    start, C, ws, slot = 5, 8, 7, 2
+    q, k, v = _pw_kv(rng, 1, C)
+    before = _pw_state(cache)
+    pos = np.arange(start, start + C)
+    blk = np.where(pos >= ws, cache.block_tables[slot][pos // _PW["bs"]],
+                   0)
+    if via == "view":
+        cache.prefill_views(slot, write_start=ws)[1].decode(
+            q, k, v, np.asarray([start], np.int32))
+    else:
+        cache.write_prefill_chunk(slot, 1, k, v, start, write_start=ws)
+    return before, 1, k, v, blk, pos % _PW["bs"]
+
+
+def _pw_ragged(cache, rng, L=1):
+    # a prefill chunk of slot 1 (fresh), one of slot 2 whose first rows
+    # are an adopted prefix, and the decode rows with slot 3 masked and
+    # slots 1, 2 mid-prefill (masked too)
+    cache.ensure(1, 6, write_from=0)
+    lens = np.array([6, 0, 9, 3], np.int64)
+    mask = np.array([False, True, True, True])
+    cache.set_decode_mask(mask)
+    desc = [("prefill", 1, 0, 6, 0), ("prefill", 2, 5, 7, 8),
+            ("decode", lens.copy(), L)]
+    views = cache.ragged_views(desc)
+    lay = views[0]._layout
+    q, k, v = _pw_kv(rng, 1, lay.total_rows)
+    before = _pw_state(cache)
+    views[0].decode(q, k, v, None)
+    return before, 0, k, v, lay.blk_np, lay.off_np
+
+
+_PW_APPENDS = {
+    "decode": lambda c, r: _pw_decode(c, r, 1),
+    "verify": lambda c, r: _pw_decode(c, r, 3),
+    "chunk_view": lambda c, r: _pw_chunk(c, r, "view"),
+    "chunk_call": lambda c, r: _pw_chunk(c, r, "call"),
+    "ragged": lambda c, r: _pw_ragged(c, r),
+    "ragged_verify": lambda c, r: _pw_ragged(c, r, L=2),
+}
+
+
+class TestPageFormWrite:
+    @pytest.mark.parametrize("dtype", ["float32", "bfloat16", "int8"])
+    @pytest.mark.parametrize("append", sorted(_PW_APPENDS))
+    def test_equals_the_row_scatter_byte_for_byte(self, append, dtype):
+        cache = _pw_cache(dtype)
+        before, layer, k, v, blk, off = _PW_APPENDS[append](
+            cache, np.random.RandomState(3))
+        assert (np.asarray(blk) != 0).any() and (np.asarray(blk) == 0).any()
+        _pw_same_bytes(_pw_state(cache),
+                       _pw_row_scatter(cache, before, layer, k, v, blk, off))
+
+    @pytest.mark.parametrize("dtype", ["float32", "int8"])
+    @pytest.mark.parametrize("write", sorted(_PW_APPENDS)
+                             + ["cow_split", "write_prefill"])
+    def test_old_pool_is_deleted_and_every_reader_still_reads(self, write,
+                                                              dtype):
+        cache = _pw_cache(dtype)
+        cache.register_prefix(0, [b"h0"])
+        held = [p.data for p in cache.pools] + \
+            [s.data for s in (cache.scales or [])]
+        view = cache.views[0]
+        if write == "cow_split":
+            cache.fork(0, 1, 6)
+            cache.ensure(1, 7, write_from=6)       # splits the tail page
+            touched = range(len(cache.pools))
+        elif write == "write_prefill":
+            rows = [paddle.to_tensor(np.random.RandomState(5).randn(
+                2, 1, _PW["heads"], _PW["bs"] * _PW["mb"],
+                _PW["hd"]).astype(np.float32))] * _PW["layers"]
+            cache.ensure(1, 8)
+            cache.write_prefill(1, rows, 8)
+            touched = range(len(cache.pools))
+        else:
+            _, layer, *_ = _PW_APPENDS[write](cache,
+                                              np.random.RandomState(3))
+            touched = [layer]
+        n = len(cache.pools)
+        for pi in touched:
+            assert held[pi].is_deleted()
+            if cache.quantized:
+                assert held[n + pi].is_deleted()
+        # whoever reads takes the array at the moment it reads
+        assert view.pool.numpy().shape == tuple(view.shape)
+        assert cache.check_invariants(deep=True)
+        snap = cache.snapshot()
+        assert snap["payload"].shape[0] == len(snap["blocks"])
+        slc = cache.export_slice(0, [b"h0"])
+        assert slc["payload"].shape[0] == 1
+        again = PagedKVCache.restore(snap)
+        assert again.snapshot()["payload"].tobytes() == \
+            snap["payload"].tobytes()
+        stats = cache.take_write_stats()
+        assert 0 < stats["pool_bytes_written"] < stats["pool_bytes"] \
+            == cache.pool_bytes_total()
+        assert cache.take_write_stats()["pages_written"] == 0
+
+    def test_layout_lists_a_real_page_once_and_sizes_by_q_lens(self):
+        from paddle_tpu.inference.paged_cache import _pages_spanned
+        sizes = set()
+        for lens, start in (((6, 0, 9, 3), 5), ((1, 0, 14, 8), 2)):
+            cache = _pw_cache("float32", lens=lens, extra=2)
+            cache.ensure(1, 12, write_from=0)
+            cache.ensure(2, start + 7, write_from=start)
+            cache.set_decode_mask(np.array([False, True, True, False]))
+            lay = cache.ragged_views(
+                [("prefill", 1, 0, 6, 0), ("prefill", 2, start, 7, start),
+                 ("decode", np.asarray(lens, np.int64), 1)])[0]._layout
+            real = lay.pg_ids_np[lay.pg_ids_np != 0]
+            assert len(set(real.tolist())) == real.shape[0] > 0
+            assert lay.pg_ids_np[0] == 0        # the trash slot
+            # every real row finds its page through its slot
+            rows = lay.blk_np != 0
+            np.testing.assert_array_equal(
+                lay.pg_ids_np[lay.pg_slot_np[rows]], lay.blk_np[rows])
+            assert (lay.pg_slot_np[~rows] == 0).all()
+            assert lay.n_pages == 1 + sum(
+                _pages_spanned(q, _PW["bs"]) for q in lay.q_lens)
+            sizes.add((lay.q_lens, lay.n_pages))
+        assert len(sizes) == 1      # same q_lens, other positions: same P
+
+    def test_layout_refuses_a_page_two_sequences_write(self):
+        cache = _pw_cache("float32")
+        cache.free_seq(1)
+        cache.fork(0, 1, 6)        # slots 0 and 1 share the tail page
+        with pytest.raises(AssertionError, match="two sequences"):
+            cache.ragged_views([("decode", np.array([6, 6, 9, 3]), 1)])

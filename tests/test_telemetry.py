@@ -16,6 +16,7 @@ The acceptance bars:
 """
 import json
 import os
+import re
 import sys
 
 import numpy as np
@@ -645,9 +646,15 @@ class TestTimelineAndLifecycle:
         # per-step gauges: pool tiers + queue depths + tenant charge
         gauges = [ev for ev in col.events if ev.get("ph") == "C"]
         tracks = {ev["name"] for ev in gauges}
-        assert tracks == {"pool", "queue", "tenant_blocks"}
+        assert tracks == {"pool", "pool_write", "queue", "tenant_blocks"}
         pool = next(ev for ev in gauges if ev["name"] == "pool")
         assert {"active", "cached_free", "free"} <= set(pool["args"])
+        # what each step's K/V appends moved: pages of the donated
+        # pool, a small share of it, and at least a row a step
+        writes = [ev["args"] for ev in gauges if ev["name"] == "pool_write"]
+        assert all(w["rows_written"] > 0 and
+                   0 < w["pool_bytes_written"] < w["pool_bytes"]
+                   and w["pages_written"] > 0 for w in writes)
         # spans nest sanely: phases sit inside their step's interval
         steps = [(ev["ts"], ev["ts"] + ev["dur"]) for ev in col.events
                  if ev.get("ph") == "X" and ev["name"] == "verify"]
@@ -755,6 +762,11 @@ class TestTimelineAndLifecycle:
         out = capsys.readouterr().out
         assert "paged_attn.grid_steps:" in out
         assert "paged_attn.pages_per_step:" in out
+        # the pool writes beside the pool's size
+        assert "pool_write.pages_written:" in out
+        assert re.search(r"pool writes: [\d.]+ page\(s\) and [\d.]+ "
+                         r"row\(s\) a step over all layers, [\d.]+ MB "
+                         r"of a [\d.]+ MB pool", out), out
         assert trace_report.main([path, "--json"]) == 0
         rep = json.loads(capsys.readouterr().out)
         assert rep["data"]["gauges"]["paged_attn.grid_steps"][

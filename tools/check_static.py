@@ -241,6 +241,9 @@ SNAPSHOT_ATTR_ALLOW: Dict[str, Dict[str, str]] = {
         "_decode_masked": "per-step mask — re-set by the next step",
         "block_tables": "derived from seq_blocks during restore",
         "_tenant_charge": "derived via _charge() during restore",
+        "_written": "pages / rows moved since the last pool_write gauge "
+                    "sample (PR 29): observational, reset by every "
+                    "take_write_stats()",
     },
     "PagedServingEngine": {
         "model": "weights are the caller's problem (restore arg)",

@@ -228,6 +228,17 @@ def summarize(trace: dict, tenant: str = None,
                      f"context behind the window "
                      f"({100.0 * behind / max(total, 1):.1f} %): skipped "
                      f"by the launch, not freed")
+    writes = counters.get("pool_write", {})
+    if "pages_written" in writes:
+        steps, pages = writes["pages_written"][:2]
+        moved = writes["pool_bytes_written"][1] / steps
+        pool = writes["pool_bytes"][4]
+        lines.append(f"  pool writes: {pages / steps:g} page(s) and "
+                     f"{writes['rows_written'][1] / steps:g} row(s) a "
+                     f"step over all layers, {moved / 1e6:.2f} MB of a "
+                     f"{pool / 1e6:.1f} MB pool "
+                     f"({100.0 * moved / max(pool, 1):.3f} %): pages of "
+                     f"a donated pool, written in place")
     if insts:
         lines.append(f"  instants: "
                      + ", ".join(f"{k} x{v}"
