@@ -15,7 +15,8 @@ user calls, at the full width of a model each supports, depth cut:
             against the cache-free forward of the same core.
   kernels   every kernel in ``paddle_tpu/ops/pallas`` compiled once at a
             production shape against its jnp reference.
-  trainer   ``LlamaSpmdTrainer`` as ``bench.py`` configures it (Llama-2-7B
+  trainer   ``LlamaSpmdTrainer`` with the settings of
+            ``benchmark/configs/mistral-7b-1chip.json`` (Llama-2-7B
             widths, 2 layers, b16 x s2048, bf16): five steps on one
             repeated batch, loss finite and falling, flash kernel in the
             lowered step.
@@ -384,7 +385,7 @@ def serving_phase(*, d_model=4096, heads=32, ffn=16384, layers=4,
     failed check."""
     import jax
     import jax.numpy as jnp
-    from paddle_tpu.incubate.nn.fused_transformer import _use_decode_kernel
+    from paddle_tpu.framework import device
     from paddle_tpu.inference.resilience import RequestOutcome
     from paddle_tpu.inference.router import build_server_from_spec
     pa = _kernel_module("paged_attention")
@@ -431,7 +432,7 @@ def serving_phase(*, d_model=4096, heads=32, ffn=16384, layers=4,
             # compiled mp >= 2 program attends with gather + sdpa inside
             # the one jitted step (ROADMAP S2) and never enters the
             # kernel wrapper
-            kernel_on_path = mp == 1 and _use_decode_kernel()
+            kernel_on_path = mp == 1 and device.use_pallas_kernels()
             if expect_kernel and mp == 1 and not kernel_on_path:
                 raise AssertionError("the paged-attention kernel is not on "
                                      "the engine's path")
@@ -829,8 +830,8 @@ def _make_trainer(*, hidden, inter, heads, vocab, layers, seq, dtype, seed,
                       num_attention_heads=heads, num_key_value_heads=heads,
                       max_position_embeddings=seq)
     dt = getattr(jnp, dtype)
-    # exactly bench.py's configuration: no remat, bf16 moments, unrolled
-    # layer scan
+    # the settings of benchmark/configs/mistral-7b-1chip.json: no remat,
+    # bf16 moments, unrolled layer scan
     return LlamaSpmdTrainer(cfg, compute_dtype=dt, remat=False,
                             remat_policy="full", moments_dtype=dt,
                             scan_unroll=2, seed=seed, n_micro=n_micro)

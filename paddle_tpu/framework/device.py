@@ -12,6 +12,8 @@ import os
 
 import jax
 
+from ..flags import get_flag
+
 
 def on_tpu() -> bool:
     """THE platform predicate: every kernel-vs-interpret, packed-step and
@@ -19,6 +21,17 @@ def on_tpu() -> bool:
     ``except``: a backend that cannot list its devices is an error to
     surface, not a reason to take the CPU path."""
     return jax.devices()[0].platform == "tpu"
+
+
+def use_pallas_kernels() -> bool:
+    """Do Pallas kernels run here: on a TPU, with
+    ``FLAGS_enable_pallas_kernels`` on. THE answer to "kernel or jnp
+    fallback" for every caller (the paged-cache seam, the serving
+    cores, the scheduler's packed step, sdpa, the int8 matmul). Call it
+    by attribute on this module (``device.use_pallas_kernels()``), never
+    bound by name at import: a test that patches it here then reaches
+    every caller at once."""
+    return on_tpu() and bool(get_flag("FLAGS_enable_pallas_kernels", True))
 
 
 def compile_cache_dir() -> str:

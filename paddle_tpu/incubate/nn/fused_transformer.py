@@ -18,16 +18,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ...framework.device import on_tpu
+from ...framework import device
 from ...framework.op import apply
 from ...framework.tensor import Tensor
 from ... import nn
 from ...nn import functional as F
-
-
-def _use_decode_kernel():
-    from ...flags import get_flag
-    return on_tpu() and bool(get_flag("FLAGS_enable_pallas_kernels", True))
 
 
 class FusedMultiHeadAttention(nn.Layer):
@@ -234,8 +229,7 @@ class FusedMultiTransformer(nn.Layer):
                 # scalar/shape-[1] time_step broadcasts across rows
                 t = jnp.broadcast_to(t.reshape(-1).astype(jnp.int32),
                                      (b,))
-                attn = caches[i].decode(q, k, v, t,
-                                        use_kernel=_use_decode_kernel())
+                attn = caches[i].decode(q, k, v, t)
                 new_caches.append(caches[i])
             elif caches is not None and time_step is not None:
                 # decode: append k/v at time_step into the static cache.
@@ -284,7 +278,7 @@ class FusedMultiTransformer(nn.Layer):
                         return jnp.stack([kc, vc])
                     cache = apply(upd, (cache, k, v), op_name="cache_kv")
                 new_caches.append(cache)
-                if l == 1 and _use_decode_kernel():
+                if l == 1 and device.use_pallas_kernels():
                     # flash-decoding over the static cache (ref
                     # fused_multi_transformer_op.cu.h:835 masked mha)
                     from ...ops.pallas.decode_attention import \

@@ -23,9 +23,7 @@ gap with two objects:
   integers the original event added, which is what makes the
   conservation check exact instead of approximate. The same numbers,
   paired with the collector's ``span.model`` durations, yield the
-  model-phase MFU/MBU the ragged kernel's tile sizing was missing
-  (tools/tile_report.py reads durations; tools/cost_report.py now
-  reads work/duration).
+  model-phase MFU/MBU (tools/cost_report.py reads work/duration).
 
 * ``CostLedger`` — the opt-in goodput ledger (``ledger=`` on
   ``PagedServingEngine`` / ``SpeculativeEngine``, the FaultInjector /

@@ -47,7 +47,6 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..framework.tensor import Tensor
-from ..incubate.nn.fused_transformer import _use_decode_kernel
 from .moe_serving import (dropless_experts, expert_row_block,
                           sigmoid_route, swiglu)
 from .paged_cache import PagedLayerCache
@@ -249,8 +248,8 @@ def _jitted(fn, *static):
 
 
 def decoder_block(cfg: DecoderConfig, layer: int, p: dict, x, positions,
-                  view, t, *, use_kernel: bool, counters=None,
-                  collector=None, route_tap=None):
+                  view, t, *, counters=None, collector=None,
+                  route_tap=None):
     """THE block: layer ``layer`` of type ``cfg.layer_types[layer]`` on
     rows ``x`` [B, L, d] float32 at ``positions`` [B, L], attending
     through ``view`` (a paged view: it appends this call's K/V and
@@ -266,8 +265,7 @@ def decoder_block(cfg: DecoderConfig, layer: int, p: dict, x, positions,
             f"view's window is {view.window}: build the cache with "
             f"PagedKVCache.for_model(core, ...)")
     q, k, v, gate = _jitted(_attn_in, cfg, sliding)(p, x, positions)
-    attn = view.decode(Tensor(q), Tensor(k), Tensor(v), t,
-                       use_kernel=use_kernel).data
+    attn = view.decode(Tensor(q), Tensor(k), Tensor(v), t).data
     if not cfg.is_moe(layer):
         return _jitted(_dense_tail, cfg)(p, x, attn, gate)
     h, m = _jitted(_attn_out, cfg)(p, x, attn, gate)
@@ -395,12 +393,10 @@ class DecoderCore:
         if self._moe_layers:
             self._calls[kind] += 1
             self._rows[kind] += b * l
-        use_kernel = _use_decode_kernel()
         for i in range(self.num_layers):
             x = decoder_block(
                 self.config, i, self.params[i], x, positions, caches[i],
-                t, use_kernel=use_kernel,
-                counters=self._counters[kind].get(i),
+                t, counters=self._counters[kind].get(i),
                 collector=self.collector, route_tap=self.route_tap)
         return Tensor(x), list(caches)
 

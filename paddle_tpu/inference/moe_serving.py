@@ -66,11 +66,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..framework import device as _device
 from ..framework.op import apply, unwrap
 from ..framework.tensor import Parameter
 from .. import nn
-from ..incubate.nn.fused_transformer import (FusedMultiTransformer,
-                                             _use_decode_kernel)
+from ..incubate.nn.fused_transformer import FusedMultiTransformer
 from ..ops.pallas.grouped_gemm import gmm
 
 
@@ -261,7 +261,7 @@ class MoeServingCore(FusedMultiTransformer):
 
     def _kernel_on(self):
         if self._use_kernel is None:
-            return _use_decode_kernel()
+            return _device.use_pallas_kernels()
         return bool(self._use_kernel)
 
     def shard_experts(self, ep, devices=None):

@@ -94,8 +94,12 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..framework import device as _device
 from ..framework.op import _trace_clean, apply, unwrap
 from ..framework.tensor import Tensor
+from ..ops.pallas.paged_attention import (gather_pages, launch_plan,
+                                          paged_attention_ragged,
+                                          resolve_tile_q)
 
 __all__ = ["BlockOOM", "BlockAllocator", "PagedKVCache",
            "PagedLayerCache", "PagedPrefillView", "PagedRaggedView",
@@ -463,6 +467,55 @@ def _group_heads(k_full, v_full, q_heads: int):
             Tensor(jnp.repeat(v_full.data, g, axis=2)))
 
 
+def _gathered(pool, tables, scales, q_heads: int):
+    """What every fallback attends over: the pages ``tables`` names,
+    gathered dense (the kernel module's gather, so both paths share one
+    layout definition; int8 pages dequantize inside it) and grouped for
+    ``q_heads`` query heads. Returns (k, v, positions gathered)."""
+    gargs = (pool, tables) if scales is None else (pool, tables, scales)
+    k_full, v_full = apply(gather_pages, gargs, op_name="paged_gather")
+    k_full, v_full = _group_heads(k_full, v_full, q_heads)
+    return k_full, v_full, k_full.shape[1]
+
+
+def _mask(kpos, qpos, window):
+    """The additive float32 mask the sdpa fallbacks pass."""
+    return Tensor(jnp.where(_visible(kpos, qpos, window), 0.0, -1e30)
+                  .astype(jnp.float32))
+
+
+def _attend(q, pool, scales, tables, lens, rows, window, fallback):
+    """THE seam between the model blocks and the paged cache: the one
+    place that decides "Pallas kernel or jnp fallback" for a view's
+    attention (``device.use_pallas_kernels()``), and the one call of the
+    kernel. A view appends its K/V, then hands over what is its own:
+    ``q`` [B, L, nh, hd] as the block gave it, the written ``pool`` (and
+    int8 ``scales`` or None), its block ``tables`` [n_seq, MB], and its
+    ``fallback`` (no arguments), which is what CPU tier-1 runs. ``rows``
+    is static: a packed batch gives its ``q_lens`` tuple, and ``lens``
+    [n_seq] are then the lengths with those rows in; a uniform call
+    (every sequence L rows: decode, verify, a prefill chunk) gives the
+    int L and ``lens`` are the rows' START positions, the block's
+    ``time_step`` as it came: the program adds L itself, so the view
+    pays no dispatch of its own for it."""
+    if not _device.use_pallas_kernels():
+        return fallback()
+
+    def launch(p, q_, ln, bta, sc=None):
+        if isinstance(rows, int):
+            q_lens, kv_lens = (rows,) * bta.shape[0], ln + rows
+        else:
+            q_lens, kv_lens = rows, ln
+        out = paged_attention_ragged(
+            q_.reshape((-1,) + q_.shape[2:]), p, bta, q_lens, kv_lens,
+            kv_scales=sc, window=window)
+        return out.reshape(q_.shape)
+
+    args = (pool, q, lens, tables)
+    return apply(launch, args if scales is None else args + (scales,),
+                 op_name="paged_attention")
+
+
 class PagedLayerCache:
     """One layer's view of the paged cache — the object that rides in
     the ``caches=`` list of FusedMultiTransformer.forward. Duck-typed
@@ -509,7 +562,7 @@ class PagedLayerCache:
         rows: row b's i-th token sits at t[b] + i."""
         return t.reshape(-1, 1) + jnp.arange(rows, dtype=jnp.int32)
 
-    def decode(self, q, k, v, t, use_kernel: bool = False):
+    def decode(self, q, k, v, t):
         """q/k/v: [B, L, H, D] Tensors (L == 1 is the plain decode
         step; L > 1 is the multi-query speculative-verification step —
         row b's L tokens land at positions t[b] .. t[b]+L-1 and each
@@ -520,13 +573,12 @@ class PagedLayerCache:
         ``ensure(row, t[row]+L, write_from=t[row])`` for every active
         row — every write position must be covered by the row's block
         table (and shared pages in the write range COW-split).
-        use_kernel routes to the Pallas paged kernel (TPU); otherwise
-        a pure-jnp gather + the SAME masked-sdpa codepath the dense
+        ``_attend`` picks the path: the Pallas paged kernel, or a
+        pure-jnp gather + the SAME masked-sdpa codepath the dense
         ragged decode uses, so paged and dense CPU decode are
         bit-identical when page capacity == dense max_len."""
         import jax as _jax
         c = self._cache
-        W = self.window
         B, L = q.shape[0], q.shape[1]
         if B != c.max_seqs:
             raise ValueError(f"batch {B} != cache max_seqs {c.max_seqs}")
@@ -568,73 +620,31 @@ class PagedLayerCache:
         new_pool, new_sc = c._write_pool(
             self._pi, _append_rows, (c.block_size, L), k, v, tt, bt,
             pages=B * _pages_spanned(L, c.block_size), rows=B * L)
+        return _attend(
+            q, new_pool, new_sc, bt, tt, L, self.window,
+            lambda: self._sdpa_over_pages(q, t, new_pool, new_sc, bt))
 
-        if use_kernel:
-            if c.quantized:
-                if L == 1:
-                    def dec_q(p, sc, q_, tv, bta):
-                        from ..ops.pallas.paged_attention import \
-                            paged_attention
-                        return paged_attention(q_[:, 0], p, bta,
-                                               tv + 1, kv_scales=sc,
-                                               window=W)[:, None]
-                    return apply(dec_q, (new_pool, new_sc, q, tt, bt),
-                                 op_name="paged_attention_q")
-
-                def dec_multi_q(p, sc, q_, tv, bta):
-                    from ..ops.pallas.paged_attention import \
-                        paged_attention_multi
-                    return paged_attention_multi(q_, p, bta, tv + L,
-                                                 kv_scales=sc,
-                                                 window=W)
-                return apply(dec_multi_q,
-                             (new_pool, new_sc, q, tt, bt),
-                             op_name="paged_attention_multi_q")
-            if L == 1:
-                def dec(p, q_, tv, bta):
-                    from ..ops.pallas.paged_attention import \
-                        paged_attention
-                    return paged_attention(q_[:, 0], p, bta, tv + 1,
-                                           window=W)[:, None]
-                return apply(dec, (new_pool, q, tt, bt),
-                             op_name="paged_attention")
-
-            def dec_multi(p, q_, tv, bta):
-                from ..ops.pallas.paged_attention import \
-                    paged_attention_multi
-                return paged_attention_multi(q_, p, bta, tv + L,
-                                             window=W)
-            return apply(dec_multi, (new_pool, q, tt, bt),
-                         op_name="paged_attention_multi")
-
-        # CPU / fallback: gather pages dense (the kernel module's
-        # gather, so both paths share one layout definition —
-        # quantized pools dequantize inside the gather), then
-        # mirror the dense ragged decode branch (same mask, same sdpa
-        # op executable). For L > 1 the L axis FOLDS INTO THE BATCH
-        # axis (virtual rows [b*L+i] share slot b's pages, query i at
-        # position t[b]+i): the sdpa executable then has the exact
-        # q-length-1 shape of the plain decode step, which is what
-        # makes a multi-token verification bit-identical to L single
-        # steps — an [L, S] attention fuses with different reduction
-        # grouping than L [1, S] attentions (~1 ulp), the same
-        # lowering trap as scheduler.MIN_PREFILL_SUFFIX_ROWS.
+    def _sdpa_over_pages(self, q, t, pool, scales, bt):
+        """The fallback: gather the pages dense, then mirror the dense
+        ragged decode branch (same mask, same sdpa op executable). For
+        L > 1 the L axis FOLDS INTO THE BATCH axis (virtual rows
+        [b*L+i] share slot b's pages, query i at position t[b]+i): the
+        sdpa executable then has the exact q-length-1 shape of the
+        plain decode step, which is what makes a multi-token
+        verification bit-identical to L single steps — an [L, S]
+        attention fuses with different reduction grouping than L
+        [1, S] attentions (~1 ulp), the same lowering trap as
+        scheduler.MIN_PREFILL_SUFFIX_ROWS."""
         from ..nn import functional as F
-        from ..ops.pallas.paged_attention import gather_pages
-        gargs = (new_pool, bt) if new_sc is None \
-            else (new_pool, bt, new_sc)
-        k_full, v_full = apply(gather_pages, gargs,
-                               op_name="paged_gather")
-        k_full, v_full = _group_heads(k_full, v_full, q.shape[2])
-        S = k_full.shape[1]
+        W = self.window
+        B, L = q.shape[0], q.shape[1]
+        k_full, v_full, S = _gathered(pool, bt, scales, q.shape[2])
         if L == 1:
             qpos = (t[:, None, None, None]
                     + jnp.arange(1)[None, None, :, None])
             kpos = jnp.arange(S)[None, None, None, :]
-            mask = Tensor(jnp.where(_visible(kpos, qpos, W), 0.0, -1e30)
-                          .astype(jnp.float32))
-            return F.scaled_dot_product_attention(q, k_full, v_full,
-                                                  attn_mask=mask)
+            return F.scaled_dot_product_attention(
+                q, k_full, v_full, attn_mask=_mask(kpos, qpos, W))
 
         qf = apply(lambda a: a.reshape((B * L, 1) + a.shape[2:]),
                    (q,), op_name="spec_fold_q")
@@ -646,9 +656,8 @@ class PagedLayerCache:
                                           B))
         qpos = tf[:, None, None, None]
         kpos = jnp.arange(S)[None, None, None, :]
-        mask = Tensor(jnp.where(_visible(kpos, qpos, W), 0.0, -1e30)
-                      .astype(jnp.float32))
-        out = F.scaled_dot_product_attention(qf, kf, vf, attn_mask=mask)
+        out = F.scaled_dot_product_attention(
+            qf, kf, vf, attn_mask=_mask(kpos, qpos, W))
         return apply(lambda a: a.reshape((B, L) + a.shape[2:]),
                      (out,), op_name="spec_unfold")
 
@@ -670,9 +679,8 @@ class PagedPrefillView:
     lowers to a GEMV with different accumulation than the same row
     inside a multi-row call (scheduler.MIN_PREFILL_SUFFIX_ROWS), while
     multi-row sdpa results are per-row invariant to BOTH the chunk
-    length and the masked key extent. On TPU the Pallas
-    ``paged_attention_prefill`` kernel serves the same contract
-    through the scalar-prefetch block table."""
+    length and the masked key extent. On TPU the ragged kernel serves
+    the same contract through the scalar-prefetch block table."""
 
     is_paged = True
 
@@ -716,7 +724,7 @@ class PagedPrefillView:
         rows: row b's i-th token sits at t[b] + i."""
         return t.reshape(-1, 1) + jnp.arange(rows, dtype=jnp.int32)
 
-    def decode(self, q, k, v, t, use_kernel: bool = False):
+    def decode(self, q, k, v, t):
         """q/k/v: [1, C, H, D] — one prompt chunk for this view's
         slot, starting at absolute position t[0] (traced int32 [1]).
         Appends the chunk's K/V through the slot's block-table row
@@ -726,7 +734,6 @@ class PagedPrefillView:
         every write position covered and COW-split."""
         import jax as _jax
         c = self._cache
-        W = self.window
         B, C = q.shape[0], q.shape[1]
         if B != 1:
             raise ValueError(
@@ -751,43 +758,21 @@ class PagedPrefillView:
         new_pool, new_sc = c._write_pool(
             self._pi, _append_rows, (c.block_size, C), k, v, tt, bt, ws,
             pages=_pages_spanned(C, c.block_size) + 1, rows=C)
+        return _attend(
+            q, new_pool, new_sc, bt, tt, C, self.window,
+            lambda: self._sdpa_over_pages(q, t, new_pool, new_sc, bt))
 
-        if use_kernel:
-            if c.quantized:
-                def att_q(p, sc, q_, tv, bta):
-                    from ..ops.pallas.paged_attention import \
-                        paged_attention_prefill
-                    return paged_attention_prefill(q_, p, bta, tv,
-                                                   kv_scales=sc,
-                                                   window=W)
-                return apply(att_q, (new_pool, new_sc, q, tt, bt),
-                             op_name="paged_attention_prefill_q")
-
-            def att(p, q_, tv, bta):
-                from ..ops.pallas.paged_attention import \
-                    paged_attention_prefill
-                return paged_attention_prefill(q_, p, bta, tv,
-                                               window=W)
-            return apply(att, (new_pool, q, tt, bt),
-                         op_name="paged_attention_prefill")
-
-        # CPU / fallback: gather the slot's pages dense and run the
-        # chunk as ONE multi-row masked sdpa (see class docstring; the
-        # mask mirrors the dense prefill branch's construction)
+    def _sdpa_over_pages(self, q, t, pool, scales, bt):
+        """The fallback: gather the slot's pages dense and run the
+        chunk as ONE multi-row masked sdpa (see class docstring; the
+        mask mirrors the dense prefill branch's construction)."""
         from ..nn import functional as F
-        from ..ops.pallas.paged_attention import gather_pages
-        gargs = (new_pool, bt) if new_sc is None \
-            else (new_pool, bt, new_sc)
-        k_full, v_full = apply(gather_pages, gargs,
-                               op_name="paged_gather")
-        k_full, v_full = _group_heads(k_full, v_full, q.shape[2])
-        S = k_full.shape[1]
-        qpos = t[0] + jnp.arange(C)[:, None]
+        k_full, v_full, S = _gathered(pool, bt, scales, q.shape[2])
+        qpos = t[0] + jnp.arange(q.shape[1])[:, None]
         kpos = jnp.arange(S)[None, :]
-        mask = Tensor(jnp.where(_visible(kpos, qpos, W), 0.0, -1e30)
-                      .astype(jnp.float32))
-        return F.scaled_dot_product_attention(q, k_full, v_full,
-                                              attn_mask=mask)
+        return F.scaled_dot_product_attention(
+            q, k_full, v_full,
+            attn_mask=_mask(kpos, qpos, self.window))
 
 
 class _RaggedLayout:
@@ -800,12 +785,11 @@ class _RaggedLayout:
     the decode mask first."""
 
     __slots__ = ("segs", "q_lens", "pg_ids", "route", "kv_lens",
-                 "bt_all", "tile_q", "tile_kv", "total_rows", "n_pages",
+                 "bt_all", "total_rows", "n_pages",
                  "blk_np", "off_np", "pos_np", "pg_ids_np", "pg_slot_np",
                  "kv_lens_np", "_pos", "_cache")
 
-    def __init__(self, cache: "PagedKVCache", segments, tile_q=None,
-                 tile_kv=None):
+    def __init__(self, cache: "PagedKVCache", segments):
         bs = cache.block_size
         tbl = cache.block_tables
         masked_tbl = tbl
@@ -894,8 +878,6 @@ class _RaggedLayout:
         self.route = jnp.asarray(np.stack([self.pg_slot_np, self.off_np]))
         self.kv_lens = Tensor(jnp.asarray(kv_lens, jnp.int32))
         self.bt_all = Tensor(jnp.asarray(np.stack(bt_rows), jnp.int32))
-        self.tile_q = tile_q
-        self.tile_kv = tile_kv
         self._cache = cache
 
     def _page_list(self, bs: int):
@@ -954,17 +936,15 @@ class _RaggedLayout:
         query head a kv head as the serving cores have): host
         arithmetic over shapes, for the collector's ``paged_attn``
         gauge."""
-        from ..ops.pallas.paged_attention import (launch_plan,
-                                                  resolve_tile_q)
         c = self._cache
-        tile_q = resolve_tile_q(self.q_lens, self.tile_q)
+        tile_q = resolve_tile_q(self.q_lens)
         return launch_plan(
             sum(-(-ql // tile_q) for ql in self.q_lens),
             c.kv_heads_per_shard,
             tile_q * (c.num_heads // c.num_kv_heads),
             c.max_blocks_per_seq,
             c.block_size, c.head_dim, c.pools[0].data.dtype.itemsize,
-            quantized=c.quantized, tile_kv=self.tile_kv)
+            quantized=c.quantized)
 
 
 class PagedRaggedView:
@@ -1031,14 +1011,13 @@ class PagedRaggedView:
         the layout (``t`` says nothing about a packed batch)."""
         return self._layout.positions()
 
-    def decode(self, q, k, v, t, use_kernel: bool = False):
+    def decode(self, q, k, v, t):
         """q/k/v: [1, R, H, D] — the packed mixed batch. ``t`` is
         ignored: the layout carries every row's absolute position.
         PRECONDITION: every segment's write range is covered and
         COW-split (the scheduler's planning pass ensure()s chunk by
         chunk) and the decode mask is set."""
         c = self._cache
-        W = self.window
         lay = self._layout
         if q.shape[0] != 1 or q.shape[1] != lay.total_rows:
             raise ValueError(
@@ -1053,101 +1032,56 @@ class PagedRaggedView:
         new_pool, new_sc = c._write_pool(
             self._pi, _ragged_append, (), k, v, lay.pg_ids, lay.route,
             pages=lay.n_pages, rows=lay.total_rows)
+        return _attend(
+            q, new_pool, new_sc, lay.bt_all, lay.kv_lens, lay.q_lens,
+            self.window,
+            lambda: self._sdpa_by_segment(q, new_pool, new_sc))
 
-        if use_kernel:
-            q_lens, tile_q, tile_kv = (lay.q_lens, lay.tile_q,
-                                       lay.tile_kv)
-
-            if c.quantized:
-                def att_q(p, sc, q_, kvl, bts):
-                    from ..ops.pallas.paged_attention import \
-                        paged_attention_ragged
-                    return paged_attention_ragged(
-                        q_[0], p, bts, q_lens, kvl, tile_q=tile_q,
-                        tile_kv=tile_kv, kv_scales=sc,
-                        window=W)[None]
-                return apply(att_q, (new_pool, new_sc, q, lay.kv_lens,
-                                     lay.bt_all),
-                             op_name="paged_attention_ragged_q")
-
-            def att(p, q_, kvl, bts):
-                from ..ops.pallas.paged_attention import \
-                    paged_attention_ragged
-                return paged_attention_ragged(
-                    q_[0], p, bts, q_lens, kvl, tile_q=tile_q,
-                    tile_kv=tile_kv, window=W)[None]
-            return apply(att, (new_pool, q, lay.kv_lens, lay.bt_all),
-                         op_name="paged_attention_ragged")
-
-        # CPU / fallback: decompose into the per-phase executables
-        # (see class docstring) and re-pack the outputs in row order
+    def _sdpa_by_segment(self, q, pool, scales):
+        """The fallback: decompose into the per-phase executables (see
+        class docstring) and re-pack the outputs in row order."""
         from ..nn import functional as F
-        from ..ops.pallas.paged_attention import gather_pages
+        c = self._cache
+        W = self.window
         outs = []
-        for seg in lay.segs:
+        for seg in self._layout.segs:
             kind, lo, hi = seg[0], seg[1], seg[2]
             if kind == "prefill":
                 slot, start = seg[3], seg[4]
-                C = hi - lo
                 qs = Tensor(q.data[:, lo:hi])
-                bt = c.bt_row_tensor(slot)
-                gargs = (new_pool, bt) if new_sc is None \
-                    else (new_pool, bt, new_sc)
-                k_full, v_full = apply(gather_pages, gargs,
-                                       op_name="paged_gather")
-                k_full, v_full = _group_heads(k_full, v_full,
-                                              q.shape[2])
-                S = k_full.shape[1]
-                qpos = start + jnp.arange(C)[:, None]
+                k_full, v_full, S = _gathered(
+                    pool, c.bt_row_tensor(slot), scales, q.shape[2])
+                qpos = start + jnp.arange(hi - lo)[:, None]
                 kpos = jnp.arange(S)[None, :]
-                mask = Tensor(jnp.where(_visible(kpos, qpos, W), 0.0, -1e30)
-                              .astype(jnp.float32))
                 out = F.scaled_dot_product_attention(
-                    qs, k_full, v_full, attn_mask=mask)
+                    qs, k_full, v_full, attn_mask=_mask(kpos, qpos, W))
                 outs.append(out.data[0])
+                continue
+            lens, L = seg[3], seg[4]
+            k_full, v_full, S = _gathered(pool, c.bt_tensor(), scales,
+                                          q.shape[2])
+            tj = jnp.asarray(lens, jnp.int32)
+            kpos = jnp.arange(S)[None, None, None, :]
+            if L == 1:
+                qd = Tensor(q.data[0, lo:hi][:, None])  # [B,1,H,D]
+                qpos = (tj[:, None, None, None]
+                        + jnp.arange(1)[None, None, :, None])
             else:
-                lens, L = seg[3], seg[4]
-                bt = c.bt_tensor()
-                gargs = (new_pool, bt) if new_sc is None \
-                    else (new_pool, bt, new_sc)
-                k_full, v_full = apply(gather_pages, gargs,
-                                       op_name="paged_gather")
-                k_full, v_full = _group_heads(k_full, v_full,
-                                              q.shape[2])
-                S = k_full.shape[1]
-                if L == 1:
-                    B = hi - lo
-                    qd = Tensor(q.data[0, lo:hi][:, None])  # [B,1,H,D]
-                    tj = jnp.asarray(lens, jnp.int32)
-                    qpos = (tj[:, None, None, None]
-                            + jnp.arange(1)[None, None, :, None])
-                    kpos = jnp.arange(S)[None, None, None, :]
-                    mask = Tensor(jnp.where(_visible(kpos, qpos, W), 0.0, -1e30)
-                                  .astype(jnp.float32))
-                    out = F.scaled_dot_product_attention(
-                        qd, k_full, v_full, attn_mask=mask)
-                    outs.append(out.data[:, 0])
-                else:
-                    # multi-query verify rows: fold the L axis into
-                    # the batch axis, exactly the PagedLayerCache
-                    # L > 1 fallback — same q-length-1 sdpa
-                    # executable, so a packed verify stays
-                    # bit-identical to the per-phase step_multi call
-                    B = (hi - lo) // L
-                    qd = Tensor(q.data[0, lo:hi][:, None])  # [B*L,1,..]
-                    kf = Tensor(jnp.repeat(k_full.data, L, axis=0))
-                    vf = Tensor(jnp.repeat(v_full.data, L, axis=0))
-                    tj = jnp.asarray(lens, jnp.int32)
-                    tf = (jnp.repeat(tj, L)
-                          + jnp.tile(jnp.arange(L, dtype=jnp.int32),
-                                     B))
-                    qpos = tf[:, None, None, None]
-                    kpos = jnp.arange(S)[None, None, None, :]
-                    mask = Tensor(jnp.where(_visible(kpos, qpos, W), 0.0, -1e30)
-                                  .astype(jnp.float32))
-                    out = F.scaled_dot_product_attention(
-                        qd, kf, vf, attn_mask=mask)
-                    outs.append(out.data[:, 0])
+                # multi-query verify rows: fold the L axis into the
+                # batch axis, exactly the PagedLayerCache L > 1
+                # fallback — same q-length-1 sdpa executable, so a
+                # packed verify stays bit-identical to the per-phase
+                # step_multi call
+                B = (hi - lo) // L
+                qd = Tensor(q.data[0, lo:hi][:, None])  # [B*L,1,..]
+                k_full = Tensor(jnp.repeat(k_full.data, L, axis=0))
+                v_full = Tensor(jnp.repeat(v_full.data, L, axis=0))
+                tf = (jnp.repeat(tj, L)
+                      + jnp.tile(jnp.arange(L, dtype=jnp.int32), B))
+                qpos = tf[:, None, None, None]
+            out = F.scaled_dot_product_attention(
+                qd, k_full, v_full, attn_mask=_mask(kpos, qpos, W))
+            outs.append(out.data[:, 0])
         return Tensor(jnp.concatenate(outs, axis=0)[None])
 
 
@@ -2420,8 +2354,7 @@ class PagedKVCache:
         return len(landing)
 
     # -- mixed ragged step --------------------------------------------
-    def ragged_views(self, segments, tile_q=None,
-                     tile_kv=None) -> List["PagedRaggedView"]:
+    def ragged_views(self, segments) -> List["PagedRaggedView"]:
         """Per-layer views for ONE mixed ragged model call (the
         scheduler's token-budget step): ``segments`` is an ordered
         list of descriptors —
@@ -2441,8 +2374,7 @@ class PagedKVCache:
         the kernel path each layer is ONE ``paged_attention_ragged``
         launch. Build AFTER ensure()ing coverage and setting the
         decode mask — the layout snapshots the current tables."""
-        layout = _RaggedLayout(self, segments, tile_q=tile_q,
-                               tile_kv=tile_kv)
+        layout = _RaggedLayout(self, segments)
         return [PagedRaggedView(self, i, layout)
                 for i in range(self.num_layers)]
 

@@ -130,6 +130,7 @@ from typing import Deque, Dict, List, Optional, Tuple
 
 import numpy as np
 
+from ..framework import device as _device
 from ..framework.autograd import no_grad
 from ..framework.tensor import Tensor
 from .paged_cache import BlockOOM, PagedKVCache, chain_block_hashes
@@ -476,9 +477,7 @@ class PagedServingEngine:
                  numeric_guard: Optional[bool] = None,
                  tenants: Optional[Dict[str, dict]] = None,
                  collector=None, monitor=None, ledger=None,
-                 ragged_step: bool = True,
-                 tile_q: Optional[int] = None,
-                 tile_kv: Optional[int] = None):
+                 ragged_step: bool = True):
         self.model = model
         # ragged mixed step (token-budget mode): plan the step's
         # prefill chunks, then launch them PACKED with the decode rows
@@ -494,11 +493,7 @@ class PagedServingEngine:
         # CPU fallback too (tests/benches of the packing machinery —
         # bit-identical at test dims, token-identical at bench dims);
         # False keeps the legacy per-chunk launches everywhere.
-        # tile_q/tile_kv pass through to paged_attention_ragged
-        # (kernel tuning knobs; None = the kernel's default table).
         self.ragged_step = ragged_step
-        self.tile_q = tile_q
-        self.tile_kv = tile_kv
         self._ragged_plan: Optional[List[dict]] = None
         self.max_batch = int(max_batch)
         self.dtype = dtype
@@ -1485,8 +1480,7 @@ class PagedServingEngine:
             # host and uploaded: paged-cache manager time too
             col.span_begin("grow", what="layout")
         try:
-            views = self.cache.ragged_views(desc, tile_q=self.tile_q,
-                                            tile_kv=self.tile_kv)
+            views = self.cache.ragged_views(desc)
             if col is not None:
                 # what each layer's launch will cost the kernel's grid
                 plan = views[0]._layout.launch_plan()
@@ -1998,8 +1992,7 @@ class PagedServingEngine:
         # plan whenever it's legal, kernel or not
         if getattr(self.model, "prefers_packed_step", False):
             return True
-        from ..incubate.nn.fused_transformer import _use_decode_kernel
-        return _use_decode_kernel()
+        return _device.use_pallas_kernels()
 
     def _step_impl(self, idle: bool, x: Tensor):
         if not self._ragged_active():
@@ -2783,8 +2776,6 @@ class PagedServingEngine:
                 "max_preemptions": self.max_preemptions,
                 "numeric_guard": self.numeric_guard,
                 "ragged_step": self.ragged_step,
-                "tile_q": self.tile_q,
-                "tile_kv": self.tile_kv,
             },
             "cache": self.cache.snapshot(),
             "requests": [self._req_rec(r, now) for r in reqs.values()],
@@ -2868,10 +2859,10 @@ class PagedServingEngine:
                   max_preemptions=cfg["max_preemptions"],
                   numeric_guard=cfg["numeric_guard"],
                   # pre-ragged snapshots restore onto the (equivalent)
-                  # ragged default; the knobs are scheduling-neutral
-                  ragged_step=cfg.get("ragged_step", True),
-                  tile_q=cfg.get("tile_q"),
-                  tile_kv=cfg.get("tile_kv"))
+                  # ragged default; the "tile_q" / "tile_kv" keys of
+                  # older snapshots are ignored (the kernel derives
+                  # its sizes from shapes)
+                  ragged_step=cfg.get("ragged_step", True))
         # nb may differ from the cache snapshot's geometry (a resized
         # engine config, or the explicit override): the pool restore
         # rehomes content-addressed blocks either way. The MESH WIDTH

@@ -593,7 +593,6 @@ class ShardedServingCore:
         import jax
         import jax.numpy as jnp
         from ..framework.op import apply
-        from ..incubate.nn.fused_transformer import _use_decode_kernel
         from ..nn import functional as F
         from ..ops.manipulation import reshape, split
         from ..ops.pallas.paged_attention import head_slice
@@ -620,7 +619,6 @@ class ShardedServingCore:
         t = time_step.data if isinstance(time_step, Tensor) \
             else jnp.asarray(time_step, jnp.int32)
         t = jnp.broadcast_to(t.reshape(-1).astype(jnp.int32), (b,))
-        use_k = _use_decode_kernel()
         new_caches = []
         for i, blk in enumerate(self.base.layers):
             residual = x
@@ -650,7 +648,7 @@ class ShardedServingCore:
                     v = Tensor(head_slice(vf.data, s, self.mp))
                 view = caches[i] if self.mp == 1 \
                     else caches[i].shard(s)
-                attn_s = view.decode(q, k, v, t, use_kernel=use_k)
+                attn_s = view.decode(q, k, v, t)
                 if self.mp == 1:
                     parts.append(attn_s)
                 else:

@@ -1,4 +1,4 @@
-"""Model zoo: the benchmark families from BASELINE.md."""
+"""Model zoo."""
 from .llama import LlamaConfig, LlamaForCausalLM  # noqa: F401
 from .bert import BertConfig, BertForPretraining, BertForSequenceClassification, BertModel  # noqa: F401
 from .gpt import GPTConfig, GPTForCausalLM, GPTModel  # noqa: F401

@@ -14,10 +14,9 @@ import math
 import jax
 import jax.numpy as jnp
 
-from ...framework.device import on_tpu
+from ...framework import device
 from ...framework.op import apply, unwrap
 from ...framework.tensor import Tensor
-from ...flags import get_flag
 
 __all__ = ["scaled_dot_product_attention", "flash_attention",
            "flash_attn_unpadded", "sdpa_reference"]
@@ -80,12 +79,11 @@ def scaled_dot_product_attention(query, key, value, attn_mask=None,
     flash-attention API."""
     rate = float(dropout_p) if training else 0.0
     use_pallas = (
-        get_flag("FLAGS_enable_pallas_kernels", True)
-        and attn_mask is None
+        attn_mask is None
         and query.shape[-1] >= 64
         and query.shape[-1] % 64 == 0
         # ragged lengths are fine: the kernel pads + masks tail blocks
-        and on_tpu()
+        and device.use_pallas_kernels()
     )
     if use_pallas:
         from ...ops.pallas.flash_attention import flash_attention_blhd

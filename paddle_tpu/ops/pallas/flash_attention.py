@@ -599,8 +599,8 @@ def flash_attention_blhd(q, k, v, causal=False, sm_scale=None,
 def _tuned_block_sizes(t_q, t_k):
     """Block sizes for jax's tuned flash kernel, measured on v5e at the
     training shape [12, 32, 2048, 128]: q1024/kM512/k512 runs the
-    fwd+bwd in 47ms vs 138ms with the library defaults (tools/
-    attn_bench.py shootout). Clamped so every block divides the
+    fwd+bwd in 47ms vs 138ms with the library defaults (a one-off
+    probe, since deleted). Clamped so every block divides the
     (padded-to-128) sequence lengths."""
     from jax.experimental.pallas.ops.tpu.flash_attention import BlockSizes
 

@@ -32,7 +32,7 @@ Grid layout: each sequence's queries are cut into tiles of ``tile_q``
 rows, and a page whose first position lies past a tile's LAST query is
 skipped outright (the causal frontier — prefill work is O(tokens
 written), not O(page capacity); a decode tile skips everything past
-its one position). On real TPU the block table, the tile->sequence map
+its one position). The block table, the tile->sequence map
 and the per-tile base positions ride as SCALAR-PREFETCH arguments
 (pltpu.PrefetchScalarGridSpec) and the grid is
 (total_tiles * nkv_heads / Hb, ceil(MB / P)): one grid step carries
@@ -54,22 +54,20 @@ scratch and float32 working set fit VMEM_BUDGET_BYTES. At the serving
 cells' shapes (32 kv heads of 128, bf16 pages of 16, 128 table
 entries) that is Hb 32 and P 8: 2 MB a step and 512 grid steps a
 decode launch, where one head of one page a step was 8 KB and 131 072
-(PERF.md, PR 27, has the sweep over P and Hb on the chip). On
-CPU the same body runs in interpret mode, one head a grid step, over
-pre-gathered pages (a branch written when interpret mode had no
-scalar-prefetch index maps — under jax 0.9.0 it has them, see ROADMAP
-D3 and tests/test_pallas_kernels.py, which interprets the chip's
-kernel); the model-level CPU fallback in inference/paged_cache.py uses
-a pure-jnp gather instead so tier-1 serving tests exercise the full
+(PERF.md, PR 27, has the sweep over P and Hb on the chip). There is
+ONE ``pallas_call``: off the chip the same call runs with
+``interpret=True`` (jax 0.9.0 interprets scalar-prefetch index maps),
+so a kernel test on the CPU runs the chip's grid, index maps and body.
+The model-level CPU fallback in inference/paged_cache.py uses a
+pure-jnp gather instead so tier-1 serving tests exercise the full
 protocol without Mosaic.
 
-Tile knobs (the README "Ragged paged attention" section carries the
-default table): ``tile_q`` is the query rows per grid step — more rows
+Tile sizes: ``tile_q`` is the query rows per grid step — more rows
 amortize each page DMA across queries but pad decode segments;
-``tile_kv`` is the PAGES per kv grid step, on both layouts. ``None``,
-which is what the engine passes, means derived: the longest segment up
-to DEFAULT_TILE_Q_CAP rows, and ``launch_plan``'s ``P`` on the chip
-(one page a step on the pre-gathered CPU layout).
+``tile_kv`` is the PAGES per kv grid step. ``None``, which is what the
+serving path passes, means derived from the shapes: the longest
+segment up to DEFAULT_TILE_Q_CAP rows (``resolve_tile_q``), and
+``launch_plan``'s ``P``. Only the kernel's own tests set them.
 
 TENSOR-PARALLEL DISPATCH (sharded pools, inference/paged_cache.py
 ``mp`` > 1): the kernel itself is shard-oblivious — attention is
@@ -92,16 +90,15 @@ table with a per-page scale array [num_blocks, 2, nkv, block_size]
 (symmetric per-position-per-head scales — see
 inference/paged_cache.py for why scales are per row, not one scalar
 per block: row granularity is what keeps the quantized payload a pure
-function of the token stream, so prefix adoption stays exact). On the
-scalar-prefetch path each scale page is DMA'd next to its int8 page
+function of the token stream, so prefix adoption stays exact). Each
+scale page is DMA'd next to its int8 page
 through the same ``bt[tile_seq[t], j * P + p]`` lookup as a
 (1, 2, Hb, block_s) block — the step's heads on the sublane axis,
 which is why a quantized launch keeps ``Hb`` whole or a multiple of 8
 — and the kernel dequantizes in-register, folding the scales into its
 score and probability tiles (int8 page bytes plus the page's scales
-over the wire instead of bf16 — the HBM win). In interpret / jnp-
-reference mode the pre-gathered pages are dequantized before the
-kernel body, which then runs unchanged in float32.
+over the wire instead of bf16 — the HBM win). The jnp reference
+dequantizes inside its page gather.
 """
 from __future__ import annotations
 
@@ -120,10 +117,10 @@ from ...framework.device import on_tpu
 
 NEG_INF = -1e30
 
-# default tile table (README carries the rationale): decode segments
-# want tile_q == 1 (no padding rows), verify wants the whole K+1 block
-# (one page sweep scores every position), prefill wants wide tiles up
-# to this cap so a long chunk never holds every row in VMEM at once.
+# the derived tile_q: decode segments want 1 (no padding rows), verify
+# wants the whole K+1 block (one page sweep scores every position),
+# prefill wants wide tiles up to this cap so a long chunk never holds
+# every row in VMEM at once.
 DEFAULT_TILE_Q_CAP = 64
 
 # what one grid step of the scalar-prefetch launch may hold in VMEM
@@ -135,9 +132,9 @@ VMEM_BUDGET_BYTES = 24 * 2 ** 20
 VMEM_LIMIT_BYTES = 2 * VMEM_BUDGET_BYTES
 KV_STEP_POSITIONS = 128
 
-# launch accounting for the dispatch-count acceptance tests and the
-# kernel microbench: every ``paged_attention_ragged`` entry (kernel,
-# interpret or delegated wrapper) bumps the counter ONCE — i.e. once
+# launch accounting for the dispatch-count acceptance tests: every
+# ``paged_attention_ragged`` entry (the launch, or the delegation to
+# the reference under an ``mp`` region) bumps the counter ONCE — i.e. once
 # per attention launch when the eager op-jit cache is off
 # (FLAGS_eager_op_jit=False; with it on, a cached executable replays
 # without re-entering this module, so tests disable it to count).
@@ -236,7 +233,7 @@ def launch_plan(T: int, nkv: int, rows: int, MB: int, block_s: int,
 def _heads_dot(a, b, b_axis: int):
     """a's last axis against ``b``'s axis ``b_axis`` (counted from the
     [.., rows, cols] pair), accumulated in float32, batched over the
-    leading head axis where there is one."""
+    leading head axis."""
     lead = a.ndim - 2
     batch = tuple(range(lead))
     return jax.lax.dot_general(
@@ -257,12 +254,9 @@ def _ragged_body(pos0, pos_last, k, v, q_ref, o_ref, m_scr, l_scr,
     the step's (Hb, rows, hd) of their blocks: row r of head h is
     query r // g of the tile, at position pos0 + r // g, masked
     causally per row. k/v hold this step's kv tile as
-    (Hb, block_s, hd) float32 — ``P`` pool pages of ``Hb`` heads on
-    the scalar-prefetch path — and both products are batched over the
-    head axis; the CPU branch's grid step is one head and passes
-    everything without that axis ((rows, hd) views, a (block_s, hd)
-    tile of ``tile_kv`` pre-gathered pages, 2-D scratch), which keeps
-    its products the plain 2-D ones. A kv step whose first position
+    (Hb, block_s, hd) float32 — ``P`` pool pages of ``Hb`` heads —
+    and both products are batched over the head axis. A kv step whose
+    first position
     lies past pos_last is fully masked for every real row and skipped
     outright (the causal frontier: decode pages above a prefill chunk
     don't exist yet — this is both the old prefill kernel's page skip
@@ -329,7 +323,7 @@ def _ragged_body(pos0, pos_last, k, v, q_ref, o_ref, m_scr, l_scr,
 
 def _kernel_ragged_prefetch(bt_ref, tseq_ref, pos_ref, q_ref, *refs,
                             n_hb, pages, quantized, **kw):
-    """The chip's kernel. ``refs``: the pool handed in ``pages`` times
+    """The kernel. ``refs``: the pool handed in ``pages`` times
     (one (1, 2, Hb, block_s, hd) page block each, see the index map),
     for int8 pages the scale array as often ((1, 2, Hb, block_s): the
     block already carries the step's heads), then the output block
@@ -349,19 +343,6 @@ def _kernel_ragged_prefetch(bt_ref, tseq_ref, pos_ref, q_ref, *refs,
     o_ref, *scratch = refs
     _ragged_body(pos_ref[t, 0], pos_ref[t, 1], k, v, q_ref.at[0],
                  o_ref.at[0], *scratch, **kw)
-
-
-def _kernel_ragged_interpret(pos_ref, q_ref, pg_ref, o_ref, m_scr,
-                             l_scr, acc_scr, *, tile_kv, **kw):
-    hd = q_ref.shape[-1]
-    i = pl.program_id(0)
-    # pg block: (1, tile_kv, 2, bs, hd) -> (2, tile_kv * bs, hd)
-    kv = jnp.swapaxes(pg_ref[...][0], 0, 1).reshape(
-        2, kw["block_s"], hd)
-    _ragged_body(pos_ref[i, 0], pos_ref[i, 1],
-                 kv[0].astype(jnp.float32), kv[1].astype(jnp.float32),
-                 q_ref.at[0, 0], o_ref.at[0, 0], m_scr, l_scr, acc_scr,
-                 **kw)
 
 
 def resolve_tile_q(q_lens, tile_q=None) -> int:
@@ -435,7 +416,7 @@ def paged_attention_ragged(q, kv_pool, block_tables, q_lens, kv_lens,
         return q         # nothing to score — no launch, not counted
     from ...parallel.mesh import inside_spmd_region
     if not on_tpu() and inside_spmd_region("mp"):
-        # callable under shard_map: the interpret-mode launch builds
+        # callable under shard_map: the launch builds
         # its tile layout from static host metadata, but the pallas
         # interpreter's emulated grid does not trace under a manual
         # mesh axis — inside an ``mp`` spmd region (the compiled
@@ -482,132 +463,75 @@ def paged_attention_ragged(q, kv_pool, block_tables, q_lens, kv_lens,
     qp = jnp.transpose(qp.reshape(T, tile_q, nkv, g, hd),
                        (0, 2, 1, 3, 4)).reshape(T, nkv, rows, hd)
 
-    out_shape = jax.ShapeDtypeStruct((T, nkv, rows, hd), q.dtype)
+    # a grid step carries Hb heads of P pages (launch_plan). A
+    # sequence's pages are not contiguous in the pool, so the pool is
+    # handed to the call P times and operand p's index map names table
+    # entry j * P + p
+    plan = launch_plan(T, nkv, rows, MB, block_s, hd,
+                       kv_pool.dtype.itemsize,
+                       q_itemsize=q.dtype.itemsize,
+                       quantized=kv_scales is not None,
+                       tile_kv=tile_kv)
+    Hb, P = plan.heads, plan.pages
+    n_hb = nkv // Hb
+    kw = dict(block_s=block_s * P, n_blocks=plan.grid[1],
+              sm_scale=scale, tile_q=tile_q, g=g, window=window)
 
-    def scratch(*heads):
-        return [pltpu.VMEM(heads + (rows, 1), jnp.float32),
-                pltpu.VMEM(heads + (rows, 1), jnp.float32),
-                pltpu.VMEM(heads + (rows, hd), jnp.float32)]
+    def q_map(i, j, bt_, ts_, pos_):
+        return (i // n_hb, i % n_hb, 0, 0)
 
-    if not on_tpu():
-        # the CPU branch: pre-gather each tile's pages instead of riding
-        # the block table as scalar prefetch (the online-softmax body is
-        # shared, one head a grid step; the pallas_call and its
-        # BlockSpecs are not).
-        # The gather is per TILE, so a sequence tiled into k query
-        # tiles duplicates its pages k-fold here — acceptable because
-        # tests run small shapes and the default tile_q covers whole
-        # chunks (k == 1); the scalar-prefetch path never gathers at
-        # all (the pages DMA straight off their pool rows).
-        # tile_kv None means one page a kv step here.
-        tkv = max(1, int(tile_kv)) if tile_kv is not None else 1
-        MBp = -(-MB // tkv) * tkv
-        if MBp != MB:
-            # pad with the reserved trash block: positions >= MB*bs
-            # are past every causal frontier, masked by construction
-            bt_p = jnp.concatenate(
-                [bt, jnp.zeros((bt.shape[0], MBp - MB), jnp.int32)], 1)
-        else:
-            bt_p = bt
-        n_kv_steps = MBp // tkv
-        pages = kv_pool[bt_p]           # [n_seq, MBp, 2, nkv, bs, hd]
-        if kv_scales is not None:
-            # interpret mode has no scalar-prefetch index maps, so the
-            # pages are already materialized — dequantize them here
-            # and run the float kernel body unchanged (the prefetch
-            # path below dequantizes in-register instead)
-            sc = jnp.asarray(kv_scales)[bt_p]   # [n_seq, MBp, 2, nkv, bs]
-            pages = pages.astype(jnp.float32) * sc[..., None]
-        pg = jnp.transpose(pages[tseq], (0, 3, 1, 2, 4, 5)).reshape(
-            T * nkv, MBp, 2, block_s, hd)
-        pos_r = jnp.repeat(pos, nkv, axis=0)        # [T * nkv, 2]
-        kw = dict(block_s=block_s * tkv, n_blocks=n_kv_steps,
-                  sm_scale=scale, tile_q=tile_q, g=g, window=window)
-        out = pl.pallas_call(
-            functools.partial(_kernel_ragged_interpret, tile_kv=tkv,
-                              **kw),
-            grid=(T * nkv, n_kv_steps),
-            in_specs=[
-                pl.BlockSpec((T * nkv, 2), lambda i, j: (0, 0)),
-                pl.BlockSpec((1, 1, rows, hd),
-                             lambda i, j: (i // nkv, i % nkv, 0, 0)),
-                pl.BlockSpec((1, tkv, 2, block_s, hd),
-                             lambda i, j: (i, j, 0, 0, 0)),
-            ],
-            out_specs=pl.BlockSpec((1, 1, rows, hd),
-                                   lambda i, j: (i // nkv, i % nkv,
-                                                 0, 0)),
-            out_shape=out_shape,
-            scratch_shapes=scratch(),
-            interpret=True,
-        )(pos_r, qp, pg)
-    else:
-        # scalar-prefetch path: a grid step carries Hb heads of P
-        # pages (launch_plan). A sequence's pages are not contiguous
-        # in the pool, so the pool is handed to the call P times and
-        # operand p's index map names table entry j * P + p
-        plan = launch_plan(T, nkv, rows, MB, block_s, hd,
-                           kv_pool.dtype.itemsize,
-                           q_itemsize=q.dtype.itemsize,
-                           quantized=kv_scales is not None,
-                           tile_kv=tile_kv)
-        Hb, P = plan.heads, plan.pages
-        n_hb = nkv // Hb
-        kw = dict(block_s=block_s * P, n_blocks=plan.grid[1],
-                  sm_scale=scale, tile_q=tile_q, g=g, window=window)
+    def page_map(p, tail):
+        # operand p's page of kv step j is table entry j * P + p — held
+        # at its LAST REAL page (the per-tile frontier, pos[t, 1]) for
+        # every step past it: a skipped step re-names the block the
+        # pipeline already holds, and no copy is issued past the
+        # frontier. An operand whose first page is already past it
+        # holds entry p throughout. On a sliding layer the steps wholly
+        # behind the tile's window hold the first live step's entry the
+        # same way.
+        def index(i, j, bt_, ts_, pos_):
+            t = i // n_hb
+            last = jnp.maximum(pos_[t, 1], 0) // block_s
+            jj = jnp.minimum(j, jnp.maximum(last - p, 0) // P)
+            if window is not None:
+                first = jnp.maximum(pos_[t, 0] - window + 1, 0) \
+                    // (block_s * P)
+                jj = jnp.maximum(jj, jnp.minimum(
+                    first, jnp.maximum(last - p, 0) // P))
+            return (bt_[ts_[t], jj * P + p], 0, i % n_hb) + tail
+        return index
 
-        def q_map(i, j, bt_, ts_, pos_):
-            return (i // n_hb, i % n_hb, 0, 0)
-
-        def page_map(p, tail):
-            # operand p's page of kv step j is table entry j * P + p —
-            # held at its LAST REAL page (the per-tile frontier,
-            # pos[t, 1]) for every step past it: a skipped step
-            # re-names the block the pipeline already holds, and no
-            # copy is issued past the frontier. An operand whose first
-            # page is already past it holds entry p throughout. On a
-            # sliding layer the steps wholly behind the tile's window
-            # hold the first live step's entry the same way.
-            def index(i, j, bt_, ts_, pos_):
-                t = i // n_hb
-                last = jnp.maximum(pos_[t, 1], 0) // block_s
-                jj = jnp.minimum(j, jnp.maximum(last - p, 0) // P)
-                if window is not None:
-                    first = jnp.maximum(pos_[t, 0] - window + 1, 0) \
-                        // (block_s * P)
-                    jj = jnp.maximum(jj, jnp.minimum(
-                        first, jnp.maximum(last - p, 0) // P))
-                return (bt_[ts_[t], jj * P + p], 0, i % n_hb) + tail
-            return index
-
-        # the pages straight out of the pool rows the block table
-        # names — the whole paged-attention trick
-        in_specs = [pl.BlockSpec((1, Hb, rows, hd), q_map)] + [
-            pl.BlockSpec((1, 2, Hb, block_s, hd), page_map(p, (0, 0)))
+    # the pages straight out of the pool rows the block table names —
+    # the whole paged-attention trick
+    in_specs = [pl.BlockSpec((1, Hb, rows, hd), q_map)] + [
+        pl.BlockSpec((1, 2, Hb, block_s, hd), page_map(p, (0, 0)))
+        for p in range(P)]
+    operands = [bt, tseq, pos, qp] + [kv_pool] * P
+    if kv_scales is not None:
+        # each scale page rides the SAME lookup as its int8 page
+        in_specs += [
+            pl.BlockSpec((1, 2, Hb, block_s), page_map(p, (0,)))
             for p in range(P)]
-        operands = [bt, tseq, pos, qp] + [kv_pool] * P
-        if kv_scales is not None:
-            # each scale page rides the SAME lookup as its int8 page
-            in_specs += [
-                pl.BlockSpec((1, 2, Hb, block_s), page_map(p, (0,)))
-                for p in range(P)]
-            operands += [jnp.asarray(kv_scales)] * P
-        grid_spec = pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3,   # bt + tile->seq map + pos (SMEM)
-            grid=plan.grid,
-            in_specs=in_specs,
-            out_specs=pl.BlockSpec((1, Hb, rows, hd), q_map),
-            scratch_shapes=scratch(Hb),
-        )
-        out = pl.pallas_call(
-            functools.partial(_kernel_ragged_prefetch, n_hb=n_hb,
-                              pages=P, quantized=kv_scales is not None,
-                              **kw),
-            grid_spec=grid_spec,
-            out_shape=out_shape,
-            compiler_params=pltpu.CompilerParams(
-                vmem_limit_bytes=VMEM_LIMIT_BYTES),
-        )(*operands)
+        operands += [jnp.asarray(kv_scales)] * P
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,   # bt + tile->seq map + pos (SMEM)
+        grid=plan.grid,
+        in_specs=in_specs,
+        out_specs=pl.BlockSpec((1, Hb, rows, hd), q_map),
+        scratch_shapes=[pltpu.VMEM((Hb, rows, 1), jnp.float32),
+                        pltpu.VMEM((Hb, rows, 1), jnp.float32),
+                        pltpu.VMEM((Hb, rows, hd), jnp.float32)],
+    )
+    out = pl.pallas_call(
+        functools.partial(_kernel_ragged_prefetch, n_hb=n_hb,
+                          pages=P, quantized=kv_scales is not None,
+                          **kw),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((T, nkv, rows, hd), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=VMEM_LIMIT_BYTES),
+        interpret=not on_tpu(),    # off the chip: the same call, interpreted
+    )(*operands)
 
     # unfold + unpad back to the packed row order
     out = jnp.transpose(out.reshape(T, nkv, tile_q, g, hd),

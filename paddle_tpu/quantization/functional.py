@@ -6,6 +6,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from ..framework import device
 from ..framework.op import apply as _apply
 from ..framework.tensor import Tensor
 
@@ -78,8 +79,7 @@ def quantized_matmul(x, w_int8, w_scale, x_scale=None, bits=8,
             # blocks (halved weight bytes — the point of int8 in the
             # weight-bound decode regime); XLA fallback materializes the
             # dequantized weight, tripling traffic
-            from ..flags import get_flag
-            if get_flag("FLAGS_enable_pallas_kernels", True) \
+            if device.use_pallas_kernels() \
                     and x_.ndim >= 2 and w_.ndim == 2:
                 from ..ops.pallas.int8_matmul import w8a16_matmul
                 lead = x_.shape[:-1]

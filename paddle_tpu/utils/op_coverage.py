@@ -1,4 +1,4 @@
-"""PHI-op coverage metric (BASELINE.json secondary metric).
+"""PHI-op coverage metric.
 
 Parses op names from the reference's YAML op registry
 (ref: /root/reference/paddle/phi/api/yaml/ops.yaml — 236 ops,

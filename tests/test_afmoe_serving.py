@@ -7,7 +7,6 @@ dropless experts with a shared expert.
 Tiny ``afmoe``: d 64, 4 heads / 2 KV heads of 16, window 8, 16 experts top
 2 + 1 shared, 1 dense + 4 expert layers (sliding x4, full), float32.
 """
-import importlib
 import os
 import sys
 import tempfile
@@ -235,17 +234,6 @@ def test_near_ties_take_the_engines_choice_inside_the_margin_only():
 
 # ---- (d) the kernel, with a window, at 6 query heads a kv head -------
 
-@pytest.fixture
-def chip_pa(monkeypatch):
-    """The scalar-prefetch branch (the chip's), every call interpreted."""
-    pa = importlib.import_module("paddle_tpu.ops.pallas.paged_attention")
-    real = pa.pl.pallas_call
-    monkeypatch.setattr(pa, "on_tpu", lambda: True)
-    monkeypatch.setattr(pa.pl, "pallas_call",
-                        lambda *a, **kw: real(*a, interpret=True, **kw))
-    return pa
-
-
 def _window_case(seed=0, nkv=2, g=6, hd=16, bs=4, MB=12, NB=40):
     r = np.random.default_rng(seed)
     pool = jnp.asarray(r.standard_normal((NB, 2, nkv, bs, hd)), jnp.float32)
@@ -259,18 +247,15 @@ def _window_case(seed=0, nkv=2, g=6, hd=16, bs=4, MB=12, NB=40):
     return q, pool, bt, q_lens, kv_lens
 
 
-@pytest.mark.parametrize("path,window", [
-    ("cpu_branch", 8), ("chip_kernel", 8), ("chip_kernel", 3),
-    ("chip_kernel", None)])
-def test_kernel_with_a_window_matches_the_reference(request, path, window):
+@pytest.mark.parametrize("window", [8, 3, None])
+def test_kernel_with_a_window_matches_the_reference(window):
     q, pool, bt, q_lens, kv_lens = _window_case()
-    launch = paged_attention_ragged if path == "cpu_branch" else \
-        request.getfixturevalue("chip_pa").paged_attention_ragged
     want = paged_attention_ragged_reference(q, pool, bt, q_lens, kv_lens,
                                             window=window)
     for tile_q, tile_kv in ((None, None), (4, 2)):
-        got = launch(q, pool, bt, q_lens, kv_lens, tile_q=tile_q,
-                     tile_kv=tile_kv, window=window)
+        got = paged_attention_ragged(q, pool, bt, q_lens, kv_lens,
+                                     tile_q=tile_q, tile_kv=tile_kv,
+                                     window=window)
         np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                    atol=2e-5)
     if window is not None:       # and the window does change the answer

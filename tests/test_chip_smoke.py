@@ -27,11 +27,10 @@ TRAINER_TOY = dict(hidden=64, inter=128, heads=4, vocab=256, layers=2,
 @pytest.fixture
 def kernel_path(monkeypatch):
     """Put the serving engine on the path it takes on the chip: packed
-    ragged steps and the ``use_kernel`` branches of the paged views, the
-    kernel body interpreted."""
-    from paddle_tpu.framework import op
-    from paddle_tpu.incubate.nn import fused_transformer as ft
-    monkeypatch.setattr(ft, "_use_decode_kernel", lambda: True)
+    ragged steps and the kernel side of the paged views' seam, the
+    kernel interpreted."""
+    from paddle_tpu.framework import device, op
+    monkeypatch.setattr(device, "use_pallas_kernels", lambda: True)
     # the phase counts kernel launches at trace time: start without the
     # executables an earlier test may have cached for the same shapes
     op._OP_JIT_CACHE.clear()

@@ -5,6 +5,8 @@ Ref kernels being mirrored: fused_layernorm_residual_dropout_bias.h,
 fused_adam_kernel.cu, cutlass moe_kernel.cu,
 fused_multi_transformer_op.cu.h:835.
 """
+import importlib
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -23,6 +25,10 @@ from paddle_tpu.ops.pallas.paged_attention import (
     paged_attention_multi_reference, paged_attention_prefill,
     paged_attention_prefill_reference, paged_attention_ragged,
     paged_attention_ragged_reference, paged_attention_reference)
+
+# the MODULE (the package re-exports a function named paged_attention
+# over it): tests that shrink its VMEM budget or read launch_plan
+pa_module = importlib.import_module("paddle_tpu.ops.pallas.paged_attention")
 
 # the kernel suite is selectable in CI like spec/faults/monitor:
 #   pytest -m kernels
@@ -496,16 +502,13 @@ class TestPagedAttentionRagged:
                         jnp.float32)
         return q, pool, bt, q_lens, kv_lens
 
-    def test_scalar_prefetch_kernels_interpreted(self, chip_pa):
+    def test_scalar_prefetch_kernels_interpreted(self):
         """The kernel the CHIP runs — ``_kernel_ragged_prefetch``, over
         bf16/float pages and over int8 pages with their scales, block
         table and tile maps riding as scalar prefetch — interpreted on
-        CPU. The default CPU branch pre-gathers pages and runs a
-        different ``pallas_call`` with different BlockSpecs, so without
-        this (and TestChipKernelInterpreted below) the chip's kernel
-        body and index maps execute nowhere in tier-1."""
+        CPU."""
         from paddle_tpu.inference.paged_cache import _quant_rows
-        pa = chip_pa
+        pa = pa_module
         q, pool, bt, q_lens, kv_lens = self._mixed()
         out = pa.paged_attention_ragged(q, pool, bt, q_lens, kv_lens)
         ref = paged_attention_ragged_reference(q, pool, bt, q_lens,
@@ -647,8 +650,8 @@ class TestPagedAttentionRagged:
         np.testing.assert_array_equal(ref, out2)
 
     def test_tile_kv_is_pure_scheduling(self):
-        """tile_kv groups pages per kv grid step on the pre-gathered
-        layout; any grouping (dividing MB or not) gives the same
+        """tile_kv groups pages per kv grid step; any grouping
+        (dividing MB or not) gives the same
         attention to float tolerance (the online-softmax update order
         changes, values do not)."""
         q, pool, bt, q_lens, kv_lens = self._mixed()
@@ -673,21 +676,6 @@ class TestPagedAttentionRagged:
         out = np.asarray(paged_attention_ragged(
             q, pool, bt, (1, 1), jnp.asarray([0, 7], jnp.int32)))
         assert np.all(out[0] == 0.0) and np.isfinite(out).all()
-
-
-@pytest.fixture
-def chip_pa(monkeypatch):
-    """``paged_attention`` as the CHIP runs it — the scalar-prefetch
-    branch, every ``pallas_call`` interpreted (the default CPU branch
-    pre-gathers pages and is a different call)."""
-    import importlib
-    pa = importlib.import_module("paddle_tpu.ops.pallas.paged_attention")
-    real = pa.pl.pallas_call
-    monkeypatch.setattr(pa, "on_tpu", lambda: True)
-    monkeypatch.setattr(
-        pa.pl, "pallas_call",
-        lambda *a, **kw: real(*a, interpret=True, **kw))
-    return pa
 
 
 def _budget_for_heads(pa, monkeypatch, heads, *shape, **kw):
@@ -751,33 +739,32 @@ class TestChipKernelInterpreted:
         return out
 
     @pytest.mark.parametrize("case", sorted(CASES))
-    def test_launch_matches_reference(self, chip_pa, case):
+    def test_launch_matches_reference(self, case):
         q_lens, kv_lens, tq, tkv, nkv, g, MB = self.CASES[case]
         q, pool, bt, kvl = self._inputs(q_lens, kv_lens, nkv, g, MB)
-        out = self._check(chip_pa, q, pool, bt, q_lens, kvl, tile_q=tq,
+        out = self._check(pa_module, q, pool, bt, q_lens, kvl, tile_q=tq,
                           tile_kv=tkv)
         if case == "length_zero_rows":
             assert np.all(out[0] == 0.0) and np.all(out[2:] == 0.0)
 
     @pytest.mark.parametrize("case", ["decode", "mixed", "verify"])
-    def test_head_groups_under_a_small_budget(self, chip_pa,
-                                              monkeypatch, case):
+    def test_head_groups_under_a_small_budget(self, monkeypatch, case):
         """A budget that holds two of the four kv heads: the grid's
         first axis walks (tile, head group) and the blocks carry
         ``Hb < nkv`` heads."""
         q_lens, kv_lens, tq, tkv, nkv, g, MB = self.CASES[case]
         q, pool, bt, kvl = self._inputs(q_lens, kv_lens, nkv, g, MB)
         rows = (tq or max(q_lens)) * g
-        _budget_for_heads(chip_pa, monkeypatch, 2, 1, nkv, rows, MB, 8,
+        _budget_for_heads(pa_module, monkeypatch, 2, 1, nkv, rows, MB, 8,
                           16, 4)
-        self._check(chip_pa, q, pool, bt, q_lens, kvl, tile_q=tq,
+        self._check(pa_module, q, pool, bt, q_lens, kvl, tile_q=tq,
                     tile_kv=tkv)
 
     @pytest.mark.parametrize("case,heads", [("mixed", None),
                                             ("decode", None),
                                             ("page_boundary", None),
                                             ("mixed", 8)])
-    def test_int8_pages(self, chip_pa, monkeypatch, case, heads):
+    def test_int8_pages(self, monkeypatch, case, heads):
         """int8 pages: each scale page rides its page's lookup as a
         (1, 2, Hb, block_s) block; with 16 kv heads and a small budget
         a step carries 8 of them (the scale block's sublane tile)."""
@@ -788,46 +775,46 @@ class TestChipKernelInterpreted:
         q, pool, bt, kvl = self._inputs(q_lens, kv_lens, nkv, g, MB)
         if heads is not None:
             rows = (tq or max(q_lens)) * g
-            _budget_for_heads(chip_pa, monkeypatch, heads, 1, nkv, rows,
+            _budget_for_heads(pa_module, monkeypatch, heads, 1, nkv, rows,
                               MB, 8, 16, 1, quantized=True)
         pool_q, scales = _quant_rows(pool)
-        self._check(chip_pa, q, pool_q, bt, q_lens, kvl,
+        self._check(pa_module, q, pool_q, bt, q_lens, kvl,
                     kv_scales=scales, tile_q=tq, tile_kv=tkv)
 
     @pytest.mark.parametrize("tile_q", [1, 4])
-    def test_segment_independence(self, chip_pa, tile_q):
+    def test_segment_independence(self, tile_q):
         """Element-exact: a sequence's rows of a packed launch equal
         the same sequence launched alone at the same tile_q."""
         q_lens, kv_lens = (1, 3, 7, 1, 10), (17, 9, 12, 33, 10)
         q, pool, bt, kvl = self._inputs(q_lens, kv_lens, 4, 1, 5)
-        out = np.asarray(chip_pa.paged_attention_ragged(
+        out = np.asarray(pa_module.paged_attention_ragged(
             q, pool, bt, q_lens, kvl, tile_q=tile_q))
         r0 = 0
         for s, ql in enumerate(q_lens):
-            solo = np.asarray(chip_pa.paged_attention_ragged(
+            solo = np.asarray(pa_module.paged_attention_ragged(
                 q[r0:r0 + ql], pool, bt[s:s + 1], (ql,), kvl[s:s + 1],
                 tile_q=tile_q))
             np.testing.assert_array_equal(out[r0:r0 + ql], solo)
             r0 += ql
 
-    def test_launch_plan_at_the_cells_shapes(self, chip_pa):
+    def test_launch_plan_at_the_cells_shapes(self):
         """The serving cells' launches (32 kv heads of 128, bf16 pages
         of 16, 128 table entries): every head of a page in one grid
         step, so at most 4 096 steps of at least 256 KB where the
         one-head one-page grid had 131 072 of 8 KB."""
-        plan = chip_pa.launch_plan(32, 32, 1, 128, 16, 128, 2)
+        plan = pa_module.launch_plan(32, 32, 1, 128, 16, 128, 2)
         assert plan.heads == 32 and plan.grid[0] == 32
         assert plan.grid_steps == plan.grid[0] * plan.grid[1] <= 4096
         assert plan.bytes_per_step >= 256 * 1024
         assert plan.grid[1] == -(-128 // plan.pages)
-        mixed = chip_pa.launch_plan(36, 32, 64, 128, 16, 128, 2)
+        mixed = pa_module.launch_plan(36, 32, 64, 128, 16, 128, 2)
         assert mixed.heads == 32 and mixed.grid_steps <= 4608
         # tile_kv, where a caller passes one, is the pages a step
-        assert chip_pa.launch_plan(36, 32, 64, 128, 16, 128, 2,
+        assert pa_module.launch_plan(36, 32, 64, 128, 16, 128, 2,
                                    tile_kv=1).grid == (36, 128)
         # grouped shards (mp 4) and an int8 pool keep whole blocks
-        assert chip_pa.launch_plan(32, 8, 1, 128, 16, 128, 2).heads == 8
-        q8 = chip_pa.launch_plan(36, 32, 64, 128, 16, 128, 1,
+        assert pa_module.launch_plan(32, 8, 1, 128, 16, 128, 2).heads == 8
+        q8 = pa_module.launch_plan(36, 32, 64, 128, 16, 128, 1,
                                  quantized=True)
         assert q8.heads % 8 == 0 and 32 % q8.heads == 0
 

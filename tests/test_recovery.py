@@ -211,6 +211,23 @@ class TestEngineSnapshotRestore:
         eng.check_invariants()
         out.check_invariants()
 
+    def test_a_snapshot_of_before_pr30_restores(self):
+        """Engine snapshots written before PR 30 carry the engine's
+        ``tile_q`` / ``tile_kv`` knobs in their config (always None: no
+        caller set them). The knobs are gone; the keys are ignored and
+        the snapshot restores as it did."""
+        model = _model()
+        eng = self._engine(model)
+        eng.submit(paddle.to_tensor(np.random.RandomState(6).randn(
+            5, D).astype(np.float32)))
+        snap = eng.snapshot()
+        assert "tile_q" not in snap["config"]
+        snap["config"].update(tile_q=None, tile_kv=None)
+        out = PagedServingEngine.restore(model, snap)
+        np.testing.assert_array_equal(out.lens, eng.lens)
+        assert not hasattr(out, "tile_q")
+        out.check_invariants()
+
     def test_deadlines_survive_restore(self):
         """A queued request's step deadline keeps ticking on the
         restored clock and fails at the SAME engine step."""

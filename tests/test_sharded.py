@@ -13,8 +13,8 @@ single CI device): numerics and the collective schedule are identical
 to a real mesh — the per-shard executables don't know their neighbors
 — only placement is degenerate. The REAL 2-device CPU mesh
 (``XLA_FLAGS=--xla_force_host_platform_device_count=2``) is exercised
-by the ``serving_sharded`` bench leg's subprocess
-(tests/test_bench_smoke.py drives it in --smoke mode).
+by the subprocesses of tests/test_sharded_compiled.py (the host-staged
+and the compiled path both).
 """
 import numpy as np
 import pytest

@@ -13,7 +13,7 @@ auto-disables (shard "devices" are not distinct), so every mesh test
 here drives a subprocess with --xla_force_host_platform_device_count
 (the tests/test_multiprocess_tp.py idiom;
 --xla_cpu_parallel_codegen_split_count=1 pins the measured XLA-CPU
-codegen nondeterminism source, per bench_extra's sharded worker).
+codegen nondeterminism source).
 What the subprocesses prove, against the eager single-chip oracle of
 tests/test_sharded.py's model:
 

@@ -597,6 +597,32 @@ class TestMutations:
         msgs = " | ".join(f.msg for f in kept)
         assert "ContinuousBatchingEngine" in msgs
 
+    @pytest.mark.parametrize("src_name,old,new,needle,says", [
+        # a name bound at import: the tests' patch would miss it again
+        ("scheduler.py", "from ..framework import device as _device",
+         "from ..framework.device import use_pallas_kernels",
+         "import use_pallas_kernels", "bound by name"),
+        # a second home for the predicate
+        ("moe_serving.py", "def moe_capacity(",
+         "def use_pallas_kernels():\n    return False\n\n\n"
+         "def moe_capacity(",
+         "def use_pallas_kernels", "second definition"),
+        # the argument every caller passed the same value for
+        ("paged_cache.py", "    def decode(self, q, k, v, t):",
+         "    def decode(self, q, k, v, t, use_kernel=False):",
+         "use_kernel=False", "PagedLayerCache.decode"),
+    ])
+    def test_kernel_seam_regressions(self, tmp_path, src_name, old, new,
+                                     needle, says):
+        """What PR 30 took out stays out: the predicate bound by name,
+        defined a second time, or travelling as ``use_kernel`` into a
+        paged view's ``decode`` flips exit 0 -> 1 at the line."""
+        root, path = _mutate(tmp_path, src_name, old, new)
+        kept, _ = run(root, ["kernel-seam"])
+        assert (path, lineno(path, needle)) in \
+            {(f.path, f.line) for f in kept}
+        assert any(says in f.msg for f in kept)
+
     def test_net_transport_time_import_flips_red(self, tmp_path):
         """The session-transport determinism gate: net.py importing
         the clock module — under ANY alias — flips exit 0 -> 1 the
@@ -654,7 +680,8 @@ class TestCLI:
         kept, supp = run(os.path.join(FIX, "snapshot"),
                          ["charge-discipline", "span-safety",
                           "hot-path-purity", "journal-coverage",
-                          "export-drift", "compiled-step-purity"])
+                          "export-drift", "compiled-step-purity",
+                          "kernel-seam"])
         assert kept == [] and supp == []
 
     def test_list_passes(self, capsys):
@@ -662,7 +689,7 @@ class TestCLI:
         out = capsys.readouterr().out
         for pid in cs.PASS_IDS:
             assert pid in out
-        assert len(cs.PASS_IDS) == 8
+        assert len(cs.PASS_IDS) == 9
 
     def test_json_envelope_clean(self, capsys):
         """--json speaks the shared paddle_tpu.report.v1 envelope

@@ -936,63 +936,6 @@ class TestChromeTraceAndReport:
             {"traceEvents": [{"ph": "X", "name": "a", "ts": 0,
                               "dur": 1}]}) == []
 
-    def test_tile_report_exit_codes(self, tmp_path, capsys):
-        """The ragged-kernel tile-sizing aid consumes the same trace
-        artifact: splits steps decode-only/mixed/verify off the
-        span.model timings and prints the tile_q sweep starting
-        point. A tiny synthetic trace keeps this test off the engine
-        (the real-trace path is covered by running the tool over the
-        artifact test_trace_report_exit_codes builds)."""
-        from tools import tile_report
-        evs = []
-
-        def step(s, model=None, prefill=None, prefilling=0, active=2):
-            t = s * 1000.0
-            if prefill is not None:
-                evs.append({"name": "prefill", "ph": "X", "ts": t,
-                            "dur": prefill, "args": {"step": s}})
-            if model is not None:
-                evs.append({"name": "model", "ph": "X", "ts": t + 300,
-                            "dur": model, "args": {"step": s}})
-            evs.append({"name": "step", "ph": "X", "ts": t,
-                        "dur": 900.0, "args": {"step": s}})
-            evs.append({"name": "queue", "ph": "C", "ts": t + 900,
-                        "args": {"depth": 0, "active": active,
-                                 "prefilling": prefilling}})
-        # 1: admission/prefill-only step — queue counter but NO model
-        #    phase (must not shift later steps' counter pairing)
-        step(1, model=None, prefilling=1, active=0)
-        # 2: per-chunk-style mixed step (prefill span carries work)
-        step(2, model=500.0, prefill=300.0, prefilling=1)
-        # 3: ragged-style COMPLETION step — prefill phase is planning
-        #    only, the packed chunk rides the model span, and the
-        #    end-of-step gauge already shows prefilling 0; the
-        #    previous step's gauge marks it mixed
-        step(3, model=500.0, prefill=1.0, prefilling=0)
-        # 4-5: pure decode steps
-        step(4, model=400.0, prefill=1.0, prefilling=0)
-        step(5, model=400.0, prefill=1.0, prefilling=0)
-        path = str(tmp_path / "t.trace.json")
-        with open(path, "w") as f:
-            json.dump({"traceEvents": evs}, f)
-        assert tile_report.main([path, "--budget", "8"]) == 0
-        out = capsys.readouterr().out
-        assert "tile report over 4" in out
-        assert "tile_q sweep candidates" in out
-        assert "default tile table" in out
-        assert tile_report.main([path, "--json"]) == 0
-        rep = json.loads(capsys.readouterr().out)
-        assert rep["steps"] == 4
-        assert rep["mixed"]["count"] == 2
-        assert rep["decode_only"]["count"] == 2
-        assert "tile_q_sweep_candidates" in rep
-        # 2: unreadable, 1: structurally invalid / no model spans
-        assert tile_report.main([str(tmp_path / "nope.json")]) == 2
-        p = str(tmp_path / "bad.json")
-        with open(p, "w") as f:
-            json.dump({"traceEvents": []}, f)
-        assert tile_report.main([p]) == 1
-
 
 # ---------------------------------------------------------------------
 # the profile session is the switch (RecoverableServer), spans on the

@@ -46,6 +46,12 @@ Passes (ids are stable — they are the suppression/selection keys):
                          exist; public ``*Engine``/``*Stats`` classes
                          defined in the package must be exported.
 
+  kernel-seam            ``use_pallas_kernels`` (kernel or jnp
+                         fallback) is defined in framework/device.py
+                         alone and never bound by name at import;
+                         ``decode`` of an ``is_paged`` view takes no
+                         ``use_kernel`` parameter.
+
 Suppression: append ``# lint: ok(<pass-id>)`` to the flagged line (or
 the line directly above it); several ids may be comma-separated.
 Suppressed findings are counted and reported, never silently dropped.
@@ -1467,12 +1473,74 @@ class NetClockPurity:
 
 
 # =====================================================================
+# pass 9: kernel-seam
+# =====================================================================
+
+# "Do Pallas kernels run here" has ONE definition, in
+# framework/device.py, and callers ask it by attribute on that module
+# (``device.use_pallas_kernels()``): a name bound at import is a copy a
+# test's patch never reaches, and a second definition is a second
+# answer. Behind it, a paged view's ``decode`` takes (q, k, v, t) and
+# nothing that selects a path: the choice is ``_attend``'s.
+SEAM_PREDICATE = "use_pallas_kernels"
+SEAM_HOME = "device.py"
+
+
+class KernelSeam:
+    id = "kernel-seam"
+    doc = ("the kernel-or-fallback predicate is defined once "
+           "(framework/device.py) and reached by attribute, never "
+           "bound by name or defined again; decode() of an is_paged "
+           "view takes no use_kernel parameter")
+
+    def run(self, files: List[SourceFile]) -> List[Finding]:
+        findings: List[Finding] = []
+        for sf in files:
+            for node in ast.walk(sf.tree):
+                if isinstance(node, ast.ImportFrom) and any(
+                        a.name == SEAM_PREDICATE for a in node.names):
+                    findings.append(Finding(
+                        self.id, sf.path, node.lineno,
+                        f"{SEAM_PREDICATE} bound by name at import — "
+                        f"a patch on framework/device.py would not "
+                        f"reach this module; import the module and "
+                        f"call device.{SEAM_PREDICATE}()"))
+                elif isinstance(node, ast.FunctionDef) and \
+                        node.name == SEAM_PREDICATE and \
+                        sf.base != SEAM_HOME:
+                    findings.append(Finding(
+                        self.id, sf.path, node.lineno,
+                        f"a second definition of {SEAM_PREDICATE} — "
+                        f"the predicate lives in framework/"
+                        f"{SEAM_HOME} alone"))
+            for cls in sf.classes():
+                paged = any(
+                    isinstance(st, ast.Assign)
+                    and any(isinstance(t, ast.Name) and t.id == "is_paged"
+                            for t in st.targets)
+                    for st in cls.body)
+                decode = methods_of(cls).get("decode")
+                if not paged or decode is None:
+                    continue
+                a = decode.args
+                for arg in a.args + a.kwonlyargs:
+                    if arg.arg == "use_kernel":
+                        findings.append(Finding(
+                            self.id, sf.path, decode.lineno,
+                            f"{cls.name}.decode takes use_kernel — a "
+                            f"paged view's decode is (q, k, v, t); the "
+                            f"path is chosen in one place "
+                            f"(paged_cache._attend)"))
+        return findings
+
+
+# =====================================================================
 # framework
 # =====================================================================
 
 PASSES = [SnapshotCompleteness(), HotPathPurity(), JournalCoverage(),
           ChargeDiscipline(), SpanSafety(), ExportDrift(),
-          CompiledStepPurity(), NetClockPurity()]
+          CompiledStepPurity(), NetClockPurity(), KernelSeam()]
 PASS_IDS = [p.id for p in PASSES]
 
 _SUPPRESS_RE = re.compile(r"#\s*lint:\s*ok\(([^)]*)\)")
