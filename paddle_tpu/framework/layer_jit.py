@@ -194,7 +194,11 @@ class _LayerExec:
         self._trace_out_tree = None
         self._trace_leak = None
         self._trace_diffable = None
-        self._fwd = jax.jit(self._fwd_impl)
+        self._fwd = self._jitted()
+
+    def _jitted(self):
+        """What ``call`` launches with the operands."""
+        return jax.jit(self._fwd_impl)
 
     @property
     def layer(self):
@@ -462,3 +466,148 @@ def try_call(layer, inputs, kwargs):
             raise
         entry["execs"][sig] = _UNSAFE
         return False, None
+
+
+# --- captures that carry state held outside the layer ------------------
+# The serving step: ``model(x, caches=views, time_step=t)`` reads and
+# writes a paged cache's K/V pools through the views. ``try_call``
+# declines it (the views are no hashable keyword), so every op of the
+# forward used to be a program of its own. ``call_with_state`` captures
+# the same forward whole: the owner of the state lends it to the trace.
+#
+# The ``state`` protocol (inference/paged_cache.py ``_LentStep``):
+#   key                    static, hashable: what shapes the program
+#                          besides the operands' shapes
+#   arrays()               -> (donated, plain): pytrees of arrays. The
+#                          donated ones are DONATED to the program and
+#                          come back as its outputs
+#   lend(donated, plain)   context manager, entered inside the trace:
+#                          binds the (traced) arrays where the forward
+#                          will find them, yields ``(kwargs, collect)``
+#                          -- the keyword arguments that carry the state
+#                          into ``forward`` and a function returning the
+#                          donated arrays' new values -- and takes every
+#                          binding back when it exits, also when the
+#                          forward raises
+#   rebind(donated)        after the program ran: the owner takes the
+#                          new arrays (the old ones are deleted)
+
+CORE = "core"                    # the layer's forward does not capture
+TRACE_FAILED = "trace_failed"    # this shape's first trace raised
+
+
+def _plain_literals(tree):
+    """``tree`` with every literal that is not plain data dropped: an
+    exec outlives its layer (jax keeps jitted functions alive), so the
+    output tree it keeps must not hold the caller's objects (cache
+    views, and through them the pools)."""
+    kind, val = tree
+    if kind in ("list", "tuple"):
+        return (kind, [_plain_literals(t) for t in val])
+    if kind == "dict":
+        return (kind, [(k, _plain_literals(t)) for k, t in val])
+    if kind == "L" and not isinstance(val, (bool, int, float, str,
+                                            type(None))):
+        return (kind, None)
+    return tree
+
+
+class _StateExec(_LayerExec):
+    """The compiled forward of one (layer, input signature, state key),
+    run without grad (the serving step). Weights, buffers and the RNG
+    key ride as operands exactly as in ``_LayerExec``; the state's
+    arrays ride in front of them."""
+
+    def __init__(self, layer, in_tree):
+        # lent for the duration of one call, never kept (see _LayerExec:
+        # the exec is immortal and must pin nothing)
+        self.state = None
+        self.returned = None
+        super().__init__(layer, False, in_tree, ())
+
+    def _jitted(self):
+        self._program = jax.jit(self.fwd, donate_argnums=(0,))
+        return self._launch
+
+    def fwd(self, donated, plain, *operands):
+        # the NAME is read: the compiled module is ``jit_fwd`` and its
+        # kernel launches ``fwd.N``, as the op executables' are
+        # (framework/op.py), and the benchmark's readers find the
+        # paged-attention launch by that (``mosaic:fwd_*``)
+        with self.state.lend(donated, plain) as (kwargs, collect):
+            self.kwargs = kwargs
+            try:
+                outs, aux, _ = self._fwd_impl(*operands)
+            finally:
+                self.kwargs = {}
+            return outs, aux, collect()
+
+    def _run(self, *operands):
+        outs, aux = super()._run(*operands)
+        self._trace_out_tree = _plain_literals(self._trace_out_tree)
+        return outs, aux
+
+    def _launch(self, *operands):
+        outs, aux, self.returned = self._program(
+            *self.state.arrays(), *operands)
+        return outs, aux, ()
+
+
+def state_programs(layer) -> int:
+    """Programs ``call_with_state`` holds for ``layer`` so far."""
+    from ..nn import Layer
+    entry = _cache.get(layer) if isinstance(layer, Layer) else None
+    return 0 if entry is None else sum(
+        isinstance(e, _StateExec) for e in entry["execs"].values())
+
+
+def call_with_state(layer, inputs, state):
+    """Run ``layer.forward(*inputs, **kwargs)`` as ONE program, where
+    ``kwargs`` and the arrays behind them are ``state``'s (protocol
+    above). Returns ``(result, None)``, or ``(None, reason)`` when the
+    forward does not capture and the caller should run it per op:
+    ``CORE`` by the rule ``try_call`` applies to a layer (an
+    ``nn.Layer``, not ``mark_unsafe``, no forward hooks, a trace that
+    leaves no tracer behind, the layer-jit on and no outer trace), and
+    ``TRACE_FAILED`` for a signature whose first trace raised: that
+    signature stays per-op, others still capture. The state is whole
+    either way: a failed trace donated nothing, and a program that ran
+    has its outputs rebound before anything else happens."""
+    from ..nn import Layer
+    if not isinstance(layer, Layer) or _state.active or not enabled() \
+            or not _trace_clean():
+        return None, CORE
+    entry = _cache.setdefault(layer, {"execs": {}})
+    hooks, training = _walk_info(layer)
+    if hooks or _UNSAFE in (entry.get("all"), entry.get("state")):
+        return None, CORE
+    in_leaves, in_tree, in_objs = _flatten(list(inputs))
+    named = list(layer.named_parameters())
+    sig = _signature(named, in_leaves, in_objs, ("state", state.key),
+                     False, in_tree, training)
+    exec_ = entry["execs"].get(sig)
+    if exec_ is _UNSAFE:
+        return None, TRACE_FAILED
+    if exec_ is None:
+        exec_ = entry["execs"][sig] = _StateExec(layer, in_tree)
+    exec_.state = state
+    try:
+        return exec_.call(in_leaves, in_objs, named), None
+    except _CaptureUnsafe:
+        # the program ran (its outputs are rebound below) but the trace
+        # left a tracer in the layer: the run is discarded, and the
+        # caller's per-op run repeats the step's writes, row for row
+        # the same (and is the one that is counted)
+        del entry["execs"][sig]
+        entry["state"] = _UNSAFE
+        return None, CORE
+    except Exception:
+        import os
+        if os.environ.get("PADDLE_TPU_LAYER_JIT_DEBUG"):
+            raise
+        entry["execs"][sig] = _UNSAFE
+        return None, TRACE_FAILED
+    finally:
+        returned, exec_.state, exec_.returned = exec_.returned, None, None
+        if returned is not None:
+            state.rebind(returned)

@@ -85,6 +85,7 @@ before handing the pool back.
 """
 from __future__ import annotations
 
+import contextlib
 import functools
 import hashlib
 from collections import OrderedDict
@@ -95,6 +96,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..framework import device as _device
+from ..framework import layer_jit as _layer_jit
 from ..framework.op import _trace_clean, apply, unwrap
 from ..framework.tensor import Tensor
 from ..ops.pallas.paged_attention import (gather_pages, launch_plan,
@@ -500,20 +502,131 @@ def _attend(q, pool, scales, tables, lens, rows, window, fallback):
     pays no dispatch of its own for it."""
     if not _device.use_pallas_kernels():
         return fallback()
+    launch, shared = _launch(paged_attention_ragged, rows, window)
+    args = (pool, q, lens, tables)
+    # inside someone's trace (a step program) every layer calls ONE
+    # jitted function, so the kernel is traced and lowered to Mosaic
+    # once a program instead of once a layer
+    return apply(launch if _trace_clean() else shared,
+                 args if scales is None else args + (scales,),
+                 op_name="paged_attention")
 
-    def launch(p, q_, ln, bta, sc=None):
+
+@functools.lru_cache(maxsize=None)
+def _launch(kernel, rows, window):
+    """``_attend``'s call of ``kernel`` for one static ``(rows,
+    window)``: the function, and the same function jitted (without
+    that, a four-layer step program spends 0.3 s a layer of set-up on
+    tracing and lowering the same kernel again, before the compile
+    cache is even asked). Named ``fwd`` as every op executable is: the
+    launch's name in a profile, ``mosaic:fwd_*``, comes from it."""
+    def fwd(p, q_, ln, bta, sc=None):
         if isinstance(rows, int):
             q_lens, kv_lens = (rows,) * bta.shape[0], ln + rows
         else:
             q_lens, kv_lens = rows, ln
-        out = paged_attention_ragged(
-            q_.reshape((-1,) + q_.shape[2:]), p, bta, q_lens, kv_lens,
-            kv_scales=sc, window=window)
+        out = kernel(q_.reshape((-1,) + q_.shape[2:]), p, bta, q_lens,
+                     kv_lens, kv_scales=sc, window=window)
         return out.reshape(q_.shape)
+    return fwd, jax.jit(fwd)
 
-    args = (pool, q, lens, tables)
-    return apply(launch, args if scales is None else args + (scales,),
-                 op_name="paged_attention")
+
+class _LentStep:
+    """One model call's cache state, as ``layer_jit.call_with_state``
+    takes it (the protocol is written down there): every layer's pool
+    (and int8 scale array) DONATED and rebound from the program's
+    outputs; ``time_step`` and the arrays every layer's view reads, the
+    batch block table of a uniform call or the packed layout's routing
+    and descriptors, as plain operands. ``key`` is what the kernel
+    launch is keyed by and nothing more: the static ``q_lens`` of a
+    packed call, ``(B, L)`` of a uniform one (and the layers' windows,
+    which no operand's shape shows). ``writes`` is what one run moves
+    (pages, rows; every layer's view appends once), known from the
+    call's shapes: a run that hits a program compiled for ANOTHER
+    cache of the same geometry (a restored engine, a second engine on
+    the model) traces nothing."""
+
+    def __init__(self, x, views, t):
+        view = views[0]
+        self.views, self.t = views, t
+        self.cache = c = view._cache
+        if isinstance(view, PagedRaggedView):
+            self.holder, rows = view._layout, view._layout.q_lens
+            self.names = ("pg_ids", "route", "bt_all", "kv_lens")
+        else:
+            c.bt_tensor()           # built, so that there is one to lend
+            self.holder, rows = c, tuple(x.shape[:2])
+            self.names = ("_bt_cached",)
+        self.key = (type(view).__name__, rows, c.layer_windows)
+        self.writes = len(views) * np.array(view._moves(*x.shape[:2]),
+                                            np.int64)
+
+    def _pools(self):
+        c = self.cache
+        return (tuple(p.data for p in c.pools),
+                tuple(s.data for s in c.scales) if c.quantized else None)
+
+    def rebind(self, donated):
+        c = self.cache
+        c.pools[:] = [Tensor(p) for p in donated[0]]
+        if c.quantized:
+            c.scales[:] = [Tensor(s) for s in donated[1]]
+
+    def arrays(self):
+        return self._pools(), (unwrap(self.t),) + tuple(
+            unwrap(getattr(self.holder, n)) for n in self.names)
+
+    @contextlib.contextmanager
+    def lend(self, donated, plain):
+        c, h = self.cache, self.holder
+        pools, scales = list(c.pools), c.quantized and list(c.scales)
+        held = [getattr(h, n) for n in self.names]
+        try:
+            self.rebind(donated)
+            for n, was, a in zip(self.names, held, plain[1:]):
+                setattr(h, n, Tensor(a) if isinstance(was, Tensor) else a)
+            yield ({"caches": self.views, "time_step": Tensor(plain[0])},
+                   self._pools)
+        finally:
+            c.pools[:] = pools
+            if c.quantized:
+                c.scales[:] = scales
+            for n, was in zip(self.names, held):
+                setattr(h, n, was)
+
+
+def model_call(model, x, views, t, collector=None):
+    """ONE model call of the paged engine, ``model(x, caches=views,
+    time_step=t)`` -> hidden: the step program where the step is the
+    chip's already, the per-op call everywhere else. The step program
+    is that same forward traced whole (``layer_jit.call_with_state``):
+    all layers, each layer's page-form K/V append and its
+    paged-attention launch in one XLA program, the pools donated
+    through it (``_LentStep``). Who gets it is what the code can
+    observe: the kernel path live (the predicate ``_attend`` asks),
+    one shard, and a core whose forward captures whole. Every other
+    call, and a shape whose first trace failed, runs per op as before,
+    and the ``step_program`` gauge says why (one sample a call:
+    ``captured``, ``programs`` compiled so far, and on a 0 the
+    reason)."""
+    if not _device.use_pallas_kernels():
+        out, why = None, "no_kernel"
+    elif views[0]._cache.mp != 1:
+        out, why = None, "mp"
+    else:
+        state = _LentStep(x, views, t)
+        out, why = _layer_jit.call_with_state(model, (x,), state)
+        if why is None:
+            state.cache._written += state.writes
+    if out is None:
+        out = model(x, caches=views, time_step=t)
+    if collector is not None:
+        series = {"captured": int(why is None),
+                  "programs": _layer_jit.state_programs(model)}
+        if why is not None:
+            series[why] = 1
+        collector.gauge("step_program", series)
+    return out[0]
 
 
 class PagedLayerCache:
@@ -561,6 +674,11 @@ class PagedLayerCache:
         """Absolute positions int32 [B, rows] of the call's query
         rows: row b's i-th token sits at t[b] + i."""
         return t.reshape(-1, 1) + jnp.arange(rows, dtype=jnp.int32)
+
+    def _moves(self, B: int, L: int) -> Tuple[int, int]:
+        """(pages, rows) one ``decode`` of a [B, L] call moves in this
+        layer's pool (``_write_pool``'s counts)."""
+        return B * _pages_spanned(L, self._cache.block_size), B * L
 
     def decode(self, q, k, v, t):
         """q/k/v: [B, L, H, D] Tensors (L == 1 is the plain decode
@@ -617,9 +735,10 @@ class PagedLayerCache:
                         f"ensure(row, position+{L}) first")
         bt = c.bt_tensor()
         tt = Tensor(t)
+        pages, rows = self._moves(B, L)
         new_pool, new_sc = c._write_pool(
             self._pi, _append_rows, (c.block_size, L), k, v, tt, bt,
-            pages=B * _pages_spanned(L, c.block_size), rows=B * L)
+            pages=pages, rows=rows)
         return _attend(
             q, new_pool, new_sc, bt, tt, L, self.window,
             lambda: self._sdpa_over_pages(q, t, new_pool, new_sc, bt))
@@ -913,6 +1032,11 @@ class _RaggedLayout:
         absolute position (uploaded once a layout, on first use — a
         model without a position encoding never asks)."""
         if self._pos is None:
+            if not _trace_clean():
+                # a constant of THIS layout in a program other layouts
+                # of the same q_lens run
+                raise RuntimeError("the packed rows' positions are not "
+                                   "an operand of the step program")
             self._pos = jnp.asarray(self.pos_np[None])
         return self._pos
 
@@ -1011,6 +1135,11 @@ class PagedRaggedView:
         the layout (``t`` says nothing about a packed batch)."""
         return self._layout.positions()
 
+    def _moves(self, *_) -> Tuple[int, int]:
+        """(pages, rows) one ``decode`` moves in this layer's pool:
+        the layout's page list and its packed rows."""
+        return self._layout.n_pages, self._layout.total_rows
+
     def decode(self, q, k, v, t):
         """q/k/v: [1, R, H, D] — the packed mixed batch. ``t`` is
         ignored: the layout carries every row's absolute position.
@@ -1029,9 +1158,10 @@ class PagedRaggedView:
                 f"head slice ({c.heads_per_shard} heads), got "
                 f"{int(q.shape[2])} — drive a sharded cache through "
                 f"a ShardedServingCore")
+        pages, rows = self._moves()
         new_pool, new_sc = c._write_pool(
             self._pi, _ragged_append, (), k, v, lay.pg_ids, lay.route,
-            pages=lay.n_pages, rows=lay.total_rows)
+            pages=pages, rows=rows)
         return _attend(
             q, new_pool, new_sc, lay.bt_all, lay.kv_lens, lay.q_lens,
             self.window,
@@ -1311,20 +1441,22 @@ class PagedKVCache:
         ``cache.pools[i].data`` at the moment it reads. Inside someone
         else's trace the write composes into their program instead.
         ``pages`` / ``rows`` are what the write moves, for the
-        ``pool_write`` gauge (``take_write_stats``)."""
+        ``pool_write`` gauge (``take_write_stats``): counted here for a
+        write that runs now, and by whoever runs the program for one
+        that is traced into it (``model_call``; a trace is not a run)."""
         pool = self.pools[pi].data
         sc = self.scales[pi].data if self.quantized else None
         arrays = tuple(unwrap(a) for a in args)
         if _trace_clean():
             pool, sc = _pool_program(fn, *static)(pool, sc, *arrays)
+            self._written[0] += pages
+            self._written[1] += rows
         else:
             pool, sc = fn(*static, pool, sc, *arrays)
         new_pool = self.pools[pi] = Tensor(pool)
         new_sc = None
         if self.quantized:
             new_sc = self.scales[pi] = Tensor(sc)
-        self._written[0] += pages
-        self._written[1] += rows
         return new_pool, new_sc
 
     def take_write_stats(self) -> dict:

@@ -133,7 +133,8 @@ import numpy as np
 from ..framework import device as _device
 from ..framework.autograd import no_grad
 from ..framework.tensor import Tensor
-from .paged_cache import BlockOOM, PagedKVCache, chain_block_hashes
+from .paged_cache import (BlockOOM, PagedKVCache, chain_block_hashes,
+                          model_call)
 from .resilience import RequestOutcome
 from .serving import (ParallelStats, PrefillStats, PrefixCacheStats,
                       ResilienceStats, TenantStats)
@@ -1508,8 +1509,8 @@ class PagedServingEngine:
                                         x.shape[-1]))
         xp = Tensor(jnp.concatenate(parts, axis=0)[None])
         with no_grad():
-            out, _ = self.model(xp, caches=views,
-                                time_step=Tensor(np.int32(0)))
+            out = model_call(self.model, xp, views,
+                             Tensor(np.int32(0)), col)
         hv = out.data
         lo = 0
         for s in segs:
@@ -2102,8 +2103,7 @@ class PagedServingEngine:
             # this call are still executing asynchronously
             t = Tensor(np.array(self.lens, np.int32))
             with no_grad():
-                out, _ = self.model(x, caches=self.cache.views,
-                                    time_step=t)
+                out = model_call(self.model, x, self.cache.views, t, col)
         if self.injector is not None:
             out = self.injector.corrupt_hidden(out)
         if col is not None:
@@ -2267,8 +2267,7 @@ class PagedServingEngine:
             # this call are still executing asynchronously
             t = Tensor(np.array(self.lens, np.int32))
             with no_grad():
-                out, _ = self.model(x, caches=self.cache.views,
-                                    time_step=t)
+                out = model_call(self.model, x, self.cache.views, t, col)
         if self.injector is not None:
             out = self.injector.corrupt_hidden(out)
         if col is not None:

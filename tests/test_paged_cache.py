@@ -11,6 +11,7 @@ import numpy as np
 import pytest
 
 import paddle_tpu as paddle
+from paddle_tpu.framework import layer_jit
 from paddle_tpu.incubate.nn import FusedMultiTransformer
 from paddle_tpu.inference import (BlockAllocator, BlockOOM,
                                   ContinuousBatchingEngine,
@@ -907,6 +908,11 @@ class TestRaggedMixedStep:
         # small geometry: interpret-mode Pallas launches run eagerly
         # here (the op-jit cache is off so the counter is exact)
         model = _model()
+        # the per-op step: a step program (PR 31) traces the launch all
+        # its layers share ONCE, so the wrapper's entries count layers
+        # only here (tests/test_step_program_hlo.py counts the
+        # program's custom calls)
+        layer_jit.mark_unsafe(model)
         rng = np.random.RandomState(78)
         eng = PagedServingEngine(model, max_batch=2, block_size=self.CAP_BS,
                                  num_blocks=12, max_blocks_per_seq=4,
@@ -1672,6 +1678,10 @@ class TestAttentionSeam:
                 mp, compiled_step=False)
         else:
             tsm = build_model_from_spec(spec)
+        if core == "gpt3":
+            # counted on the per-op step: its step program (PR 31)
+            # enters the wrapper once for all its layers
+            layer_jit.mark_unsafe(tsm.core)
         eng = SpeculativeEngine(tsm, k=0, max_batch=2, block_size=4,
                                 num_blocks=40, max_blocks_per_seq=12,
                                 prefill_token_budget=8)

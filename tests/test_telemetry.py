@@ -646,7 +646,13 @@ class TestTimelineAndLifecycle:
         # per-step gauges: pool tiers + queue depths + tenant charge
         gauges = [ev for ev in col.events if ev.get("ph") == "C"]
         tracks = {ev["name"] for ev in gauges}
-        assert tracks == {"pool", "pool_write", "queue", "tenant_blocks"}
+        assert tracks == {"pool", "pool_write", "queue", "tenant_blocks",
+                          "step_program"}
+        # the CPU runs every model call per op, and the gauge says why
+        calls = [ev["args"] for ev in gauges if ev["name"] == "step_program"]
+        assert len(calls) >= col.steps
+        assert all(a == {"captured": 0, "programs": 0, "no_kernel": 1}
+                   for a in calls)
         pool = next(ev for ev in gauges if ev["name"] == "pool")
         assert {"active", "cached_free", "free"} <= set(pool["args"])
         # what each step's K/V appends moved: pages of the donated
@@ -767,6 +773,11 @@ class TestTimelineAndLifecycle:
         assert re.search(r"pool writes: [\d.]+ page\(s\) and [\d.]+ "
                          r"row\(s\) a step over all layers, [\d.]+ MB "
                          r"of a [\d.]+ MB pool", out), out
+        # the step program's gauge, a sample a model call: on the CPU
+        # the kernel predicate is false and every call runs per op
+        assert re.search(r"step program: 0 of \d+ model call\(s\) ran as "
+                         r"one compiled program \(0\.0 %\), 0 program\(s\) "
+                         r"compiled; per op because: no_kernel x\d+", out), out
         assert trace_report.main([path, "--json"]) == 0
         rep = json.loads(capsys.readouterr().out)
         assert rep["data"]["gauges"]["paged_attn.grid_steps"][

@@ -239,6 +239,16 @@ def summarize(trace: dict, tenant: str = None,
                      f"{pool / 1e6:.1f} MB pool "
                      f"({100.0 * moved / max(pool, 1):.3f} %): pages of "
                      f"a donated pool, written in place")
+    calls = counters.get("step_program", {})
+    if "captured" in calls:
+        n, captured = calls["captured"][:2]
+        why = ", ".join(f"{k} x{calls[k][0]}" for k in sorted(calls)
+                        if k not in ("captured", "programs"))
+        lines.append(f"  step program: {captured:g} of {n} model call(s) "
+                     f"ran as one compiled program "
+                     f"({100.0 * captured / max(n, 1):.1f} %), "
+                     f"{calls['programs'][4]:g} program(s) compiled"
+                     + (f"; per op because: {why}" if why else ""))
     if insts:
         lines.append(f"  instants: "
                      + ", ".join(f"{k} x{v}"
