@@ -1084,6 +1084,96 @@ def decoder_phase(*, hidden=3072, heads=48, kv_heads=8, head_dim=128,
             "moe": moe}
 
 
+def latent_phase(*, hidden=2048, heads=32, q_rank=1536, kv_rank=512,
+                 nope=128, rope=64, v_dim=128, dense_width=7168,
+                 experts=256, top_k=8, expert_width=768, vocab=16384,
+                 prompt=8704, chunk=2048, block_size=16, max_batch=8,
+                 weight_dtype="bfloat16", kv_dtype="bfloat16",
+                 expect_kernel=True, logits_tol=DECODER_LOGITS_TOL,
+                 tol=KERNEL_TOL, seed=0, meter=None) -> dict:
+    """The ``joyai_llm_flash`` core at published widths: the latent
+    (``v_dim``) ragged launch at ``heads`` query heads on ONE kv head of
+    ``kv_rank + rope`` columns (stored in whole 128-lane tiles), a chunk and decode rows past 8 k positions,
+    against its jnp reference, then a two-layer server (the dense layer,
+    an expert layer with every expert) through ``build_server_from_spec``
+    whose probe is held to the plain UN-absorbed reference's logits."""
+    import jax.numpy as jnp
+    import numpy as np
+    import tempfile
+    from benchmark.jobs import serve_latent
+    pa = _kernel_module("paged_attention")
+
+    t_phase = time.perf_counter()
+    rng = np.random.default_rng(seed)
+    width = -(-(kv_rank + rope) // 128) * 128        # as stored
+    scale = (nope + rope) ** -0.5
+    max_blocks = -(-(prompt + 64) // block_size)
+    q_lens = (chunk,) + (1,) * max_batch
+    kv_lens = [prompt] + list(rng.integers(2, prompt, size=max_batch - 1)) \
+        + [prompt - 3]
+    num_blocks = 1 + sum(-(-int(n) // block_size) for n in kv_lens)
+    _, _, bt, kvl = _paged_inputs(
+        rng, q_lens, kv_lens, heads=1, head_dim=1, block_size=block_size,
+        num_blocks=num_blocks, max_blocks=max_blocks, pool_dtype="float32")
+    q = jnp.asarray(rng.standard_normal((sum(q_lens), heads, width)),
+                    kv_dtype)
+    pool = jnp.asarray(rng.standard_normal(
+        (num_blocks, 1, 1, block_size, width)), kv_dtype)
+    rows = [_run_check(
+        "paged_ragged/latent",
+        f"R={sum(q_lens)} nh={heads} nkv=1 hd={width} v_dim={kv_rank}",
+        lambda q_, p_, bt_, kl_: pa.paged_attention_ragged(
+            q_, p_, bt_, q_lens, kl_, sm_scale=scale, v_dim=kv_rank),
+        lambda q_, p_, bt_, kl_: pa.paged_attention_ragged_reference(
+            q_, p_, bt_, q_lens, kl_, sm_scale=scale, v_dim=kv_rank),
+        (q, pool, bt, kvl), expect_kernel, tol)]
+
+    config = {
+        "model_type": "joyai_llm_flash", "reference": "joyai_llm_flash",
+        "hidden_size": hidden, "num_attention_heads": heads,
+        "intermediate_size": dense_width,
+        "moe_intermediate_size": expert_width, "q_lora_rank": q_rank,
+        "kv_lora_rank": kv_rank, "qk_nope_head_dim": nope,
+        "qk_rope_head_dim": rope, "v_head_dim": v_dim,
+        "first_k_dense_replace": 1, "n_routed_experts": experts,
+        "n_shared_experts": 1, "num_experts_per_tok": top_k,
+        "norm_topk_prob": True, "routed_scaling_factor": 2.5,
+        "rope_interleave": True, "rope_theta": 32000000,
+        "rms_norm_eps": 1e-6, "vocab_size": vocab,
+        "weight_dtype": weight_dtype, "layers_run": [0, 1],
+        "engine": {"mp": 1, "k": 0, "max_batch": max_batch,
+                   "block_size": block_size,
+                   "num_blocks": 2 * max_blocks + 8,
+                   "max_blocks_per_seq": max_blocks, "prefix_cache": True,
+                   "prefill_token_budget": chunk, "kv_dtype": kv_dtype},
+    }
+    stats = {}
+    with tempfile.TemporaryDirectory(prefix="chip_smoke_") as workdir:
+        server = serve_latent.build_server(config, seed, workdir)
+        try:
+            cache = server.engine.engine.cache
+            with _device_ops_traced("latent",
+                                    {tuple(p.shape) for p in cache.pools}):
+                probe = serve_latent.probe_engine(
+                    server, config, {"table": [[prompt, 8]]}, seed)
+            err = serve_latent.compare_probe(
+                server.engine.target, config, probe, tol=logits_tol,
+                stats=stats)
+            per_token = cache.kv_bytes_per_token()
+        finally:
+            server.close()
+    log(f"[latent] probe of {prompt} tokens: rel. L2 {err:.2e}, "
+        f"{stats.get('route_ties_taken')} of {stats.get('route_rows')} "
+        f"routed rows tied (widest gap {stats.get('route_widest_gap')}); "
+        f"{per_token} cache bytes a token")
+    if per_token != 2 * width * jnp.dtype(kv_dtype).itemsize:
+        raise AssertionError(f"the latent pool holds {per_token} bytes a "
+                             f"token over two layers of {width} columns")
+    if meter is not None:
+        _phase_line("latent", time.perf_counter() - t_phase, meter.take())
+    return {"kernels": rows, "probe_rel_l2": err, "route": stats}
+
+
 def main() -> None:
     t_start = time.perf_counter()
     device = require_tpu()
@@ -1101,6 +1191,8 @@ def main() -> None:
     _free_device_memory("kernels")
     decoder_phase(meter=meter)
     _free_device_memory("decoder")
+    latent_phase(meter=meter)
+    _free_device_memory("latent")
     trainer_phase(meter=meter)
     _free_device_memory("trainer")
 

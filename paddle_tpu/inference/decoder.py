@@ -10,7 +10,10 @@ speak: ``core(x, caches=views, time_step=t) -> (hidden, views)`` with
 ``is_paged`` views (``inference/paged_cache.py``), so chunked prefill,
 the prefix cache, the journal, snapshots and preemption hold unchanged.
 
-What the block covers today is the ``afmoe`` layer (Arcee Trinity):
+The block has two statics, which ``DecoderConfig.from_spec`` derives from
+the spec's ``arch``: the ATTENTION KIND (``gqa`` | ``mla``) and the
+RESIDUAL FORM (``sandwich`` | ``pre_norm``). ``afmoe`` (Arcee Trinity) is
+``gqa`` in a ``sandwich``:
 
     a = RMSNorm_in(h);  q, k, v, g = a Wq, a Wk, a Wv, a Wg
     q, k = RMSNorm_q(q), RMSNorm_k(k)            (over the head dim)
@@ -19,12 +22,30 @@ What the block covers today is the ``afmoe`` layer (Arcee Trinity):
     h = h + RMSNorm_post_attn(o)
     m = RMSNorm_pre_mlp(h);  h = h + RMSNorm_post_mlp(F(m))
 
-with ``F`` a SwiGLU in the first ``num_dense_layers`` layers and, after
+``joyai_llm_flash`` (DeepSeek-V3's layer) is ``mla`` in ``pre_norm``
+(``h = h + attn(RMSNorm(h))``; ``h = h + F(RMSNorm(h))``), multi-head
+latent attention in its ABSORBED form over a latent cache:
+
+    c_q = RMSNorm(a W_qa);  [q_nope | q_rope]_head = c_q W_qb
+    [c_kv | k_r] = a W_kva;  c = RMSNorm(c_kv);  RoPE on q_rope, k_r
+        (interleaved pairs (2i, 2i+1), the rope dimensions only; k_r is
+        ONE head, shared by all query heads)
+    cached row = [c | RoPE(k_r) | 0]    (kv_lora_rank + qk_rope_head_dim,
+        zero-padded to whole 128-lane tiles: ``DecoderConfig.kv_width``)
+    q_abs_head = [q_nope W_kvb^K(head)^T | RoPE(q_rope) | 0]
+    o_head = (softmax(q_abs . row / sqrt(nope + rope), causal) row[:rank])
+             W_kvb^V(head);   h = h + concat_heads(o) W_o
+
+so the pool holds ONE row a position a layer and no decompressed K or V
+is ever written (``PagedKVCache``'s latent form; the view takes
+``decode(q_abs, row, None, t)``). Every layer is a full layer.
+
+``F`` is a SwiGLU in the first ``num_dense_layers`` layers and, after
 them, a shared SwiGLU expert plus this chip's share of the routed ones
 (``inference/moe_serving.py``: sigmoid scores over ALL experts, top-k of
-score + bias, dropless grouped GEMM over the experts held here). Each KV
-head serves ``num_attention_heads / num_key_value_heads`` query heads;
-the pool stores the KV heads only.
+score + bias, dropless grouped GEMM over the experts held here). With
+``gqa`` each KV head serves ``num_attention_heads / num_key_value_heads``
+query heads; the pool stores the KV heads only.
 
 Precision: weights are stored in ``weight_dtype``; every product
 accumulates in float32 and hands its result on in ``weight_dtype``; the
@@ -52,24 +73,36 @@ from .moe_serving import (dropless_experts, expert_row_block,
 from .paged_cache import PagedLayerCache
 
 __all__ = ["DecoderConfig", "DecoderCore", "decoder_block", "rms_norm",
-           "rope_half_split"]
+           "rope_half_split", "rope_interleaved"]
 
 SLIDING, FULL = "sliding_attention", "full_attention"
+# arch -> (attention kind, residual form)
+ARCHS = {"afmoe": ("gqa", "sandwich"),
+         "joyai_llm_flash": ("mla", "pre_norm")}
+# the published keys of an ``mla`` configuration (DeepSeek-V3's names) and
+# the fields they fill
+MLA_KEYS = {"first_k_dense_replace": "num_dense_layers",
+            "n_routed_experts": "num_experts",
+            "n_shared_experts": "num_shared_experts",
+            "norm_topk_prob": "route_norm",
+            "routed_scaling_factor": "route_scale"}
 
 
 @dataclasses.dataclass(frozen=True)
 class DecoderConfig:
     """The catalog's ``config.json`` keys, plus which experts live here
-    (``experts_held`` of ``num_experts`` from ``expert_offset``) and the
-    stored weight type."""
+    (``experts_held`` of ``num_experts`` from ``expert_offset``), the
+    stored weight type and the block's two statics. An ``mla`` spec
+    speaks DeepSeek-V3's keys (``MLA_KEYS``, ``num_hidden_layers``, the
+    five latent widths, ``rope_interleave``)."""
     hidden_size: int
     num_attention_heads: int
-    num_key_value_heads: int
-    head_dim: int
     layer_types: Tuple[str, ...]
-    sliding_window: int
     num_dense_layers: int
     intermediate_size: int
+    num_key_value_heads: int = 1
+    head_dim: int = 0
+    sliding_window: int = 0
     num_experts: int = 0
     num_experts_per_tok: int = 0
     num_shared_experts: int = 0
@@ -82,6 +115,14 @@ class DecoderConfig:
     experts_held: Optional[int] = None
     expert_offset: int = 0
     weight_dtype: str = "bfloat16"
+    attention: str = "gqa"
+    residual: str = "sandwich"
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    rope_interleave: bool = True
 
     KEYS = ("hidden_size", "num_attention_heads", "num_key_value_heads",
             "head_dim", "layer_types", "sliding_window",
@@ -89,11 +130,21 @@ class DecoderConfig:
             "num_experts_per_tok", "num_shared_experts",
             "moe_intermediate_size", "route_norm", "route_scale",
             "rope_theta", "rms_norm_eps", "mup_enabled", "experts_held",
-            "expert_offset", "weight_dtype")
+            "expert_offset", "weight_dtype", "q_lora_rank", "kv_lora_rank",
+            "qk_nope_head_dim", "qk_rope_head_dim", "v_head_dim",
+            "rope_interleave")
 
     @classmethod
     def from_spec(cls, spec: dict) -> "DecoderConfig":
         kw = {k: spec[k] for k in cls.KEYS if spec.get(k) is not None}
+        kw["attention"], kw["residual"] = ARCHS[spec.get("arch", "afmoe")]
+        if kw["attention"] == "mla":
+            kw.update({field: spec[k] for k, field in MLA_KEYS.items()
+                       if spec.get(k) is not None})
+            kw["layer_types"] = (FULL,) * int(spec["num_hidden_layers"])
+            # the cache's geometry, not the published K/V heads: one
+            # latent head a position
+            kw["num_key_value_heads"] = 1
         kw["layer_types"] = tuple(kw["layer_types"])
         cfg = cls(**kw)
         held = cfg.num_experts if cfg.experts_held is None \
@@ -102,10 +153,21 @@ class DecoderConfig:
         bad = set(cfg.layer_types) - {SLIDING, FULL}
         if bad:
             raise ValueError(f"unknown layer types {sorted(bad)}")
-        if cfg.num_attention_heads % cfg.num_key_value_heads:
+        if cfg.attention == "mla":
+            if min(cfg.q_lora_rank, cfg.kv_lora_rank, cfg.qk_nope_head_dim,
+                   cfg.qk_rope_head_dim, cfg.v_head_dim) < 1 \
+                    or cfg.qk_rope_head_dim % 2:
+                raise ValueError(
+                    "an mla spec takes q_lora_rank, kv_lora_rank, "
+                    "qk_nope_head_dim, v_head_dim and an even "
+                    "qk_rope_head_dim")
+            if not cfg.rope_interleave:
+                raise ValueError("mla rotates interleaved pairs "
+                                 "(rope_interleave true) only")
+        elif cfg.num_attention_heads % cfg.num_key_value_heads:
             raise ValueError("num_attention_heads must be a multiple of "
                              "num_key_value_heads")
-        if cfg.head_dim % 2:
+        elif cfg.head_dim < 2 or cfg.head_dim % 2:
             raise ValueError("head_dim must be even (half-split RoPE)")
         if cfg.num_layers > cfg.num_dense_layers and not (
                 0 < cfg.num_experts_per_tok <= cfg.num_experts and
@@ -133,6 +195,25 @@ class DecoderConfig:
     def is_moe(self, layer: int) -> bool:
         return layer >= self.num_dense_layers
 
+    @property
+    def kv_width(self) -> int:
+        """Columns the cache STORES a position and kv head: the head
+        (``gqa``); the latent and the shared rope head, padded with
+        zeros to whole 128-lane tiles (``mla``: 576 -> 640). A pool
+        whose rows are not whole tiles is laid out by the TPU compiler
+        with the BLOCK axis minor, and every page write and kernel launch
+        then copies the whole pool there and back
+        (tests/test_pool_write_hlo.py compiles both widths)."""
+        if self.attention != "mla":
+            return self.head_dim
+        return -(-(self.kv_lora_rank + self.qk_rope_head_dim) // 128) * 128
+
+    @property
+    def attn_scale(self) -> float:
+        """``mla``: over the UN-absorbed head, not the cached row."""
+        return (self.qk_nope_head_dim + self.qk_rope_head_dim) ** -0.5 \
+            if self.attention == "mla" else self.head_dim ** -0.5
+
 
 # ---------------------------------------------------------------------
 # the block's pieces: pure functions of arrays
@@ -158,14 +239,61 @@ def rope_half_split(x, positions, theta):
     return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
 
 
+def rope_interleaved(x, positions, theta):
+    """Rotary embedding over the whole of ``x``'s last axis, interleaved
+    pairing (dimension 2i rotates with 2i + 1): ``x`` [..., rows, heads,
+    rd] float32, ``positions`` [..., rows] int32."""
+    half = x.shape[-1] // 2
+    inv = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
+    ang = positions.astype(jnp.float32)[..., None, None] * inv
+    cos, sin = jnp.cos(ang), jnp.sin(ang)
+    x1, x2 = x[..., 0::2], x[..., 1::2]
+    return jnp.stack([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
+                     -1).reshape(x.shape)
+
+
 def _dot(x, w):
     return jnp.dot(x, w, preferred_element_type=jnp.float32)
+
+
+def _heads(x, w, spec):
+    """A per-head product (``w`` [nh, .., ..]) accumulated in float32."""
+    return jnp.einsum(spec, x, w, preferred_element_type=jnp.float32)
+
+
+def _mla_in(cfg: DecoderConfig, p, x, positions):
+    """Rows to the ABSORBED attention's operands: q_abs [B, L, nh,
+    kv_width] and the cached row [B, L, 1, kv_width] (rank + rope
+    columns and the zero padding), in the weight type; no value, no
+    gate."""
+    nh, nope, rd, rank = (cfg.num_attention_heads, cfg.qk_nope_head_dim,
+                          cfg.qk_rope_head_dim, cfg.kv_lora_rank)
+    wt = p["q_a"].dtype
+    eps, lead = cfg.rms_norm_eps, x.shape[:-1]
+    a = rms_norm(x, p["in_norm"], eps).astype(wt)
+    c_q = rms_norm(_dot(a, p["q_a"]), p["q_a_norm"], eps).astype(wt)
+    q = _dot(c_q, p["q_b"]).reshape(lead + (nh, nope + rd))
+    kva = _dot(a, p["kv_a"])
+    c = rms_norm(kva[..., :rank], p["kv_a_norm"], eps)
+    k_r = rope_interleaved(kva[..., None, rank:], positions,
+                           cfg.rope_theta)
+    q_r = rope_interleaved(q[..., nope:], positions, cfg.rope_theta)
+    q_c = _heads(q[..., :nope].astype(wt), p["kv_b_k"], "...hn,hnr->...hr")
+
+    def row(*parts):
+        pad = cfg.kv_width - rank - rd
+        if pad:
+            parts += (jnp.zeros(parts[0].shape[:-1] + (pad,), jnp.float32),)
+        return jnp.concatenate(parts, -1).astype(wt)
+    return row(q_c, q_r), row(c[..., None, :], k_r), None, None
 
 
 def _attn_in(cfg: DecoderConfig, sliding: bool, p, x, positions):
     """Rows to the attention's operands: q [B, L, nh, hd], k, v
     [B, L, nkv, hd] and the output gate [B, L, nh * hd], all in the
-    weight type."""
+    weight type (``mla``: ``_mla_in``'s)."""
+    if cfg.attention == "mla":
+        return _mla_in(cfg, p, x, positions)
     nh, nkv, hd = (cfg.num_attention_heads, cfg.num_key_value_heads,
                    cfg.head_dim)
     wt = p["qkvg"].dtype
@@ -186,19 +314,29 @@ def _attn_in(cfg: DecoderConfig, sliding: bool, p, x, positions):
 
 
 def _attn_out(cfg: DecoderConfig, p, x, attn, gate):
-    """Gate, output projection and the first half of the sandwich:
-    returns (h, m) with ``m = RMSNorm_pre_mlp(h)`` in the weight
-    type."""
+    """Gate (``gqa``) or the value's way out of the latent (``mla``:
+    ``attn`` [B, L, nh, rank] through W_kvb^V per head), the output
+    projection and the residual, with the first half of the sandwich
+    where there is one: returns (h, m) with ``m = RMSNorm_pre_mlp(h)``
+    in the weight type."""
     wt = p["o"].dtype
-    o = attn.reshape(gate.shape).astype(jnp.float32) \
-        * jax.nn.sigmoid(gate.astype(jnp.float32))
-    h = x + rms_norm(_dot(o.astype(wt), p["o"]), p["post_attn_norm"],
-                     cfg.rms_norm_eps)
+    if cfg.attention == "mla":
+        o = _heads(attn, p["kv_b_v"], "...hr,hrv->...hv")
+        o = o.reshape(x.shape[:-1] + (-1,))
+    else:
+        o = attn.reshape(gate.shape).astype(jnp.float32) \
+            * jax.nn.sigmoid(gate.astype(jnp.float32))
+    o = _dot(o.astype(wt), p["o"])
+    if cfg.residual == "sandwich":
+        o = rms_norm(o, p["post_attn_norm"], cfg.rms_norm_eps)
+    h = x + o
     return h, rms_norm(h, p["pre_mlp_norm"], cfg.rms_norm_eps).astype(wt)
 
 
 def _mlp_out(cfg: DecoderConfig, p, h, f):
-    return h + rms_norm(f, p["post_mlp_norm"], cfg.rms_norm_eps)
+    if cfg.residual == "sandwich":
+        f = rms_norm(f, p["post_mlp_norm"], cfg.rms_norm_eps)
+    return h + f
 
 
 def _dense_tail(cfg: DecoderConfig, p, x, attn, gate):
@@ -252,8 +390,11 @@ def decoder_block(cfg: DecoderConfig, layer: int, p: dict, x, positions,
                   route_tap=None):
     """THE block: layer ``layer`` of type ``cfg.layer_types[layer]`` on
     rows ``x`` [B, L, d] float32 at ``positions`` [B, L], attending
-    through ``view`` (a paged view: it appends this call's K/V and
-    masks by its layer's window). ``counters`` (a dict holding ``acc``)
+    through ``view`` (a paged view: it appends this call's K/V, or its
+    latent row, and masks by its layer's window). ``mla`` records a span
+    ``mla`` a call with ``mla.project`` (down-projections, norms, RoPE,
+    absorption), ``mla.attend`` (append and launch) and ``mla.out`` (on a
+    dense layer the SwiGLU rides in its program). ``counters`` (a dict holding ``acc``)
     takes the expert layer's device-side row counts; ``route_tap`` (a
     list) is handed (layer, view, positions, chosen experts) of every
     expert layer call, the arrays on the device. Returns the rows after
@@ -264,16 +405,29 @@ def decoder_block(cfg: DecoderConfig, layer: int, p: dict, x, positions,
             f"layer {layer} is {cfg.layer_types[layer]} but its cache "
             f"view's window is {view.window}: build the cache with "
             f"PagedKVCache.for_model(core, ...)")
-    q, k, v, gate = _jitted(_attn_in, cfg, sliding)(p, x, positions)
-    attn = view.decode(Tensor(q), Tensor(k), Tensor(v), t).data
-    if not cfg.is_moe(layer):
-        return _jitted(_dense_tail, cfg)(p, x, attn, gate)
-    h, m = _jitted(_attn_out, cfg)(p, x, attn, gate)
-    block_m = expert_row_block(m.shape[0] * m.shape[1],
-                               cfg.num_experts_per_tok, cfg.num_experts)
     col = collector
+    mla = col if cfg.attention == "mla" else None   # who records ``mla*``
     depth = col.span_depth if col is not None else 0
     try:
+        if mla is not None:
+            mla.span_begin("mla", layer=layer)
+            mla.span_begin("mla.project")
+        q, k, v, gate = _jitted(_attn_in, cfg, sliding)(p, x, positions)
+        if mla is not None:
+            mla.span_end()
+            mla.span_begin("mla.attend")
+        attn = view.decode(Tensor(q), Tensor(k),
+                           None if v is None else Tensor(v), t).data
+        if mla is not None:
+            mla.span_end()
+            mla.span_begin("mla.out")
+        if not cfg.is_moe(layer):
+            return _jitted(_dense_tail, cfg)(p, x, attn, gate)
+        h, m = _jitted(_attn_out, cfg)(p, x, attn, gate)
+        if mla is not None:
+            mla.span_unwind(depth)
+        block_m = expert_row_block(m.shape[0] * m.shape[1],
+                                   cfg.num_experts_per_tok, cfg.num_experts)
         if col is not None:
             col.span_begin("moe", layer=layer)
             col.span_begin("moe.route")
@@ -310,11 +464,26 @@ def _draw_layer(cfg: DecoderConfig, moe: bool, key) -> dict:
         return 1.0 + 0.1 * jax.random.normal(next(keys), (n,),
                                              jnp.float32)
 
-    p = {"in_norm": gain(d), "q_norm": gain(hd), "k_norm": gain(hd),
-         "post_attn_norm": gain(d), "pre_mlp_norm": gain(d),
-         "post_mlp_norm": gain(d),
-         "qkvg": matrix(d, (2 * nh + 2 * nkv) * hd),
-         "o": matrix(nh * hd, d)}
+    if cfg.attention == "mla":
+        nope, rd, rank, vd = (cfg.qk_nope_head_dim, cfg.qk_rope_head_dim,
+                              cfg.kv_lora_rank, cfg.v_head_dim)
+        # W_kvb is held per head and split: its key half transposed
+        # (absorbed into q), its value half as it is (applied to the
+        # attention's output); both have the latent as fan-in
+        p = {"in_norm": gain(d), "pre_mlp_norm": gain(d),
+             "q_a": matrix(d, cfg.q_lora_rank),
+             "q_a_norm": gain(cfg.q_lora_rank),
+             "q_b": matrix(cfg.q_lora_rank, nh * (nope + rd)),
+             "kv_a": matrix(d, rank + rd), "kv_a_norm": gain(rank),
+             "kv_b_k": jnp.swapaxes(matrix(nh, rank, nope), 1, 2),
+             "kv_b_v": matrix(nh, rank, vd),
+             "o": matrix(nh * vd, d)}
+    else:
+        p = {"in_norm": gain(d), "q_norm": gain(hd), "k_norm": gain(hd),
+             "post_attn_norm": gain(d), "pre_mlp_norm": gain(d),
+             "post_mlp_norm": gain(d),
+             "qkvg": matrix(d, (2 * nh + 2 * nkv) * hd),
+             "o": matrix(nh * hd, d)}
     if not moe:
         p["gate_up"] = matrix(d, 2 * cfg.intermediate_size)
         p["down"] = matrix(cfg.intermediate_size, d)
@@ -347,7 +516,12 @@ class DecoderCore:
         self.embed_dim = cfg.hidden_size
         self.num_heads = cfg.num_attention_heads
         self.num_kv_heads = cfg.num_key_value_heads
-        self.head_dim = cfg.head_dim
+        self.head_dim = cfg.kv_width
+        # what ``PagedKVCache.for_model`` reads: ``mla`` caches one
+        # latent row a position, whose leading columns are the value
+        self.latent_cache = {"v_dim": cfg.kv_lora_rank,
+                             "sm_scale": cfg.attn_scale} \
+            if cfg.attention == "mla" else None
         self.num_layers = cfg.num_layers
         self.layer_windows = tuple(cfg.window_of(i)
                                    for i in range(cfg.num_layers))

@@ -8,7 +8,11 @@ concurrency. Here K/V live in a per-layer POOL of fixed-size blocks
 arxiv 2604.15464); each sequence owns a block table (int32 row of pool
 indices) and grows allocate-on-write, one block at a time. Blocks are
 refcounted so a forked request can share its prefix pages and split
-them copy-on-write at the first divergent append.
+them copy-on-write at the first divergent append. A LATENT pool
+(``PagedKVCache(v_dim=, sm_scale=)``: absorbed multi-head latent
+attention) is [num_blocks, 1, 1, block_size, width]: ONE row a position,
+which is the key and whose leading ``v_dim`` columns are the value;
+everything host-side below is the same for both forms.
 
 The cache layout is a PROTOCOL, not a tensor shape:
 ``FusedMultiTransformer.forward(..., caches=..., time_step=...)``
@@ -351,9 +355,11 @@ def _set_rows(arr, pg_ids, slot, off, k, v):
     # offset off[r]. pg_ids [P] names every real page once; its other
     # slots hold the trash block 0 (pad slots, and the slots that rows
     # routed to trash write), which the write-back may hit in any order.
+    # A LATENT pool [NB, 1, H, bs, D] has one plane: ``v`` is None.
     pages = arr[pg_ids]
     pages = pages.at[slot, 0, :, off].set(k.astype(arr.dtype))
-    pages = pages.at[slot, 1, :, off].set(v.astype(arr.dtype))
+    if v is not None:
+        pages = pages.at[slot, 1, :, off].set(v.astype(arr.dtype))
     return arr.at[pg_ids].set(pages)
 
 
@@ -397,7 +403,8 @@ def _append_rows(block_size, n_tokens, pool, scales, k, v, t, bt,
     return _write_rows(pool, scales, pg_ids, slot.reshape(-1),
                        (pos % block_size).reshape(-1),
                        k.reshape((-1,) + k.shape[2:]),
-                       v.reshape((-1,) + v.shape[2:]))
+                       None if v is None
+                       else v.reshape((-1,) + v.shape[2:]))
 
 
 def _ragged_append(pool, scales, k, v, pg_ids, route):
@@ -407,7 +414,7 @@ def _ragged_append(pool, scales, k, v, pg_ids, route):
     # (prefill chunks through their slots' tables, decode rows through
     # the masked batch table) in ONE page-form write.
     return _write_rows(pool, scales, pg_ids, route[0], route[1], k[0],
-                       v[0])
+                       None if v is None else v[0])
 
 
 def _block_copy(pool, scales, src, dst):
@@ -469,13 +476,16 @@ def _group_heads(k_full, v_full, q_heads: int):
             Tensor(jnp.repeat(v_full.data, g, axis=2)))
 
 
-def _gathered(pool, tables, scales, q_heads: int):
+def _gathered(pool, tables, scales, q_heads: int, v_dim=None):
     """What every fallback attends over: the pages ``tables`` names,
     gathered dense (the kernel module's gather, so both paths share one
-    layout definition; int8 pages dequantize inside it) and grouped for
-    ``q_heads`` query heads. Returns (k, v, positions gathered)."""
+    layout definition; int8 pages dequantize inside it; a latent page's
+    value is its leading ``v_dim`` columns) and grouped for ``q_heads``
+    query heads. Returns (k, v, positions gathered)."""
     gargs = (pool, tables) if scales is None else (pool, tables, scales)
-    k_full, v_full = apply(gather_pages, gargs, op_name="paged_gather")
+    k_full, v_full = apply(gather_pages, gargs,
+                           {"v_dim": v_dim} if v_dim else {},
+                           op_name="paged_gather")
     k_full, v_full = _group_heads(k_full, v_full, q_heads)
     return k_full, v_full, k_full.shape[1]
 
@@ -486,7 +496,17 @@ def _mask(kpos, qpos, window):
                   .astype(jnp.float32))
 
 
-def _attend(q, pool, scales, tables, lens, rows, window, fallback):
+def _masked_sdpa(q, k_full, v_full, mask, sm_scale=None):
+    """The fallbacks' attention: the dense masked sdpa executable, at
+    the cache's own ``sm_scale`` where it has one (a latent pool: the
+    scale is the un-absorbed head's, not the row width's)."""
+    from ..nn.functional.attention import sdpa_reference
+    # what F.scaled_dot_product_attention runs for a masked call
+    return sdpa_reference(q, k_full, v_full, mask, scale=sm_scale)
+
+
+def _attend(q, pool, scales, tables, lens, rows, window, fallback,
+            latent=None):
     """THE seam between the model blocks and the paged cache: the one
     place that decides "Pallas kernel or jnp fallback" for a view's
     attention (``device.use_pallas_kernels()``), and the one call of the
@@ -499,10 +519,11 @@ def _attend(q, pool, scales, tables, lens, rows, window, fallback):
     (every sequence L rows: decode, verify, a prefill chunk) gives the
     int L and ``lens`` are the rows' START positions, the block's
     ``time_step`` as it came: the program adds L itself, so the view
-    pays no dispatch of its own for it."""
+    pays no dispatch of its own for it. ``latent``: a latent pool's
+    ``(v_dim, sm_scale)`` (``PagedKVCache.latent``), else None."""
     if not _device.use_pallas_kernels():
         return fallback()
-    launch, shared = _launch(paged_attention_ragged, rows, window)
+    launch, shared = _launch(paged_attention_ragged, rows, window, latent)
     args = (pool, q, lens, tables)
     # inside someone's trace (a step program) every layer calls ONE
     # jitted function, so the kernel is traced and lowered to Mosaic
@@ -513,9 +534,9 @@ def _attend(q, pool, scales, tables, lens, rows, window, fallback):
 
 
 @functools.lru_cache(maxsize=None)
-def _launch(kernel, rows, window):
+def _launch(kernel, rows, window, latent=None):
     """``_attend``'s call of ``kernel`` for one static ``(rows,
-    window)``: the function, and the same function jitted (without
+    window, latent)``: the function, and the same function jitted (without
     that, a four-layer step program spends 0.3 s a layer of set-up on
     tracing and lowering the same kernel again, before the compile
     cache is even asked). Named ``fwd`` as every op executable is: the
@@ -525,9 +546,11 @@ def _launch(kernel, rows, window):
             q_lens, kv_lens = (rows,) * bta.shape[0], ln + rows
         else:
             q_lens, kv_lens = rows, ln
+        form = {} if latent is None else \
+            {"v_dim": latent[0], "sm_scale": latent[1]}
         out = kernel(q_.reshape((-1,) + q_.shape[2:]), p, bta, q_lens,
-                     kv_lens, kv_scales=sc, window=window)
-        return out.reshape(q_.shape)
+                     kv_lens, kv_scales=sc, window=window, **form)
+        return out.reshape(q_.shape[:-1] + out.shape[-1:])
     return fwd, jax.jit(fwd)
 
 
@@ -681,7 +704,9 @@ class PagedLayerCache:
         return B * _pages_spanned(L, self._cache.block_size), B * L
 
     def decode(self, q, k, v, t):
-        """q/k/v: [B, L, H, D] Tensors (L == 1 is the plain decode
+        """q/k/v: [B, L, H, D] Tensors (a latent pool: ``k`` is the
+        row [B, L, 1, width], ``v`` is None, and the output is
+        [B, L, nh, v_dim]; so in every view) (L == 1 is the plain decode
         step; L > 1 is the multi-query speculative-verification step —
         row b's L tokens land at positions t[b] .. t[b]+L-1 and each
         query attends causally up to its own position). t: traced
@@ -741,7 +766,8 @@ class PagedLayerCache:
             pages=pages, rows=rows)
         return _attend(
             q, new_pool, new_sc, bt, tt, L, self.window,
-            lambda: self._sdpa_over_pages(q, t, new_pool, new_sc, bt))
+            lambda: self._sdpa_over_pages(q, t, new_pool, new_sc, bt),
+            c.latent)
 
     def _sdpa_over_pages(self, q, t, pool, scales, bt):
         """The fallback: gather the pages dense, then mirror the dense
@@ -754,16 +780,16 @@ class PagedLayerCache:
         attention fuses with different reduction grouping than L
         [1, S] attentions (~1 ulp), the same lowering trap as
         scheduler.MIN_PREFILL_SUFFIX_ROWS."""
-        from ..nn import functional as F
-        W = self.window
+        W, c = self.window, self._cache
         B, L = q.shape[0], q.shape[1]
-        k_full, v_full, S = _gathered(pool, bt, scales, q.shape[2])
+        k_full, v_full, S = _gathered(pool, bt, scales, q.shape[2],
+                                      c.v_dim)
         if L == 1:
             qpos = (t[:, None, None, None]
                     + jnp.arange(1)[None, None, :, None])
             kpos = jnp.arange(S)[None, None, None, :]
-            return F.scaled_dot_product_attention(
-                q, k_full, v_full, attn_mask=_mask(kpos, qpos, W))
+            return _masked_sdpa(q, k_full, v_full, _mask(kpos, qpos, W),
+                                c.sm_scale)
 
         qf = apply(lambda a: a.reshape((B * L, 1) + a.shape[2:]),
                    (q,), op_name="spec_fold_q")
@@ -775,8 +801,7 @@ class PagedLayerCache:
                                           B))
         qpos = tf[:, None, None, None]
         kpos = jnp.arange(S)[None, None, None, :]
-        out = F.scaled_dot_product_attention(
-            qf, kf, vf, attn_mask=_mask(kpos, qpos, W))
+        out = _masked_sdpa(qf, kf, vf, _mask(kpos, qpos, W), c.sm_scale)
         return apply(lambda a: a.reshape((B, L) + a.shape[2:]),
                      (out,), op_name="spec_unfold")
 
@@ -879,19 +904,20 @@ class PagedPrefillView:
             pages=_pages_spanned(C, c.block_size) + 1, rows=C)
         return _attend(
             q, new_pool, new_sc, bt, tt, C, self.window,
-            lambda: self._sdpa_over_pages(q, t, new_pool, new_sc, bt))
+            lambda: self._sdpa_over_pages(q, t, new_pool, new_sc, bt),
+            c.latent)
 
     def _sdpa_over_pages(self, q, t, pool, scales, bt):
         """The fallback: gather the slot's pages dense and run the
         chunk as ONE multi-row masked sdpa (see class docstring; the
         mask mirrors the dense prefill branch's construction)."""
-        from ..nn import functional as F
-        k_full, v_full, S = _gathered(pool, bt, scales, q.shape[2])
+        c = self._cache
+        k_full, v_full, S = _gathered(pool, bt, scales, q.shape[2],
+                                      c.v_dim)
         qpos = t[0] + jnp.arange(q.shape[1])[:, None]
         kpos = jnp.arange(S)[None, :]
-        return F.scaled_dot_product_attention(
-            q, k_full, v_full,
-            attn_mask=_mask(kpos, qpos, self.window))
+        return _masked_sdpa(q, k_full, v_full,
+                            _mask(kpos, qpos, self.window), c.sm_scale)
 
 
 class _RaggedLayout:
@@ -1061,14 +1087,13 @@ class _RaggedLayout:
         arithmetic over shapes, for the collector's ``paged_attn``
         gauge."""
         c = self._cache
-        tile_q = resolve_tile_q(self.q_lens)
+        g = c.num_heads // c.num_kv_heads
+        tile_q = resolve_tile_q(self.q_lens, g=g)
         return launch_plan(
             sum(-(-ql // tile_q) for ql in self.q_lens),
-            c.kv_heads_per_shard,
-            tile_q * (c.num_heads // c.num_kv_heads),
-            c.max_blocks_per_seq,
+            c.kv_heads_per_shard, tile_q * g, c.max_blocks_per_seq,
             c.block_size, c.head_dim, c.pools[0].data.dtype.itemsize,
-            quantized=c.quantized)
+            quantized=c.quantized, v_dim=c.v_dim)
 
 
 class PagedRaggedView:
@@ -1165,12 +1190,11 @@ class PagedRaggedView:
         return _attend(
             q, new_pool, new_sc, lay.bt_all, lay.kv_lens, lay.q_lens,
             self.window,
-            lambda: self._sdpa_by_segment(q, new_pool, new_sc))
+            lambda: self._sdpa_by_segment(q, new_pool, new_sc), c.latent)
 
     def _sdpa_by_segment(self, q, pool, scales):
         """The fallback: decompose into the per-phase executables (see
         class docstring) and re-pack the outputs in row order."""
-        from ..nn import functional as F
         c = self._cache
         W = self.window
         outs = []
@@ -1180,16 +1204,17 @@ class PagedRaggedView:
                 slot, start = seg[3], seg[4]
                 qs = Tensor(q.data[:, lo:hi])
                 k_full, v_full, S = _gathered(
-                    pool, c.bt_row_tensor(slot), scales, q.shape[2])
+                    pool, c.bt_row_tensor(slot), scales, q.shape[2],
+                    c.v_dim)
                 qpos = start + jnp.arange(hi - lo)[:, None]
                 kpos = jnp.arange(S)[None, :]
-                out = F.scaled_dot_product_attention(
-                    qs, k_full, v_full, attn_mask=_mask(kpos, qpos, W))
+                out = _masked_sdpa(qs, k_full, v_full,
+                                   _mask(kpos, qpos, W), c.sm_scale)
                 outs.append(out.data[0])
                 continue
             lens, L = seg[3], seg[4]
             k_full, v_full, S = _gathered(pool, c.bt_tensor(), scales,
-                                          q.shape[2])
+                                          q.shape[2], c.v_dim)
             tj = jnp.asarray(lens, jnp.int32)
             kpos = jnp.arange(S)[None, None, None, :]
             if L == 1:
@@ -1209,8 +1234,8 @@ class PagedRaggedView:
                 tf = (jnp.repeat(tj, L)
                       + jnp.tile(jnp.arange(L, dtype=jnp.int32), B))
                 qpos = tf[:, None, None, None]
-            out = F.scaled_dot_product_attention(
-                qd, k_full, v_full, attn_mask=_mask(kpos, qpos, W))
+            out = _masked_sdpa(qd, k_full, v_full, _mask(kpos, qpos, W),
+                               c.sm_scale)
             outs.append(out.data[:, 0])
         return Tensor(jnp.concatenate(outs, axis=0)[None])
 
@@ -1228,7 +1253,8 @@ class PagedKVCache:
                  dtype: str = "float32", prefix_cache: bool = False,
                  mp: int = 1, shard_devices=None,
                  num_kv_heads: Optional[int] = None,
-                 layer_windows=None):
+                 layer_windows=None, v_dim: Optional[int] = None,
+                 sm_scale: Optional[float] = None):
         import paddle_tpu as paddle
         self.num_layers = int(num_layers)
         self.num_heads = int(num_heads)
@@ -1257,6 +1283,35 @@ class PagedKVCache:
                 f"layer_windows has {len(self.layer_windows)} entries "
                 f"for {self.num_layers} layers")
         self.head_dim = int(head_dim)
+        # THE LATENT FORM (``v_dim``; absorbed multi-head latent
+        # attention): a page holds ONE row a position and kv head,
+        # [num_blocks, 1, H, bs, head_dim] with ``head_dim`` the row's
+        # stored width (kv_lora_rank + the shared rope head), which IS
+        # the key; its leading ``v_dim`` columns are the value, so there
+        # is no V plane, and the views take ``decode(q, row, None, t)``
+        # and return ``v_dim`` columns a head. ``sm_scale`` is the
+        # attention's scale (the un-absorbed head's, which no shape here
+        # shows). Pools, appends, the byte model and the snapshot /
+        # slice payloads size by ``planes``; the allocator, tables,
+        # refcounts, the chain-hash index and admission never see it.
+        self.v_dim = None if v_dim is None else int(v_dim)
+        self.sm_scale = None if sm_scale is None else float(sm_scale)
+        self.planes = 2 if self.v_dim is None else 1
+        if self.v_dim is not None:
+            if not 0 < self.v_dim <= self.head_dim or sm_scale is None:
+                raise ValueError(
+                    f"a latent pool takes 0 < v_dim <= the row width "
+                    f"{self.head_dim} and an sm_scale; got v_dim={v_dim}, "
+                    f"sm_scale={sm_scale}")
+            if str(dtype) == "int8":
+                raise ValueError(
+                    "int8 latent pages are not built (ROADMAP M3): one "
+                    "scale a row would cover the normed latent and the "
+                    "rotated rope head alike; use a float kv_dtype")
+            if int(mp) != 1:
+                raise ValueError(
+                    "a latent pool has one kv head and does not split "
+                    "over mp shards (ROADMAP M3): serve it at mp 1")
         self.block_size = int(block_size)
         self.num_blocks = int(num_blocks)
         self.max_seqs = int(max_seqs)
@@ -1340,7 +1395,7 @@ class PagedKVCache:
         Hs = self.kv_heads_per_shard
         self.pools: List[Tensor] = [
             self._place(paddle.zeros(
-                [self.num_blocks, 2, Hs, self.block_size,
+                [self.num_blocks, self.planes, Hs, self.block_size,
                  self.head_dim], dtype=dtype), pi)
             for pi in range(self.num_layers * self.mp)]
         # per-page dequantization scales (int8 pools only):
@@ -1402,7 +1457,8 @@ class PagedKVCache:
                    mp=getattr(model, "mp", 1),
                    shard_devices=getattr(model, "shard_devices", None),
                    num_kv_heads=getattr(model, "num_kv_heads", None),
-                   layer_windows=getattr(model, "layer_windows", None))
+                   layer_windows=getattr(model, "layer_windows", None),
+                   **(getattr(model, "latent_cache", None) or {}))
 
     def _place(self, t: Tensor, pi: int) -> Tensor:
         """Commit a pool/scale entry to its shard's device (mp > 1);
@@ -1426,6 +1482,18 @@ class PagedKVCache:
         """K/V heads each mp shard's pool stores."""
         return self.num_kv_heads // self.mp
 
+    @property
+    def latent(self) -> Optional[Tuple[int, float]]:
+        """``(v_dim, sm_scale)`` of a latent pool, None of a K/V one:
+        what ``_attend`` keys the launch by."""
+        return None if self.v_dim is None else (self.v_dim, self.sm_scale)
+
+    def _latent_geometry(self) -> dict:
+        """The keys a latent pool adds to a snapshot's or a slice's
+        geometry (a K/V pool adds none: its records read as before)."""
+        return {} if self.v_dim is None else \
+            {"v_dim": self.v_dim, "sm_scale": self.sm_scale}
+
     def pool_index(self, layer: int, shard: int = 0) -> int:
         """Index of (layer, shard)'s entry in the flat ``pools`` /
         ``scales`` lists."""
@@ -1446,7 +1514,7 @@ class PagedKVCache:
         that is traced into it (``model_call``; a trace is not a run)."""
         pool = self.pools[pi].data
         sc = self.scales[pi].data if self.quantized else None
-        arrays = tuple(unwrap(a) for a in args)
+        arrays = tuple(None if a is None else unwrap(a) for a in args)
         if _trace_clean():
             pool, sc = _pool_program(fn, *static)(pool, sc, *arrays)
             self._written[0] += pages
@@ -1538,7 +1606,8 @@ class PagedKVCache:
     def kv_bytes_per_token(self) -> int:
         """PER-SHARD HBM bytes one token's K/V occupies across every
         layer (2 x kv heads/mp x (head_dim x payload itemsize + scale
-        bytes) x layers) — the KV-traffic unit of the analytic work
+        bytes) x layers; ONE plane of the stored row width in the
+        latent form) — the KV-traffic unit of the analytic work
         model (inference/accounting.py), per DEVICE: each shard reads
         and writes only its own head slice, so MBU paired against one
         chip's peak bandwidth must price one chip's traffic. int8
@@ -1547,7 +1616,7 @@ class PagedKVCache:
         per_head = self.head_dim * self.pools[0].data.dtype.itemsize
         if self.quantized:
             per_head += self.scales[0].data.dtype.itemsize
-        return int(2 * self.kv_heads_per_shard * per_head
+        return int(self.planes * self.kv_heads_per_shard * per_head
                    * self.num_layers)
 
     # -- tenant accounting --------------------------------------------
@@ -1843,6 +1912,7 @@ class PagedKVCache:
             # PAYLOAD is canonical (full heads) regardless, and
             # restore(mp=...) re-slices for any target width
             "mp": self.mp,
+            **self._latent_geometry(),
         }
         clean = set()
         if base is not None:
@@ -1875,9 +1945,9 @@ class PagedKVCache:
             payload = np.stack([arr[dirty] for arr in arrs],
                                axis=1)                 # [n, L, 2, H, bs, D]
         else:
-            payload = np.zeros((0, self.num_layers, 2, self.num_kv_heads,
-                                self.block_size, self.head_dim),
-                               arrs[0].dtype)
+            payload = np.zeros((0, self.num_layers, self.planes,
+                                self.num_kv_heads, self.block_size,
+                                self.head_dim), arrs[0].dtype)
         scale_payload = None
         if self.quantized:
             # content-addressing over QUANTIZED bytes: the snapshot
@@ -1969,7 +2039,8 @@ class PagedKVCache:
                     dtype=g["dtype"], prefix_cache=g["prefix_cache"],
                     mp=mp_t, shard_devices=shard_devices,
                     num_kv_heads=g.get("num_kv_heads"),
-                    layer_windows=g.get("layer_windows"))
+                    layer_windows=g.get("layer_windows"),
+                    v_dim=g.get("v_dim"), sm_scale=g.get("sm_scale"))
         refcount = {int(b): int(n) for b, n in snap["refcount"].items()}
         cached = [int(b) for b in snap["cached_order"]]
         live = sorted(b for b, n in refcount.items() if n > 0)
@@ -2377,6 +2448,7 @@ class PagedKVCache:
                 "head_dim": self.head_dim,
                 "block_size": self.block_size,
                 "dtype": self.dtype,
+                **self._latent_geometry(),
             },
             "hashes": list(hashes[:len(blocks)]),
             "payload": payload,
@@ -2431,7 +2503,8 @@ class PagedKVCache:
                 "num_heads": self.num_heads,
                 "num_kv_heads": self.num_kv_heads,
                 "head_dim": self.head_dim,
-                "block_size": self.block_size, "dtype": self.dtype}
+                "block_size": self.block_size, "dtype": self.dtype,
+                "v_dim": self.v_dim}
         # slices written before grouped kv heads carry no such key:
         # their pages hold num_heads heads
         g = dict(g, num_kv_heads=g.get("num_kv_heads", g.get("num_heads")))

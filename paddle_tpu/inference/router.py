@@ -123,11 +123,13 @@ def build_model_from_spec(spec: dict):
     from ..incubate.nn import FusedMultiTransformer
     from .speculative import TokenServingModel
 
+    from .decoder import ARCHS
     arch = spec.get("arch", "gpt3")
-    if arch == "afmoe":
+    if arch in ARCHS:
         return _build_decoder_model(spec)
     if arch != "gpt3":
-        raise ValueError(f"unknown arch {arch!r} (gpt3 | afmoe)")
+        raise ValueError(f"unknown arch {arch!r} "
+                         f"(gpt3 | {' | '.join(ARCHS)})")
     paddle.seed(int(spec.get("model_seed", 0)))
     core = FusedMultiTransformer(
         int(spec.get("d_model", 32)), int(spec.get("heads", 4)),
@@ -150,7 +152,7 @@ def build_model_from_spec(spec: dict):
 
 
 def _build_decoder_model(spec: dict):
-    """``arch: "afmoe"``: the config-driven ``DecoderCore``
+    """``arch: "afmoe"`` or ``"joyai_llm_flash"``: the config-driven ``DecoderCore``
     (inference/decoder.py) behind a ``TokenServingModel`` with a final
     RMSNorm, an UNTIED head and the embedding multiplier. Core, head
     and final gains are drawn on the device from ``model_seed`` in the
@@ -164,8 +166,10 @@ def _build_decoder_model(spec: dict):
     from .speculative import TokenServingModel
 
     if int(spec.get("mp", 1)) != 1:
-        raise ValueError("arch 'afmoe' serves on one chip (mp 1): its "
-                         "deployment splits experts, not heads")
+        raise ValueError(
+            f"arch {spec['arch']!r} serves on one chip (mp 1): afmoe's "
+            f"deployment splits experts, not heads, and a latent cache "
+            f"has one kv head")
     cfg = DecoderConfig.from_spec(spec)
     seed = int(spec.get("model_seed", 0))
     core = DecoderCore(cfg, seed=seed)

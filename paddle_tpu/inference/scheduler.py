@@ -1496,6 +1496,12 @@ class PagedServingEngine:
                     (series["pages_in_context"],
                      series["pages_behind_window"]) = \
                         views[0]._layout.window_pages(min(windows))
+                if self.cache.v_dim is not None:
+                    # a latent pool: the rows' contexts as the bytes of
+                    # cache their launches read, over all layers
+                    series["latent_bytes_in_context"] = \
+                        int(views[0]._layout.kv_lens_np.sum()) \
+                        * self.cache.kv_bytes_per_token()
                 col.gauge("paged_attn", series)
         finally:
             if col is not None:

@@ -85,6 +85,17 @@ would alias the GQA group ratio and silently misread — the paged
 views guard this (they know the mesh width; the kernel only rejects
 ratios that are not whole groups).
 
+THE LATENT FORM (``v_dim``; absorbed multi-head latent attention,
+inference/decoder.py ``mla``): the pool is [num_blocks, 1, 1,
+block_size, width], ONE row a position which is the key, and whose
+leading ``v_dim`` columns are the value. Same grid, prefetch, frontier
+and page-skip logic and the same body; a page is one (1, 1, Hb,
+block_s, width) block, DMA'd once; the output and the accumulator are
+``v_dim`` wide; ``sm_scale`` is the caller's (the un-absorbed head's).
+At 32 query heads on the one kv head a decode tile is 32 rows, and
+``launch_plan`` gives a step LATENT_STEP_POSITIONS positions and a tile
+at most MAX_TILE_ROWS rows (PERF.md, PR 32, has the sweep on the chip).
+
 QUANTIZED PAGES (``kv_scales``): an int8 KV pool rides the SAME block
 table with a per-page scale array [num_blocks, 2, nkv, block_size]
 (symmetric per-position-per-head scales — see
@@ -122,6 +133,14 @@ NEG_INF = -1e30
 # prefill wants wide tiles up to this cap so a long chunk never holds
 # every row in VMEM at once.
 DEFAULT_TILE_Q_CAP = 64
+# ... and a tile never carries more than this many ROWS (tile_q times
+# the query heads a kv head serves): every decode row of a mixed launch
+# is padded to a whole tile, and at 32 query heads on the one head of a
+# latent pool a 64-query tile is 2 048 rows of which a decode row fills
+# 32 (on the chip 37.5 ms a mixed launch at 2 048 rows, 22.5 at 1 024,
+# 25.0 at 512: PERF.md, PR 32). No K/V cell reaches it (64 x 6 = 384
+# rows at most).
+MAX_TILE_ROWS = 1024
 
 # what one grid step of the scalar-prefetch launch may hold in VMEM
 # (launch_plan sizes Hb and P against it) and what Mosaic is told it
@@ -131,6 +150,10 @@ DEFAULT_TILE_Q_CAP = 64
 VMEM_BUDGET_BYTES = 24 * 2 ** 20
 VMEM_LIMIT_BYTES = 2 * VMEM_BUDGET_BYTES
 KV_STEP_POSITIONS = 128
+# ... of the latent form (``v_dim``): one head of 576 is a quarter of
+# the bytes a position of the K/V cells brings, so a step takes four
+# times the positions for the same DMA (PERF.md, PR 32, has the sweep)
+LATENT_STEP_POSITIONS = 512
 
 # launch accounting for the dispatch-count acceptance tests: every
 # ``paged_attention_ragged`` entry (the launch, or the delegation to
@@ -171,7 +194,7 @@ class LaunchPlan(NamedTuple):
     heads: int            # Hb: kv heads a grid step carries
     pages: int            # P: pool pages a kv grid step carries
     grid: Tuple[int, int]
-    bytes_per_step: int   # K/V (and scale) bytes a full step DMAs
+    bytes_per_step: int   # page (and scale) bytes a full step DMAs
 
     @property
     def grid_steps(self) -> int:
@@ -191,7 +214,8 @@ def _vmem_bytes(shape, itemsize: int) -> int:
 def launch_plan(T: int, nkv: int, rows: int, MB: int, block_s: int,
                 hd: int, kv_itemsize: int, *, q_itemsize: int = 4,
                 quantized: bool = False,
-                tile_kv: Optional[int] = None) -> LaunchPlan:
+                tile_kv: Optional[int] = None,
+                v_dim: Optional[int] = None) -> LaunchPlan:
     """The scalar-prefetch launch for a shape — pure host arithmetic,
     shared by the kernel wrapper and the telemetry gauge. ``Hb`` is
     the largest divisor of ``nkv`` whose grid step fits
@@ -203,17 +227,25 @@ def launch_plan(T: int, nkv: int, rows: int, MB: int, block_s: int,
     that still fit, up to one KV_STEP_POSITIONS score tile (and never
     more than the table has). A quantized launch keeps ``Hb`` whole
     or a multiple of 8: its (2, Hb, block_s) scale block has the heads
-    on the sublane axis."""
+    on the sublane axis. ``v_dim`` (the latent form): a page is ONE
+    plane whose leading ``v_dim`` columns are the value, the output and
+    the accumulator are ``v_dim`` wide, and a step may carry
+    LATENT_STEP_POSITIONS."""
+    planes, od = (2, hd) if v_dim is None else (1, int(v_dim))
+
     def fits(hb, p):
         n = p * block_s
-        blocks = p * _vmem_bytes((2 * hb, block_s, hd), kv_itemsize) \
-            + 2 * _vmem_bytes((hb, rows, hd), q_itemsize)
+        blocks = p * _vmem_bytes((planes * hb, block_s, hd), kv_itemsize) \
+            + _vmem_bytes((hb, rows, hd), q_itemsize) \
+            + _vmem_bytes((hb, rows, od), q_itemsize)
         if quantized:
             blocks += p * _vmem_bytes((2, hb, block_s), 4)
         scratch = 2 * _vmem_bytes((hb, rows, 1), 4) \
-            + _vmem_bytes((hb, rows, hd), 4)
-        work = 2 * _vmem_bytes((hb, n, hd), 4) \
+            + _vmem_bytes((hb, rows, od), 4)
+        work = planes * _vmem_bytes((hb, n, hd), 4) \
             + 2 * _vmem_bytes((hb, rows, n), 4)
+        if v_dim is not None:     # the body's float32 copy of a wide q
+            work += _vmem_bytes((hb, rows, hd), 4)
         return 2 * blocks + scratch + work <= VMEM_BUDGET_BYTES
 
     cands = [h for h in range(nkv, 0, -1) if nkv % h == 0
@@ -222,11 +254,13 @@ def launch_plan(T: int, nkv: int, rows: int, MB: int, block_s: int,
     if tile_kv is not None:
         p = min(max(1, int(tile_kv)), MB)
     else:
-        cap = max(1, min(MB, KV_STEP_POSITIONS // block_s))
+        positions = KV_STEP_POSITIONS if v_dim is None \
+            else LATENT_STEP_POSITIONS
+        cap = max(1, min(MB, positions // block_s))
         p = next((n for n in range(cap, 1, -1) if fits(hb, n)), 1)
     grid = (T * (nkv // hb), -(-MB // p))
-    step = p * 2 * hb * block_s * (hd * kv_itemsize
-                                   + (4 if quantized else 0))
+    step = p * planes * hb * block_s * (hd * kv_itemsize
+                                        + (4 if quantized else 0))
     return LaunchPlan(hb, p, grid, step)
 
 
@@ -322,9 +356,10 @@ def _ragged_body(pos0, pos_last, k, v, q_ref, o_ref, m_scr, l_scr,
 
 
 def _kernel_ragged_prefetch(bt_ref, tseq_ref, pos_ref, q_ref, *refs,
-                            n_hb, pages, quantized, **kw):
+                            n_hb, pages, quantized, v_dim=None, **kw):
     """The kernel. ``refs``: the pool handed in ``pages`` times
-    (one (1, 2, Hb, block_s, hd) page block each, see the index map),
+    (one (1, 2, Hb, block_s, hd) page block each, see the index map;
+    (1, 1, Hb, block_s, hd) in the latent form, ``v_dim``),
     for int8 pages the scale array as often ((1, 2, Hb, block_s): the
     block already carries the step's heads), then the output block
     and the m / l / acc scratch. bt/tseq feed the index maps only; pos
@@ -334,7 +369,10 @@ def _kernel_ragged_prefetch(bt_ref, tseq_ref, pos_ref, q_ref, *refs,
     t = pl.program_id(0) // n_hb
     kv = [r[0].astype(jnp.float32) for r in pool_refs]
     k = jnp.concatenate([x[0] for x in kv], axis=1)   # [Hb, P*bs, hd]
-    v = jnp.concatenate([x[1] for x in kv], axis=1)
+    if v_dim is None:
+        v = jnp.concatenate([x[1] for x in kv], axis=1)
+    else:       # the latent form: the value is the row's leading columns
+        v = k[..., :v_dim]
     if quantized:
         sc_refs, refs = refs[:pages], refs[pages:]
         # [2, Hb, P*bs] -> the (Hb, 1, P*bs) row vectors of the body
@@ -345,11 +383,13 @@ def _kernel_ragged_prefetch(bt_ref, tseq_ref, pos_ref, q_ref, *refs,
                  o_ref.at[0], *scratch, **kw)
 
 
-def resolve_tile_q(q_lens, tile_q=None) -> int:
-    """Query rows a tile: the caller's, else the longest segment up to
-    DEFAULT_TILE_Q_CAP."""
+def resolve_tile_q(q_lens, tile_q=None, g: int = 1) -> int:
+    """Queries a tile: the caller's, else the longest segment up to
+    DEFAULT_TILE_Q_CAP and to MAX_TILE_ROWS rows at ``g`` query heads a
+    kv head."""
     if tile_q is None:
-        tile_q = min(DEFAULT_TILE_Q_CAP, max(q_lens))
+        tile_q = min(DEFAULT_TILE_Q_CAP, max(1, MAX_TILE_ROWS // g),
+                     max(q_lens))
     return max(1, int(tile_q))
 
 
@@ -385,7 +425,7 @@ def _tile_layout(q_lens, tile_q):
 
 def paged_attention_ragged(q, kv_pool, block_tables, q_lens, kv_lens,
                            sm_scale=None, tile_q=None, tile_kv=None,
-                           kv_scales=None, window=None):
+                           kv_scales=None, window=None, v_dim=None):
     """THE kernel: one launch scores a mixed prefill+decode+verify
     batch. q: [R, nh, hd] — every sequence's query rows packed
     back-to-back (R == sum(q_lens)). q_lens: STATIC per-sequence query
@@ -406,14 +446,31 @@ def paged_attention_ragged(q, kv_pool, block_tables, q_lens, kv_lens,
     position i sees key j iff 0 <= i - j < window; pages wholly behind
     a tile's first query's window are skipped like pages past the
     causal frontier (no copy issued on the chip).
-    Returns [R, nh, hd] in packed order."""
+    ``v_dim`` (static int or None): the LATENT form. The pool holds ONE
+    row a position, [num_blocks, 1, nkv, block_size, hd], which is the
+    key, and its leading ``v_dim`` columns are the value (absorbed
+    multi-head latent attention: nh query heads over one head of
+    kv_lora_rank + rope columns). A page is DMA'd once, the output is
+    [R, nh, v_dim], and ``sm_scale`` must be handed in: the scale is the
+    un-absorbed head's, not ``hd ** -0.5``.
+    Returns [R, nh, hd] in packed order ([R, nh, v_dim] in the latent
+    form)."""
     q_lens = tuple(int(x) for x in q_lens)
     R, nh, hd = q.shape
     if R != sum(q_lens):
         raise ValueError(f"packed q has {R} rows, q_lens sum to "
                          f"{sum(q_lens)}")
+    planes, od = (2, hd) if v_dim is None else (1, int(v_dim))
+    if kv_pool.shape[1] != planes:
+        raise ValueError(
+            f"a pool of {kv_pool.shape[1]} plane(s) a page does not "
+            f"match v_dim={v_dim}: the K/V form has 2, the latent form 1")
+    if v_dim is not None and (sm_scale is None or kv_scales is not None
+                              or not 0 < od <= hd):
+        raise ValueError("the latent form takes sm_scale, a v_dim of at "
+                         "most the row's width, and no int8 scales")
     if R == 0:
-        return q         # nothing to score — no launch, not counted
+        return q[..., :od]   # nothing to score — no launch, not counted
     from ...parallel.mesh import inside_spmd_region
     if not on_tpu() and inside_spmd_region("mp"):
         # callable under shard_map: the launch builds
@@ -427,7 +484,8 @@ def paged_attention_ragged(q, kv_pool, block_tables, q_lens, kv_lens,
         _DISPATCH["count"] += 1
         return paged_attention_ragged_reference(
             q, kv_pool, block_tables, q_lens, kv_lens,
-            sm_scale=sm_scale, kv_scales=kv_scales, window=window)
+            sm_scale=sm_scale, kv_scales=kv_scales, window=window,
+            v_dim=v_dim)
     _DISPATCH["count"] += 1
     nkv, block_s = kv_pool.shape[2], kv_pool.shape[3]
     MB = block_tables.shape[1]
@@ -439,7 +497,7 @@ def paged_attention_ragged(q, kv_pool, block_tables, q_lens, kv_lens,
             f"head_slice(q, shard, mp), one launch per shard)")
     g = nh // nkv
     scale = sm_scale if sm_scale is not None else 1.0 / math.sqrt(hd)
-    tile_q = resolve_tile_q(q_lens, tile_q)
+    tile_q = resolve_tile_q(q_lens, tile_q, g=g)
     tile_seq, tile_off, tile_n, pad_idx, out_idx = \
         _tile_layout(q_lens, tile_q)
     T = tile_seq.shape[0]
@@ -471,7 +529,7 @@ def paged_attention_ragged(q, kv_pool, block_tables, q_lens, kv_lens,
                        kv_pool.dtype.itemsize,
                        q_itemsize=q.dtype.itemsize,
                        quantized=kv_scales is not None,
-                       tile_kv=tile_kv)
+                       tile_kv=tile_kv, v_dim=v_dim)
     Hb, P = plan.heads, plan.pages
     n_hb = nkv // Hb
     kw = dict(block_s=block_s * P, n_blocks=plan.grid[1],
@@ -504,7 +562,7 @@ def paged_attention_ragged(q, kv_pool, block_tables, q_lens, kv_lens,
     # the pages straight out of the pool rows the block table names —
     # the whole paged-attention trick
     in_specs = [pl.BlockSpec((1, Hb, rows, hd), q_map)] + [
-        pl.BlockSpec((1, 2, Hb, block_s, hd), page_map(p, (0, 0)))
+        pl.BlockSpec((1, planes, Hb, block_s, hd), page_map(p, (0, 0)))
         for p in range(P)]
     operands = [bt, tseq, pos, qp] + [kv_pool] * P
     if kv_scales is not None:
@@ -517,25 +575,25 @@ def paged_attention_ragged(q, kv_pool, block_tables, q_lens, kv_lens,
         num_scalar_prefetch=3,   # bt + tile->seq map + pos (SMEM)
         grid=plan.grid,
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, Hb, rows, hd), q_map),
+        out_specs=pl.BlockSpec((1, Hb, rows, od), q_map),
         scratch_shapes=[pltpu.VMEM((Hb, rows, 1), jnp.float32),
                         pltpu.VMEM((Hb, rows, 1), jnp.float32),
-                        pltpu.VMEM((Hb, rows, hd), jnp.float32)],
+                        pltpu.VMEM((Hb, rows, od), jnp.float32)],
     )
     out = pl.pallas_call(
         functools.partial(_kernel_ragged_prefetch, n_hb=n_hb,
                           pages=P, quantized=kv_scales is not None,
-                          **kw),
+                          v_dim=v_dim, **kw),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((T, nkv, rows, hd), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((T, nkv, rows, od), q.dtype),
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=VMEM_LIMIT_BYTES),
         interpret=not on_tpu(),    # off the chip: the same call, interpreted
     )(*operands)
 
     # unfold + unpad back to the packed row order
-    out = jnp.transpose(out.reshape(T, nkv, tile_q, g, hd),
-                        (0, 2, 1, 3, 4)).reshape(T * tile_q, nh, hd)
+    out = jnp.transpose(out.reshape(T, nkv, tile_q, g, od),
+                        (0, 2, 1, 3, 4)).reshape(T * tile_q, nh, od)
     return jnp.take(out, jnp.asarray(out_idx), axis=0)
 
 
@@ -590,7 +648,7 @@ def paged_attention_prefill(q, kv_pool, block_tables, start_pos,
 
 # --- references: ONE ragged reference, per-phase ones delegate --------
 
-def gather_pages(kv_pool, block_tables, kv_scales=None):
+def gather_pages(kv_pool, block_tables, kv_scales=None, v_dim=None):
     """Pure-jnp page gather: materialize the block-table indirection as
     dense K/V. kv_pool: [NB, 2, nkv, bs, hd]; block_tables: int32
     [B, MB]. Returns (k, v) each [B, MB*bs, nkv, hd] — the layout
@@ -598,7 +656,9 @@ def gather_pages(kv_pool, block_tables, kv_scales=None):
     whatever its (trash/stale) pages hold; callers mask by length.
     ``kv_scales`` ([NB, 2, nkv, bs], int8 pools) dequantizes the
     gathered pages to float32 — the ONE place the fallback layout
-    learns quantization, shared by every CPU/jnp serving path."""
+    learns quantization, shared by every CPU/jnp serving path.
+    ``v_dim``: the latent form, kv_pool [NB, 1, nkv, bs, hd]; v is the
+    leading ``v_dim`` columns of k."""
     pages = kv_pool[jnp.asarray(block_tables, jnp.int32)]
     if kv_scales is not None:
         sc = jnp.asarray(kv_scales)[jnp.asarray(block_tables,
@@ -606,15 +666,18 @@ def gather_pages(kv_pool, block_tables, kv_scales=None):
         pages = pages.astype(jnp.float32) * sc[..., None]
     # [B, MB, 2, nkv, bs, hd] -> [B, MB, bs, nkv, hd] per K/V
     k = jnp.moveaxis(pages[:, :, 0], 2, 3)
-    v = jnp.moveaxis(pages[:, :, 1], 2, 3)
     B, MB, bs, nkv, hd = k.shape
-    return (k.reshape(B, MB * bs, nkv, hd),
-            v.reshape(B, MB * bs, nkv, hd))
+    k = k.reshape(B, MB * bs, nkv, hd)
+    if v_dim is not None:
+        return k, k[..., :v_dim]
+    v = jnp.moveaxis(pages[:, :, 1], 2, 3)
+    return k, v.reshape(B, MB * bs, nkv, hd)
 
 
 def paged_attention_ragged_reference(q, kv_pool, block_tables, q_lens,
                                      kv_lens, sm_scale=None,
-                                     kv_scales=None, window=None):
+                                     kv_scales=None, window=None,
+                                     v_dim=None):
     """jnp reference for the ragged kernel — and the ONE place the
     reference semantics live: the per-phase ``*_reference`` functions
     below are thin delegations, so kernel and reference can no longer
@@ -624,12 +687,12 @@ def paged_attention_ragged_reference(q, kv_pool, block_tables, q_lens,
     q_lens = tuple(int(x) for x in q_lens)
     R, nh, hd = q.shape
     if R == 0:
-        return q
+        return q if v_dim is None else q[..., :v_dim]
     nkv = kv_pool.shape[2]
     g = nh // nkv
     scale = sm_scale if sm_scale is not None else 1.0 / math.sqrt(hd)
-    k, v = gather_pages(kv_pool, block_tables,
-                        kv_scales=kv_scales)     # [n_seq, S, nkv, hd]
+    k, v = gather_pages(kv_pool, block_tables, kv_scales=kv_scales,
+                        v_dim=v_dim)             # [n_seq, S, nkv, hd]
     S = k.shape[1]
     k = jnp.repeat(k, g, axis=2)                 # GQA: broadcast kv heads
     v = jnp.repeat(v, g, axis=2)

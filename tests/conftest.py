@@ -155,7 +155,8 @@ def pytest_sessionfinish(session, exitstatus):
 # The metrics of a cell with another architecture read things that run
 # lacks (expert counters, row lengths, a grouped-GEMM launch): around that
 # test, for those metrics, the run is completed with the planted data of
-# tests/benchmark_suite/planted_afmoe.py before the REAL reader reads it.
+# tests/benchmark_suite/planted_afmoe.py (or planted_latent.py: latent
+# widths and row lengths) before the REAL reader reads it.
 
 @pytest.fixture(autouse=True)
 def _planted_run_for_the_reader_test(request, monkeypatch):
@@ -165,13 +166,14 @@ def _planted_run_for_the_reader_test(request, monkeypatch):
             "test_layer_metric_readers" or not isinstance(metric, dict):
         return
     from benchmark import cells
-    from tests.benchmark_suite import planted_afmoe
-    if metric["name"] not in planted_afmoe.PLANTED_VALUES:
+    from tests.benchmark_suite import planted_afmoe, planted_latent
+    source = next((m for m in (planted_afmoe, planted_latent)
+                   if metric["name"] in m.PLANTED_VALUES), None)
+    if source is None:
         return
     real = cells.read_layer_metric
 
     def planted(name, run):
-        return real(name, planted_afmoe.plant(run)
-                    if run.get("trace") else run)
+        return real(name, source.plant(run) if run.get("trace") else run)
     monkeypatch.setattr(cells, "read_layer_metric", planted)
 

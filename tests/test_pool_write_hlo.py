@@ -103,6 +103,67 @@ def test_write_moves_no_whole_pool(one_chip, cell, write):
     assert "input_output_alias" in head and "(0, {}" in head, head[:300]
 
 
+# the latent pool of joyai-flash.long-decode: ONE row a position, no V
+# plane (blocks, table width, prompt chunk, slots): 576 columns stored
+# as 640, whole 128-lane tiles
+LATENT = (25600, 896, 2048, 64)
+LATENT_WIDTH = 640
+
+
+def _latent_programs():
+    nb, mb, chunk, slots = LATENT
+    f32, i32 = jnp.float32, jnp.int32
+
+    def row(b, n):                     # the row, and no value
+        return [((b, n, 1, LATENT_WIDTH), f32), None]
+
+    def ragged(rows, q_lens):
+        pages = 1 + sum(pc._pages_spanned(q, BS) for q in q_lens)
+        return (pc._ragged_append, (),
+                row(1, rows) + [((pages,), i32), ((2, rows), i32)])
+
+    return {
+        "decode": (pc._append_rows, (BS, 1),
+                   row(slots, 1) + [((slots,), i32), ((slots, mb), i32)]),
+        "chunk": (pc._append_rows, (BS, chunk),
+                  row(1, chunk) + [((1,), i32), ((1, mb), i32),
+                                   ((1,), i32)]),
+        "ragged_decode": ragged(slots, (1,) * slots),
+        "ragged_mixed": ragged(chunk + slots, (chunk,) + (1,) * slots),
+        "ragged_two_chunks": ragged(chunk + slots, (chunk // 2,) * 2
+                                    + (1,) * slots),
+        "block_copy": (pc._block_copy, (), [((1,), i32)] * 2),
+    }
+
+
+@pytest.mark.parametrize("write", ["decode", "chunk", "ragged_decode",
+                                   "ragged_mixed", "ragged_two_chunks",
+                                   "block_copy"])
+def test_latent_write_moves_no_whole_pool(one_chip, write):
+    """The latent page form (one plane, one head of 576): the same
+    page-granular write on the donated pool, no move of the pool's
+    size."""
+    pool = (LATENT[0], 1, 1, BS, LATENT_WIDTH)
+    fn, static, rest = _latent_programs()[write]
+    hlo = _compile(pc._pool_program(fn, *static),
+                   [(pool, jnp.bfloat16), None] + rest, one_chip)
+    assert not _pool_sized_moves(hlo, pool), _pool_sized_moves(hlo, pool)
+    head = hlo.splitlines()[0]
+    assert "input_output_alias" in head and "(0, {}" in head, head[:300]
+
+
+def test_a_latent_row_of_576_columns_would_copy_the_pool(one_chip):
+    """Why the row is stored 640 wide: at 576 (4.5 lane tiles) the
+    compiler lays the pool out with the block axis minor and the same
+    page write copies it there and back."""
+    pool = (LATENT[0], 1, 1, BS, 576)
+    fn, static, rest = _latent_programs()["ragged_decode"]
+    rest = [((1, LATENT[3], 1, 576), jnp.float32)] + rest[1:]
+    hlo = _compile(pc._pool_program(fn, *static),
+                   [(pool, jnp.bfloat16), None] + rest, one_chip)
+    assert len(_pool_sized_moves(hlo, pool)) == 2
+
+
 def test_int8_pool_and_scales_alias(one_chip):
     """The int8 twin: payload and scale pages written page-granular,
     both aliased; no move of the int8 pool's size."""

@@ -66,7 +66,8 @@ _ROUND = ("round", "spec_round", "draft_roll", "embed", "verify", "step",
           "grow", "sample_verify", "device_wait", "journal", "snapshot")
 _SUBMIT = ("submit", "submit.journal", "submit.embed", "submit.hash",
            "submit.admit")
-_MODEL = ("moe", "moe.route", "moe.experts")     # inside the model phase
+_MODEL = ("mla", "mla.project", "mla.attend", "mla.out",
+          "moe", "moe.route", "moe.experts")     # inside the model phase
 
 
 def _fmt_s(us: float) -> str:
