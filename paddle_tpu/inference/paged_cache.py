@@ -104,6 +104,7 @@ from ..framework import layer_jit as _layer_jit
 from ..framework.op import _trace_clean, apply, unwrap
 from ..framework.tensor import Tensor
 from ..ops.pallas.paged_attention import (gather_pages, launch_plan,
+                                          live_steps,
                                           paged_attention_ragged,
                                           resolve_tile_q)
 
@@ -1094,6 +1095,15 @@ class _RaggedLayout:
             c.kv_heads_per_shard, tile_q * g, c.max_blocks_per_seq,
             c.block_size, c.head_dim, c.pools[0].data.dtype.itemsize,
             quantized=c.quantized, v_dim=c.v_dim)
+
+    def live_steps(self, plan) -> int:
+        """The grid steps ``plan``'s launch walks on a layer WITHOUT a
+        window (the kernel module's count of its own work list, on the
+        layout's host lengths; a sliding layer walks fewer,
+        ``window_pages``). For the ``paged_attn`` gauge."""
+        c = self._cache
+        return live_steps(plan, self.q_lens, self.kv_lens_np,
+                          c.block_size, g=c.num_heads // c.num_kv_heads)
 
 
 class PagedRaggedView:

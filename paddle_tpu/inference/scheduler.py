@@ -1483,9 +1483,12 @@ class PagedServingEngine:
         try:
             views = self.cache.ragged_views(desc)
             if col is not None:
-                # what each layer's launch will cost the kernel's grid
-                plan = views[0]._layout.launch_plan()
+                # what each layer's launch will cost the kernel's grid:
+                # the plan's bound, and the steps it walks (live ones)
+                layout = views[0]._layout
+                plan = layout.launch_plan()
                 series = {"grid_steps": plan.grid_steps,
+                          "live_steps": layout.live_steps(plan),
                           "pages_per_step": plan.pages,
                           "heads_per_step": plan.heads}
                 windows = [w for w in self.cache.layer_windows if w]

@@ -29,38 +29,55 @@ instead of one per phase per slot (inference/paged_cache.py
 ``ragged_views`` builds the batch; inference/scheduler.py launches it).
 
 Grid layout: each sequence's queries are cut into tiles of ``tile_q``
-rows, and a page whose first position lies past a tile's LAST query is
-skipped outright (the causal frontier — prefill work is O(tokens
-written), not O(page capacity); a decode tile skips everything past
-its one position). The block table, the tile->sequence map
-and the per-tile base positions ride as SCALAR-PREFETCH arguments
-(pltpu.PrefetchScalarGridSpec) and the grid is
-(total_tiles * nkv_heads / Hb, ceil(MB / P)): one grid step carries
-``Hb`` kv heads of ``P`` pages. The q and output blocks are
-(1, Hb, rows, hd), the m / l / acc scratch (Hb, rows, ..), and both
-products are batched over the head axis. A sequence's pages are not
-contiguous in the pool, so the pool is handed to the call ``P`` times;
-operand p's BlockSpec (1, 2, Hb, block_s, hd) index_map reads
-``bt[tile_seq[t], j * P + p]``, so each page is DMA'd HBM->VMEM
-directly from its pool row, every head at once — the gathered
-[B, S, H, D] view never materializes — and the body joins the ``P``
-pages into one (Hb, P * block_s, hd) kv tile. Past a tile's frontier
-each operand's index is held at its last real page (the per-tile last
-position is prefetched), so a skipped step re-names the block the
-pipeline already holds and no copy is issued for it. ``Hb`` and ``P``
-come from ``launch_plan``: the largest divisor of nkv, then the most
-pages up to one 128-position score tile, whose double-buffered blocks,
-scratch and float32 working set fit VMEM_BUDGET_BYTES. At the serving
-cells' shapes (32 kv heads of 128, bf16 pages of 16, 128 table
-entries) that is Hb 32 and P 8: 2 MB a step and 512 grid steps a
-decode launch, where one head of one page a step was 8 KB and 131 072
-(PERF.md, PR 27, has the sweep over P and Hb on the chip). There is
-ONE ``pallas_call``: off the chip the same call runs with
-``interpret=True`` (jax 0.9.0 interprets scalar-prefetch index maps),
-so a kernel test on the CPU runs the chip's grid, index maps and body.
-The model-level CPU fallback in inference/paged_cache.py uses a
-pure-jnp gather instead so tier-1 serving tests exercise the full
-protocol without Mosaic.
+rows, and a kv step (``P`` pages) whose first position lies past a
+tile's LAST real query is never visited (the causal frontier — prefill
+work is O(tokens written), not O(page capacity); a decode tile stops
+at its one position), nor one wholly behind a sliding layer's window.
+The grid has ONE axis, and it walks a WORK LIST (``_work_list``, built
+by XLA from the traced lengths in the same program): an item a live
+(tile x head group, kv step) pair, tile by tile; the grid's size is
+the number of items, a traced scalar. The list rides as
+SCALAR-PREFETCH arguments (pltpu.PrefetchScalarGridSpec) beside the
+block table: the items, each tile's (first, last real) query positions,
+(first, last) live steps and sequence, and the last table entry each
+page operand of a tile may name — an index map is five SMEM reads, a
+multiply-add and a minimum, and no division. (PR 33, on the chip: a
+grid step costs the scalar core an index map and a block comparison
+for every page operand, whether the step is live or not; over the
+(tiles, ceil(MB / P)) grid this launch had before, with the
+block-table arithmetic inside the index maps, that toll was nine
+tenths of a decode launch and the two products a twentieth. PERF.md,
+PR 33.) One grid step carries ``Hb`` kv heads of ``P`` pages. The q
+and output blocks are (1, Hb, rows, hd), the m / l / acc scratch
+(Hb, rows, ..), and both products are batched over the head axis. A
+sequence's pages are not contiguous in the pool, so the pool is
+handed to the call ``P`` times; operand p's BlockSpec
+(1, 2, Hb, block_s, hd) index_map names the item's page p, so each
+page is DMA'd HBM->VMEM directly from its pool row, every head at once
+— the gathered [B, S, H, D] view never materializes — and the body
+joins the ``P`` pages into one (Hb, P * block_s, hd) kv tile. Where a
+tile's last step runs past its frontier each operand is held at its
+last real page, so it re-names the block the pipeline already holds
+and no copy is issued for it. WHAT THE PRODUCTS TAKE: float32
+copies of q and of the step's pages, made inside the live branch;
+scores, mask and the softmax state are float32. Feeding both products
+the pages as a 16-bit pool holds them (PR 33 built it) gives the same
+bits on the chip (Mosaic feeds the MXU a float32 operand in one bf16
+pass) at the same speed, on the old grid and on this one: the copies
+are not what a step costs, so there is one form (PERF.md, PR 33).
+``Hb`` and ``P`` come from ``launch_plan``: the largest divisor of
+nkv, then the most pages, up to the positions STEP_PAGE_BYTES buys,
+whose double-buffered blocks, scratch and working set fit
+VMEM_BUDGET_BYTES. At chat's shapes (32 kv heads of 128, bf16 pages of
+16, 128 table entries) that is Hb 32 and P 8, 2 MB a step (PERF.md,
+PR 27, has the sweep over P and Hb on the chip); at trinity's 8 kv
+heads P 32 (24 at the mixed launch's 384 rows). There
+is ONE ``pallas_call``: off the chip the same call runs with
+``interpret=True`` (jax 0.9.0 interprets scalar-prefetch index maps
+and a traced grid size), so a kernel test on the CPU runs the chip's
+grid, index maps and body. The model-level CPU fallback in
+inference/paged_cache.py uses a pure-jnp gather instead so tier-1
+serving tests exercise the full protocol without Mosaic.
 
 Tile sizes: ``tile_q`` is the query rows per grid step — more rows
 amortize each page DMA across queries but pad decode segments;
@@ -93,8 +110,9 @@ and page-skip logic and the same body; a page is one (1, 1, Hb,
 block_s, width) block, DMA'd once; the output and the accumulator are
 ``v_dim`` wide; ``sm_scale`` is the caller's (the un-absorbed head's).
 At 32 query heads on the one kv head a decode tile is 32 rows, and
-``launch_plan`` gives a step LATENT_STEP_POSITIONS positions and a tile
-at most MAX_TILE_ROWS rows (PERF.md, PR 32, has the sweep on the chip).
+``launch_plan`` gives a step 1 024 positions (STEP_PAGE_BYTES over a
+row of 1 280 bytes) and a tile at most MAX_TILE_ROWS rows (PERF.md,
+PR 33, has the sweep on the chip).
 
 QUANTIZED PAGES (``kv_scales``): an int8 KV pool rides the SAME block
 table with a per-page scale array [num_blocks, 2, nkv, block_size]
@@ -103,7 +121,7 @@ inference/paged_cache.py for why scales are per row, not one scalar
 per block: row granularity is what keeps the quantized payload a pure
 function of the token stream, so prefix adoption stays exact). Each
 scale page is DMA'd next to its int8 page
-through the same ``bt[tile_seq[t], j * P + p]`` lookup as a
+through the same work-list lookup as a
 (1, 2, Hb, block_s) block — the step's heads on the sublane axis,
 which is why a quantized launch keeps ``Hb`` whole or a multiple of 8
 — and the kernel dequantizes in-register, folding the scales into its
@@ -137,23 +155,26 @@ DEFAULT_TILE_Q_CAP = 64
 # the query heads a kv head serves): every decode row of a mixed launch
 # is padded to a whole tile, and at 32 query heads on the one head of a
 # latent pool a 64-query tile is 2 048 rows of which a decode row fills
-# 32 (on the chip 37.5 ms a mixed launch at 2 048 rows, 22.5 at 1 024,
-# 25.0 at 512: PERF.md, PR 32). No K/V cell reaches it (64 x 6 = 384
-# rows at most).
+# 32 (on the chip, a mixed launch of long-decode at 512 / 1 024 / 2 048
+# rows: 14.8 / 14.7 / 18.0 ms, PERF.md, PR 33; 25.0 / 22.5 / 37.5 under
+# PR 32's grid). No K/V cell reaches it (64 x 6 = 384 rows at most).
 MAX_TILE_ROWS = 1024
 
 # what one grid step of the scalar-prefetch launch may hold in VMEM
 # (launch_plan sizes Hb and P against it) and what Mosaic is told it
 # may use, which leaves the compiler room for its own temporaries; a
-# v5e core has 128 MiB. KV_STEP_POSITIONS caps the kv positions of a
-# step at one 128-lane score tile.
+# v5e core has 128 MiB.
 VMEM_BUDGET_BYTES = 24 * 2 ** 20
 VMEM_LIMIT_BYTES = 2 * VMEM_BUDGET_BYTES
-KV_STEP_POSITIONS = 128
-# ... of the latent form (``v_dim``): one head of 576 is a quarter of
-# the bytes a position of the K/V cells brings, so a step takes four
-# times the positions for the same DMA (PERF.md, PR 32, has the sweep)
-LATENT_STEP_POSITIONS = 512
+# ... and the page bytes a step is given: the kv positions of a step
+# are the power of two that brings at most this much (never under one
+# 128-lane score tile). ONE figure for every page form, because what
+# a step costs beside its products is a fixed toll a page operand
+# (PERF.md, PR 33): 128 positions at chat's 32 heads of 128 (16 KiB a
+# position), 512 at trinity's 8 (4 KiB), 1 024 over a latent row of
+# 640 (1.25 KiB), each the best of its sweep on the chip (PR 27: 128;
+# PR 33: 64 / 128 / 256 / 512 and 128 ... 2 048).
+STEP_PAGE_BYTES = 2 * 2 ** 20
 
 # launch accounting for the dispatch-count acceptance tests: every
 # ``paged_attention_ragged`` entry (the launch, or the delegation to
@@ -222,15 +243,17 @@ def launch_plan(T: int, nkv: int, rows: int, MB: int, block_s: int,
     VMEM_BUDGET_BYTES at one page a step: the pool (and scale) blocks
     and the q / out blocks, all double-buffered by the pipeline, the
     m / l / acc scratch, and the float32 working set the body makes of
-    them (the K and V tiles, the score and probability tiles). ``P``
-    is ``tile_kv`` where the caller passes one, else the most pages
-    that still fit, up to one KV_STEP_POSITIONS score tile (and never
-    more than the table has). A quantized launch keeps ``Hb`` whole
-    or a multiple of 8: its (2, Hb, block_s) scale block has the heads
-    on the sublane axis. ``v_dim`` (the latent form): a page is ONE
-    plane whose leading ``v_dim`` columns are the value, the output and
-    the accumulator are ``v_dim`` wide, and a step may carry
-    LATENT_STEP_POSITIONS."""
+    them: the score and probability tiles, the K and V tiles and, in
+    the latent form, q. ``P`` is ``tile_kv`` where the caller
+    passes one, else the most pages that still fit, up to the
+    positions STEP_PAGE_BYTES buys (never more than the table has),
+    in whole 128-lane score tiles. A quantized launch keeps ``Hb``
+    whole or a multiple of 8: its (2, Hb, block_s) scale block has the
+    heads on the sublane axis. ``v_dim`` (the latent form): a page is
+    ONE plane whose leading ``v_dim`` columns are the value, the
+    output and the accumulator are ``v_dim`` wide. ``grid`` is the
+    launch's BOUND, (tiles x head groups, kv steps a tile): the launch
+    itself walks one grid step a LIVE pair (``_work_list``)."""
     planes, od = (2, hd) if v_dim is None else (1, int(v_dim))
 
     def fits(hb, p):
@@ -248,20 +271,23 @@ def launch_plan(T: int, nkv: int, rows: int, MB: int, block_s: int,
             work += _vmem_bytes((hb, rows, hd), 4)
         return 2 * blocks + scratch + work <= VMEM_BUDGET_BYTES
 
+    def position_bytes(hb):     # page (and scale) bytes a kv position
+        return planes * hb * (hd * kv_itemsize + (4 if quantized else 0))
+
     cands = [h for h in range(nkv, 0, -1) if nkv % h == 0
              and (not quantized or h == nkv or h % 8 == 0)]
     hb = next((h for h in cands if fits(h, 1)), cands[-1])
     if tile_kv is not None:
         p = min(max(1, int(tile_kv)), MB)
     else:
-        positions = KV_STEP_POSITIONS if v_dim is None \
-            else LATENT_STEP_POSITIONS
+        bought = max(1, STEP_PAGE_BYTES // position_bytes(hb))
+        positions = max(128, 1 << bought.bit_length() - 1)
         cap = max(1, min(MB, positions // block_s))
-        p = next((n for n in range(cap, 1, -1) if fits(hb, n)), 1)
+        tile = max(1, 128 // block_s)       # pages a 128-lane score tile
+        p = next((n for n in range(cap, 1, -1) if fits(hb, n)
+                  and (n == cap or n < tile or n % tile == 0)), 1)
     grid = (T * (nkv // hb), -(-MB // p))
-    step = p * planes * hb * block_s * (hd * kv_itemsize
-                                        + (4 if quantized else 0))
-    return LaunchPlan(hb, p, grid, step)
+    return LaunchPlan(hb, p, grid, p * block_s * position_bytes(hb))
 
 
 def _heads_dot(a, b, b_axis: int):
@@ -275,56 +301,48 @@ def _heads_dot(a, b, b_axis: int):
         preferred_element_type=jnp.float32)
 
 
-def _ragged_body(pos0, pos_last, k, v, q_ref, o_ref, m_scr, l_scr,
-                 acc_scr, *, block_s, n_blocks, sm_scale, tile_q, g,
-                 window=None, k_scale=None, v_scale=None):
-    """Online-softmax update for one (tile x head-group, kv-step) grid
-    step — THE paged-attention body, shared by every phase. ``pos0``
-    is this tile's first query's absolute position and ``pos_last``
-    its LAST REAL query's (both read out of SMEM/prefetch by the
-    wrapper; a partial tail tile's pos_last excludes the padding rows,
-    so a decode row padded into a wide mixed-batch tile still skips
-    everything past its single position). ``q_ref`` / ``o_ref`` view
-    the step's (Hb, rows, hd) of their blocks: row r of head h is
-    query r // g of the tile, at position pos0 + r // g, masked
-    causally per row. k/v hold this step's kv tile as
-    (Hb, block_s, hd) float32 — ``P`` pool pages of ``Hb`` heads —
-    and both products are batched over the head axis. A kv step whose
-    first position
-    lies past pos_last is fully masked for every real row and skipped
-    outright (the causal frontier: decode pages above a prefill chunk
-    don't exist yet — this is both the old prefill kernel's page skip
-    and the old decode kernel's length skip, unified; padding rows
-    lose those pages too, but their outputs are dropped on unpack).
-    ``k_scale`` / ``v_scale`` (int8 pages): the tile's per-position
-    dequantization scales as (Hb, 1, block_s) ROW vectors.
+def _ragged_body(j, first, last, pos0, pos_last, tiles, q_ref, o_ref,
+                 m_scr, l_scr, acc_scr, *, block_s, sm_scale, g,
+                 window=None, scales=None):
+    """Online-softmax update for one grid step, kv step ``j`` of one
+    (tile x head-group) — THE paged-attention body, shared by every
+    phase. ``first`` / ``last`` are that tile's first and last LIVE kv
+    steps (the work list visits no other: see ``_work_list``);
+    ``pos0`` is the tile's first query's absolute position and
+    ``pos_last`` its LAST REAL query's (all four read out of
+    SMEM/prefetch by the kernel; a partial tail tile's pos_last
+    excludes the padding rows, so a decode row padded into a wide
+    mixed-batch tile still stops at its single position). ``q_ref`` /
+    ``o_ref`` view the step's (Hb, rows, hd) of their blocks: row r of
+    head h is query r // g of the tile, at position pos0 + r // g,
+    masked causally per row. ``tiles()`` gives this step's kv tile, k
+    and v each (Hb, block_s, hd) float32 — ``P`` pool pages of ``Hb``
+    heads; both products are batched over the head axis. q and the
+    pages are read inside the live branch: the one step that is not
+    live is a tile's only one where no query of it has a key (a
+    length-0 row), and it reads neither and emits zeros.
+    ``scales()`` (int8 pages): the tile's per-position dequantization
+    scales, k's and v's, as (Hb, 1, block_s) ROW vectors.
     Dequantization folds into the two products — q.(s_k k)^T =
     (q.k^T) s_k and p.(s_v v) = (p s_v).v — so the scales multiply the
     [Hb, rows, block_s] score/probability tiles along their lane axis
     and never have to be turned into a column.
     ``window`` (a sliding layer): key kpos is visible to query qpos
-    iff 0 <= qpos - kpos < window, so beside the causal frontier
-    there is a LOWER one: a kv step whose last position lies behind
-    the tile's first query's window (pos0 - window + 1) is behind
-    every row's and is skipped the same way; the mask inside the
-    boundary steps is per row."""
-    j = pl.program_id(1)
-
-    @pl.when(j == 0)
+    iff 0 <= qpos - kpos < window; the mask inside the boundary steps
+    is per row (the steps wholly behind the tile's first query's
+    window are not on the work list)."""
+    @pl.when(j == first)
     def _init():
         m_scr[...] = jnp.full_like(m_scr, NEG_INF)
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    q = q_ref[...].astype(jnp.float32)          # [Hb, tile_q * g, hd]
-
-    live = j * block_s <= pos_last
-    if window is not None:
-        live = live & ((j + 1) * block_s > pos0 - window + 1)
-
-    @pl.when(live)
+    @pl.when(j * block_s <= pos_last)
     def _update():
+        q = q_ref[...].astype(jnp.float32)      # [Hb, tile_q * g, hd]
+        k, v = tiles()
         scores = _heads_dot(q, k, 1) * sm_scale
+        k_scale, v_scale = (None, None) if scales is None else scales()
         if k_scale is not None:
             scores = scores * k_scale
         kpos = j * block_s + jax.lax.broadcasted_iota(
@@ -347,7 +365,7 @@ def _ragged_body(pos0, pos_last, k, v, q_ref, o_ref, m_scr, l_scr,
             p if v_scale is None else p * v_scale, v, 0)
         m_scr[...] = m_new
 
-    @pl.when(j == n_blocks - 1)
+    @pl.when(j == last)
     def _done():
         l = l_scr[...]
         # rows with no valid key (length-0 sequences) emit zeros
@@ -355,32 +373,111 @@ def _ragged_body(pos0, pos_last, k, v, q_ref, o_ref, m_scr, l_scr,
                       jnp.where(l == 0.0, 1.0, l)).astype(o_ref.dtype)
 
 
-def _kernel_ragged_prefetch(bt_ref, tseq_ref, pos_ref, q_ref, *refs,
-                            n_hb, pages, quantized, v_dim=None, **kw):
-    """The kernel. ``refs``: the pool handed in ``pages`` times
-    (one (1, 2, Hb, block_s, hd) page block each, see the index map;
-    (1, 1, Hb, block_s, hd) in the latent form, ``v_dim``),
-    for int8 pages the scale array as often ((1, 2, Hb, block_s): the
-    block already carries the step's heads), then the output block
-    and the m / l / acc scratch. bt/tseq feed the index maps only; pos
-    is a prefetched [T, 2] (first, last) query-position table."""
-    del bt_ref, tseq_ref
+def _kernel_ragged_prefetch(item_ref, tile_ref, held_ref, bt_ref, q_ref,
+                            *refs, n_hb, pages, quantized, v_dim=None, **kw):
+    """The kernel. Grid step ``n`` is work item ``n`` (``_work_list``):
+    ``item_ref[n]`` = (tile x head-group, kv step), ``tile_ref[t]`` =
+    that tile's (first, last real) query positions and (first, last)
+    live kv steps; ``held_ref`` and ``bt_ref`` feed the index maps
+    only. ``refs``:
+    the pool handed in ``pages`` times (one (1, 2, Hb, block_s, hd)
+    page block each, see the index map; (1, 1, Hb, block_s, hd) in the
+    latent form, ``v_dim``), for int8 pages the scale array as often
+    ((1, 2, Hb, block_s): the block already carries the step's heads),
+    then the output block and the m / l / acc scratch."""
+    del held_ref, bt_ref
     pool_refs, refs = refs[:pages], refs[pages:]
-    t = pl.program_id(0) // n_hb
-    kv = [r[0].astype(jnp.float32) for r in pool_refs]
-    k = jnp.concatenate([x[0] for x in kv], axis=1)   # [Hb, P*bs, hd]
-    if v_dim is None:
-        v = jnp.concatenate([x[1] for x in kv], axis=1)
-    else:       # the latent form: the value is the row's leading columns
-        v = k[..., :v_dim]
+    n = pl.program_id(0)
+    t, j = item_ref[0, n] // n_hb, item_ref[1, n]
+
+    def join(plane):      # the step's P pages of one plane: [Hb, P*bs, hd]
+        return jnp.concatenate([r[0, plane].astype(jnp.float32)
+                                for r in pool_refs], axis=1)
+
+    def tiles():
+        k = join(0)
+        # the latent form: the value is the row's leading columns
+        return k, (join(1) if v_dim is None else k[..., :v_dim])
+
+    scales = None
     if quantized:
         sc_refs, refs = refs[:pages], refs[pages:]
-        # [2, Hb, P*bs] -> the (Hb, 1, P*bs) row vectors of the body
-        sc = jnp.concatenate([r[0] for r in sc_refs], axis=-1)
-        kw.update(k_scale=sc[0][:, None, :], v_scale=sc[1][:, None, :])
+
+        def scales():
+            # [2, Hb, P*bs] -> the (Hb, 1, P*bs) row vectors of the body
+            sc = jnp.concatenate([r[0] for r in sc_refs], axis=-1)
+            return sc[0][:, None, :], sc[1][:, None, :]
     o_ref, *scratch = refs
-    _ragged_body(pos_ref[t, 0], pos_ref[t, 1], k, v, q_ref.at[0],
-                 o_ref.at[0], *scratch, **kw)
+    _ragged_body(j, tile_ref[2, t], tile_ref[3, t], tile_ref[0, t],
+                 tile_ref[1, t], tiles, q_ref.at[0], o_ref.at[0],
+                 *scratch, scales=scales, **kw)
+
+
+def _live_range(xp, pos0, pos_last, span, window):
+    """Each tile's (first, last) LIVE kv step of ``span`` positions:
+    from the step that holds the first key inside the tile's first
+    query's window (0 without one) to the step of its last real
+    query's position — THE count of what the launch walks, in plain
+    integer arithmetic on ``xp`` (jnp over the traced lengths in
+    ``_work_list``; numpy on the host in ``live_steps``)."""
+    last = xp.maximum(pos_last, 0) // span
+    if window is None:
+        return xp.zeros_like(last), last
+    return xp.minimum(xp.maximum(pos0 - window + 1, 0) // span, last), last
+
+
+def live_steps(plan: LaunchPlan, q_lens, kv_lens, block_s: int,
+               g: int = 1, window=None) -> int:
+    """The grid steps ``plan``'s launch walks for a packed batch (host
+    lengths): the size of ``_work_list``'s list, counted by the same
+    ``_live_range`` over the same tiles. For the telemetry gauge;
+    ``plan.grid_steps`` is the bound."""
+    tile_seq, tile_off, tile_n, _, _ = _tile_layout(
+        q_lens, resolve_tile_q(q_lens, g=g))
+    pos0 = (np.asarray(kv_lens, np.int64)
+            - np.asarray(q_lens, np.int64))[tile_seq] + tile_off
+    first, last = _live_range(np, pos0, pos0 + tile_n - 1,
+                              plan.pages * block_s, window)
+    return int((last - first + 1).sum()) * (plan.grid[0] // len(tile_seq))
+
+
+def _work_list(pos0, pos_last, tseq, n_hb, steps, P, block_s, window):
+    """What the launch's ONE grid axis walks: an item a LIVE (tile x
+    head-group, kv step) pair, tile by tile, a tile's steps in order —
+    so a grid step is never spent on a kv step past a tile's causal
+    frontier or wholly behind its window (each costs the scalar core
+    an index map and a block comparison a page operand, live or not:
+    PERF.md, PR 33), and the grid's size is the number of live steps,
+    handed to the call as a traced scalar. All of it is computed here,
+    by XLA, from the traced lengths, elementwise over at most
+    N = T * n_hb * steps items (no gather: the block table is read by
+    the index maps, out of SMEM). ``pos0`` / ``pos_last`` [T]: each
+    tile's first and last real query positions; ``tseq`` [T]: its
+    sequence. Returns (count, items [2, N], tiles [5, T], held [T * P]):
+    item n is (tile x head-group, kv step); a tile's (pos0, pos_last,
+    first live step, last live step, sequence); and ``held[t * P + p]``,
+    the LAST table entry page operand p of tile t may name: operand p
+    of kv step j reads entry ``min(j * P + p, held)``, so where the
+    step's pages run past the tile's frontier it re-names the block
+    the pipeline already holds and no copy is issued for it (entry p
+    where even its first page lies past it). A tile none of whose
+    queries has a key (a length-0 row) keeps one item, which only
+    writes its zeros."""
+    T = pos0.shape[0]
+    first, last = _live_range(jnp, pos0, pos_last, block_s * P, window)
+    last_page = jnp.maximum(pos_last, 0) // block_s
+    count = jnp.repeat(last - first + 1, n_hb)              # [T * n_hb]
+    start = jnp.cumsum(count) - count
+    N = T * n_hb * steps
+    item_i = jnp.repeat(jnp.arange(T * n_hb, dtype=jnp.int32), count,
+                        total_repeat_length=N)
+    item_j = first[item_i // n_hb] + jnp.arange(N, dtype=jnp.int32) \
+        - start[item_i]
+    p = jnp.arange(P, dtype=jnp.int32)
+    held = jnp.maximum(last_page[:, None] - p, 0) // P * P + p   # [T, P]
+    return (count.sum(), jnp.stack([item_i, item_j]),
+            jnp.stack([pos0, pos_last, first, last, tseq]),
+            held.reshape(T * P).astype(jnp.int32))
 
 
 def resolve_tile_q(q_lens, tile_q=None, g: int = 1) -> int:
@@ -508,12 +605,12 @@ def paged_attention_ragged(q, kv_pool, block_tables, q_lens, kv_lens,
     qlen_arr = jnp.asarray(q_lens, jnp.int32)
     tseq = jnp.asarray(tile_seq)
     # per-tile (first, LAST REAL) query positions (kv_lens may be
-    # traced): the last-real column is the causal frontier — a decode
+    # traced): the last real one is the causal frontier — a decode
     # row padded into a wide mixed-batch tile keeps its single
     # position, so the page sweep never runs past it
     pos0 = (lens[tseq] - qlen_arr[tseq]
             + jnp.asarray(tile_off)).astype(jnp.int32)
-    pos = jnp.stack([pos0, pos0 + jnp.asarray(tile_n) - 1], axis=1)
+    pos_last = pos0 + jnp.asarray(tile_n) - 1
 
     # pad + fold: [R, nh, hd] -> [T, nkv, tile_q*g, hd]
     qp = jnp.take(q.reshape(R, nkv, g, hd), jnp.asarray(pad_idx),
@@ -523,8 +620,8 @@ def paged_attention_ragged(q, kv_pool, block_tables, q_lens, kv_lens,
 
     # a grid step carries Hb heads of P pages (launch_plan). A
     # sequence's pages are not contiguous in the pool, so the pool is
-    # handed to the call P times and operand p's index map names table
-    # entry j * P + p
+    # handed to the call P times and operand p's index map names the
+    # work item's page p
     plan = launch_plan(T, nkv, rows, MB, block_s, hd,
                        kv_pool.dtype.itemsize,
                        q_itemsize=q.dtype.itemsize,
@@ -532,31 +629,20 @@ def paged_attention_ragged(q, kv_pool, block_tables, q_lens, kv_lens,
                        tile_kv=tile_kv, v_dim=v_dim)
     Hb, P = plan.heads, plan.pages
     n_hb = nkv // Hb
-    kw = dict(block_s=block_s * P, n_blocks=plan.grid[1],
-              sm_scale=scale, tile_q=tile_q, g=g, window=window)
+    count, items, tiles, held = _work_list(
+        pos0, pos_last, tseq, n_hb, plan.grid[1], P, block_s, window)
 
-    def q_map(i, j, bt_, ts_, pos_):
-        return (i // n_hb, i % n_hb, 0, 0)
+    def split(i):
+        return (i, 0) if n_hb == 1 else (i // n_hb, i % n_hb)
+
+    def q_map(n, item_, tile_, held_, bt_):
+        return split(item_[0, n]) + (0, 0)
 
     def page_map(p, tail):
-        # operand p's page of kv step j is table entry j * P + p — held
-        # at its LAST REAL page (the per-tile frontier, pos[t, 1]) for
-        # every step past it: a skipped step re-names the block the
-        # pipeline already holds, and no copy is issued past the
-        # frontier. An operand whose first page is already past it
-        # holds entry p throughout. On a sliding layer the steps wholly
-        # behind the tile's window hold the first live step's entry the
-        # same way.
-        def index(i, j, bt_, ts_, pos_):
-            t = i // n_hb
-            last = jnp.maximum(pos_[t, 1], 0) // block_s
-            jj = jnp.minimum(j, jnp.maximum(last - p, 0) // P)
-            if window is not None:
-                first = jnp.maximum(pos_[t, 0] - window + 1, 0) \
-                    // (block_s * P)
-                jj = jnp.maximum(jj, jnp.minimum(
-                    first, jnp.maximum(last - p, 0) // P))
-            return (bt_[ts_[t], jj * P + p], 0, i % n_hb) + tail
+        def index(n, item_, tile_, held_, bt_):
+            t, head = split(item_[0, n])
+            entry = jnp.minimum(item_[1, n] * P + p, held_[t * P + p])
+            return (bt_[tile_[4, t], entry], 0, head) + tail
         return index
 
     # the pages straight out of the pool rows the block table names —
@@ -564,7 +650,7 @@ def paged_attention_ragged(q, kv_pool, block_tables, q_lens, kv_lens,
     in_specs = [pl.BlockSpec((1, Hb, rows, hd), q_map)] + [
         pl.BlockSpec((1, planes, Hb, block_s, hd), page_map(p, (0, 0)))
         for p in range(P)]
-    operands = [bt, tseq, pos, qp] + [kv_pool] * P
+    operands = [items, tiles, held, bt, qp] + [kv_pool] * P
     if kv_scales is not None:
         # each scale page rides the SAME lookup as its int8 page
         in_specs += [
@@ -572,8 +658,8 @@ def paged_attention_ragged(q, kv_pool, block_tables, q_lens, kv_lens,
             for p in range(P)]
         operands += [jnp.asarray(kv_scales)] * P
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,   # bt + tile->seq map + pos (SMEM)
-        grid=plan.grid,
+        num_scalar_prefetch=4,   # the work list and the block table (SMEM)
+        grid=(count,),           # one step a live (tile, kv step)
         in_specs=in_specs,
         out_specs=pl.BlockSpec((1, Hb, rows, od), q_map),
         scratch_shapes=[pltpu.VMEM((Hb, rows, 1), jnp.float32),
@@ -583,7 +669,9 @@ def paged_attention_ragged(q, kv_pool, block_tables, q_lens, kv_lens,
     out = pl.pallas_call(
         functools.partial(_kernel_ragged_prefetch, n_hb=n_hb,
                           pages=P, quantized=kv_scales is not None,
-                          v_dim=v_dim, **kw),
+                          v_dim=v_dim,
+                          block_s=block_s * P, sm_scale=scale, g=g,
+                          window=window),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((T, nkv, rows, od), q.dtype),
         compiler_params=pltpu.CompilerParams(

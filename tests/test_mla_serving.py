@@ -259,13 +259,14 @@ def test_a_broken_mechanism_moves_the_logits_by_a_wide_margin(served,
 
 # ---- (d) the kernel, latent form, 32 query heads on one kv head -------
 
-def _latent_case(seed=0, nh=32, hd=24, v_dim=16, bs=4, MB=12, NB=40):
+def _latent_case(seed=0, nh=32, hd=24, v_dim=16, bs=4, MB=12, NB=40,
+                 dtype=jnp.float32):
     r = np.random.default_rng(seed)
-    pool = jnp.asarray(r.standard_normal((NB, 1, 1, bs, hd)), jnp.float32)
+    pool = jnp.asarray(r.standard_normal((NB, 1, 1, bs, hd)), dtype)
     q_lens = (7, 1, 1, 1, 10)
     kv_lens = jnp.asarray([30, 5, 9, 47, 13], jnp.int32)
     bt = jnp.asarray(r.integers(1, NB, (len(q_lens), MB)), jnp.int32)
-    q = jnp.asarray(r.standard_normal((sum(q_lens), nh, hd)), jnp.float32)
+    q = jnp.asarray(r.standard_normal((sum(q_lens), nh, hd)), dtype)
     return q, pool, bt, q_lens, kv_lens, v_dim
 
 
@@ -279,6 +280,36 @@ def test_latent_kernel_matches_the_reference(tiles):
                                  v_dim=v_dim)
     assert got.shape == (sum(q_lens), 32, v_dim)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-5)
+
+
+# bf16 q over bf16 latent rows, as long-decode hands the launch: the body
+# copies both to float32 (exactly), so against the reference on the SAME
+# bf16 inputs what is left off the chip is the output's own rounding to
+# bf16, 2^-9 of values up to about 2.5; a wrong page, mask or value column
+# is O(1).
+BF16_TOL = 1e-2
+
+
+@pytest.mark.parametrize("tiles", [(None, None), (4, 2), (4, 5), (1, 1)])
+def test_latent_kernel_over_bf16_matches_the_reference(tiles):
+    """32 query heads on the one kv head, a mixed batch with partial tail
+    tiles, 12 table entries in steps of 5 pages, and a length-0 row."""
+    q, pool, bt, q_lens, kv_lens, v_dim = _latent_case(dtype=jnp.bfloat16)
+    q_lens = q_lens + (1,)                           # a slot with nothing in
+    q = jnp.concatenate([q, q[:1]])
+    kv_lens = jnp.concatenate([kv_lens, jnp.zeros((1,), jnp.int32)])
+    bt = jnp.concatenate([bt, jnp.zeros((1, bt.shape[1]), jnp.int32)])
+    want = paged_attention_ragged_reference(
+        q.astype(jnp.float32), pool, bt, q_lens, kv_lens, sm_scale=0.2,
+        v_dim=v_dim)
+    got = paged_attention_ragged(q, pool, bt, q_lens, kv_lens, sm_scale=0.2,
+                                 tile_q=tiles[0], tile_kv=tiles[1],
+                                 v_dim=v_dim)
+    assert got.shape == (sum(q_lens), 32, v_dim) and got.dtype == q.dtype
+    got = np.asarray(got.astype(jnp.float32))
+    np.testing.assert_allclose(got, np.asarray(want), atol=BF16_TOL,
+                               rtol=BF16_TOL)
+    assert np.all(got[-1] == 0.0)
 
 
 def test_latent_reference_is_the_masked_softmax_over_one_row():
@@ -305,24 +336,69 @@ def test_latent_form_refuses_what_it_cannot_run():
 
 
 def test_launch_plans():
-    """The K/V cells' plans are what they were; the latent launch takes
-    512 positions a step and tiles of at most 1 024 rows."""
-    # chat's decode launch, trinity's mixed launch (PERF.md, PR 27 / 28)
+    """A step is given at most 2 MiB of pages, a power of two of positions:
+    chat's plan is what it was (128 positions at 32 heads), trinity's takes
+    512 (PR 33's sweep), the latent launch 1 024, in tiles of at most 1 024
+    rows. ``grid`` is the bound; the launch walks its live steps only."""
+    # chat's decode launch (PERF.md, PR 27): float32 q over bf16 pages
     chat = launch_plan(32, 32, 1, 128, 16, 128, 2, q_itemsize=4)
     assert (chat.heads, chat.pages, chat.grid) == (32, 8, (32, 16))
+    assert chat.bytes_per_step == 2 * 2 ** 20
+    # trinity's decode and mixed launches: bf16 q over bf16 pages
+    decode = launch_plan(32, 8, 6, 784, 16, 128, 2, q_itemsize=2)
+    assert (decode.heads, decode.pages, decode.grid) == (8, 32, (32, 25))
     trinity = launch_plan(64, 8, 384, 784, 16, 128, 2, q_itemsize=2)
-    assert (trinity.heads, trinity.pages) == (8, 8)
+    # 512 positions at 384 rows do not fit the budget: whole score tiles
+    assert (trinity.heads, trinity.pages, trinity.grid) == (8, 24, (64, 33))
     assert resolve_tile_q((2048,) + (1,) * 32, None, 6) == 64
     assert resolve_tile_q((256,) + (1,) * 32) == 64
     # the latent launch of joyai-flash.long-decode
     assert resolve_tile_q((1,) * 64, None, 32) == 1
     assert resolve_tile_q((2048,) + (1,) * 64, None, 32) == 32
     decode = launch_plan(64, 1, 32, 896, 16, 640, 2, q_itemsize=2, v_dim=512)
-    assert (decode.heads, decode.pages, decode.grid) == (1, 32, (64, 28))
-    assert decode.bytes_per_step == 32 * 16 * 640 * 2       # one plane
+    assert (decode.heads, decode.pages, decode.grid) == (1, 64, (64, 14))
+    assert decode.bytes_per_step == 64 * 16 * 640 * 2       # one plane
     mixed = launch_plan(128, 1, 1024, 896, 16, 640, 2, q_itemsize=2,
                         v_dim=512)
-    assert (mixed.heads, mixed.pages) == (1, 32)
+    assert (mixed.heads, mixed.pages) == (1, 64)
+
+
+def test_work_list_walks_the_live_steps_only():
+    """``_work_list`` against a plain enumeration: tile by tile, a head
+    group after the other, the kv steps from the first inside the window to
+    the last real query's, one item for a tile without a key; and the last
+    table entry each page operand may name (its last real page, or entry p
+    where even its first page lies past the tile's frontier)."""
+    import importlib
+    pa = importlib.import_module("paddle_tpu.ops.pallas.paged_attention")
+    P, bs, steps, n_hb = 2, 4, 6, 2
+    pos0 = np.asarray([0, 5, 40, -1, 17], np.int32)
+    pos_last = np.asarray([6, 5, 43, -1, 30], np.int32)
+    tseq = np.asarray([0, 1, 1, 2, 3], np.int32)
+    for window in (None, 9):
+        count, items, tiles, held = pa._work_list(
+            jnp.asarray(pos0), jnp.asarray(pos_last), jnp.asarray(tseq),
+            n_hb, steps, P, bs, window)
+        want = []
+        for t in range(5):
+            last_page = max(int(pos_last[t]), 0) // bs
+            last = last_page // P
+            first = 0 if window is None else \
+                min(max(int(pos0[t]) - window + 1, 0) // (bs * P), last)
+            np.testing.assert_array_equal(
+                np.asarray(tiles)[:, t],
+                [pos0[t], pos_last[t], first, last, tseq[t]])
+            np.testing.assert_array_equal(
+                np.asarray(held)[t * P:(t + 1) * P],
+                [max(last_page - p, 0) // P * P + p for p in range(P)])
+            want += [(t * n_hb + h, j) for h in range(n_hb)
+                     for j in range(first, last + 1)]
+        assert int(count) == len(want)
+        np.testing.assert_array_equal(np.asarray(items)[:, :len(want)],
+                                      np.asarray(want).T)
+    assert items.shape == (2, 5 * n_hb * steps)
+    # tile 0 of the last run: pages 0 and 1 are real (positions 0..6)
+    assert list(np.asarray(held)[:2]) == [0, 1]
 
 
 # ---- (e) the latent pool through the cache's life ----------------------
@@ -528,6 +604,45 @@ def test_mla_spans_gauge_and_counters_in_a_traced_session(served,
     text = trace_report.summarize(dump)
     assert "model spans" in text and "mla.attend" in text
     assert dump["metadata"]["registry"]["moe.experts_held"] == 16
+
+
+@pytest.mark.parametrize("pages", ["float32", "bfloat16"])
+def test_paged_attn_gauge_counts_the_latent_launchs_live_steps(monkeypatch,
+                                                               pages):
+    """The ``paged_attn`` gauge on the kernel path, a sample a packed
+    launch: ``live_steps`` (what the work list walks) beside ``grid_steps``
+    (the plan's bound), over float32 pages as CPU tier-1 has them and over
+    bf16 pages as the cells have; the launch is handed the core's float32
+    rows and the pool as it is."""
+    import importlib
+    from paddle_tpu.inference.telemetry import TraceCollector
+    pa = importlib.import_module("paddle_tpu.ops.pallas.paged_attention")
+    monkeypatch.setattr(device, "use_pallas_kernels", lambda: True)
+    handed = []
+
+    def recording(q, kv_pool, *a, **kw):
+        handed.append((str(q.dtype), str(kv_pool.dtype)))
+        return pa.paged_attention_ragged(q, kv_pool, *a, **kw)
+    monkeypatch.setattr(pc, "paged_attention_ragged", recording)
+    config = dict(TINY, engine=dict(TINY["engine"], kv_dtype=pages))
+    with tempfile.TemporaryDirectory() as workdir:
+        server = serve_latent.build_server(config, 7, workdir)
+        col = TraceCollector()
+        server.engine.engine.collector = col
+        try:
+            rid = server.submit(list(range(20)))
+            for _ in range(3):                # two chunks, then a token
+                server.step()
+            server.release(rid)
+        finally:
+            server.engine.engine.collector = None
+            server.close()
+    gauge = [e["args"] for e in col.events
+             if e.get("ph") == "C" and e["name"] == "paged_attn"]
+    # contexts of at most 20 positions: every tile's one step is live
+    assert gauge and all(1 <= g["live_steps"] <= g["grid_steps"]
+                         for g in gauge)
+    assert handed and set(handed) == {("float32", pages)}
 
 
 # ---- (g) the chip smoke's latent phase, rehearsed ------------------------

@@ -1572,6 +1572,42 @@ def _seam_call(cache, kind, rng):
 
 
 class TestAttentionSeam:
+    @pytest.mark.parametrize("tile_q", [None, 2])
+    def test_the_layout_counts_the_launchs_grid_steps(self, monkeypatch,
+                                                      tile_q):
+        """``_RaggedLayout.live_steps`` (the ``paged_attn`` gauge's
+        ``live_steps``: the kernel module's own count, on the layout's host
+        lengths) is the size of the grid the work list gives the same
+        packed launch on a layer without a window; with two-query tiles
+        the chunk is three tiles, each with its own frontier."""
+        import jax.numpy as jnp
+        pa = _kernel_module()
+        cache = _seam_cache("float32", None)
+        lens = np.asarray(_SEAM_LENS, np.int64)
+        cache.set_decode_mask(np.array([False, True, False]))
+        lay = cache.ragged_views([("prefill", 1, int(lens[1]), 6, 0),
+                                  ("decode", lens, 1)])[0]._layout
+        if tile_q is not None:
+            monkeypatch.setattr(pa, "DEFAULT_TILE_Q_CAP", tile_q)
+        g = _SEAM["heads"] // _SEAM["kv_heads"]
+        tq = pa.resolve_tile_q(lay.q_lens, g=g)
+        seq, off, n, _, _ = pa._tile_layout(lay.q_lens, tq)
+        assert lay.launch_plan().grid[0] == len(seq)
+        # the layout's plan carries every page in one step here: count
+        # under a plan of one page a step (contexts of 10, 11, 15)
+        plan = pa.launch_plan(len(seq), _SEAM["kv_heads"], tq * g,
+                              _SEAM["mb"], _SEAM["bs"], _SEAM["hd"], 4,
+                              tile_kv=1)
+        pos0 = (lay.kv_lens_np - np.asarray(lay.q_lens))[seq] + off
+        count = pa._work_list(
+            jnp.asarray(pos0, jnp.int32), jnp.asarray(pos0 + n - 1, jnp.int32),
+            jnp.asarray(seq), _SEAM["kv_heads"] // plan.heads,
+            plan.grid[1], plan.pages, _SEAM["bs"], None)[0]
+        assert lay.live_steps(plan) == int(count) < plan.grid_steps
+        assert plan.grid == ((4 if tile_q is None else 6), 8)
+        if tile_q is None:      # pages of 4: last positions 10, 9, 5, 14
+            assert int(count) == 3 + 3 + 2 + 4
+
     @pytest.mark.parametrize("window", [None, 6])
     @pytest.mark.parametrize("dtype", ["float32", "int8"])
     @pytest.mark.parametrize("kind",

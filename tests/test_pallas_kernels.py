@@ -698,7 +698,8 @@ class TestChipKernelInterpreted:
     CASES = {
         # rows == 1: one M = 1 product a head; P = MB, one kv step
         "decode": ((1,) * 5, (17, 1, 40, 33, 8), 1, None, 4, 1, 5),
-        # derived P = 16 of MB = 20: two kv steps, the last one short
+        # derived P = 16 of MB = 20 (``_derived_16_pages``): two kv
+        # steps, the last one short
         "decode_pages_not_dividing": ((1,) * 3, (150, 129, 5), 1, None,
                                       4, 1, 20),
         "verify": ((3,) * 4, (9, 3, 24, 40), 3, 2, 4, 1, 5),
@@ -717,35 +718,98 @@ class TestChipKernelInterpreted:
     }
 
     def _inputs(self, q_lens, kv_lens, nkv, g, MB, hd=16, bs=8,
-                seed=0):
+                seed=0, dtype=jnp.float32):
         r = np.random.default_rng(seed)
         NB = 2 * MB + 4
-        pool = jnp.asarray(r.standard_normal((NB, 2, nkv, bs, hd)),
-                           jnp.float32)
+        pool = jnp.asarray(r.standard_normal((NB, 2, nkv, bs, hd)), dtype)
         bt = jnp.asarray(r.integers(1, NB, (len(q_lens), MB)),
                          jnp.int32)
         q = jnp.asarray(r.standard_normal((sum(q_lens), nkv * g, hd)),
-                        jnp.float32)
+                        dtype)
         return q, pool, bt, jnp.asarray(kv_lens, jnp.int32)
 
     def _check(self, pa, q, pool, bt, q_lens, kv_lens, kv_scales=None,
-               **tiles):
+               window=None, tol=2e-5, **tiles):
         out = np.asarray(pa.paged_attention_ragged(
-            q, pool, bt, q_lens, kv_lens, kv_scales=kv_scales, **tiles))
+            q, pool, bt, q_lens, kv_lens, kv_scales=kv_scales,
+            window=window, **tiles).astype(jnp.float32))
         ref = np.asarray(paged_attention_ragged_reference(
-            q, pool, bt, q_lens, kv_lens, kv_scales=kv_scales))
+            q.astype(jnp.float32), pool, bt, q_lens, kv_lens,
+            kv_scales=kv_scales, window=window))
         assert np.isfinite(out).all()
-        np.testing.assert_allclose(out, ref, atol=2e-5, rtol=2e-5)
+        np.testing.assert_allclose(out, ref, atol=tol, rtol=tol)
         return out
 
+    def _derived_16_pages(self, monkeypatch, case, itemsize):
+        """``decode_pages_not_dividing`` runs the DERIVED plan: a step
+        is given the bytes of 128 positions (4 heads of 16, two planes),
+        so ``launch_plan`` hands it 16 pages of the table's 20."""
+        if case != "decode_pages_not_dividing":
+            return
+        monkeypatch.setattr(pa_module, "STEP_PAGE_BYTES",
+                            128 * 2 * 4 * 16 * itemsize)
+        plan = pa_module.launch_plan(3, 4, 1, 20, 8, 16, itemsize,
+                                     q_itemsize=itemsize)
+        assert (plan.heads, plan.pages, plan.grid) == (4, 16, (3, 2))
+
     @pytest.mark.parametrize("case", sorted(CASES))
-    def test_launch_matches_reference(self, case):
+    def test_launch_matches_reference(self, monkeypatch, case):
         q_lens, kv_lens, tq, tkv, nkv, g, MB = self.CASES[case]
         q, pool, bt, kvl = self._inputs(q_lens, kv_lens, nkv, g, MB)
+        self._derived_16_pages(monkeypatch, case, 4)
         out = self._check(pa_module, q, pool, bt, q_lens, kvl, tile_q=tq,
                           tile_kv=tkv)
         if case == "length_zero_rows":
             assert np.all(out[0] == 0.0) and np.all(out[2:] == 0.0)
+
+    # bf16 q over a bf16 pool, as long-decode and mixed-queue hand the
+    # launch: the body copies both to float32 (exactly), so against the
+    # reference on the SAME bf16 inputs in float32 what is left off the
+    # chip is the output's own rounding to bf16 (2^-9 of values up to
+    # 2.6: 5e-3). 1e-2 holds it, and a wrong page, mask or column range
+    # is O(1).
+    BF16_TOL = 1e-2
+
+    @pytest.mark.parametrize("case,g,window", [
+        ("decode", 1, None), ("decode_pages_not_dividing", 1, None),
+        ("mixed", 1, None), ("tile_kv_not_dividing", 1, None),
+        ("length_zero_rows", 1, None), ("page_boundary", 1, None),
+        ("gqa_mixed", 6, 9), ("gqa_decode", 6, 9), ("gqa_mixed", 6, None)])
+    def test_launch_over_bf16_matches_reference(self, monkeypatch, case, g,
+                                                window):
+        """Mixed batches with partial tail tiles, ``MB`` no multiple of
+        ``P``, length-0 rows, and the K/V form at six query heads a kv
+        head under a window, bf16 q over bf16 pages."""
+        q_lens, kv_lens, tq, tkv, nkv, _, MB = self.CASES[case]
+        q, pool, bt, kvl = self._inputs(q_lens, kv_lens, nkv, g, MB,
+                                        dtype=jnp.bfloat16)
+        self._derived_16_pages(monkeypatch, case, 2)
+        out = self._check(pa_module, q, pool, bt, q_lens, kvl, tile_q=tq,
+                          tile_kv=tkv, window=window, tol=self.BF16_TOL)
+        if case == "length_zero_rows":
+            assert np.all(out[0] == 0.0) and np.all(out[2:] == 0.0)
+
+    @pytest.mark.parametrize("window", [None, 9])
+    @pytest.mark.parametrize("case", sorted(CASES))
+    def test_live_steps_is_the_work_lists_count(self, case, window):
+        """``live_steps`` (host numpy, the ``paged_attn`` gauge's series)
+        and ``_work_list`` (jnp, the launch's grid size) count with the
+        one ``_live_range``: equal at one page a step and at the derived
+        plan, and never over the plan's bound."""
+        q_lens, kv_lens, _, _, nkv, g, MB = self.CASES[case]
+        tq = pa_module.resolve_tile_q(q_lens, g=g)
+        seq, off, n, _, _ = pa_module._tile_layout(q_lens, tq)
+        pos0 = (np.asarray(kv_lens) - np.asarray(q_lens))[seq] + off
+        for tile_kv in (1, None):
+            plan = pa_module.launch_plan(len(seq), nkv, tq * g, MB, 8, 16,
+                                         4, tile_kv=tile_kv)
+            count = pa_module._work_list(
+                jnp.asarray(pos0, jnp.int32),
+                jnp.asarray(pos0 + n - 1, jnp.int32), jnp.asarray(seq),
+                nkv // plan.heads, plan.grid[1], plan.pages, 8, window)[0]
+            live = pa_module.live_steps(plan, q_lens, kv_lens, 8, g=g,
+                                        window=window)
+            assert len(seq) <= live == int(count) <= plan.grid_steps
 
     @pytest.mark.parametrize("case", ["decode", "mixed", "verify"])
     def test_head_groups_under_a_small_budget(self, monkeypatch, case):

@@ -751,8 +751,10 @@ class TestTimelineAndLifecycle:
         assert samples and len(samples) == len(layouts)
         for ev in samples:
             a = ev["args"]
-            assert set(a) == {"grid_steps", "pages_per_step",
-                              "heads_per_step"}
+            assert set(a) == {"grid_steps", "live_steps",
+                              "pages_per_step", "heads_per_step"}
+            # what the launch walks, under the plan's bound
+            assert 1 <= a["live_steps"] <= a["grid_steps"]
             # 10 table entries a sequence, HEADS kv heads, one shard
             assert a["heads_per_step"] == HEADS
             assert a["grid_steps"] % -(-10 // a["pages_per_step"]) == 0
@@ -768,6 +770,9 @@ class TestTimelineAndLifecycle:
         out = capsys.readouterr().out
         assert "paged_attn.grid_steps:" in out
         assert "paged_attn.pages_per_step:" in out
+        assert "paged_attn.live_steps:" in out
+        assert re.search(r"paged-attention launch: \d+ live grid step\(s\) "
+                         r"walked of a bound of \d+ \(", out), out
         # the pool writes beside the pool's size
         assert "pool_write.pages_written:" in out
         assert re.search(r"pool writes: [\d.]+ page\(s\) and [\d.]+ "

@@ -229,6 +229,12 @@ def summarize(trace: dict, tenant: str = None,
                      f"context behind the window "
                      f"({100.0 * behind / max(total, 1):.1f} %): skipped "
                      f"by the launch, not freed")
+    if "live_steps" in window:
+        live, bound = window["live_steps"][1], window["grid_steps"][1]
+        lines.append(f"  paged-attention launch: {live:g} live grid "
+                     f"step(s) walked of a bound of {bound:g} "
+                     f"({100.0 * live / max(bound, 1):.1f} %; a layer "
+                     f"without a window)")
     writes = counters.get("pool_write", {})
     if "pages_written" in writes:
         steps, pages = writes["pages_written"][:2]
