@@ -1174,6 +1174,167 @@ def latent_phase(*, hidden=2048, heads=32, q_rank=1536, kv_rank=512,
     return {"kernels": rows, "probe_rel_l2": err, "route": stats}
 
 
+def conv_phase(*, hidden=2048, heads=32, kv_heads=8, dense_width=11776,
+               experts=64, top_k=4, expert_width=1536, vocab=16384,
+               prompt=2048, chunk=1024, block_size=16, max_batch=8,
+               kernel=3, weight_dtype="bfloat16", kv_dtype="bfloat16",
+               expect_kernel=True, logits_tol=DECODER_LOGITS_TOL,
+               tol=KERNEL_TOL, seed=0, meter=None) -> dict:
+    """The ``lfm2_moe`` core at published widths: the ragged launch over
+    TWO heads of 64 a 128-lane pool row (``kv_heads / 2`` stored rows, a
+    query head's operand zero in the other head's half) against the jnp
+    reference over the same K/V kept a head of 64 each; the short
+    convolution's mix (a chunk and decode rows through the state store,
+    one XLA program) against a plain whole-sequence convolution; then a
+    three-layer server (the dense conv layer, an attention and a conv
+    layer with every expert) through ``build_server_from_spec`` whose
+    probe, two chunks long, is held to the plain reference's logits."""
+    import jax.numpy as jnp
+    import numpy as np
+    import tempfile
+    from benchmark.jobs import serve_conv
+    from paddle_tpu.inference import decoder, paged_cache
+    pa = _kernel_module("paged_attention")
+
+    t_phase = time.perf_counter()
+    rng = np.random.default_rng(seed)
+    hd = hidden // heads
+    cfg = decoder.DecoderConfig.from_spec(dict(
+        arch="lfm2_moe", hidden_size=hidden, num_attention_heads=heads,
+        num_key_value_heads=kv_heads, layer_types=["full_attention"],
+        num_dense_layers=1, intermediate_size=dense_width))
+    pack = cfg.kv_pack
+    max_blocks = -(-(prompt + 64) // block_size)
+    q_lens = (chunk,) + (1,) * max_batch
+    kv_lens = [prompt] + list(rng.integers(2, prompt, size=max_batch - 1)) \
+        + [prompt - 3]
+    num_blocks = 1 + sum(-(-int(n) // block_size) for n in kv_lens)
+    _, _, bt, kvl = _paged_inputs(
+        rng, q_lens, kv_lens, heads=1, head_dim=1, block_size=block_size,
+        num_blocks=num_blocks, max_blocks=max_blocks, pool_dtype="float32")
+    q = jnp.asarray(rng.standard_normal((sum(q_lens), heads, hd)), kv_dtype)
+    pool = jnp.asarray(rng.standard_normal(
+        (num_blocks, 2, kv_heads, block_size, hd)), kv_dtype)
+
+    def packed(q_, p_, bt_, kl_):
+        # the pool as the cache stores it, q as ``_attn_in`` hands it,
+        # and each head's own half of the output as ``_attn_out`` takes it
+        lane = decoder._lane_of_head(cfg)                # [heads, pack]
+        rows = jnp.transpose(
+            p_.reshape(num_blocks, 2, kv_heads // pack, pack, block_size,
+                       hd), (0, 1, 2, 4, 3, 5)).reshape(
+            num_blocks, 2, kv_heads // pack, block_size, pack * hd)
+        wide = (q_[:, :, None, :] * lane[None, :, :, None]).astype(
+            q_.dtype).reshape(-1, heads, pack * hd)
+        out = pa.paged_attention_ragged(wide, rows, bt_, q_lens, kl_,
+                                        sm_scale=cfg.attn_scale)
+        return jnp.einsum("rhnd,hn->rhd", out.astype(jnp.float32).reshape(
+            -1, heads, pack, hd), lane)
+    rows = [_run_check(
+        "paged_ragged/two_heads_a_row",
+        f"R={sum(q_lens)} nh={heads} nkv={kv_heads} hd={hd} pack={pack}",
+        packed,
+        lambda q_, p_, bt_, kl_: pa.paged_attention_ragged_reference(
+            q_, p_, bt_, q_lens, kl_),
+        (q, pool, bt, kvl), expect_kernel, tol)]
+
+    # the mix: slot 0's second chunk beside every slot's decode row
+    # (slot 0 masked: it is mid-prefill), against the plain convolution
+    cache = paged_cache.PagedKVCache(
+        2, heads, pack * hd, block_size, max_batch * max_blocks + 8, max_batch,
+        max_blocks_per_seq=max_blocks, dtype=kv_dtype,
+        num_kv_heads=kv_heads // pack, sm_scale=cfg.attn_scale,
+        layer_state=[None, (kernel - 1, hidden)])
+    taps = rng.standard_normal((hidden, kernel)).astype(np.float32)
+    seq = rng.standard_normal((max_batch, 2 * chunk + 1, hidden)) \
+        .astype(np.float32)
+    seq = np.asarray(jnp.asarray(seq, kv_dtype).astype(jnp.float32))
+
+    def plain(u):
+        y = np.zeros_like(u)
+        for j in range(kernel):
+            y[j:] += u[:len(u) - j] * taps[:, kernel - 1 - j]
+        return y
+    for slot in range(max_batch):
+        cache.ensure(slot, chunk, write_from=0)
+        cache.prefill_views(slot)[1].mix(
+            jnp.asarray(seq[slot][None, :chunk], kv_dtype),
+            jnp.asarray(taps))
+    cache.ensure(0, 2 * chunk, write_from=chunk)
+    for slot in range(1, max_batch):
+        cache.ensure(slot, chunk + 1)
+    mask = np.zeros(max_batch, bool)
+    mask[0] = True
+    cache.set_decode_mask(mask)
+    view = cache.ragged_views([
+        ("prefill", 0, chunk, chunk, 0),
+        ("decode", np.full(max_batch, chunk), 1)])[1]
+    u = np.concatenate([seq[0][chunk:2 * chunk], seq[:, chunk]])
+    got = np.asarray(view.mix(jnp.asarray(u[None], kv_dtype),
+                              jnp.asarray(taps)))[0]
+    want = np.concatenate([plain(seq[0][:2 * chunk])[chunk:]] + [
+        plain(seq[s][:chunk + 1])[-1:] for s in range(max_batch)])
+    mix_err = float(np.abs(got[:chunk] - want[:chunk]).max()
+                    / np.abs(want).max())
+    mix_err = max(mix_err, float(np.abs(got[chunk + 1:] - want[chunk + 1:])
+                                 .max() / np.abs(want).max()))
+    log(f"[conv] mix of a {chunk}-row chunk beside {max_batch} decode rows "
+        f"at d={hidden}: err={mix_err:.2e}")
+    if mix_err > tol:
+        raise AssertionError(f"conv mix: error {mix_err:.3e} > {tol}")
+    del cache
+
+    config = {
+        "model_type": "lfm2_moe", "reference": "lfm2_moe",
+        "hidden_size": hidden, "num_attention_heads": heads,
+        "num_key_value_heads": kv_heads, "intermediate_size": dense_width,
+        "moe_intermediate_size": expert_width, "num_dense_layers": 1,
+        "num_experts": experts, "num_experts_per_tok": top_k,
+        "norm_topk_prob": True, "routed_scaling_factor": 1,
+        "use_expert_bias": True, "conv_L_cache": kernel,
+        "conv_bias": False, "norm_eps": 1e-5,
+        "rope_parameters": {"rope_theta": 1000000, "rope_type": "default"},
+        "vocab_size": vocab, "weight_dtype": weight_dtype,
+        "layer_types": ["conv", "conv", "full_attention", "conv"],
+        "layers_run": [1, 2, 3],
+        "engine": {"mp": 1, "k": 0, "max_batch": max_batch,
+                   "block_size": block_size,
+                   "num_blocks": 2 * max_blocks + 8,
+                   "max_blocks_per_seq": max_blocks, "prefix_cache": False,
+                   "prefill_token_budget": chunk, "kv_dtype": kv_dtype},
+    }
+    stats = {}
+    with tempfile.TemporaryDirectory(prefix="chip_smoke_") as workdir:
+        server = serve_conv.build_server(config, seed, workdir)
+        try:
+            cache = server.engine.engine.cache
+            with _device_ops_traced("conv",
+                                    {tuple(p.shape) for p in cache.pools}):
+                probe = serve_conv.probe_engine(
+                    server, config, {"table": [[prompt, 8]]}, seed)
+            err = serve_conv.compare_probe(
+                server.engine.target, config, probe, tol=logits_tol,
+                stats=stats)
+            per_token, state = cache.kv_bytes_per_token(), cache.state_bytes()
+        finally:
+            server.close()
+    log(f"[conv] probe of {prompt} tokens in chunks of {chunk}: rel. L2 "
+        f"{err:.2e}, {stats.get('route_ties_taken')} of "
+        f"{stats.get('route_rows')} routed rows tied (widest gap "
+        f"{stats.get('route_widest_gap')}); {per_token} cache bytes a "
+        f"token, {state} bytes of state store")
+    size = jnp.dtype(kv_dtype).itemsize
+    if per_token != 2 * kv_heads * hd * size or \
+            state != 2 * max_batch * (kernel - 1) * hidden * size:
+        raise AssertionError(
+            f"one K/V layer holds {per_token} bytes a token and two conv "
+            f"layers {state} bytes of state")
+    if meter is not None:
+        _phase_line("conv", time.perf_counter() - t_phase, meter.take())
+    return {"kernels": rows, "mix_err": mix_err, "probe_rel_l2": err,
+            "route": stats}
+
+
 def main() -> None:
     t_start = time.perf_counter()
     device = require_tpu()
@@ -1193,6 +1354,8 @@ def main() -> None:
     _free_device_memory("decoder")
     latent_phase(meter=meter)
     _free_device_memory("latent")
+    conv_phase(meter=meter)
+    _free_device_memory("conv")
     trainer_phase(meter=meter)
     _free_device_memory("trainer")
 

@@ -40,6 +40,27 @@ so the pool holds ONE row a position a layer and no decompressed K or V
 is ever written (``PagedKVCache``'s latent form; the view takes
 ``decode(q_abs, row, None, t)``). Every layer is a full layer.
 
+``lfm2_moe`` (LiquidAI LFM2) is ``gqa`` in ``pre_norm`` with a LAYER KIND
+a layer (``layer_types``: ``conv`` | ``full_attention``). What ``afmoe``
+does to its attention is the arch's, not ``gqa``'s (``ARCH_FIELDS``):
+here every attention layer rotates (half-split RoPE), none gates its
+output, the router's normalising sum adds 1e-6, and there is no shared
+expert. A ``conv`` layer holds NO K/V: it is a gated short convolution
+over its own input,
+
+    [B | C | x] = a W_in;  u = B * x
+    y_t = sum_j taps[:, K-1-j] * u_{t-j},  j = 0 .. K-1  (u_t = 0, t < 0)
+    h = h + (C * y) W_out
+
+whose memory is the last ``K - 1`` rows of ``u`` a slot: the cache's
+STATE STORE (``PagedKVCache(layer_state=)``), reached through the same
+views (``view.mix(u, taps)``: predecessors from earlier rows of the
+call or from the slot's stored rows, write-back of each slot's last
+rows). Heads of 64 are cached two a 128-lane row (``kv_pack``): the pool
+holds ``num_key_value_heads / 2`` heads of 128, a query head's operand
+is zero in the other head's half, and the block takes its own half of
+the launch's output.
+
 ``F`` is a SwiGLU in the first ``num_dense_layers`` layers and, after
 them, a shared SwiGLU expert plus this chip's share of the routed ones
 (``inference/moe_serving.py``: sigmoid scores over ALL experts, top-k of
@@ -75,10 +96,15 @@ from .paged_cache import PagedLayerCache
 __all__ = ["DecoderConfig", "DecoderCore", "decoder_block", "rms_norm",
            "rope_half_split", "rope_interleaved"]
 
-SLIDING, FULL = "sliding_attention", "full_attention"
+SLIDING, FULL, CONV = "sliding_attention", "full_attention", "conv"
 # arch -> (attention kind, residual form)
 ARCHS = {"afmoe": ("gqa", "sandwich"),
-         "joyai_llm_flash": ("mla", "pre_norm")}
+         "joyai_llm_flash": ("mla", "pre_norm"),
+         "lfm2_moe": ("gqa", "pre_norm")}
+# what else an arch fixes, as configuration fields (absent: afmoe's)
+ARCH_FIELDS = {"lfm2_moe": {"rope_layers": "all", "attn_gate": False,
+                            "route_norm_eps": 1e-6, "pack_kv_heads": True}}
+LANES = 128     # a pool row is stored in whole lane tiles
 # the published keys of an ``mla`` configuration (DeepSeek-V3's names) and
 # the fields they fill
 MLA_KEYS = {"first_k_dense_replace": "num_dense_layers",
@@ -86,6 +112,10 @@ MLA_KEYS = {"first_k_dense_replace": "num_dense_layers",
             "n_shared_experts": "num_shared_experts",
             "norm_topk_prob": "route_norm",
             "routed_scaling_factor": "route_scale"}
+# the published keys of an ``lfm2_moe`` configuration that are not the
+# fields' own names (``rope_parameters.rope_theta`` is read too)
+LFM2_KEYS = {"norm_eps": "rms_norm_eps", "norm_topk_prob": "route_norm",
+             "routed_scaling_factor": "route_scale"}
 
 
 @dataclasses.dataclass(frozen=True)
@@ -94,7 +124,9 @@ class DecoderConfig:
     (``experts_held`` of ``num_experts`` from ``expert_offset``), the
     stored weight type and the block's two statics. An ``mla`` spec
     speaks DeepSeek-V3's keys (``MLA_KEYS``, ``num_hidden_layers``, the
-    five latent widths, ``rope_interleave``)."""
+    five latent widths, ``rope_interleave``); an ``lfm2_moe`` spec its
+    own (``LFM2_KEYS``, ``conv_L_cache``, ``use_expert_bias``,
+    ``rope_parameters``; ``head_dim`` defaults to hidden / heads)."""
     hidden_size: int
     num_attention_heads: int
     layer_types: Tuple[str, ...]
@@ -123,6 +155,13 @@ class DecoderConfig:
     qk_rope_head_dim: int = 0
     v_head_dim: int = 0
     rope_interleave: bool = True
+    conv_L_cache: int = 0          # a conv layer's kernel (taps a column)
+    use_expert_bias: bool = True   # False: the selection bias is zero
+    # the arch's own (``ARCH_FIELDS``), never a spec key
+    rope_layers: str = "sliding"   # which attention layers rotate: | all
+    attn_gate: bool = True         # sigmoid(a Wg) on the attention output
+    route_norm_eps: float = 1e-20  # added to the chosen scores' sum
+    pack_kv_heads: bool = False    # heads under 128 wide share a pool row
 
     KEYS = ("hidden_size", "num_attention_heads", "num_key_value_heads",
             "head_dim", "layer_types", "sliding_window",
@@ -132,12 +171,25 @@ class DecoderConfig:
             "rope_theta", "rms_norm_eps", "mup_enabled", "experts_held",
             "expert_offset", "weight_dtype", "q_lora_rank", "kv_lora_rank",
             "qk_nope_head_dim", "qk_rope_head_dim", "v_head_dim",
-            "rope_interleave")
+            "rope_interleave", "conv_L_cache", "use_expert_bias")
 
     @classmethod
     def from_spec(cls, spec: dict) -> "DecoderConfig":
         kw = {k: spec[k] for k in cls.KEYS if spec.get(k) is not None}
-        kw["attention"], kw["residual"] = ARCHS[spec.get("arch", "afmoe")]
+        arch = spec.get("arch", "afmoe")
+        kw["attention"], kw["residual"] = ARCHS[arch]
+        kw.update(ARCH_FIELDS.get(arch, {}))
+        if arch == "lfm2_moe":
+            kw.update({field: spec[k] for k, field in LFM2_KEYS.items()
+                       if spec.get(k) is not None})
+            rope = spec.get("rope_parameters") or {}
+            if "rope_theta" in rope:
+                kw["rope_theta"] = rope["rope_theta"]
+            kw.setdefault("head_dim", int(spec["hidden_size"])
+                          // int(spec["num_attention_heads"]))
+            if spec.get("conv_bias"):
+                raise ValueError("conv_bias true is not built: the "
+                                 "short convolution here has no bias")
         if kw["attention"] == "mla":
             kw.update({field: spec[k] for k, field in MLA_KEYS.items()
                        if spec.get(k) is not None})
@@ -150,9 +202,15 @@ class DecoderConfig:
         held = cfg.num_experts if cfg.experts_held is None \
             else cfg.experts_held
         cfg = dataclasses.replace(cfg, experts_held=int(held))
-        bad = set(cfg.layer_types) - {SLIDING, FULL}
+        bad = set(cfg.layer_types) - {SLIDING, FULL, CONV}
         if bad:
             raise ValueError(f"unknown layer types {sorted(bad)}")
+        if CONV in cfg.layer_types and (
+                cfg.conv_L_cache < 2 or cfg.attention != "gqa"
+                or cfg.residual != "pre_norm"):
+            raise ValueError(
+                "conv layers take conv_L_cache >= 2 (the kernel) in a "
+                "gqa, pre_norm arch (lfm2_moe)")
         if cfg.attention == "mla":
             if min(cfg.q_lora_rank, cfg.kv_lora_rank, cfg.qk_nope_head_dim,
                    cfg.qk_rope_head_dim, cfg.v_head_dim) < 1 \
@@ -195,17 +253,36 @@ class DecoderConfig:
     def is_moe(self, layer: int) -> bool:
         return layer >= self.num_dense_layers
 
+    def rotates(self, layer: int) -> bool:
+        """Whether attention layer ``layer`` carries RoPE."""
+        return self.rope_layers == "all" \
+            or self.layer_types[layer] == SLIDING
+
+    @property
+    def kv_pack(self) -> int:
+        """KV heads stored side by side in one pool row: 128 / head_dim
+        where the arch packs (``pack_kv_heads``) and the heads divide,
+        else 1. A 64-wide pool row makes the TPU compiler lay the pool
+        out block-axis-minor and copy it whole at every write, as a
+        576-wide one does (tests/test_pool_write_hlo.py compiles both);
+        two heads a row store the published bytes and no padding."""
+        n = LANES // self.head_dim if self.pack_kv_heads \
+            and 0 < self.head_dim < LANES and LANES % self.head_dim == 0 \
+            else 1
+        return n if self.num_key_value_heads % n == 0 else 1
+
     @property
     def kv_width(self) -> int:
         """Columns the cache STORES a position and kv head: the head
         (``gqa``); the latent and the shared rope head, padded with
-        zeros to whole 128-lane tiles (``mla``: 576 -> 640). A pool
+        zeros to whole 128-lane tiles (``mla``: 576 -> 640); ``kv_pack``
+        heads side by side (``gqa`` where the arch packs). A pool
         whose rows are not whole tiles is laid out by the TPU compiler
         with the BLOCK axis minor, and every page write and kernel launch
         then copies the whole pool there and back
         (tests/test_pool_write_hlo.py compiles both widths)."""
         if self.attention != "mla":
-            return self.head_dim
+            return self.head_dim * self.kv_pack
         return -(-(self.kv_lora_rank + self.qk_rope_head_dim) // 128) * 128
 
     @property
@@ -288,33 +365,56 @@ def _mla_in(cfg: DecoderConfig, p, x, positions):
     return row(q_c, q_r), row(c[..., None, :], k_r), None, None
 
 
-def _attn_in(cfg: DecoderConfig, sliding: bool, p, x, positions):
+def _lane_of_head(cfg: DecoderConfig):
+    """[nh, kv_pack] float32, one 1 a row: which part of its KV head's
+    pool row query head h reads (``kv_pack`` heads lie side by side
+    there, in head order)."""
+    g = cfg.num_attention_heads // cfg.num_key_value_heads
+    kv_head = jnp.arange(cfg.num_attention_heads) // g
+    return jax.nn.one_hot(kv_head % cfg.kv_pack, cfg.kv_pack,
+                          dtype=jnp.float32)
+
+
+def _attn_in(cfg: DecoderConfig, rope: bool, p, x, positions):
     """Rows to the attention's operands: q [B, L, nh, hd], k, v
-    [B, L, nkv, hd] and the output gate [B, L, nh * hd], all in the
-    weight type (``mla``: ``_mla_in``'s)."""
+    [B, L, nkv, hd] and the output gate [B, L, nh * hd] (None where the
+    arch has none), all in the weight type (``mla``: ``_mla_in``'s).
+    Where the arch packs (``kv_pack`` n > 1) k and v come as the pool
+    stores them, [B, L, nkv / n, n * hd], and q [B, L, nh, n * hd] with
+    zeros outside its KV head's part, so that the product over the row
+    is the product over the head."""
     if cfg.attention == "mla":
         return _mla_in(cfg, p, x, positions)
     nh, nkv, hd = (cfg.num_attention_heads, cfg.num_key_value_heads,
                    cfg.head_dim)
-    wt = p["qkvg"].dtype
+    w = p["qkvg" if cfg.attn_gate else "qkv"]
+    wt = w.dtype
     a = rms_norm(x, p["in_norm"], cfg.rms_norm_eps).astype(wt)
-    qkvg = _dot(a, p["qkvg"])
-    q, k, v, g = jnp.split(
-        qkvg, [nh * hd, (nh + nkv) * hd, (nh + 2 * nkv) * hd], axis=-1)
+    cuts = [nh * hd, (nh + nkv) * hd, (nh + 2 * nkv) * hd]
+    q, k, v, *g = jnp.split(_dot(a, w), cuts if cfg.attn_gate
+                            else cuts[:2], axis=-1)
     lead = x.shape[:-1]
     q = rms_norm(q.reshape(lead + (nh, hd)), p["q_norm"],
                  cfg.rms_norm_eps)
     k = rms_norm(k.reshape(lead + (nkv, hd)), p["k_norm"],
                  cfg.rms_norm_eps)
-    if sliding:                 # full layers carry no position encoding
+    if rope:             # afmoe's full layers carry no position encoding
         q = rope_half_split(q, positions, cfg.rope_theta)
         k = rope_half_split(k, positions, cfg.rope_theta)
-    return (q.astype(wt), k.astype(wt),
-            v.reshape(lead + (nkv, hd)).astype(wt), g.astype(wt))
+    v = v.reshape(lead + (nkv, hd))
+    n = cfg.kv_pack
+    if n > 1:
+        q = (q[..., None, :] * _lane_of_head(cfg)[:, :, None]) \
+            .reshape(lead + (nh, n * hd))
+        k = k.reshape(lead + (nkv // n, n * hd))
+        v = v.reshape(lead + (nkv // n, n * hd))
+    return (q.astype(wt), k.astype(wt), v.astype(wt),
+            g[0].astype(wt) if g else None)
 
 
 def _attn_out(cfg: DecoderConfig, p, x, attn, gate):
-    """Gate (``gqa``) or the value's way out of the latent (``mla``:
+    """Gate (where the arch has one) or the value's way out of the
+    latent (``mla``:
     ``attn`` [B, L, nh, rank] through W_kvb^V per head), the output
     projection and the residual, with the first half of the sandwich
     where there is one: returns (h, m) with ``m = RMSNorm_pre_mlp(h)``
@@ -322,9 +422,17 @@ def _attn_out(cfg: DecoderConfig, p, x, attn, gate):
     wt = p["o"].dtype
     if cfg.attention == "mla":
         o = _heads(attn, p["kv_b_v"], "...hr,hrv->...hv")
-        o = o.reshape(x.shape[:-1] + (-1,))
+    elif cfg.kv_pack > 1:        # each head's own part of the row
+        o = jnp.einsum(
+            "...hnd,hn->...hd",
+            attn.astype(jnp.float32).reshape(
+                attn.shape[:-1] + (cfg.kv_pack, cfg.head_dim)),
+            _lane_of_head(cfg))
     else:
-        o = attn.reshape(gate.shape).astype(jnp.float32) \
+        o = attn
+    o = o.reshape(x.shape[:-1] + (-1,))
+    if gate is not None:
+        o = o.astype(jnp.float32) \
             * jax.nn.sigmoid(gate.astype(jnp.float32))
     o = _dot(o.astype(wt), p["o"])
     if cfg.residual == "sandwich":
@@ -344,11 +452,34 @@ def _dense_tail(cfg: DecoderConfig, p, x, attn, gate):
     return _mlp_out(cfg, p, h, swiglu(m, p["gate_up"], p["down"]))
 
 
+def _conv_in(cfg: DecoderConfig, p, x):
+    """Rows to the short convolution's operands: ``u = B * x`` and the
+    output gate ``C`` of ``[B | C | x] = RMSNorm(h) W_in``, [B, L, d]
+    each, in the weight type."""
+    wt = p["conv_in"].dtype
+    a = rms_norm(x, p["in_norm"], cfg.rms_norm_eps).astype(wt)
+    b, c, xg = jnp.split(_dot(a, p["conv_in"]), 3, axis=-1)
+    return (b * xg).astype(wt), c.astype(wt)
+
+
+def _conv_out(cfg: DecoderConfig, p, x, c, y):
+    """``(C * y) W_out`` and the residual: returns (h, m) as
+    ``_attn_out`` does."""
+    wt = p["conv_out"].dtype
+    h = x + _dot((c.astype(jnp.float32) * y).astype(wt), p["conv_out"])
+    return h, rms_norm(h, p["pre_mlp_norm"], cfg.rms_norm_eps).astype(wt)
+
+
+def _conv_dense_tail(cfg: DecoderConfig, p, x, c, y):
+    h, m = _conv_out(cfg, p, x, c, y)
+    return _mlp_out(cfg, p, h, swiglu(m, p["gate_up"], p["down"]))
+
+
 def _route(cfg: DecoderConfig, p, m):
     rows = m.reshape(-1, m.shape[-1])
     idx, w, _ = sigmoid_route(rows, p["router"], p["router_bias"],
                               cfg.num_experts_per_tok, cfg.route_norm,
-                              cfg.route_scale)
+                              cfg.route_scale, cfg.route_norm_eps)
     return idx, w
 
 
@@ -391,7 +522,10 @@ def decoder_block(cfg: DecoderConfig, layer: int, p: dict, x, positions,
     """THE block: layer ``layer`` of type ``cfg.layer_types[layer]`` on
     rows ``x`` [B, L, d] float32 at ``positions`` [B, L], attending
     through ``view`` (a paged view: it appends this call's K/V, or its
-    latent row, and masks by its layer's window). ``mla`` records a span
+    latent row, and masks by its layer's window; a ``conv`` layer mixes
+    through it with the slot's stored rows instead, ``view.mix``, under
+    a span ``conv`` with ``conv.project``, ``conv.mix`` and
+    ``conv.out``). ``mla`` records a span
     ``mla`` a call with ``mla.project`` (down-projections, norms, RoPE,
     absorption), ``mla.attend`` (append and launch) and ``mla.out`` (on a
     dense layer the SwiGLU rides in its program). ``counters`` (a dict holding ``acc``)
@@ -399,7 +533,7 @@ def decoder_block(cfg: DecoderConfig, layer: int, p: dict, x, positions,
     list) is handed (layer, view, positions, chosen experts) of every
     expert layer call, the arrays on the device. Returns the rows after
     the layer."""
-    sliding = cfg.layer_types[layer] == SLIDING
+    kind = cfg.layer_types[layer]
     if view.window != cfg.window_of(layer):
         raise ValueError(
             f"layer {layer} is {cfg.layer_types[layer]} but its cache "
@@ -409,23 +543,40 @@ def decoder_block(cfg: DecoderConfig, layer: int, p: dict, x, positions,
     mla = col if cfg.attention == "mla" else None   # who records ``mla*``
     depth = col.span_depth if col is not None else 0
     try:
-        if mla is not None:
-            mla.span_begin("mla", layer=layer)
-            mla.span_begin("mla.project")
-        q, k, v, gate = _jitted(_attn_in, cfg, sliding)(p, x, positions)
-        if mla is not None:
-            mla.span_end()
-            mla.span_begin("mla.attend")
-        attn = view.decode(Tensor(q), Tensor(k),
-                           None if v is None else Tensor(v), t).data
-        if mla is not None:
-            mla.span_end()
-            mla.span_begin("mla.out")
-        if not cfg.is_moe(layer):
-            return _jitted(_dense_tail, cfg)(p, x, attn, gate)
-        h, m = _jitted(_attn_out, cfg)(p, x, attn, gate)
-        if mla is not None:
-            mla.span_unwind(depth)
+        if kind == CONV:
+            if col is not None:
+                col.span_begin("conv", layer=layer)
+                col.span_begin("conv.project")
+            u, c = _jitted(_conv_in, cfg)(p, x)
+            if col is not None:
+                col.span_end()
+                col.span_begin("conv.mix")
+            y = view.mix(u, p["conv_taps"])
+            if col is not None:
+                col.span_end()
+                col.span_begin("conv.out")
+            if not cfg.is_moe(layer):
+                return _jitted(_conv_dense_tail, cfg)(p, x, c, y)
+            h, m = _jitted(_conv_out, cfg)(p, x, c, y)
+        else:
+            if mla is not None:
+                mla.span_begin("mla", layer=layer)
+                mla.span_begin("mla.project")
+            q, k, v, gate = _jitted(_attn_in, cfg, cfg.rotates(layer))(
+                p, x, positions)
+            if mla is not None:
+                mla.span_end()
+                mla.span_begin("mla.attend")
+            attn = view.decode(Tensor(q), Tensor(k),
+                               None if v is None else Tensor(v), t).data
+            if mla is not None:
+                mla.span_end()
+                mla.span_begin("mla.out")
+            if not cfg.is_moe(layer):
+                return _jitted(_dense_tail, cfg)(p, x, attn, gate)
+            h, m = _jitted(_attn_out, cfg)(p, x, attn, gate)
+        if col is not None:
+            col.span_unwind(depth)
         block_m = expert_row_block(m.shape[0] * m.shape[1],
                                    cfg.num_experts_per_tok, cfg.num_experts)
         if col is not None:
@@ -449,7 +600,7 @@ def decoder_block(cfg: DecoderConfig, layer: int, p: dict, x, positions,
 # parameters
 # ---------------------------------------------------------------------
 
-def _draw_layer(cfg: DecoderConfig, moe: bool, key) -> dict:
+def _draw_layer(cfg: DecoderConfig, conv: bool, moe: bool, key) -> dict:
     d, nh, nkv, hd = (cfg.hidden_size, cfg.num_attention_heads,
                       cfg.num_key_value_heads, cfg.head_dim)
     wt = jnp.dtype(cfg.weight_dtype)
@@ -478,6 +629,19 @@ def _draw_layer(cfg: DecoderConfig, moe: bool, key) -> dict:
              "kv_b_k": jnp.swapaxes(matrix(nh, rank, nope), 1, 2),
              "kv_b_v": matrix(nh, rank, vd),
              "o": matrix(nh * vd, d)}
+    elif conv:
+        # [B | C | x] in that order; the taps as published ([d, 1, K],
+        # the middle axis dropped): column K - 1 weighs the row itself
+        taps = jax.random.normal(next(keys), (d, cfg.conv_L_cache),
+                                 jnp.float32) / math.sqrt(cfg.conv_L_cache)
+        p = {"in_norm": gain(d), "pre_mlp_norm": gain(d),
+             "conv_in": matrix(d, 3 * d), "conv_taps": taps.astype(wt),
+             "conv_out": matrix(d, d)}
+    elif not cfg.attn_gate:      # gqa without a gate, in pre_norm
+        p = {"in_norm": gain(d), "q_norm": gain(hd), "k_norm": gain(hd),
+             "pre_mlp_norm": gain(d),
+             "qkv": matrix(d, (nh + 2 * nkv) * hd),
+             "o": matrix(nh * hd, d)}
     else:
         p = {"in_norm": gain(d), "q_norm": gain(hd), "k_norm": gain(hd),
              "post_attn_norm": gain(d), "pre_mlp_norm": gain(d),
@@ -492,8 +656,8 @@ def _draw_layer(cfg: DecoderConfig, moe: bool, key) -> dict:
     p["router"] = matrix(d, cfg.num_experts)
     # small and non-zero, so that choosing with it and weighing
     # without it is exercised
-    p["router_bias"] = 0.01 * jax.random.normal(
-        next(keys), (cfg.num_experts,), jnp.float32)
+    p["router_bias"] = (0.01 if cfg.use_expert_bias else 0.0) \
+        * jax.random.normal(next(keys), (cfg.num_experts,), jnp.float32)
     if cfg.num_shared_experts:
         p["shared_gate_up"] = matrix(d, 2 * im * cfg.num_shared_experts)
         p["shared_down"] = matrix(im * cfg.num_shared_experts, d)
@@ -515,21 +679,31 @@ class DecoderCore:
         self.config = cfg = config
         self.embed_dim = cfg.hidden_size
         self.num_heads = cfg.num_attention_heads
-        self.num_kv_heads = cfg.num_key_value_heads
+        self.num_kv_heads = cfg.num_key_value_heads // cfg.kv_pack
         self.head_dim = cfg.kv_width
         # what ``PagedKVCache.for_model`` reads: ``mla`` caches one
-        # latent row a position, whose leading columns are the value
+        # latent row a position, whose leading columns are the value;
+        # packed heads (``kv_pack``) attend at the head's scale, not
+        # the stored row's
         self.latent_cache = {"v_dim": cfg.kv_lora_rank,
                              "sm_scale": cfg.attn_scale} \
-            if cfg.attention == "mla" else None
+            if cfg.attention == "mla" else \
+            {"sm_scale": cfg.attn_scale} if cfg.kv_pack > 1 else None
+        # a conv layer holds no K/V: its last kernel - 1 input rows a
+        # slot live in the cache's state store
+        self.layer_state = tuple(
+            (cfg.conv_L_cache - 1, cfg.hidden_size) if t == CONV else None
+            for t in cfg.layer_types) \
+            if CONV in cfg.layer_types else None
         self.num_layers = cfg.num_layers
         self.layer_windows = tuple(cfg.window_of(i)
                                    for i in range(cfg.num_layers))
         keys = jax.random.split(jax.random.PRNGKey(int(seed)),
                                 cfg.num_layers)
-        draw = jax.jit(_draw_layer, static_argnums=(0, 1))
-        self.params: List[dict] = [draw(cfg, cfg.is_moe(i), keys[i])
-                                   for i in range(cfg.num_layers)]
+        draw = jax.jit(_draw_layer, static_argnums=(0, 1, 2))
+        self.params: List[dict] = [
+            draw(cfg, cfg.layer_types[i] == CONV, cfg.is_moe(i), keys[i])
+            for i in range(cfg.num_layers)]
         self.collector = None      # PagedServingEngine keeps it current
         # a list here receives every expert layer call's (layer, view,
         # positions, chosen experts): the benchmark's probe compares

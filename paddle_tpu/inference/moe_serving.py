@@ -491,18 +491,19 @@ def expert_row_block(rows: int, top_k: int, num_experts: int) -> int:
 
 
 def sigmoid_route(m, router, bias, top_k: int, route_norm: bool,
-                  route_scale: float):
+                  route_scale: float, norm_eps: float = 1e-20):
     """Scores over ALL experts in float32: ``s = sigmoid(m @ router)``,
     choose ``top_k`` of ``s + bias`` (the bias picks, it never weighs),
-    weigh with ``s`` normalised over the chosen (``route_norm``) times
-    ``route_scale``. Returns (expert ids [R, k] int32, weights [R, k]
-    float32, scores [R, E] float32)."""
+    weigh with ``s`` normalised over the chosen plus ``norm_eps``, the
+    architecture's own (``route_norm``), times ``route_scale``. Returns
+    (expert ids [R, k] int32, weights [R, k] float32, scores [R, E]
+    float32)."""
     logits = jnp.dot(m, router, preferred_element_type=jnp.float32)
     s = jax.nn.sigmoid(logits.astype(jnp.float32))
     _, idx = jax.lax.top_k(s + bias[None, :], top_k)
     w = jnp.take_along_axis(s, idx, axis=-1)
     if route_norm:
-        w = w / (jnp.sum(w, -1, keepdims=True) + 1e-20)
+        w = w / (jnp.sum(w, -1, keepdims=True) + norm_eps)
     return idx.astype(jnp.int32), w * route_scale, s
 
 

@@ -14,6 +14,19 @@ attention) is [num_blocks, 1, 1, block_size, width]: ONE row a position,
 which is the key and whose leading ``v_dim`` columns are the value;
 everything host-side below is the same for both forms.
 
+THE STATE STORE (``layer_state``): a layer may hold NO K/V at all (a gated
+short convolution: its memory is the last few rows of its own input,
+not a context). Such a layer gets no pool; the manager keeps, for each
+state layer, ONE array [max_seqs, rows, width] of per-SLOT rows beside
+the pools. It is zeroed when a slot is (re)admitted (``ensure`` on an
+empty slot), dead with the slot, carried by ``snapshot`` / ``restore``,
+and never paged, hashed or shared: what would need a state at a block
+boundary (prefix adoption, ``fork``, slices, ``truncate``) and ``mp`` > 1
+are refused by name. Every view hands a state layer its rows through
+``mix(u, taps)``: one body (``_conv_mix``) for decode rows, a prompt
+chunk and a packed batch, fed by index lists the view builds on the
+host from the layout it already holds.
+
 The cache layout is a PROTOCOL, not a tensor shape:
 ``FusedMultiTransformer.forward(..., caches=..., time_step=...)``
 accepts either dense per-layer Tensors or the `PagedLayerCache` views
@@ -458,6 +471,142 @@ def _pool_program(fn, *static):
     return jax.jit(functools.partial(fn, *static), donate_argnums=(0, 1))
 
 
+# -- the per-slot state store -----------------------------------------
+# A state layer's memory is the last ``n`` rows of its own input ``u``
+# (n = kernel - 1 of a causal depthwise convolution), a slot. One call's
+# rows and the stored rows are laid side by side (``src``: the store's
+# S * n rows, then the call's R rows); two index lists built on the host
+# say, for every row, where its j-th predecessor is (an earlier row of
+# the same segment, or the slot's stored row) and, for every slot, which
+# ``n`` rows of ``src`` are its last ones now (its own, where the call
+# did not touch it).
+
+def _conv_mix(state, u, taps, pred, keep):
+    """THE short-convolution mix, one body for every view: ``state``
+    [S, n, d] (the store), ``u`` [B, L, d] (the call's rows), ``taps``
+    [d, n + 1] (column n multiplies the row itself, column n - j its
+    j-th predecessor), ``pred`` [n, R] and ``keep`` [S, n] int32 into
+    ``src``. Returns (y [B, L, d] float32, the new store). Predecessors
+    are read in the STORE's type whether they come from the store or
+    from this call, so how a sequence is cut into calls changes no
+    number."""
+    S, n, d = state.shape
+    rows = u.reshape(-1, d).astype(state.dtype)
+    src = jnp.concatenate([state.reshape(S * n, d), rows])
+    tp = taps.astype(jnp.float32)
+    y = rows.astype(jnp.float32) * tp[:, n]
+    for j in range(n):
+        y = y + src[pred[j]].astype(jnp.float32) * tp[:, n - 1 - j]
+    return y.reshape(u.shape), src[keep]
+
+
+def _state_clear(states, kept):
+    """Zero the rows of the slots ``kept`` [S] bool leaves out, in
+    every state layer's store (a select, so that a NaN does not
+    survive its slot)."""
+    return tuple(jnp.where(kept[:, None, None], s, 0) for s in states)
+
+
+_conv_mix_program = jax.jit(_conv_mix)
+_state_clear_program = jax.jit(_state_clear)
+# what a plan counts of the segments that write (``slot_state`` gauge)
+_STATE_COUNTS = ("rows", "segments", "segments_carried", "prompt_segments",
+                 "prompt_segments_carried")
+
+
+class _StatePlan:
+    """One call's index lists for ``_conv_mix`` (host arithmetic, then
+    uploaded once for all state layers) and the host counts the
+    ``slot_state`` gauge takes."""
+
+    __slots__ = ("pred", "keep", "stats")
+
+    def __init__(self, S: int, n: int, R: int, groups, fresh):
+        """``groups``: (slots [G], first rows [G], L, writes [G] bool,
+        is_prompt) for G segments of ``L`` rows each; a segment that
+        does not write (a masked or empty decode row) reads its own
+        slot's rows and leaves them. ``fresh``: slots whose store is
+        about to be zeroed (their segment starts a sequence)."""
+        pred = np.zeros((n, R), np.int64)
+        keep = np.arange(S * n, dtype=np.int64).reshape(S, n)
+        stats = dict.fromkeys(_STATE_COUNTS, 0)
+        written = np.zeros(S, bool)
+        for slots, los, L, writes, is_prompt in groups:
+            slots = np.asarray(slots, np.int64)
+            los = np.asarray(los, np.int64)
+            writes = np.asarray(writes, bool)
+            back = np.arange(L)[None, :]                   # [1, L]
+            cols = (los[:, None] + back).reshape(-1)
+            for j in range(n):
+                b = back - (j + 1)
+                pred[j, cols] = np.where(
+                    b >= 0, S * n + los[:, None] + b,
+                    slots[:, None] * n + n + b).reshape(-1)
+            ws, wl = slots[writes], los[writes]
+            if written[ws].any() or np.unique(ws).shape[0] != ws.shape[0]:
+                raise AssertionError(
+                    f"state store: slot(s) {ws[written[ws]].tolist()} "
+                    f"are written by two segments of one call")
+            written[ws] = True
+            for m in range(n):
+                t = L - n + m
+                keep[ws, m] = S * n + wl + t if t >= 0 else ws * n + m + L
+            carried = int(ws.shape[0] - np.isin(ws, list(fresh)).sum())
+            stats["rows"] += int(ws.shape[0]) * L
+            stats["segments"] += int(ws.shape[0])
+            stats["segments_carried"] += carried
+            if is_prompt:
+                stats["prompt_segments"] += int(ws.shape[0])
+                stats["prompt_segments_carried"] += carried
+        self.pred = jnp.asarray(pred.astype(np.int32))
+        self.keep = jnp.asarray(keep.astype(np.int32))
+        self.stats = stats
+
+
+class _StateMixer:
+    """What every view adds for a STATE layer: ``mix``. A view is
+    bound to its layer's pool (``_pi``) or its layer's store (``_si``),
+    never both; ``_state_groups`` is the view's own (see
+    ``_StatePlan``)."""
+
+    def _bind(self, cache: "PagedKVCache", layer: int, shard: int):
+        self._cache, self._layer, self._shard = cache, layer, int(shard)
+        self._pi = cache.pool_index(layer, self._shard)
+        self._si = cache.state_index(layer)
+
+    def _need_pool(self):
+        if self._pi is None:
+            raise ValueError(
+                f"layer {self._layer} holds no K/V (a state layer of "
+                f"the state store): hand it rows through mix()")
+
+    def _plan_state(self, B: int, L: int) -> _StatePlan:
+        c = self._cache
+        return _StatePlan(c.max_seqs, c.state[0].shape[1], B * L,
+                          self._state_groups(B, L), c._state_fresh)
+
+    def mix(self, u, taps):
+        """``u`` [B, L, d] (a jax array, the call's rows in the view's
+        own row order) through this layer's short convolution with
+        ``taps`` [d, rows + 1]: returns y [B, L, d] float32 and leaves
+        each written slot's last rows in the store."""
+        c = self._cache
+        if self._si is None:
+            raise ValueError(f"layer {self._layer} is a K/V layer: it "
+                             f"has no rows in the state store")
+        if not _trace_clean():
+            raise RuntimeError("the state store is not an operand of "
+                               "a step program")
+        plan = self._plan_state(int(u.shape[0]), int(u.shape[1]))
+        if self._si == 0:
+            for k, v in plan.stats.items():
+                c._state_seen[k] += v
+        c._flush_state_resets()
+        y, c.state[self._si] = _conv_mix_program(
+            c.state[self._si], u, taps, plan.pred, plan.keep)
+        return y
+
+
 def _visible(kpos, qpos, window):
     """The attention mask every fallback path shares: key ``kpos`` is
     visible to query ``qpos`` causally and, on a layer with a sliding
@@ -582,8 +731,8 @@ class _LentStep:
             self.holder, rows = c, tuple(x.shape[:2])
             self.names = ("_bt_cached",)
         self.key = (type(view).__name__, rows, c.layer_windows)
-        self.writes = len(views) * np.array(view._moves(*x.shape[:2]),
-                                            np.int64)
+        self.writes = len(c.kv_layers) * np.array(
+            view._moves(*x.shape[:2]), np.int64)
 
     def _pools(self):
         c = self.cache
@@ -632,7 +781,8 @@ def model_call(model, x, views, t, collector=None):
     call, and a shape whose first trace failed, runs per op as before,
     and the ``step_program`` gauge says why (one sample a call:
     ``captured``, ``programs`` compiled so far, and on a 0 the
-    reason)."""
+    reason). A cache with a state store also gives the ``slot_state``
+    gauge its sample here (``take_state_stats``)."""
     if not _device.use_pallas_kernels():
         out, why = None, "no_kernel"
     elif views[0]._cache.mp != 1:
@@ -650,10 +800,13 @@ def model_call(model, x, views, t, collector=None):
         if why is not None:
             series[why] = 1
         collector.gauge("step_program", series)
+        if views[0]._cache.state_layers:
+            collector.gauge("slot_state",
+                            views[0]._cache.take_state_stats())
     return out[0]
 
 
-class PagedLayerCache:
+class PagedLayerCache(_StateMixer):
     """One layer's view of the paged cache — the object that rides in
     the ``caches=`` list of FusedMultiTransformer.forward. Duck-typed
     protocol: ``is_paged`` marks it, ``decode(q, k, v, t)`` appends one
@@ -664,10 +817,16 @@ class PagedLayerCache:
 
     def __init__(self, cache: "PagedKVCache", layer: int,
                  shard: int = 0):
-        self._cache = cache
-        self._layer = layer
-        self._shard = int(shard)
-        self._pi = cache.pool_index(layer, self._shard)
+        self._bind(cache, layer, shard)
+
+    def _state_groups(self, B: int, L: int):
+        # one segment a batch row; a row whose table presents as trash
+        # (an empty slot, one mid-prefill) leaves its slot's rows alone
+        c = self._cache
+        live = c.block_tables[:, 0] != 0
+        if c._decode_masked is not None:
+            live &= ~c._decode_masked
+        return [(np.arange(B), np.arange(B) * L, L, live, False)]
 
     def shard(self, s: int) -> "PagedLayerCache":
         """This layer's view of mp shard ``s`` — the per-shard cache
@@ -722,6 +881,7 @@ class PagedLayerCache:
         ragged decode uses, so paged and dense CPU decode are
         bit-identical when page capacity == dense max_len."""
         import jax as _jax
+        self._need_pool()
         c = self._cache
         B, L = q.shape[0], q.shape[1]
         if B != c.max_seqs:
@@ -807,7 +967,7 @@ class PagedLayerCache:
                      (out,), op_name="spec_unfold")
 
 
-class PagedPrefillView:
+class PagedPrefillView(_StateMixer):
     """One layer's CHUNKED-PREFILL view of a single slot — the object
     that rides in ``caches=`` for a batch-1 chunk call
     (``PagedKVCache.prefill_views``). Same duck-typed protocol as
@@ -831,15 +991,15 @@ class PagedPrefillView:
 
     def __init__(self, cache: "PagedKVCache", layer: int, slot: int,
                  write_start: int = 0, shard: int = 0):
-        self._cache = cache
-        self._layer = layer
+        self._bind(cache, layer, shard)
         self._slot = slot
-        self._shard = int(shard)
-        self._pi = cache.pool_index(layer, self._shard)
         # positions below write_start are an adopted (possibly shared)
         # prefix whose pages already hold these exact K/V — recomputed
         # rows there attend but do not write (see _append_rows)
         self._write_start = int(write_start)
+
+    def _state_groups(self, B: int, L: int):
+        return [([self._slot], [0], L, [True], True)]
 
     def shard(self, s: int) -> "PagedPrefillView":
         """This (layer, slot) chunk view of mp shard ``s``."""
@@ -878,6 +1038,7 @@ class PagedPrefillView:
         ``ensure(slot, t[0]+C, write_from=t[0], start_block=...)`` —
         every write position covered and COW-split."""
         import jax as _jax
+        self._need_pool()
         c = self._cache
         B, C = q.shape[0], q.shape[1]
         if B != 1:
@@ -933,7 +1094,8 @@ class _RaggedLayout:
     __slots__ = ("segs", "q_lens", "pg_ids", "route", "kv_lens",
                  "bt_all", "total_rows", "n_pages",
                  "blk_np", "off_np", "pos_np", "pg_ids_np", "pg_slot_np",
-                 "kv_lens_np", "_pos", "_cache")
+                 "kv_lens_np", "_pos", "_cache", "decode_live",
+                 "state_plan")
 
     def __init__(self, cache: "PagedKVCache", segments):
         bs = cache.block_size
@@ -943,6 +1105,10 @@ class _RaggedLayout:
                 cache._decode_masked.any():
             masked_tbl = tbl.copy()
             masked_tbl[cache._decode_masked] = 0
+        # the decode rows that are some slot's: what the state store
+        # lets a decode segment write (the others present trash tables)
+        self.decode_live = masked_tbl[:, 0] != 0
+        self.state_plan: Optional[_StatePlan] = None
         self.segs: List[tuple] = []
         q_lens: List[int] = []
         kv_lens: List[int] = []
@@ -1106,7 +1272,7 @@ class _RaggedLayout:
                           c.block_size, g=c.num_heads // c.num_kv_heads)
 
 
-class PagedRaggedView:
+class PagedRaggedView(_StateMixer):
     """One layer's MIXED-BATCH view: the object that rides in
     ``caches=`` for the scheduler's ragged step — prefill chunks of
     several slots AND the fused decode rows packed into one
@@ -1134,11 +1300,31 @@ class PagedRaggedView:
 
     def __init__(self, cache: "PagedKVCache", layer: int,
                  layout: _RaggedLayout, shard: int = 0):
-        self._cache = cache
-        self._layer = layer
-        self._shard = int(shard)
-        self._pi = cache.pool_index(layer, self._shard)
+        self._bind(cache, layer, shard)
         self._layout = layout
+
+    def _plan_state(self, B: int, L: int) -> _StatePlan:
+        # built once a layout: every state layer of the call shares it
+        lay = self._layout
+        if lay.state_plan is None:
+            lay.state_plan = super()._plan_state(B, L)
+        return lay.state_plan
+
+    def _state_groups(self, B: int, L: int):
+        lay = self._layout
+        if (B, L) != (1, lay.total_rows):
+            raise ValueError(f"ragged call expects [1, {lay.total_rows}, "
+                             f"d], got [{B}, {L}, d]")
+        groups = []
+        for seg in lay.segs:
+            if seg[0] == "prefill":
+                groups.append(([seg[3]], [seg[1]], seg[2] - seg[1],
+                               [True], True))
+            else:
+                n = seg[3].shape[0]
+                groups.append((np.arange(n), seg[1] + np.arange(n) * seg[4],
+                               seg[4], lay.decode_live, False))
+        return groups
 
     def shard(self, s: int) -> "PagedRaggedView":
         """This layer's ragged view of mp shard ``s`` — the SAME
@@ -1181,6 +1367,7 @@ class PagedRaggedView:
         PRECONDITION: every segment's write range is covered and
         COW-split (the scheduler's planning pass ensure()s chunk by
         chunk) and the decode mask is set."""
+        self._need_pool()
         c = self._cache
         lay = self._layout
         if q.shape[0] != 1 or q.shape[1] != lay.total_rows:
@@ -1264,9 +1451,46 @@ class PagedKVCache:
                  mp: int = 1, shard_devices=None,
                  num_kv_heads: Optional[int] = None,
                  layer_windows=None, v_dim: Optional[int] = None,
-                 sm_scale: Optional[float] = None):
+                 sm_scale: Optional[float] = None, layer_state=None):
         import paddle_tpu as paddle
         self.num_layers = int(num_layers)
+        # THE STATE STORE (``layer_state[i]``: None for a K/V layer, or
+        # ``(rows, width)`` for a layer that holds NO K/V and keeps its
+        # last ``rows`` input rows of ``width`` a slot instead; the
+        # module docstring has the rules). ``kv_layers`` are the layers
+        # that get a pool, ``state_layers`` those that get a store;
+        # views, windows and ``num_layers`` count every layer.
+        layer_state = tuple(layer_state or (None,) * self.num_layers)
+        if len(layer_state) != self.num_layers:
+            raise ValueError(
+                f"layer_state has {len(layer_state)} entries for "
+                f"{self.num_layers} layers")
+        self.layer_state = tuple(
+            None if s is None else (int(s[0]), int(s[1]))
+            for s in layer_state)
+        self.kv_layers = tuple(i for i, s in enumerate(self.layer_state)
+                               if s is None)
+        self.state_layers = tuple(i for i, s in enumerate(self.layer_state)
+                                  if s is not None)
+        if self.state_layers:
+            forms = {self.layer_state[i] for i in self.state_layers}
+            if len(forms) != 1 or min(forms.pop()) < 1:
+                raise ValueError(
+                    f"the state store holds one (rows, width) >= 1 for "
+                    f"all its layers, got {self.layer_state}")
+            if not self.kv_layers:
+                raise ValueError(
+                    "a paged cache needs a layer that holds K/V: the "
+                    "block tables are its slots' lengths too")
+            if prefix_cache:
+                raise ValueError(
+                    "prefix_cache with a state store: an adopted prefix "
+                    "has no stored state to start from (the state store "
+                    "keeps a slot's LAST rows, none at block boundaries)")
+            if int(mp) != 1:
+                raise ValueError(
+                    "the state store is not split over mp shards: "
+                    "serve a model with state layers at mp 1")
         self.num_heads = int(num_heads)
         # GROUPED KV HEADS: the pool stores ``num_kv_heads`` heads a
         # position (== num_heads unless the model shares each kv head
@@ -1407,7 +1631,7 @@ class PagedKVCache:
             self._place(paddle.zeros(
                 [self.num_blocks, self.planes, Hs, self.block_size,
                  self.head_dim], dtype=dtype), pi)
-            for pi in range(self.num_layers * self.mp)]
+            for pi in range(len(self.kv_layers) * self.mp)]
         # per-page dequantization scales (int8 pools only):
         # [num_blocks, 2, heads/mp, block_size] float32 per
         # layer x shard — zero-init dequantizes to exact zeros,
@@ -1416,8 +1640,20 @@ class PagedKVCache:
             self._place(paddle.zeros(
                 [self.num_blocks, 2, Hs, self.block_size],
                 dtype="float32"), pi)
-            for pi in range(self.num_layers * self.mp)] \
+            for pi in range(len(self.kv_layers) * self.mp)] \
             if self.quantized else None
+        # the state store: one [max_seqs, rows, width] array a state
+        # layer, in the pool's type (int8 pools: the rows stay float32),
+        # zeros until written; ``_state_fresh`` are the slots admitted
+        # since the last model call, whose rows the next ``mix`` zeroes
+        # first (``_flush_state_resets``)
+        self.state: List = [
+            jnp.zeros((self.max_seqs,) + self.layer_state[i],
+                      jnp.float32 if self.quantized
+                      else self.pools[0].data.dtype)
+            for i in self.state_layers]
+        self._state_fresh: set = set()
+        self._state_seen = dict.fromkeys(_STATE_COUNTS + ("slots_reset",), 0)
         # all entries at the trash block until allocated
         self.block_tables = np.zeros(
             (self.max_seqs, self.max_blocks_per_seq), np.int32)
@@ -1468,6 +1704,7 @@ class PagedKVCache:
                    shard_devices=getattr(model, "shard_devices", None),
                    num_kv_heads=getattr(model, "num_kv_heads", None),
                    layer_windows=getattr(model, "layer_windows", None),
+                   layer_state=getattr(model, "layer_state", None),
                    **(getattr(model, "latent_cache", None) or {}))
 
     def _place(self, t: Tensor, pi: int) -> Tensor:
@@ -1493,21 +1730,74 @@ class PagedKVCache:
         return self.num_kv_heads // self.mp
 
     @property
-    def latent(self) -> Optional[Tuple[int, float]]:
-        """``(v_dim, sm_scale)`` of a latent pool, None of a K/V one:
-        what ``_attend`` keys the launch by."""
-        return None if self.v_dim is None else (self.v_dim, self.sm_scale)
+    def latent(self) -> Optional[Tuple[Optional[int], float]]:
+        """``(v_dim, sm_scale)`` of a latent pool, ``(None, sm_scale)``
+        of a K/V pool whose attention scale is not its stored width's
+        (two heads a lane row), None of a plain K/V one: what
+        ``_attend`` keys the launch by."""
+        return None if self.sm_scale is None \
+            else (self.v_dim, self.sm_scale)
 
     def _latent_geometry(self) -> dict:
         """The keys a latent pool adds to a snapshot's or a slice's
         geometry (a K/V pool adds none: its records read as before)."""
-        return {} if self.v_dim is None else \
-            {"v_dim": self.v_dim, "sm_scale": self.sm_scale}
+        out = {} if self.v_dim is None else {"v_dim": self.v_dim}
+        if self.sm_scale is not None:
+            out["sm_scale"] = self.sm_scale
+        return out
 
-    def pool_index(self, layer: int, shard: int = 0) -> int:
+    def pool_index(self, layer: int, shard: int = 0) -> Optional[int]:
         """Index of (layer, shard)'s entry in the flat ``pools`` /
-        ``scales`` lists."""
-        return layer * self.mp + shard
+        ``scales`` lists; None for a state layer, which has none."""
+        if self.layer_state[layer] is not None:
+            return None
+        return self.kv_layers.index(layer) * self.mp + shard
+
+    def state_index(self, layer: int) -> Optional[int]:
+        """Index of ``layer``'s array in ``state``; None for a K/V
+        layer."""
+        if self.layer_state[layer] is None:
+            return None
+        return self.state_layers.index(layer)
+
+    def state_bytes(self) -> int:
+        """Bytes of the state store: every state layer's rows of every
+        slot, live or not (it is allocated whole)."""
+        return sum(int(np.prod(a.shape)) * a.dtype.itemsize
+                   for a in self.state)
+
+    def _flush_state_resets(self) -> None:
+        """Zero the rows of the slots admitted since the last call, in
+        every state layer, in one program (``mix`` calls this first; so
+        does ``snapshot``)."""
+        if not self._state_fresh:
+            return
+        kept = np.ones(self.max_seqs, bool)
+        kept[sorted(self._state_fresh)] = False
+        self._state_seen["slots_reset"] += len(self._state_fresh)
+        self._state_fresh.clear()
+        self.state[:] = _state_clear_program(tuple(self.state),
+                                             jnp.asarray(kept))
+
+    def take_state_stats(self) -> dict:
+        """What the state store saw since the last call, and reset:
+        host counts for the collector's ``slot_state`` gauge (one
+        sample a model call). ``rows`` written through ``mix`` a state
+        layer, in ``segments`` (a prompt chunk, a decode row), of which
+        ``segments_carried`` began from stored rows (not a fresh
+        slot's zeros); the same two for prompt chunks alone;
+        ``slots_reset``; and the store's ``state_bytes``."""
+        out = dict(self._state_seen, state_bytes=self.state_bytes())
+        for k in self._state_seen:
+            self._state_seen[k] = 0
+        return out
+
+    def _refuse_with_state(self, what: str) -> None:
+        if self.state_layers:
+            raise ValueError(
+                f"{what} with a state store: the state store keeps each "
+                f"slot's LAST rows only, so there is no state to share, "
+                f"ship or roll back to at a block boundary")
 
     def _write_pool(self, pi: int, fn, static, *args, pages: int,
                     rows: int = 0) -> Tuple[Tensor, Optional[Tensor]]:
@@ -1546,7 +1836,7 @@ class PagedKVCache:
         the collector's ``pool_write`` gauge."""
         pages, rows = (int(n) for n in self._written)
         self._written[:] = 0
-        per_page = (self.kv_bytes_per_token() // self.num_layers
+        per_page = (self.kv_bytes_per_token() // len(self.kv_layers)
                     * self.block_size)
         return {"pages_written": pages, "rows_written": rows,
                 "pool_bytes_written": pages * per_page,
@@ -1627,7 +1917,7 @@ class PagedKVCache:
         if self.quantized:
             per_head += self.scales[0].data.dtype.itemsize
         return int(self.planes * self.kv_heads_per_shard * per_head
-                   * self.num_layers)
+                   * len(self.kv_layers))
 
     # -- tenant accounting --------------------------------------------
     def _charge(self, slot: int, delta: int) -> None:
@@ -1903,6 +2193,7 @@ class PagedKVCache:
         way — only payload rows are elided — and ``restore(...,
         base=...)`` reconstitutes the full pool."""
         a = self.allocator
+        self._flush_state_resets()      # the store as a reader sees it
         cached_order = [int(b) for b in a._cached]
         keep = sorted({b for b in range(1, self.num_blocks)
                        if a.refcount[b] > 0} | set(cached_order))
@@ -1923,6 +2214,9 @@ class PagedKVCache:
             # restore(mp=...) re-slices for any target width
             "mp": self.mp,
             **self._latent_geometry(),
+            **({"layer_state": [None if s is None else list(s)
+                                for s in self.layer_state]}
+               if self.state_layers else {}),
         }
         clean = set()
         if base is not None:
@@ -1948,14 +2242,14 @@ class PagedKVCache:
             # the canonical bytes, identical across mesh widths
             arrs = [np.concatenate(
                 arrs[i * self.mp:(i + 1) * self.mp], axis=2)
-                for i in range(self.num_layers)]
+                for i in range(len(self.kv_layers))]
         if dirty:
             # one fancy-index gather per layer, not a Python loop per
             # block — snapshots sit on the serving hot path
             payload = np.stack([arr[dirty] for arr in arrs],
                                axis=1)                 # [n, L, 2, H, bs, D]
         else:
-            payload = np.zeros((0, self.num_layers, self.planes,
+            payload = np.zeros((0, len(self.kv_layers), self.planes,
                                 self.num_kv_heads, self.block_size,
                                 self.head_dim), arrs[0].dtype)
         scale_payload = None
@@ -1969,13 +2263,13 @@ class PagedKVCache:
             if self.mp > 1:
                 sarrs = [np.concatenate(
                     sarrs[i * self.mp:(i + 1) * self.mp], axis=2)
-                    for i in range(self.num_layers)]
+                    for i in range(len(self.kv_layers))]
             if dirty:
                 scale_payload = np.stack([a[dirty] for a in sarrs],
                                          axis=1)   # [n, L, 2, H, bs]
             else:
                 scale_payload = np.zeros(
-                    (0, self.num_layers, 2, self.num_kv_heads,
+                    (0, len(self.kv_layers), 2, self.num_kv_heads,
                      self.block_size), np.float32)
         return {
             "kind": "paged_kv_cache",
@@ -1997,6 +2291,10 @@ class PagedKVCache:
             "base_blocks": sorted(int(b) for b in clean),
             **({"scale_payload": scale_payload}
                if scale_payload is not None else {}),
+            # the state store rides whole (every slot's rows of every
+            # state layer: a few rows a slot), never as a delta
+            **({"state": [np.asarray(a) for a in self.state]}
+               if self.state_layers else {}),
         }
 
     @classmethod
@@ -2050,7 +2348,8 @@ class PagedKVCache:
                     mp=mp_t, shard_devices=shard_devices,
                     num_kv_heads=g.get("num_kv_heads"),
                     layer_windows=g.get("layer_windows"),
-                    v_dim=g.get("v_dim"), sm_scale=g.get("sm_scale"))
+                    v_dim=g.get("v_dim"), sm_scale=g.get("sm_scale"),
+                    layer_state=g.get("layer_state"))
         refcount = {int(b): int(n) for b, n in snap["refcount"].items()}
         cached = [int(b) for b in snap["cached_order"]]
         live = sorted(b for b, n in refcount.items() if n > 0)
@@ -2115,16 +2414,21 @@ class PagedKVCache:
             Hs = cache.kv_heads_per_shard
             spay = (np.asarray(snap["scale_payload"])[rows]
                     if cache.quantized else None)
-            for i in range(cache.num_layers):
+            for i in range(len(cache.kv_layers)):
                 for s in range(cache.mp):
                     # each target shard takes its head slice of the
                     # canonical page (the whole page at mp == 1)
                     heads = slice(s * Hs, (s + 1) * Hs)
                     cache._write_pool(
-                        cache.pool_index(i, s), _set_pages, (), ids,
+                        i * cache.mp + s, _set_pages, (), ids,
                         payload[:, i, :, heads],
                         None if spay is None else spay[:, i, :, heads],
                         pages=len(rows))
+        if cache.state_layers:
+            # slot numbers survive a restore at any ``num_blocks``
+            # (tables are rehomed, slots are not), so the rows do too
+            cache.state[:] = [jnp.asarray(a, cache.state[0].dtype)
+                              for a in snap["state"]]
         cache.peak_blocks_used = int(snap["peak_blocks_used"])
         cache._tables_dirty()
         cache.check_invariants(deep=True)
@@ -2201,6 +2505,10 @@ class PagedKVCache:
         have = self.seq_blocks[slot]
         if need > len(have):
             new = self.allocator.alloc(need - len(have))
+            if not have and self.state_layers:
+                # an empty slot's first pages: a request is (re)admitted
+                # here, and its state starts from zero
+                self._state_fresh.add(int(slot))
             self.block_tables[slot, len(have):need] = new
             have.extend(new)
             self._charge(slot, len(new))
@@ -2232,6 +2540,7 @@ class PagedKVCache:
         keep = self.blocks_needed(length)
         if keep >= len(have):
             return  # nothing past the boundary
+        self._refuse_with_state("truncate (a rejected draft's rollback)")
         drop = have[keep:]
         self.release_to_cache(drop)
         del have[keep:]
@@ -2274,6 +2583,7 @@ class PagedKVCache:
         divergent append splits it copy-on-write)."""
         if self.seq_blocks[dst]:
             raise ValueError(f"dst slot {dst} already allocated")
+        self._refuse_with_state("fork")
         shared = self.seq_blocks[src][:self.blocks_needed(length)]
         self.allocator.ref(shared)
         for b in shared:   # fresh share epoch for the content audit
@@ -2427,6 +2737,7 @@ class PagedKVCache:
         gather per layer, no allocator state: export is a pure read.
         Returns None when the slot holds no full indexed-identity
         block yet (nothing migratable)."""
+        self._refuse_with_state("export_slice")
         blocks = [int(b) for b in
                   self.seq_blocks[slot][:len(hashes)]]
         if not blocks:
@@ -2504,6 +2815,7 @@ class PagedKVCache:
         no prefix index to adopt into."""
         if slc.get("kind") != "kv_slice":
             raise ValueError(f"not a kv_slice: {slc.get('kind')!r}")
+        self._refuse_with_state("import_slice")
         if not self.prefix_cache:
             raise ValueError(
                 "import_slice needs prefix_cache=True — migrated "

@@ -152,7 +152,8 @@ def build_model_from_spec(spec: dict):
 
 
 def _build_decoder_model(spec: dict):
-    """``arch: "afmoe"`` or ``"joyai_llm_flash"``: the config-driven ``DecoderCore``
+    """``arch: "afmoe"``, ``"joyai_llm_flash"`` or ``"lfm2_moe"``: the
+    config-driven ``DecoderCore``
     (inference/decoder.py) behind a ``TokenServingModel`` with a final
     RMSNorm, an UNTIED head and the embedding multiplier. Core, head
     and final gains are drawn on the device from ``model_seed`` in the
@@ -168,8 +169,9 @@ def _build_decoder_model(spec: dict):
     if int(spec.get("mp", 1)) != 1:
         raise ValueError(
             f"arch {spec['arch']!r} serves on one chip (mp 1): afmoe's "
-            f"deployment splits experts, not heads, and a latent cache "
-            f"has one kv head")
+            f"deployment splits experts, not heads, a latent cache "
+            f"has one kv head, and the state store of conv layers is "
+            f"not split over shards")
     cfg = DecoderConfig.from_spec(spec)
     seed = int(spec.get("model_seed", 0))
     core = DecoderCore(cfg, seed=seed)

@@ -908,6 +908,9 @@ class PagedServingEngine:
         if n > self.max_batch:
             raise ValueError(
                 f"n={n} branches exceed max_batch={self.max_batch}")
+        if n > 1:
+            # the branches fork the prompt's pages, and its state
+            self.cache._refuse_with_state("fork (n > 1 samples a prompt)")
         ten = self._resolve_tenant(tenant_id)
         req = PagedRequest(self._next_rid, arr)
         self._next_rid += 1
@@ -2320,6 +2323,9 @@ class PagedServingEngine:
                 f"rollback of slot {slot} to {new_len} outside "
                 f"[1, {int(self.lens[slot])}]")
         rejected = int(self.lens[slot]) - new_len
+        if rejected > 0:
+            # rows past new_len advanced the state store's rows too
+            self.cache._refuse_with_state("rollback of accepted rows")
         # buffered inputs must reach the history BEFORE trimming it
         self._flush_history()
         self._requests[slot].truncate_history(new_len,

@@ -480,6 +480,13 @@ class SpeculativeEngine:
                  collector=None, monitor=None, ledger=None):
         if k < 0:
             raise ValueError("k must be >= 0")
+        if k > 0 and any(getattr(m.core, "layer_state", None)
+                         for m in (target, draft) if m is not None):
+            raise ValueError(
+                "k > 0 with a state store: a rejected draft would have "
+                "advanced the state store's rows of its slot, and the "
+                "state store keeps no earlier rows to roll back to; "
+                "serve a model with state layers at k 0")
         self.target = target
         self.k = int(k)
         self.draft = (draft if draft is not None else target) \

@@ -156,7 +156,8 @@ def pytest_sessionfinish(session, exitstatus):
 # lacks (expert counters, row lengths, a grouped-GEMM launch): around that
 # test, for those metrics, the run is completed with the planted data of
 # tests/benchmark_suite/planted_afmoe.py (or planted_latent.py: latent
-# widths and row lengths) before the REAL reader reads it.
+# widths and row lengths; planted_conv.py: layer kinds and the stored K/V
+# row) before the REAL reader reads it.
 
 @pytest.fixture(autouse=True)
 def _planted_run_for_the_reader_test(request, monkeypatch):
@@ -166,8 +167,9 @@ def _planted_run_for_the_reader_test(request, monkeypatch):
             "test_layer_metric_readers" or not isinstance(metric, dict):
         return
     from benchmark import cells
-    from tests.benchmark_suite import planted_afmoe, planted_latent
-    source = next((m for m in (planted_afmoe, planted_latent)
+    from tests.benchmark_suite import (planted_afmoe, planted_conv,
+                                       planted_latent)
+    source = next((m for m in (planted_afmoe, planted_latent, planted_conv)
                    if metric["name"] in m.PLANTED_VALUES), None)
     if source is None:
         return

@@ -789,6 +789,58 @@ class TestChipKernelInterpreted:
         if case == "length_zero_rows":
             assert np.all(out[0] == 0.0) and np.all(out[2:] == 0.0)
 
+    @pytest.mark.parametrize("dtype,tol", [(jnp.float32, 2e-5),
+                                           (jnp.bfloat16, BF16_TOL)])
+    @pytest.mark.parametrize("case", ["decode", "mixed", "page_boundary",
+                                      "length_zero_rows"])
+    def test_two_heads_of_64_a_pool_row(self, case, dtype, tol):
+        """Four query heads a KV head at ``hd`` 64, as ``lfm2_moe`` hands
+        the launch: the pool keeps TWO heads side by side in a 128-lane
+        row (half the heads, twice the width), a query head's operand is
+        zero in the other head's half and it takes its own half of the
+        output, at the head's scale. Against the reference over the same
+        K/V kept one head of 64 each."""
+        q_lens, kv_lens, tq, tkv, _, _, MB = self.CASES[case]
+        nkv, g, hd, bs = 4, 4, 64, 8
+        q, pool, bt, kvl = self._inputs(q_lens, kv_lens, nkv, g, MB, hd=hd,
+                                        dtype=dtype)
+        NB = pool.shape[0]
+        rows = jnp.transpose(pool.reshape(NB, 2, nkv // 2, 2, bs, hd),
+                             (0, 1, 2, 4, 3, 5)).reshape(
+            NB, 2, nkv // 2, bs, 2 * hd)
+        lane = jax.nn.one_hot((jnp.arange(nkv * g) // g) % 2, 2,
+                              dtype=jnp.float32)
+        wide = (q[:, :, None, :] * lane[None, :, :, None]).astype(
+            dtype).reshape(-1, nkv * g, 2 * hd)
+        out = pa_module.paged_attention_ragged(
+            wide, rows, bt, q_lens, kvl, sm_scale=hd ** -0.5, tile_q=tq,
+            tile_kv=tkv)
+        assert out.shape == (sum(q_lens), nkv * g, 2 * hd)
+        got = np.asarray(jnp.einsum(
+            "rhnd,hn->rhd",
+            out.astype(jnp.float32).reshape(-1, nkv * g, 2, hd), lane))
+        ref = np.asarray(paged_attention_ragged_reference(
+            q.astype(jnp.float32), pool, bt, q_lens, kvl))
+        assert np.isfinite(got).all()
+        np.testing.assert_allclose(got, ref, atol=tol, rtol=tol)
+
+    def test_plan_at_two_heads_of_64_a_row(self):
+        """busy-chat's launches: 32 query heads over 8 KV heads of 64,
+        stored as 4 rows of 128: eight query rows a stored row; a step is
+        given 2 MiB of pages, 1 024 positions of 2 048 B (mixed: what
+        fits VMEM)."""
+        assert pa_module.resolve_tile_q((1,) * 192, None, 8) == 1
+        decode = pa_module.launch_plan(192, 4, 8, 304, 16, 128, 2,
+                                       q_itemsize=2)
+        assert (decode.heads, decode.pages, decode.grid) \
+            == (4, 64, (192, 5))
+        assert decode.bytes_per_step == 64 * 16 * 2 * 4 * 128 * 2 == 2 ** 21
+        tile_q = pa_module.resolve_tile_q((1024,) + (1,) * 192, None, 8)
+        mixed = pa_module.launch_plan(
+            -(-1024 // tile_q) + 192, 4, tile_q * 8, 304, 16, 128, 2,
+            q_itemsize=2)
+        assert mixed.heads == 4 and 128 <= mixed.pages * 16 <= 1024
+
     @pytest.mark.parametrize("window", [None, 9])
     @pytest.mark.parametrize("case", sorted(CASES))
     def test_live_steps_is_the_work_lists_count(self, case, window):

@@ -194,3 +194,63 @@ def test_the_reading_finds_a_row_scatter(one_chip):
                    + [((1, SLOTS, h, HD), jnp.float32)] * 2
                    + [((SLOTS,), jnp.int32)] * 2, one_chip)
     assert len(_pool_sized_moves(hlo, pool)) == 2
+
+
+# the K/V pools of lfm2-24b.busy-chat: heads of 64, stored TWO a 128-lane
+# row (blocks, stored rows a position, table width, prompt chunk, slots)
+PACKED = (24000, 4, 304, 1024, 192)
+
+
+def _packed_programs(width=HD, rows=PACKED[1]):
+    nb, _, mb, chunk, slots = PACKED
+    bf16, i32 = jnp.bfloat16, jnp.int32
+
+    def kv(b, n):
+        return [((b, n, rows, width), bf16)] * 2
+
+    def ragged(n_rows, q_lens):
+        pages = 1 + sum(pc._pages_spanned(q, BS) for q in q_lens)
+        return (pc._ragged_append, (),
+                kv(1, n_rows) + [((pages,), i32), ((2, n_rows), i32)])
+
+    return {
+        "decode": (pc._append_rows, (BS, 1),
+                   kv(slots, 1) + [((slots,), i32), ((slots, mb), i32)]),
+        "chunk": (pc._append_rows, (BS, chunk),
+                  kv(1, chunk) + [((1,), i32), ((1, mb), i32),
+                                  ((1,), i32)]),
+        "ragged_decode": ragged(slots, (1,) * slots),
+        "ragged_mixed": ragged(chunk + slots, (chunk,) + (1,) * slots),
+        "ragged_two_chunks": ragged(chunk + slots, (chunk // 2,) * 2
+                                    + (1,) * slots),
+        "block_copy": (pc._block_copy, (), [((1,), i32)] * 2),
+    }
+
+
+@pytest.mark.parametrize("write", ["decode", "chunk", "ragged_decode",
+                                   "ragged_mixed", "ragged_two_chunks",
+                                   "block_copy"])
+def test_two_heads_a_row_write_moves_no_whole_pool(one_chip, write):
+    """Heads of 64 stored two a 128-lane row ([24 000, 2, 4, 16, 128]):
+    the same page-granular write on the donated pool, no move of the
+    pool's size."""
+    pool = (PACKED[0], 2, PACKED[1], BS, HD)
+    fn, static, rest = _packed_programs()[write]
+    hlo = _compile(pc._pool_program(fn, *static),
+                   [(pool, jnp.bfloat16), None] + rest, one_chip)
+    assert not _pool_sized_moves(hlo, pool), _pool_sized_moves(hlo, pool)
+    head = hlo.splitlines()[0]
+    assert "input_output_alias" in head and "(0, {}" in head, head[:300]
+
+
+@pytest.mark.parametrize("write", ["decode", "ragged_decode",
+                                   "ragged_mixed"])
+def test_a_pool_row_of_64_columns_would_copy_the_pool(one_chip, write):
+    """Why the heads are paired: at one head of 64 a row (half a lane
+    tile; [24 000, 2, 8, 16, 64]) the compiler lays the pool out with the
+    block axis minor and the same page write copies it there and back."""
+    pool = (PACKED[0], 2, 8, BS, 64)
+    fn, static, rest = _packed_programs(width=64, rows=8)[write]
+    hlo = _compile(pc._pool_program(fn, *static),
+                   [(pool, jnp.bfloat16), None] + rest, one_chip)
+    assert len(_pool_sized_moves(hlo, pool)) == 2

@@ -3,10 +3,11 @@
 
     python3 tools/probe_readings.py --workload trinity-large.mixed-queue --seed N
     python3 tools/probe_readings.py --workload joyai-flash.long-decode --seed N
+    python3 tools/probe_readings.py --workload lfm2-24b.busy-chat --seed N
 
 (any cell whose job has ``build_server``, ``probe_engine``,
 ``probed_positions``, ``compare_probe`` and ``LOGITS_TOL``: ``serve_arch``,
-``serve_latent``)
+``serve_latent``, ``serve_conv``)
 
 1. what the engine gives: its logits at the probed positions against the
    plain float32 reference (the comparison that decides a run's ``correct``);

@@ -67,6 +67,7 @@ _ROUND = ("round", "spec_round", "draft_roll", "embed", "verify", "step",
 _SUBMIT = ("submit", "submit.journal", "submit.embed", "submit.hash",
            "submit.admit")
 _MODEL = ("mla", "mla.project", "mla.attend", "mla.out",
+          "conv", "conv.project", "conv.mix", "conv.out",
           "moe", "moe.route", "moe.experts")     # inside the model phase
 
 
@@ -246,6 +247,19 @@ def summarize(trace: dict, tenant: str = None,
                      f"{pool / 1e6:.1f} MB pool "
                      f"({100.0 * moved / max(pool, 1):.3f} %): pages of "
                      f"a donated pool, written in place")
+    state = counters.get("slot_state", {})
+    if "segments" in state:
+        calls_, segs = state["segments"][:2]
+        chunks = state["prompt_segments"][1]
+        lines.append(f"  state store: {state['rows'][1] / calls_:g} "
+                     f"row(s) in {segs / calls_:g} segment(s) a model "
+                     f"call a state layer, "
+                     f"{state['segments_carried'][1]:g} of {segs:g} "
+                     f"carried from stored rows"
+                     f" ({state['prompt_segments_carried'][1]:g} of "
+                     f"{chunks:g} prompt chunks), "
+                     f"{state['slots_reset'][1]:g} slot(s) zeroed, "
+                     f"{state['state_bytes'][4] / 1e6:.2f} MB held")
     calls = counters.get("step_program", {})
     if "captured" in calls:
         n, captured = calls["captured"][:2]
