@@ -126,25 +126,56 @@ __all__ = ["BlockOOM", "BlockAllocator", "PagedKVCache",
            "chain_hash", "chain_block_hashes"]
 
 
+# A block's identity is hashed from its KEYS: whatever identifies each
+# of its rows to the caller. A caller that serves tokens hands token
+# ids (int32, 4 B a token); one that has only embedding rows hands the
+# rows (float32, 4 x d_model B a token). Which of the two a chain reads
+# follows from the dtype of what it is handed, nothing else. An id
+# chain's FIRST link is seeded with a tag where a row chain's starts
+# from b"" (as it always has: indexes of older snapshots stay valid),
+# so an id-keyed and a row-keyed block never share a hash.
+ID_CHAIN_TAG = b"token-ids/int32:"
+
+
+def _key_material(block_tokens) -> Tuple[np.ndarray, bytes]:
+    """(C-contiguous key material, the seed of a chain of its kind):
+    integer input is a run of token ids, anything else rows."""
+    arr = np.asarray(block_tokens)
+    if arr.dtype.kind in "iu":
+        return np.ascontiguousarray(arr, "<i4"), ID_CHAIN_TAG
+    return np.ascontiguousarray(arr, np.float32), b""
+
+
+def _link(parent: bytes, material: np.ndarray) -> bytes:
+    h = hashlib.blake2b(parent, digest_size=16)
+    h.update(material)          # read in place: no bytes copy
+    return h.digest()
+
+
 def chain_hash(parent: bytes, block_tokens) -> bytes:
     """One link of the block-identity chain: hash of the parent block's
-    chained hash + this block's token content (prompt rows are
-    embeddings here, so content identity is float32 byte identity)."""
-    arr = np.ascontiguousarray(np.asarray(block_tokens, np.float32))
-    return hashlib.blake2b(parent + arr.tobytes(),
-                           digest_size=16).digest()
+    chained hash + this block's keys (token ids where ``block_tokens``
+    is an integer array, else its rows' float32 bytes). ``parent``
+    b"" starts a chain."""
+    material, seed = _key_material(block_tokens)
+    return _link(parent or seed, material)
 
 
 def chain_block_hashes(tokens, block_size: int,
                        parent: bytes = b"") -> List[bytes]:
-    """Chained hashes for every FULL block of ``tokens`` ([T, ...]).
-    Partial trailing blocks are never indexed — their content is not
-    yet block-identity-stable (the owner keeps appending into them)."""
+    """Chained hashes for every FULL block of ``tokens``: ``[T]`` token
+    ids or ``[T, ...]`` rows. Partial trailing blocks are never indexed
+    — their content is not yet block-identity-stable (the owner keeps
+    appending into them)."""
     arr = np.asarray(tokens)
+    n_full = arr.shape[0] // block_size
+    if n_full == 0:
+        return []
+    material, seed = _key_material(arr[:n_full * block_size])
     out: List[bytes] = []
-    h = parent
-    for i in range(arr.shape[0] // block_size):
-        h = chain_hash(h, arr[i * block_size:(i + 1) * block_size])
+    h = parent or seed
+    for block in material.reshape(n_full, -1):
+        h = _link(h, block)
         out.append(h)
     return out
 

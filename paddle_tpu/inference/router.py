@@ -101,15 +101,15 @@ class WorkerError(RuntimeError):
 
 def token_chain_hashes(model, token_ids, block_size: int):
     """The chain-hash identity of a token stream as the POOLS compute
-    it (hashes are over embedding rows, the serving engines' history
-    unit): what a router's ``hash_fn`` should be, built from the same
-    ``TokenServingModel`` the workers serve (identical weights =>
-    identical hashes — the content address IS the embedded content).
+    it for a request submitted as tokens: the chain over the token ids
+    themselves (``PagedRequest.block_hashes`` with keys), so nothing
+    is embedded here. What a router's ``hash_fn`` should be; ``model``
+    (the ``TokenServingModel`` the workers serve) only bounds the ids.
     Returns one hash per FULL block."""
-    toks = [int(t) for t in np.asarray(token_ids).reshape(-1)]
-    if not toks:
-        return []
-    return chain_block_hashes(model.embed(toks), block_size)
+    toks = np.asarray(token_ids, np.int64).reshape(-1)
+    if toks.size and (toks.min() < 0 or toks.max() >= model.vocab_size):
+        raise ValueError("token id out of range")
+    return chain_block_hashes(toks, block_size)
 
 
 def build_model_from_spec(spec: dict):

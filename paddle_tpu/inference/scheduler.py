@@ -296,21 +296,49 @@ def chunked_prefill(model, cache: PagedKVCache, slot: int, rows,
     return pos, (out[:, -1] if out is not None else None)
 
 
+def _request_rows(history) -> np.ndarray:
+    """The [T, d_model] float32 array a request keeps as its history.
+    A float32 C-contiguous array that OWNS its buffer and that its
+    giver has frozen (``flags.writeable`` False: the token wrapper does
+    that to the rows it has just gathered, speculative.py ``submit``)
+    is the request's from here on, with no copy: nobody can write to
+    it through that reference any more. Anything else is copied (a
+    view, another dtype, and every array its caller can still write
+    to: a foreign caller may keep filling its buffer)."""
+    if isinstance(history, np.ndarray) and history.dtype == np.float32 \
+            and history.flags.owndata and history.flags.c_contiguous \
+            and not history.flags.writeable:
+        return history
+    return np.array(history, np.float32, copy=True)
+
+
 class PagedRequest:
     """One sequence. ``history`` is every embedding row the model has
     consumed for it (prompt rows + each decode-step input row): exactly
     what a re-prefill needs to rebuild the evicted cache. It is ONE
     growable [T, d_model] ndarray (amortized append), not a list of
     rows — re-admission previously paid an O(T) np.stack on every
-    prefill and a per-row list append on every history flush."""
+    prefill and a per-row list append on every history flush.
 
-    def __init__(self, rid: int, history: np.ndarray):
+    ``keys`` (optional) is one integer a history row — the token id the
+    row embeds — in a sequence the request READS and its giver keeps
+    APPENDING to as it steps the request (the token wrapper's stream,
+    ``_SpecSeq.toks``): it must cover every row of the history whenever
+    block hashes are asked for, and may run ahead of it. A request
+    that has keys hashes its blocks from them and never touches its
+    rows for that; one without (a caller with raw rows only) hashes
+    its rows. What the request was handed decides, nothing else."""
+
+    def __init__(self, rid: int, history: np.ndarray, keys=None):
         self.rid = rid
-        arr = np.array(history, np.float32, copy=True)
+        arr = _request_rows(history)
         if arr.ndim != 2:
             raise ValueError("history must be [T, d_model] rows")
         self._hist = arr
         self._len = arr.shape[0]
+        self.keys = None
+        if keys is not None:
+            self.bind_keys(keys)
         # chain hashes are append-only like the history: memoized and
         # extended in place, never recomputed across re-admissions
         self._hashes: List[bytes] = []
@@ -344,8 +372,34 @@ class PagedRequest:
         """[T, d_model] view of every consumed row (no copy)."""
         return self._hist[:self._len]
 
+    def bind_keys(self, keys) -> None:
+        """Read the keys from ``keys`` from now on (the stream of the
+        wrapper that steps this request: a fork, a branch and a
+        restored request are bound to THEIR stream, which continues the
+        one they were cut from)."""
+        if len(keys) < self._len:
+            raise ValueError(
+                f"keys cover {len(keys)} of the history's {self._len} "
+                f"rows")
+        self.keys = keys
+
+    @property
+    def key_bytes(self) -> int:
+        """Bytes of key material the chain reads a history row."""
+        return 4 if self.keys is not None else 4 * self._hist.shape[1]
+
+    def keys_held(self) -> Optional[np.ndarray]:
+        """The keys of the rows in the history, as an int32 array of
+        its own (a fork's and a snapshot's copy), or None."""
+        if self.keys is None:
+            return None
+        return np.array(self.keys[:self._len], np.int32)
+
     def append_history(self, row) -> None:
-        if self._len == self._hist.shape[0]:
+        # a frozen array (the gathered prompt rows, kept uncopied) is
+        # full by construction; the test holds for one rolled back too
+        if self._len == self._hist.shape[0] or \
+                not self._hist.flags.writeable:
             grown = np.empty((max(8, 2 * self._hist.shape[0]),
                               self._hist.shape[1]), np.float32)
             grown[:self._len] = self._hist[:self._len]
@@ -355,13 +409,24 @@ class PagedRequest:
 
     def block_hashes(self, block_size: int) -> List[bytes]:
         """Chained hashes of every FULL block of the history (the
-        prompt-hash identity the prefix cache indexes by)."""
+        identity the prefix cache indexes by), from the keys where the
+        request has them and from the rows where it has not."""
         n_full = self._len // block_size
         have = len(self._hashes)
         if have < n_full:
+            lo, hi = have * block_size, n_full * block_size
+            if self.keys is None:
+                material = self._hist[lo:hi]
+            elif len(self.keys) < hi:
+                raise ValueError(
+                    f"request {self.rid}: keys cover {len(self.keys)} "
+                    f"of {self._len} history rows; whoever steps a "
+                    f"request it submitted with keys appends a key "
+                    f"for every row it hands the engine")
+            else:
+                material = np.asarray(self.keys[lo:hi], np.int32)
             self._hashes.extend(chain_block_hashes(
-                self._hist[have * block_size:n_full * block_size],
-                block_size,
+                material, block_size,
                 parent=self._hashes[-1] if self._hashes else b""))
         return self._hashes[:n_full]
 
@@ -370,7 +435,9 @@ class PagedRequest:
         (speculative rejection): rows past it were consumed
         speculatively and rejected, so a re-prefill must not replay
         them. Memoized chain hashes past the new last full block are
-        dropped with them."""
+        dropped with them; the keys of the rows that stay are the
+        first ``length`` of the key sequence, whose owner never
+        appended the rejected ones."""
         if length < 0 or length > self._len:
             raise ValueError(
                 f"truncate to {length} outside [0, {self._len}]")
@@ -837,17 +904,19 @@ class PagedServingEngine:
         self._collector = col
         if hasattr(self.model, "collector"):
             self.model.collector = col
-        if col is not None and col.registry is not None and \
-                hasattr(self.model, "moe_metrics"):
-            # the expert layer's device-side counters, scraped cold when
-            # the collector is dumped (never on the hot path)
-            col.registry.attach("moe", self.model.moe_metrics)
+        if col is not None and col.registry is not None:
+            # scraped cold when the collector is dumped (never on the
+            # hot path): what the block-identity chain read, and the
+            # expert layer's device-side counters
+            col.registry.attach("prefix_cache", self.prefix_stats)
+            if hasattr(self.model, "moe_metrics"):
+                col.registry.attach("moe", self.model.moe_metrics)
 
     def submit(self, prompt, *, max_preemptions: Optional[int] = None,
                deadline_steps: Optional[int] = None,
                deadline_s: Optional[float] = None,
                tenant_id: Optional[str] = None,
-               n: int = 1) -> int:
+               n: int = 1, keys=None) -> int:
         """Queue a prompt ([T, d_model] embeddings) and try to admit.
         Returns the request id; if admission succeeded an
         ``(rid, slot, last_hidden)`` event is in ``admitted``. With
@@ -893,11 +962,22 @@ class PagedServingEngine:
         branch is a normal slot (growth COW-splits the written block;
         preemption degrades a branch to an independent re-prefill).
         Admission requires n free slots; the group is the admission
-        unit. The return value is the LEAD's rid == the group id."""
+        unit. The return value is the LEAD's rid == the group id.
+
+        ``keys``: one integer a row (the token id it embeds), for a
+        caller that has them: the request's block identities are then
+        hashed from the keys and the rows are never read for it. It is
+        a sequence the caller keeps appending to, a key for every row
+        it later hands ``step`` for this request (``PagedRequest``).
+        Without keys the rows themselves are hashed. ``prompt`` is
+        copied unless its giver froze it (``_request_rows``)."""
         arr = np.asarray(prompt.numpy() if hasattr(prompt, "numpy")
                          else prompt, np.float32)
         if arr.shape[0] == 0:
             raise ValueError("empty prompt")
+        if keys is not None and len(keys) < arr.shape[0]:
+            raise ValueError(
+                f"{len(keys)} keys for {arr.shape[0]} prompt rows")
         if arr.shape[0] > self.max_len:
             raise ValueError(
                 f"prompt length {arr.shape[0]} > per-seq page capacity "
@@ -912,7 +992,7 @@ class PagedServingEngine:
             # the branches fork the prompt's pages, and its state
             self.cache._refuse_with_state("fork (n > 1 samples a prompt)")
         ten = self._resolve_tenant(tenant_id)
-        req = PagedRequest(self._next_rid, arr)
+        req = PagedRequest(self._next_rid, arr, keys)
         self._next_rid += 1
         req.tenant = ten.tid
         if n > 1:
@@ -946,15 +1026,18 @@ class PagedServingEngine:
             # the prompt's chain hashes, memoized on the request: the
             # admission pass (now or rounds later) probes the prefix
             # index with them. Computed here so that their cost — a
-            # hash over every prompt row — is the submit's, and has a
-            # span of its own
+            # hash over the prompt's keys — is the submit's, and has a
+            # span of its own, which says what the chain read
+            read = self.prefix_stats.hashed_bytes
             if col is not None:
                 col.span_begin("submit.hash", rid=req.rid)
             try:
-                req.block_hashes(self.cache.block_size)
+                self._block_hashes(req)
             finally:
                 if col is not None:
-                    col.span_end()
+                    col.span_end(
+                        bytes=self.prefix_stats.hashed_bytes - read,
+                        keyed="rows" if req.keys is None else "ids")
         self._bump_vtime(ten.tid)
         depth = col.span_depth if col is not None else 0
         if col is not None:
@@ -969,6 +1052,28 @@ class PagedServingEngine:
         if col is not None:
             col.span_unwind(depth)
         return req.rid
+
+    def _block_hashes(self, req: PagedRequest) -> List[bytes]:
+        """``req.block_hashes`` at the pool's block size, with what the
+        chain had to read booked in ``prefix_stats``."""
+        bs = self.cache.block_size
+        have = len(req._hashes)
+        hashes = req.block_hashes(bs)
+        new = len(req._hashes) - have
+        self.prefix_stats.hashed_bytes += new * bs * req.key_bytes
+        if req.keys is None:
+            self.prefix_stats.row_keyed_blocks += new
+        return hashes
+
+    def request_of(self, rid: int) -> Optional[PagedRequest]:
+        """The live request ``rid``: in a slot, or queued."""
+        for r in self._requests:
+            if r is not None and r.rid == rid:
+                return r
+        for r in self.queue:
+            if r.rid == rid:
+                return r
+        return None
 
     def _admission_health(self, req: PagedRequest,
                           ten: Tenant) -> str:
@@ -1101,7 +1206,7 @@ class PagedServingEngine:
                 # consumes one free unit, same as an alloc) so only the
                 # active ones discount `need`
                 matched = self.cache.match_prefix(
-                    req.block_hashes(self.cache.block_size))
+                    self._block_hashes(req))
                 rc = self.cache.allocator.refcount
                 need -= sum(1 for b in matched if rc[b] > 0)
             # physical pool draw: the prompt pages land ONCE however
@@ -1164,7 +1269,7 @@ class PagedServingEngine:
         hashes: List[bytes] = []
         n_cached = 0
         if self.prefix_cache:
-            hashes = req.block_hashes(bs)
+            hashes = self._block_hashes(req)
             n_cached = self.cache.adopt_prefix(slot, hashes)
             self.prefix_stats.lookups += 1
             self.prefix_stats.lookup_blocks += len(hashes)
@@ -1354,8 +1459,12 @@ class PagedServingEngine:
             budget -= c
             ran = True
             if pos >= T:
+                # the slot, and the branches its completion forked: the
+                # caller has drained none of their admitted events yet
+                idle = ~self.active
                 self._complete_prefill(slot, h)
-                fresh.append(slot)
+                fresh.extend(int(s) for s in
+                             np.flatnonzero(self.active & idle))
         if ran:
             self.prefill_stats.prefill_steps += 1
         return ran, fresh
@@ -1588,7 +1697,8 @@ class PagedServingEngine:
             else:
                 bslot = int(np.flatnonzero(~self.active
                                            & ~self.prefilling)[0])
-            breq = PagedRequest(self._next_rid, req.history)
+            breq = PagedRequest(self._next_rid, req.history,
+                                keys=req.keys_held())
             self._next_rid += 1
             breq.tenant = req.tenant
             breq.gid = req.gid
@@ -1664,7 +1774,8 @@ class PagedServingEngine:
             g["forked"] = True
             self.parallel_stats.groups += 1
         g = self.groups.groups[req.gid]
-        breq = PagedRequest(self._next_rid, req.history)
+        breq = PagedRequest(self._next_rid, req.history,
+                            keys=req.keys_held())
         self._next_rid += 1
         breq.tenant = req.tenant
         breq.gid = req.gid
@@ -1705,16 +1816,7 @@ class PagedServingEngine:
         ledger work resolved as ``bestof_pruned`` waste. Works on
         running, mid-prefill and queued (preempted) members alike.
         Returns False for an unknown/already-terminal rid."""
-        req = None
-        for r in self._requests:
-            if r is not None and r.rid == rid:
-                req = r
-                break
-        if req is None:
-            for r in self.queue:
-                if r.rid == rid:
-                    req = r
-                    break
+        req = self.request_of(rid)
         if req is None:
             return False
         self._fail(req, RequestOutcome.CANCELLED,
@@ -2697,11 +2799,7 @@ class PagedServingEngine:
         router then migrates cold (plain resubmission). A pure read:
         no allocator or scheduler state moves."""
         self._flush_history()
-        req = None
-        for r in self._requests:
-            if r is not None and r.rid == rid:
-                req = r
-                break
+        req = self.request_of(rid)
         if req is None or req.slot is None:
             return None
         slot = int(req.slot)
@@ -2712,7 +2810,7 @@ class PagedServingEngine:
         n_full = covered // self.cache.block_size
         if n_full <= 0:
             return None
-        hashes = req.block_hashes(self.cache.block_size)[:n_full]
+        hashes = self._block_hashes(req)[:n_full]
         if not hashes:
             return None
         return self.cache.export_slice(slot, hashes)
@@ -2741,6 +2839,9 @@ class PagedServingEngine:
         return {
             "rid": req.rid,
             "history": np.array(req.history, np.float32, copy=True),
+            # a few bytes a row beside 4 x d_model: what the hashes
+            # were (and the rest of the chain will be) made from
+            "keys": req.keys_held(),
             "hashes": list(req._hashes),
             "slot": req.slot,
             "admit_seq": req.admit_seq,
@@ -2907,7 +3008,12 @@ class PagedServingEngine:
         eng._vclock = snap.get("vclock", 0.0)
         reqs: Dict[int, PagedRequest] = {}
         for rec in snap["requests"]:
-            req = PagedRequest(rec["rid"], rec["history"])
+            # a snapshot written before requests had keys has none in
+            # its records: such a request goes on hashing its ROWS, as
+            # the hashes it memoized and the index this snapshot
+            # carries were made
+            req = PagedRequest(rec["rid"], rec["history"],
+                               keys=rec.get("keys"))
             req._hashes = list(rec["hashes"])
             req.tenant = rec.get("tenant", DEFAULT_TENANT)
             req.slot = rec["slot"]

@@ -50,10 +50,17 @@ class PrefixCacheStats(StatsBase):
       hit_blocks      blocks shared instead of allocated
       tokens_skipped  prompt tokens whose prefill was skipped
       tokens_computed prompt tokens actually prefilled
+      hashed_bytes    bytes of key material the block-identity chain
+                      read (4 B a token where requests come with token
+                      ids; 4 x d_model B a token where they have rows
+                      only)
+      row_keyed_blocks  blocks whose identity was hashed from embedding
+                      rows (0 behind a token wrapper)
     """
 
     __slots__ = FIELDS = ("lookups", "lookup_blocks", "hit_blocks",
-                          "tokens_skipped", "tokens_computed")
+                          "tokens_skipped", "tokens_computed",
+                          "hashed_bytes", "row_keyed_blocks")
     DERIVED = {"blocks_saved": None, "hit_rate": 4}
     REPR = ("hit_rate", "blocks_saved", "tokens_skipped")
 

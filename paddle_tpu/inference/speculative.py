@@ -419,14 +419,17 @@ class _SpecSeq:
     to the caller but not yet consumed by the models). Branch-group
     members additionally carry their group id / branch index, their
     private snapshot-carried RNG lane (``branch_lane_seed``) and the
-    name of their grammar mask."""
+    name of their grammar mask. ``toks`` is the list it is GIVEN, kept
+    (hand it one of its own): the engine's request of the same rid
+    reads its keys from that list (``PagedRequest.keys``), one token id
+    a history row, as far as its history goes."""
 
     __slots__ = ("rid", "toks", "prompt_len", "slot", "started",
                  "lane", "gid", "branch", "mask")
 
     def __init__(self, rid: int, prompt: List[int]):
         self.rid = rid
-        self.toks: List[int] = list(prompt)
+        self.toks: List[int] = prompt
         self.prompt_len = len(prompt)
         self.slot: Optional[int] = None
         self.started = False    # first token sampled at admission?
@@ -626,11 +629,16 @@ class SpeculativeEngine:
         finally:
             if col is not None:
                 col.span_end(tokens=len(prefix))
+        # the gathered rows are the request's from here on: frozen, so
+        # that the engine can see nobody writes to them and keeps them
+        # without a copy (scheduler._request_rows). Its block hashes
+        # are made from ``toks``, the stream this wrapper appends to
+        rows.flags.writeable = False
         rid = self.engine.submit(rows,
                                  max_preemptions=max_preemptions,
                                  deadline_steps=deadline_steps,
                                  deadline_s=deadline_s,
-                                 tenant_id=tenant_id, n=n)
+                                 tenant_id=tenant_id, n=n, keys=toks)
         seq = _SpecSeq(rid, toks)
         seq.mask = logit_mask
         if seed is not None:
@@ -771,9 +779,9 @@ class SpeculativeEngine:
         branch = breq.branch
         g["next_branch"] = max(g["next_branch"], branch + 1)
         g["rids"].append(brid)
-        clone = _SpecSeq(brid, [])
-        clone.toks = list(seq.toks)
+        clone = _SpecSeq(brid, list(seq.toks))
         clone.prompt_len = seq.prompt_len
+        self._bind_keys(breq, clone)
         clone.started = True
         clone.slot = bslot
         clone.gid = gid
@@ -794,6 +802,17 @@ class SpeculativeEngine:
             self._clear_draft_slot(bslot)
             self._draft_dirty.add(bslot)
         return brid
+
+    @staticmethod
+    def _bind_keys(req, seq: _SpecSeq) -> None:
+        """An engine request that was cut from another (a fork, a
+        branch) or loaded from a snapshot holds the keys of the rows it
+        had then: from here on it reads them from ``seq``'s stream,
+        which this wrapper goes on appending to. A request without
+        keys (a snapshot from before requests had them) keeps hashing
+        its rows."""
+        if req is not None and req.keys is not None:
+            req.bind_keys(seq.toks)
 
     def _clear_draft_slot(self, slot: int) -> None:
         if self.draft_cache is not None:
@@ -907,6 +926,8 @@ class SpeculativeEngine:
             seq = self._by_rid.get(rid)
             if seq is None:
                 seq = self._adopt_branch(rid)
+                if seq is not None:
+                    self._bind_keys(eng._requests[slot], seq)
             if seq is None:
                 # released while queued (release() drops queued
                 # requests, so this is a belt-and-braces path): never
@@ -956,7 +977,7 @@ class SpeculativeEngine:
         branch = g["next_branch"]
         g["next_branch"] = branch + 1
         g["rids"].append(rid)
-        seq = _SpecSeq(rid, g["prompt"])
+        seq = _SpecSeq(rid, list(g["prompt"]))
         seq.gid = g["gid"]
         seq.branch = branch
         seq.mask = g["mask"]
@@ -1517,7 +1538,7 @@ class SpeculativeEngine:
             collector=collector, monitor=monitor, ledger=ledger)
         spec.engine.registry.attach("spec", spec.stats)
         for rec in snap["seqs"]:
-            seq = _SpecSeq(rec["rid"], rec["toks"])
+            seq = _SpecSeq(rec["rid"], list(rec["toks"]))
             seq.prompt_len = rec["prompt_len"]
             seq.slot = rec["slot"]
             seq.started = rec["started"]
@@ -1531,6 +1552,7 @@ class SpeculativeEngine:
             spec._by_rid[seq.rid] = seq
             if seq.slot is not None:
                 spec._seqs[seq.slot] = seq
+            cls._bind_keys(spec.engine.request_of(seq.rid), seq)
         spec._rng.set_state(snap["rng"])
         spec._groups = {int(g["gid"]): dict(g)
                         for g in snap.get("groups", [])}

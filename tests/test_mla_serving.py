@@ -452,8 +452,10 @@ def test_snapshot_restore_and_slices_over_latent_pages(served):
         np.testing.assert_array_equal(np.array(a.numpy())[keep],
                                       np.array(b.numpy())[keep])
     assert back.snapshot(base=snap)["base_blocks"]        # a delta: clean
-    hashes = pc.chain_block_hashes(server.engine.target.embed(prompt), 4)
+    # a request submitted as tokens is keyed by its ids
+    hashes = pc.chain_block_hashes(np.asarray(prompt), 4)
     slot = server.engine._by_rid[rid].slot
+    assert eng._requests[slot].block_hashes(4)[:len(hashes)] == hashes
     slc = cache.export_slice(slot, hashes)
     assert slc["geometry"]["v_dim"] == 32
     assert slc["payload"].shape == (len(hashes), 5, 1, 1, 4, STORED)

@@ -539,3 +539,359 @@ class TestWarmResumeMidPrefill:
                                   num_blocks=10, max_blocks_per_seq=MB)
         _, hc = _admit(cold, prompt)
         _assert_same_to_a_few_ulp(h.numpy(), hc.numpy())
+
+
+# ---------------------------------------------------------------------
+# a block's identity from token ids (PR 36): a request that was handed
+# keys hashes them and never its rows; one that has rows only hashes
+# the rows; the token wrapper's gathered rows are kept, not copied
+# ---------------------------------------------------------------------
+
+from paddle_tpu.inference import (SpeculativeEngine,      # noqa: E402
+                                  TokenServingModel, token_chain_hashes)
+from paddle_tpu.inference.paged_cache import ID_CHAIN_TAG  # noqa: E402
+from paddle_tpu.inference.scheduler import PagedRequest    # noqa: E402
+
+TBS = 4                                  # 4-token pages for these
+_TOK_RNG = np.random.RandomState(4321)
+_TOK_EMBED = _TOK_RNG.randn(_VOCAB, D).astype(np.float32)
+_TOK_HEAD = _TOK_RNG.randn(D, _VOCAB).astype(np.float32)
+
+
+def _tsm(layers=LAYERS, seed=0):
+    paddle.seed(seed)
+    return TokenServingModel(
+        FusedMultiTransformer(D, HEADS, FFN, num_layers=layers),
+        _TOK_EMBED, _TOK_HEAD)
+
+
+def _token_engine(tsm=None, draft=None, k=0, **kw):
+    kw.setdefault("num_blocks", 64)
+    kw.setdefault("max_blocks_per_seq", 16)
+    return SpeculativeEngine(tsm or _tsm(), draft, k=k, max_batch=2,
+                             block_size=TBS, prefix_cache=True, **kw)
+
+
+def _ids(seed, n):
+    return [int(t) for t in
+            np.random.RandomState(seed).randint(0, _VOCAB, n)]
+
+
+def _consumed(eng, rid):
+    """(the engine's request, the ids of the rows in its history)."""
+    eng.engine._flush_history()
+    req = eng.engine.request_of(rid)
+    return req, eng.tokens(rid)[:len(req)]
+
+
+class TestBlockIdentityFromTokenIds:
+    def test_same_ids_share_as_rows_did_and_a_second_turn_adopts_decoded_blocks(self):
+        tsm = _tsm()
+        eng = _token_engine(tsm)
+        st = eng.engine.prefix_stats
+        p = _ids(1, 10)
+        r1 = eng.submit(p)
+        for _ in range(9):
+            eng.step()
+        # blocks that filled during decoding reach the index when the
+        # request is prefilled again: preempt it, let it come back
+        eng.engine.preempt(eng._by_rid[r1].slot)
+        eng._handle_events()
+        indexed = len(eng.engine.request_of(r1)) // TBS
+        for _ in range(3):
+            eng.step()
+        req, turn1 = _consumed(eng, r1)
+        assert indexed == 4 and req.keys is eng._by_rid[r1].toks
+        eng.release(r1)
+        # two submits of the same ids share the prompt's full blocks,
+        # as two submits of the same ROWS do on a bare engine
+        hits = st.hit_blocks
+        r2 = eng.submit(p)
+        assert st.hit_blocks - hits == len(p) // TBS
+        eng.release(r2)
+        bare = PagedServingEngine(tsm.core, max_batch=2, block_size=TBS,
+                                  num_blocks=64, max_blocks_per_seq=16,
+                                  prefix_cache=True)
+        for _ in range(2):
+            bare.submit(tsm.embed(p))
+            (_, slot, _), = bare.admitted
+            bare.admitted.clear()
+            bare.release(slot)
+        assert bare.prefix_stats.hit_blocks == len(p) // TBS
+        assert bare.prefix_stats.row_keyed_blocks == 2 * (len(p) // TBS)
+        # the second turn: the first prompt, its answer, new tokens
+        hits = st.hit_blocks
+        r3 = eng.submit(turn1 + _ids(2, 5))
+        assert st.hit_blocks - hits == indexed > len(p) // TBS
+        assert st.row_keyed_blocks == 0
+        assert eng.engine.check_invariants()
+        eng.release(r3)
+
+    def test_an_id_keyed_and_a_row_keyed_block_never_share_a_hash(self):
+        tsm = _tsm()
+        p = _ids(3, 12)
+        rows = tsm.embed(p)
+        by_ids = PagedRequest(0, rows, keys=p).block_hashes(TBS)
+        by_rows = PagedRequest(1, rows).block_hashes(TBS)
+        assert len(by_ids) == len(by_rows) == 3
+        assert not set(by_ids) & set(by_rows)
+        assert by_ids == chain_block_hashes(np.asarray(p), TBS)
+        assert by_rows == chain_block_hashes(rows, TBS)
+        # the tag alone tells them apart where the BYTES are equal
+        # (width-1 rows whose float32 bits are the ids' int32 bits)
+        ids = np.arange(100, 108, dtype=np.int32)
+        twins = ids.view(np.float32).reshape(-1, 1)
+        assert twins.tobytes() == ids.tobytes() and ID_CHAIN_TAG
+        assert not set(chain_block_hashes(ids, TBS)) \
+            & set(chain_block_hashes(twins, TBS))
+        # and on one engine neither adopts the other's pages
+        eng = PagedServingEngine(tsm.core, max_batch=2, block_size=TBS,
+                                 num_blocks=64, max_blocks_per_seq=16,
+                                 prefix_cache=True)
+        eng.submit(rows.copy(), keys=list(p))
+        eng.submit(rows.copy())
+        assert eng.prefix_stats.hit_blocks == 0
+        assert eng.prefix_stats.row_keyed_blocks == 3
+        assert eng.prefix_stats.hashed_bytes == 4 * 12 + rows.nbytes
+        with pytest.raises(ValueError, match="keys for"):
+            eng.submit(rows.copy(), keys=p[:5])
+
+    def test_truncate_history_drops_keys_and_memoized_hashes_together(self):
+        tsm = _tsm()
+        p = _ids(4, 8)
+        pend, d1, d2, d3, fix, nxt = _ids(5, 6)
+        toks = list(p)
+        req = PagedRequest(0, tsm.embed(p), keys=toks)
+        h0 = list(req.block_hashes(TBS))
+        # a verify round: the pending token's row and three drafts'
+        for t in (pend, d1, d2, d3):
+            req.append_history(tsm.embed([t])[0])
+        # nobody appended the drafts' ids, and no hash is made of rows
+        with pytest.raises(ValueError, match="keys cover 8 of 12"):
+            req.block_hashes(TBS)
+        toks.extend([pend, d1, d2, d3])
+        drafted = list(req.block_hashes(TBS))
+        assert drafted[:2] == h0 and len(drafted) == 3
+        # d2 is rejected: two rows go, the ids after d1 go, the hash
+        # of the block the drafts had filled goes
+        req.truncate_history(10, TBS)
+        del toks[10:]
+        toks.extend([fix, nxt])
+        assert req.block_hashes(TBS) == h0
+        for t in (fix, nxt):
+            req.append_history(tsm.embed([t])[0])
+        got = req.block_hashes(TBS)
+        assert got == chain_block_hashes(
+            np.asarray(p + [pend, d1, fix, nxt]), TBS)
+        assert got[2] != drafted[2]
+
+    def test_the_stream_after_rejected_drafts_indexes_accepted_tokens_only(self):
+        paddle.seed(99)
+        draft = TokenServingModel(
+            FusedMultiTransformer(D, HEADS, FFN, num_layers=1),
+            _TOK_EMBED, _TOK_HEAD)
+        eng = _token_engine(draft=draft, k=3)
+        rid = eng.submit(_ids(6, 9))
+        for _ in range(8):
+            eng.step()
+        assert eng.stats.rolled_back > 0
+        req, consumed = _consumed(eng, rid)
+        assert req.keys is eng._by_rid[rid].toks
+        assert len(consumed) == len(req) >= 12
+        # prefilled again, the request registers the blocks of what
+        # was ACCEPTED: the hashes of its ids, no draft among them
+        eng.engine.preempt(eng._by_rid[rid].slot)
+        eng._handle_events()
+        eng.step()
+        req, consumed = _consumed(eng, rid)
+        want = token_chain_hashes(eng.target, consumed, TBS)
+        assert req.block_hashes(TBS) == want
+        cache = eng.engine.cache
+        assert cache.match_prefix(want) == \
+            cache.seq_blocks[req.slot][:len(want)]
+        assert eng.engine.prefix_stats.row_keyed_blocks == 0
+        assert eng.check_invariants()
+
+    def test_snapshot_restore_and_journal_replay_reproduce_the_hits(
+            self, tmp_path):
+        from paddle_tpu.inference import RecoverableServer
+        tsm = _tsm()
+        paths = dict(journal_path=str(tmp_path / "j.wal"),
+                     snapshot_path=str(tmp_path / "s.snap"))
+        srv = RecoverableServer(_token_engine(tsm), **paths)
+        p = _ids(7, 13)
+        r1 = srv.submit(p)
+        for _ in range(4):
+            srv.step()
+        srv.release(r1)
+        r2 = srv.submit(p + _ids(8, 3))        # hits the first's blocks
+        srv.save_snapshot()
+        srv.step()
+        srv.release(r2)
+        r3 = srv.submit(p[:9] + _ids(9, 6))    # after the snapshot
+        srv.step()
+        live = srv.engine.engine.prefix_stats
+        assert live.hit_blocks == 3 + 2 and live.row_keyed_blocks == 0
+        want = srv.engine.tokens(r3)
+        srv.journal.close()
+        back = RecoverableServer.recover(_tsm(), **paths)
+        got = back.engine.engine.prefix_stats
+        assert (got.hit_blocks, got.lookup_blocks, got.tokens_skipped,
+                got.row_keyed_blocks) == \
+            (live.hit_blocks, live.lookup_blocks, live.tokens_skipped, 0)
+        assert back.engine.tokens(r3) == want
+        req = back.engine.engine.request_of(r3)
+        assert req.keys is back.engine._by_rid[r3].toks
+        assert req.block_hashes(TBS) == token_chain_hashes(
+            tsm, want[:len(req)], TBS)
+        back.close()
+
+    def test_a_snapshot_without_keys_loads_and_serves(self):
+        """The layout of a snapshot written before requests had keys:
+        no ``keys`` in a request's record, hashes and index made from
+        rows, no ``hashed_bytes`` / ``row_keyed_blocks`` among the
+        stats. Its requests go on hashing rows."""
+        tsm = _tsm()
+        old = _token_engine(tsm)
+        submit = old.engine.submit
+        old.engine.submit = lambda rows, keys=None, **kw: \
+            submit(rows, **kw)                 # as the parent did
+        p = _ids(10, 11)
+        rid = old.submit(p)
+        for _ in range(3):
+            old.step()
+        snap = old.snapshot()
+        for rec in snap["engine"]["requests"]:
+            assert rec.pop("keys") is None
+            assert rec["hashes"] == chain_block_hashes(
+                rec["history"], TBS)[:len(rec["hashes"])]
+        for name in ("hashed_bytes", "row_keyed_blocks"):
+            del snap["engine"]["stats"]["prefix"][name]
+        live = _token_engine(tsm)
+        live.submit(p)
+        for _ in range(3):
+            live.step()
+        back = SpeculativeEngine.restore(tsm, None, snap)
+        req = back.engine.request_of(rid)
+        assert req.keys is None
+        for _ in range(4):
+            assert back.step() == live.step()
+        # prefilled again it adopts its own pages: row hashes, as the
+        # index the snapshot carried was made
+        hits = back.engine.prefix_stats.hit_blocks
+        back.engine.preempt(back._by_rid[rid].slot)
+        back._handle_events()
+        back.step()
+        assert back.engine.prefix_stats.hit_blocks - hits >= len(p) // TBS
+        assert back.engine.prefix_stats.row_keyed_blocks > 0
+        assert back.check_invariants()
+        # a request submitted now is keyed by ids, and shares nothing
+        # with the row-keyed pages
+        hits = back.engine.prefix_stats.hit_blocks
+        back.submit(p)
+        assert back.engine.prefix_stats.hit_blocks == hits
+
+    @pytest.mark.parametrize("budget", [None, 8])
+    def test_forks_and_branches_read_their_own_streams(self, budget):
+        """A branch group's members and a forked stream are requests of
+        their own: each reads ITS stream's ids, the rows in its history
+        are those ids' rows (a branch forked inside a mixed step sits
+        that step's decode out, as its lead does), and a preempted
+        member comes back over its own blocks."""
+        tsm = _tsm()
+        eng = SpeculativeEngine(tsm, None, k=0, max_batch=4,
+                                block_size=TBS, num_blocks=80,
+                                max_blocks_per_seq=16, prefix_cache=True,
+                                prefill_token_budget=budget)
+        gid = eng.submit(_ids(13, 10), n=3, seed=5)
+        for _ in range(6):
+            eng.step()
+        rids = list(eng.group(gid)["rids"])
+        rids.append(eng.fork_stream(rids[1]))
+        for _ in range(3):
+            eng.step()
+        for rid in rids[1:]:
+            eng.engine.preempt(eng._by_rid[rid].slot)
+            eng._handle_events()
+        for _ in range(5):
+            eng.step()
+        for rid in rids:
+            req, consumed = _consumed(eng, rid)
+            assert req.keys is eng._by_rid[rid].toks
+            assert len(eng.tokens(rid)) == len(req) + 1    # the pending
+            np.testing.assert_array_equal(req.history,
+                                          tsm.embed(consumed))
+            assert req.block_hashes(TBS) == token_chain_hashes(
+                tsm, consumed, TBS)
+        assert eng.engine.prefix_stats.row_keyed_blocks == 0
+        assert eng.check_invariants()
+        back = SpeculativeEngine.restore(tsm, None, eng.snapshot())
+        for rid in rids:
+            assert back.engine.request_of(rid).keys \
+                is back._by_rid[rid].toks
+        for _ in range(3):
+            assert back.step() == eng.step()
+
+    def test_token_chain_hashes_is_the_engines_chain_and_embeds_nothing(
+            self, monkeypatch):
+        tsm = _tsm()
+        eng = _token_engine(tsm, prefill_token_budget=8)
+        ids = _ids(11, 23)
+        rid = eng.submit(ids)
+        req = eng.engine.request_of(rid)
+
+        def no_embed(*a, **kw):
+            raise AssertionError("token_chain_hashes embedded its ids")
+        monkeypatch.setattr(tsm, "embed", no_embed)
+        got = token_chain_hashes(tsm, ids, TBS)
+        assert got == req.block_hashes(TBS) and len(got) == 23 // TBS
+        assert got == chain_block_hashes(np.asarray(ids, np.int64), TBS)
+        assert token_chain_hashes(tsm, np.asarray(ids[:3]), TBS) == []
+        with pytest.raises(ValueError, match="out of range"):
+            token_chain_hashes(tsm, [0, _VOCAB], TBS)
+
+    def test_submit_reads_ids_only_and_keeps_the_gathered_rows(
+            self, monkeypatch):
+        tsm = _tsm()
+        T = 4096
+        eng = _token_engine(tsm, num_blocks=T // TBS + 8,
+                            max_blocks_per_seq=T // TBS + 2,
+                            prefill_token_budget=16)
+        gathered = []
+        embed = tsm.embed
+
+        def spy(ids):
+            gathered.append(embed(ids))
+            return gathered[-1]
+        monkeypatch.setattr(tsm, "embed", spy)
+        rid = eng.submit(_ids(12, T))
+        st = eng.engine.prefix_stats
+        assert st.hashed_bytes == 4 * T and st.row_keyed_blocks == 0
+        req = eng.engine.request_of(rid)
+        rows, = gathered
+        assert rows.shape == (T, D)
+        assert req._hist is rows                  # no second [T, d]
+        assert np.shares_memory(req.history, rows)
+        assert not rows.flags.writeable           # handed over: frozen
+        # the first decode row grows the history into an array of the
+        # request's own; the frozen one is never written
+        before = rows.copy()
+        req.append_history(np.ones(D, np.float32))
+        assert len(req) == T + 1 and req._hist is not rows
+        np.testing.assert_array_equal(rows, before)
+        np.testing.assert_array_equal(req.history[:T], before)
+        # a foreign caller's rows are copied: it may keep writing
+        bare = PagedServingEngine(tsm.core, max_batch=2, block_size=TBS,
+                                  num_blocks=64, max_blocks_per_seq=16,
+                                  prefix_cache=True,
+                                  prefill_token_budget=16)
+        mine = np.random.RandomState(0).randn(24, D).astype(np.float32)
+        kept = mine.copy()
+        for handed in (mine, mine[:20], paddle.to_tensor(mine)):
+            r = bare.submit(handed)
+            theirs = bare.request_of(r)
+            assert not np.shares_memory(theirs.history, mine)
+            mine[:] = 0.0
+            np.testing.assert_array_equal(theirs.history,
+                                          kept[:len(theirs)])
+            mine[:] = kept

@@ -1341,3 +1341,38 @@ class TestTraceReportKnowsTheRound:
             trace_report.main([])
         assert trace_report.main(
             ["--xplane", str(tmp_path / "missing.xplane.pb")]) == 2
+
+    def test_what_the_hash_chain_read_is_printed(self, tmp_path, capsys):
+        """``submit.hash`` ends with ``bytes`` and ``keyed``; the dump's
+        registry carries the engine's ``prefix_cache.*`` counters: the
+        report prints both under the submit spans."""
+        from tools import trace_report
+        col = TraceCollector()
+        eng = SpeculativeEngine(_tsm(), None, k=0, max_batch=2,
+                                block_size=4, num_blocks=40,
+                                max_blocks_per_seq=8, prefix_cache=True,
+                                prefill_token_budget=8, collector=col)
+        for n in (9, 14):
+            eng.submit(list(range(n)))
+        rows = np.zeros((8, D), np.float32)        # a caller with rows
+        eng.engine.submit(rows)
+        ends = [ev for ev in col.chrome_trace()["traceEvents"]
+                if ev["name"] == "submit.hash"]
+        assert [(ev["args"]["bytes"], ev["args"]["keyed"])
+                for ev in ends] == \
+            [(4 * 8, "ids"), (4 * 12, "ids"), (rows.nbytes, "rows")]
+        path = str(tmp_path / "hash.json")
+        col.save_chrome_trace(path)
+        assert trace_report.main([path]) == 0
+        out = capsys.readouterr().out
+        total = 4 * 20 + rows.nbytes
+        assert (f"submit.hash read {total} B in 3 submit(s), "
+                f"{total / 3:.0f} B a request; keyed by ids x2, rows x1"
+                ) in out
+        assert (f"prefix cache: hashed_bytes {total:g}, "
+                f"row_keyed_blocks 2") in out
+        rep = trace_report.machine_report(col.chrome_trace())
+        assert rep["submit_hash"] == {
+            "submits": 3, "bytes": total,
+            "keyed": {"ids": 2, "rows": 1},
+            "hashed_bytes": total, "row_keyed_blocks": 2}

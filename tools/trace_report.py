@@ -188,6 +188,46 @@ def _expert_lines(meta) -> list:
     return lines
 
 
+def _submit_hash(evs, meta) -> dict:
+    """What the block-identity chain read: the ``bytes`` and ``keyed``
+    (``ids`` | ``rows``) that each ``submit.hash`` span carries, and
+    the engine's ``prefix_cache.*`` counters from the dump's registry
+    (all the chain's reads, re-admissions and slice exports too)."""
+    out = {"submits": 0, "bytes": 0, "keyed": {}}
+    for ev in evs:
+        args = ev.get("args") or {}
+        if ev.get("ph") == "X" and ev["name"] == "submit.hash" \
+                and "keyed" in args:
+            out["submits"] += 1
+            out["bytes"] += int(args.get("bytes", 0))
+            out["keyed"][args["keyed"]] = \
+                out["keyed"].get(args["keyed"], 0) + 1
+    reg = (meta or {}).get("registry") or {} \
+        if isinstance(meta, dict) else {}
+    for name in ("hashed_bytes", "row_keyed_blocks"):
+        if f"prefix_cache.{name}" in reg:
+            out[name] = reg[f"prefix_cache.{name}"]
+    return out
+
+
+def _submit_hash_lines(evs, meta) -> list:
+    got = _submit_hash(evs, meta)
+    lines = []
+    if got["submits"]:
+        keyed = ", ".join(f"{k} x{n}"
+                          for k, n in sorted(got["keyed"].items()))
+        lines.append(f"    submit.hash read {got['bytes']} B in "
+                     f"{got['submits']} submit(s), "
+                     f"{got['bytes'] / got['submits']:.0f} B a request; "
+                     f"keyed by {keyed}")
+    if "hashed_bytes" in got:
+        lines.append(f"    prefix cache: hashed_bytes "
+                     f"{got['hashed_bytes']:g}, row_keyed_blocks "
+                     f"{got.get('row_keyed_blocks', 0):g} (blocks whose "
+                     f"identity was hashed from embedding rows)")
+    return lines
+
+
 def summarize(trace: dict, tenant: str = None,
               show_requests: bool = False) -> str:
     evs = trace["traceEvents"]
@@ -214,6 +254,8 @@ def summarize(trace: dict, tenant: str = None,
                          f"mean {_fmt_s(tot / n)}, max {_fmt_s(mx)}"
                          + (f", self {_fmt_s(tot - children[name])}"
                             if name in children else ""))
+        if title == "submit spans":
+            lines.extend(_submit_hash_lines(evs, trace.get("metadata")))
     if counters:
         lines.append(f"  gauge tracks: {sorted(counters)}")
         for track in sorted(counters):
@@ -347,6 +389,9 @@ def machine_report(trace: dict) -> dict:
                    for k, (n, tot, lo, hi, last)
                    in counters[track].items()},
     }
+    hashed = _submit_hash(trace["traceEvents"], meta)
+    if hashed["submits"] or "hashed_bytes" in hashed:
+        out["submit_hash"] = hashed
     if isinstance(meta, dict) and "summary" in meta:
         out["steps"] = meta.get("steps")
         out["replayed_steps"] = meta.get("replayed_steps")
