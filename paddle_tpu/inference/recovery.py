@@ -532,7 +532,7 @@ class RecoverableServer:
         # ``submit.embed`` / ``submit.hash`` / ``submit.admit``
         depth = col.span_depth if col is not None else 0
         if col is not None:
-            col.span_begin("submit")
+            col.span_begin("submit", counters=True)
             col.span_begin("submit.journal")
         try:
             self._flush_drains()
@@ -557,7 +557,7 @@ class RecoverableServer:
         # ``round`` is the parent of every span of the round; what no
         # named child covers is its self time
         depth = col.span_depth
-        col.span_begin("round")
+        col.span_begin("round", counters=True)
         try:
             emitted = self._round(col)
         except BaseException:

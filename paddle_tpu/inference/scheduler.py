@@ -1007,8 +1007,7 @@ class PagedServingEngine:
         if deadline_s is not None:
             req.deadline_time = time.monotonic() + float(deadline_s)
         if self.collector is not None:
-            self.collector.on_submit(req.rid, ten.tid, arr.shape[0],
-                                     gid=req.gid)
+            self.collector.on_submit(req.rid, ten.tid, arr.shape[0])
         if self.ledger is not None:
             self.ledger.on_submit(req.rid, ten.tid, arr.shape[0])
         reject = self._admission_health(req, ten)
@@ -1709,8 +1708,7 @@ class PagedServingEngine:
             breq.submit_step = req.submit_step
             self.groups.add_branch(req.gid, breq.rid)
             if self.collector is not None:
-                self.collector.on_submit(breq.rid, breq.tenant, T,
-                                         gid=breq.gid)
+                self.collector.on_submit(breq.rid, breq.tenant, T)
             if self.ledger is not None:
                 self.ledger.on_submit(breq.rid, breq.tenant, T)
                 self.ledger.on_fork(breq.rid, T)
@@ -1787,8 +1785,7 @@ class PagedServingEngine:
         g["n"] += 1
         self.groups.add_branch(req.gid, breq.rid)
         if self.collector is not None:
-            self.collector.on_submit(breq.rid, breq.tenant, L,
-                                     gid=breq.gid)
+            self.collector.on_submit(breq.rid, breq.tenant, L)
         if self.ledger is not None:
             self.ledger.on_submit(breq.rid, breq.tenant, L)
             self.ledger.on_fork(breq.rid, L)

@@ -623,7 +623,7 @@ class SpeculativeEngine:
         prefix = toks[:-1] if resume else toks
         col = self.engine.collector
         if col is not None:
-            col.span_begin("submit.embed")
+            col.span_begin("submit.embed", counters=True)
         try:
             rows = self.target.embed(prefix)
         finally:

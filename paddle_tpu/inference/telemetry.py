@@ -43,6 +43,42 @@ that subsystem for the paged serving stack:
       span that encloses it (``args["round"]`` / ``args["parent"]``),
       so self time — duration less children — can be computed; the
       timeline is a ring of the newest ``max_events``;
+    - WORK OR WAIT: every span, phase and step reads a second clock
+      at its open and its close, the calling thread's CPU time
+      (``cpu_clock``, default ``time.thread_time``), and carries the
+      difference as ``args["cpu"]`` (seconds). Wait is ``dur - cpu``
+      and is never stored; self CPU follows from ``parent`` as self
+      time does. ``cpu`` is CPU time of the CALLING thread only: work
+      the runtime or numpy do on other threads reads as wait; time
+      the kernel spends for the thread (page faults) reads as work; a
+      runtime that spins instead of sleeping reads as work, so the
+      ``cpu / dur`` of ``device_wait``, a span that is nothing but
+      waiting, says how far ``wait`` can be trusted on a host (near
+      0: the runtime sleeps and wait means what it says; large: it
+      spins and every wait is a lower bound). The clock's
+      resolution is the platform's (``CLOCK_THREAD_CPUTIME_ID``):
+      where the kernel books CPU time by timer tick (10 ms on the
+      benchmark's chip host), one span's ``cpu`` is a whole number
+      of ticks and only a sum over many spans is a reading. A span
+      closed on another thread than it was opened on carries no
+      ``cpu``. The spans opened with ``counters=True`` (the server's
+      ``round`` and ``submit``, and ``submit.embed``) also carry what
+      ``getrusage(RUSAGE_THREAD)`` counted between open and close:
+      ``faults`` (pages faulted in without I/O), ``faults_major``,
+      ``preempted`` (the OS took the core away) and ``yields`` (the
+      thread went to sleep of its own accord); absent where the
+      platform has no ``RUSAGE_THREAD``, all 0 on a kernel that does
+      not count them (the chip host's). The interpreter's collector
+      is watched through ONE ``gc.callbacks`` entry for the process
+      (appended with the first ``TraceCollector``, never before):
+      every collection that runs on the thread of an open span adds
+      its duration to the OUTERMOST open span (``args["gc"]``
+      seconds, ``args["gc_n"]`` collections: ``round`` / ``submit``
+      on a server) and is a ``pt.gc`` annotation; one of generation
+      2, or longer than ``GC_SPAN_S``, is also a span ``gc`` under
+      the innermost open span (``generation``, ``collected``). A
+      collection another thread runs is not counted: the span's
+      thread sleeps on the interpreter lock meanwhile, which is wait;
     - the PROFILER'S CLOCK: every span and phase is also a
       ``jax.profiler.TraceAnnotation`` named ``pt.<name>`` for its
       lifetime, so a profile taken while the collector is installed
@@ -88,14 +124,18 @@ that subsystem for the paged serving stack:
       from latency percentiles (their replay-time stamps are not
       serving latencies).
 
-The injectable ``clock`` (default ``time.perf_counter``) keeps tests
-deterministic and is how the counting-clock test proves the
-zero-overhead contract.
+The injectable ``clock`` (default ``time.perf_counter``) and
+``cpu_clock`` (default ``time.thread_time``) keep tests deterministic
+and are how the counting-clock tests prove the zero-overhead contract
+for both clocks.
 """
 from __future__ import annotations
 
 import collections
+import gc
 import json
+import resource
+import threading
 import time
 import weakref
 from typing import Any, Callable, Dict, List, Optional, Tuple
@@ -356,16 +396,14 @@ class _ReqTrace:
     """Lifecycle record of one request (collector-internal; exported
     via ``as_dict``). Timestamps are collector-relative seconds."""
 
-    __slots__ = ("rid", "tenant", "gid", "submit_ts", "admit_ts",
+    __slots__ = ("rid", "tenant", "submit_ts", "admit_ts",
                  "first_ts", "last_ts", "tokens", "chunks",
                  "preemptions", "stall_s", "_preempt_ts", "outcome",
                  "outcome_step", "events", "replayed")
 
-    def __init__(self, rid: int, tenant, ts, replayed: bool = False,
-                 gid=None):
+    def __init__(self, rid: int, tenant, ts, replayed: bool = False):
         self.rid = rid
         self.tenant = tenant
-        self.gid = gid             # fork-shared branch group, or None
         self.submit_ts = ts
         self.admit_ts = None
         self.first_ts = None
@@ -404,7 +442,6 @@ class _ReqTrace:
     def as_dict(self) -> dict:
         r = lambda v: None if v is None else round(v, 6)  # noqa: E731
         return {"rid": self.rid, "tenant": self.tenant,
-                "gid": self.gid,
                 "tokens": self.tokens, "chunks": self.chunks,
                 "preemptions": self.preemptions,
                 "outcome": self.outcome,
@@ -429,10 +466,17 @@ class TraceCollector:
     # record without bound; counters keep counting past it)
     MAX_REQ_EVENTS = 512
 
+    # a collection below generation 2 that is shorter than this is
+    # counted on the outermost span and gets no ``gc`` span of its own
+    GC_SPAN_S = 1e-3
+
     def __init__(self, clock: Optional[Callable[[], float]] = None,
+                 cpu_clock: Optional[Callable[[], float]] = None,
                  max_events: int = 500_000,
                  max_requests: int = 100_000):
         self._clock = time.perf_counter if clock is None else clock
+        self._cpu_clock = time.thread_time if cpu_clock is None \
+            else cpu_clock
         self._t0 = self._clock()
         self.max_events = int(max_events)
         self.max_requests = int(max_requests)
@@ -451,16 +495,28 @@ class TraceCollector:
         # each round and submit); None on a bare engine
         self.round_no: Optional[int] = None
         self._replay = False
-        # open step: (t, step_id, kind, span depth at open, parent,
-        # annotation); open phase: (t, name, annotation); open spans:
-        # (t, name, args, parent, annotation)
+        # open step: (stamp, step_id, kind, span depth at open, parent,
+        # annotation); open phase: (stamp, name, annotation); open
+        # spans: (stamp, name, args, parent, annotation, thread usage
+        # at open or None); a stamp is ``_stamp()`` at the open
         self._step: Optional[tuple] = None
         self._phase: Optional[tuple] = None
         self._spans: List[tuple] = []
-        _watch_compiles(self)
+        # collections inside the outermost open span: seconds, count,
+        # and the one in progress (stamp, annotation)
+        self._gc_s = 0.0
+        self._gc_n = 0
+        self._gc_open: Optional[tuple] = None
+        _watch_process(self)
 
     def now(self) -> float:
         return self._clock() - self._t0
+
+    def _stamp(self) -> tuple:
+        """(wall seconds, CPU seconds of the calling thread, that
+        thread) where a span, a phase or a step opens or closes: the
+        one place the second clock is read."""
+        return self.now(), self._cpu_clock(), threading.get_ident()
 
     # -- low-level emit -----------------------------------------------
     def _emit(self, ev: dict) -> None:
@@ -491,18 +547,19 @@ class TraceCollector:
             return self._phase[1] if self._phase else self._step[2]
         return self._spans[-1][1] if self._spans else None
 
-    def _span_event(self, name: str, t0: float, t1: float,
+    def _span_event(self, name: str, opened: tuple, closed: tuple,
                     args: Optional[dict] = None,
                     parent: Optional[str] = None) -> None:
+        (t0, cpu0, thread0), (t1, cpu1, thread1) = opened, closed
         args = dict(args) if args else {}
+        if thread0 == thread1:      # ``thread_time`` is per thread
+            args["cpu"] = cpu1 - cpu0
         if self.round_no is not None:
             args["round"] = self.round_no
         if parent is not None:
             args["parent"] = parent
-        ev = {"name": name, "ph": "X", "ts": t0, "dur": t1 - t0}
-        if args:
-            ev["args"] = args
-        self._emit(ev)
+        self._emit({"name": name, "ph": "X", "ts": t0, "dur": t1 - t0,
+                    "args": args})
         # every span duration also lands in a windowed registry
         # histogram (``span.<name>``): percentiles_since over these is
         # the windowed per-phase step-timing view the health monitor
@@ -515,29 +572,29 @@ class TraceCollector:
     def begin_step(self, step: int, kind: str = "step") -> None:
         """Open the span for one engine step (auto-closing a step a
         crash left dangling) and its first phase."""
-        t = self.now()
+        at = self._stamp()
         if self._step is not None:
-            self._close_step(t, aborted=True)
+            self._close_step(at, aborted=True)
         parent = self._innermost()
-        self._step = (t, int(step), kind, len(self._spans), parent,
+        self._step = (at, int(step), kind, len(self._spans), parent,
                       self._annotate(kind))
-        self._phase = (t, "bookkeeping", self._annotate("bookkeeping"))
+        self._phase = (at, "bookkeeping", self._annotate("bookkeeping"))
 
     def phase(self, name: str) -> None:
         """Close the current phase span, open the next. No-op outside
         a step (a crash may have torn one down)."""
         if self._step is None:
             return
-        t = self.now()
-        self._close_phase(t)
-        self._phase = (t, name, self._annotate(name))
+        at = self._stamp()
+        self._close_phase(at)
+        self._phase = (at, name, self._annotate(name))
 
-    def _close_phase(self, t: float) -> None:
+    def _close_phase(self, at: tuple) -> None:
         if self._phase is None:
             return
-        t0, name, ann = self._phase
+        opened, name, ann = self._phase
         ann.__exit__(None, None, None)
-        self._span_event(name, t0, t, {"step": self._step[1]},
+        self._span_event(name, opened, at, {"step": self._step[1]},
                          parent=self._step[2])
         self._phase = None
 
@@ -551,12 +608,12 @@ class TraceCollector:
         step-boundary sample."""
         if self._step is None:
             return
-        t = self.now()
-        self._close_step(t, aborted=aborted)
+        at = self._stamp()
+        self._close_step(at, aborted=aborted)
         if aborted:
             return
         for track, series in (gauges or {}).items():
-            self.gauge(track, series, ts=t)
+            self.gauge(track, series, ts=at[0])
 
     def gauge(self, track: str, series: dict,
               ts: Optional[float] = None) -> None:
@@ -568,14 +625,14 @@ class TraceCollector:
         for k, v in series.items():
             self.registry.gauge(f"{track}.{k}", v)
 
-    def _close_step(self, t: float, aborted: bool = False) -> None:
-        self._close_phase(t)
-        t0, step, kind, _, parent, ann = self._step
+    def _close_step(self, at: tuple, aborted: bool = False) -> None:
+        self._close_phase(at)
+        opened, step, kind, _, parent, ann = self._step
         ann.__exit__(None, None, None)
         args = {"step": step}
         if aborted:
             args["aborted"] = True
-        self._span_event(kind, t0, t, args, parent=parent)
+        self._span_event(kind, opened, at, args, parent=parent)
         self._step = None
         if aborted:
             # a torn step is not a completed step: it either replays
@@ -593,20 +650,34 @@ class TraceCollector:
     def span_depth(self) -> int:
         return len(self._spans)
 
-    def span_begin(self, name: str, **args) -> None:
+    def span_begin(self, name: str, counters: bool = False,
+                   **args) -> None:
+        """``counters``: the span also carries the thread's
+        ``USAGE_FIELDS`` between open and close (one ``getrusage`` at
+        each end, a few microseconds: for the few spans a round and a
+        submit have at their top, not for the 40-80 inside a step)."""
         parent = self._innermost()
-        self._spans.append((self.now(), name, args, parent,
-                            self._annotate(name, args)))
+        if not self._spans:             # the outermost span opens
+            self._gc_s, self._gc_n = 0.0, 0
+        self._spans.append((self._stamp(), name, args, parent,
+                            self._annotate(name, args),
+                            _thread_usage() if counters else None))
 
     def span_end(self, **extra) -> None:
         if not self._spans:
             return
-        t0, name, args, parent, ann = self._spans.pop()
+        opened, name, args, parent, ann, usage = self._spans.pop()
+        usage_now = None if usage is None else _thread_usage()
         ann.__exit__(None, None, None)
+        closed = self._stamp()
+        if usage_now is not None and opened[2] == closed[2]:
+            extra.update(zip(USAGE_FIELDS, (
+                b - a for a, b in zip(usage, usage_now))))
+        if not self._spans:             # the outermost span closes
+            extra.update(gc=self._gc_s, gc_n=self._gc_n)
         if extra:
             args = dict(args, **extra)
-        self._span_event(name, t0, self.now(), args or None,
-                         parent=parent)
+        self._span_event(name, opened, closed, args, parent=parent)
 
     def span_unwind(self, depth: int, aborted: bool = False) -> None:
         """Close every span above ``depth``. ``aborted=True`` is for
@@ -641,6 +712,30 @@ class TraceCollector:
         if self._spans or self._step is not None:
             self.on_event("compile", {"seconds": float(seconds)})
 
+    def on_gc(self, phase: str, info: dict) -> None:
+        """The interpreter's collector started or stopped a collection
+        (the one process-wide ``gc.callbacks`` entry below calls
+        this). It counts where it ran on the thread of this
+        collector's outermost open span; see the module docstring."""
+        if not self._spans or \
+                self._spans[0][0][2] != threading.get_ident():
+            return
+        if phase == "start":
+            self._gc_open = (self._stamp(), self._annotate("gc"))
+        elif self._gc_open is not None:
+            opened, ann = self._gc_open
+            self._gc_open = None
+            ann.__exit__(None, None, None)
+            closed = self._stamp()
+            seconds = closed[0] - opened[0]
+            self._gc_s += seconds
+            self._gc_n += 1
+            if info["generation"] == 2 or seconds > self.GC_SPAN_S:
+                self._span_event("gc", opened, closed, {
+                    "generation": info["generation"],
+                    "collected": info["collected"]},
+                    parent=self._innermost())
+
     # -- request lifecycle --------------------------------------------
     def _rec_event(self, rec: _ReqTrace, ts: float, name: str,
                    args: Optional[dict] = None) -> None:
@@ -666,7 +761,7 @@ class TraceCollector:
         return self._replay and not rec.replayed
 
     def on_submit(self, rid: int, tenant: str,
-                  prompt_tokens: int, gid=None) -> None:
+                  prompt_tokens: int) -> None:
         if rid in self.requests:        # replayed submit of a known
             return                      # rid: the live record stands
         if len(self.requests) >= self.max_requests:
@@ -679,8 +774,7 @@ class TraceCollector:
                 del self.requests[victim]
                 self.evicted_requests += 1
         ts = self.now()
-        rec = _ReqTrace(rid, tenant, ts, replayed=self._replay,
-                        gid=None if gid is None else int(gid))
+        rec = _ReqTrace(rid, tenant, ts, replayed=self._replay)
         rec.events.append((ts, "submitted",
                            {"prompt_tokens": int(prompt_tokens)}))
         self.requests[rid] = rec
@@ -806,33 +900,6 @@ class TraceCollector:
                 "per_tenant": {t: roll(rs)
                                for t, rs in by_tenant.items()}}
 
-    def group_summary(self) -> dict:
-        """Per fork-shared branch group (scheduler ``submit(n>1)`` /
-        ``fork_stream``): branch count, total tokens, and GROUP TTFT —
-        the wall time from the group's earliest submit (the lead's;
-        branches are forked later, at prefill completion) to the
-        earliest first token emitted by ANY member. That is the
-        latency the caller of one n-way request observes, which
-        per-branch ``ttft_s`` (tiny for forked branches) does not
-        measure. Non-replayed records only, keyed by str(gid) for
-        JSON round-tripping."""
-        by_gid: Dict[int, list] = {}
-        for r in self.requests.values():
-            if r.gid is not None and not r.replayed:
-                by_gid.setdefault(r.gid, []).append(r)
-        out = {}
-        for gid, recs in by_gid.items():
-            firsts = [r.first_ts for r in recs if r.first_ts is not None]
-            submit = min(r.submit_ts for r in recs)
-            out[str(gid)] = {
-                "branches": len(recs),
-                "tokens": sum(r.tokens for r in recs),
-                "group_ttft_s": None if not firsts
-                else round(min(firsts) - submit, 6),
-                "outcomes": sorted(r.outcome for r in recs
-                                   if r.outcome is not None)}
-        return out
-
     def as_dict(self) -> dict:
         return {"steps": self.steps,
                 "replayed_steps": self.replayed_steps,
@@ -841,8 +908,7 @@ class TraceCollector:
                 "requests": len(self.requests),
                 "evicted_requests": self.evicted_requests,
                 "registry": self.registry.as_dict(),
-                "summary": self.request_summary(),
-                "groups": self.group_summary()}
+                "summary": self.request_summary()}
 
     def chrome_trace(self) -> dict:
         """The ``trace_events`` JSON object (Chrome/Perfetto): engine
@@ -912,7 +978,24 @@ def _json_default(o):
 
 
 # ---------------------------------------------------------------------
-# compile events: one process-wide listener
+# what the OS counted for the calling thread
+# ---------------------------------------------------------------------
+
+USAGE_FIELDS = ("faults", "faults_major", "preempted", "yields")
+_RUSAGE_THREAD = getattr(resource, "RUSAGE_THREAD", None)   # Linux
+
+
+def _thread_usage() -> Optional[tuple]:
+    """``USAGE_FIELDS`` of the calling thread so far (None where the
+    platform does not count by thread)."""
+    if _RUSAGE_THREAD is None:
+        return None
+    ru = resource.getrusage(_RUSAGE_THREAD)
+    return ru.ru_minflt, ru.ru_majflt, ru.ru_nivcsw, ru.ru_nvcsw
+
+
+# ---------------------------------------------------------------------
+# compile events and collections: one process-wide listener each
 # ---------------------------------------------------------------------
 
 _COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
@@ -926,14 +1009,22 @@ def _on_jax_duration(event: str, seconds: float, **_kw) -> None:
             col.on_compile(seconds)
 
 
-def _watch_compiles(col: TraceCollector) -> None:
+def _on_gc(phase: str, info: dict) -> None:
+    for col in list(_collectors):
+        col.on_gc(phase, info)
+
+
+def _watch_process(col: TraceCollector) -> None:
     """jax's monitoring listeners cannot be removed, so there is ONE
-    for the process, registered with the first collector; it hands
-    each backend compile to the collectors that are alive."""
+    for the process, registered with the first collector, and beside
+    it ONE ``gc.callbacks`` entry (a process that never made a
+    collector has neither); they hand each backend compile and each
+    collection to the collectors that are alive."""
     global _listening
     if not _listening:
         jax.monitoring.register_event_duration_secs_listener(
             _on_jax_duration)
+        gc.callbacks.append(_on_gc)
         _listening = True
     _collectors.add(col)
 

@@ -56,6 +56,11 @@ class CountingTime:
         import time
         return time.monotonic()
 
+    def thread_time(self):
+        self.calls += 1
+        import time
+        return time.thread_time()
+
 
 @pytest.fixture
 def counting_clock(monkeypatch):
@@ -157,7 +162,9 @@ def pytest_sessionfinish(session, exitstatus):
 # test, for those metrics, the run is completed with the planted data of
 # tests/benchmark_suite/planted_afmoe.py (or planted_latent.py: latent
 # widths and row lengths; planted_conv.py: layer kinds and the stored K/V
-# row) before the REAL reader reads it.
+# row) before the REAL reader reads it. The metrics that read what a span
+# carries beside its duration (planted_host_clock.py) read a collector: for
+# them a fabricated session that has the fields is planted as the last one.
 
 @pytest.fixture(autouse=True)
 def _planted_run_for_the_reader_test(request, monkeypatch):
@@ -168,7 +175,13 @@ def _planted_run_for_the_reader_test(request, monkeypatch):
         return
     from benchmark import cells
     from tests.benchmark_suite import (planted_afmoe, planted_conv,
-                                       planted_latent)
+                                       planted_host_clock, planted_latent)
+    if metric["name"] in planted_host_clock.PLANTED_VALUES:
+        # these read the last profile session's collector, not the run
+        from paddle_tpu.inference import telemetry
+        monkeypatch.setattr(telemetry, "_session",
+                            planted_host_clock.planted_collector())
+        return
     source = next((m for m in (planted_afmoe, planted_latent, planted_conv)
                    if metric["name"] in m.PLANTED_VALUES), None)
     if source is None:

@@ -512,7 +512,7 @@ class TestMutations:
         root, path = _mutate(tmp_path, "recovery.py", old, new)
         kept, _ = run(root, ["span-safety"])
         assert [(f.path, f.line) for f in kept] == \
-            [(path, lineno(path, 'col.span_begin("round")'))]
+            [(path, lineno(path, 'col.span_begin("round"'))]
 
     def test_host_pull_in_compiled_dispatch(self, tmp_path):
         """The compiled-collectives acceptance: a host pull sneaking

@@ -395,6 +395,26 @@ class TestZeroOverheadWhenOff:
         self._serve(collector=TraceCollector())
         assert counting_clock.calls > 0
 
+    def test_collector_reads_the_cpu_clock_where_it_reads_the_other(
+            self):
+        """Both clocks are injectable, and the second is read only
+        where a span, a phase or a step opens or closes: never more
+        often than the first, at most twice a span event."""
+        reads = {"wall": 0, "cpu": 0}
+
+        def clock(which):
+            def read():
+                reads[which] += 1
+                return 0.0
+            return read
+        col = TraceCollector(clock=clock("wall"), cpu_clock=clock("cpu"))
+        assert reads == {"wall": 1, "cpu": 0}       # construction
+        self._serve(collector=col)
+        spans = [ev for ev in col.events if ev["ph"] == "X"]
+        assert spans and all(ev["args"]["cpu"] == 0.0 for ev in spans)
+        assert 0 < reads["cpu"] <= 2 * len(spans)
+        assert reads["cpu"] < reads["wall"]
+
     def test_deterministic_injected_clock(self):
         """A fake clock makes every latency exact: TTFT/TPOT/queue
         wait derive purely from the recorded stamps."""
@@ -960,7 +980,8 @@ class TestChromeTraceAndReport:
 
 ROUND_CHILDREN = {"spec_round", "draft_roll", "embed", "verify", "grow",
                   "prefill", "bookkeeping", "model", "admission",
-                  "sample_verify", "device_wait", "journal", "snapshot"}
+                  "sample_verify", "device_wait", "journal", "snapshot",
+                  "gc"}      # a long collection, wherever it fell
 SUBMIT_CHILDREN = {"submit.journal", "submit.embed", "submit.hash",
                    "submit.admit"}
 
@@ -1106,6 +1127,7 @@ class TestProfileSessionSwitch:
         for top in (ev for ev in spans if ev["name"] == "submit"):
             kids = [ev for ev in spans
                     if ev["args"].get("parent") == "submit"
+                    and ev["name"] != "gc"
                     and top["ts"] - eps <= ev["ts"]
                     and ev["ts"] + ev["dur"]
                     <= top["ts"] + top["dur"] + eps]
@@ -1140,6 +1162,31 @@ class TestProfileSessionSwitch:
         assert admits and sorted(e[3]["rid"] for e in admits) == \
             sorted(col.requests)
         assert any(e[0] == "pt.submit" for e in pt)
+
+    def test_session_spans_say_work_and_wait(self, tmp_path,
+                                             fresh_session):
+        """The session's collector reads the thread's CPU clock at
+        every span, and the OS's counters at the round, the submit
+        and the gather (tests/test_span_cpu.py holds each field)."""
+        srv = _spec_server(tmp_path)
+        with _Profile(tmp_path / "prof"):
+            _serve_closed_loop(srv, _prompts(6, n=5, lo=9, hi=14), 4, 8)
+        srv.step()
+        srv.close()
+        spans = [ev for ev in fresh_session.last_session_collector().events
+                 if ev["ph"] == "X"]
+        counted = {"round", "submit", "submit.embed"}
+        assert counted <= {ev["name"] for ev in spans}
+        for ev in spans:
+            args = ev["args"]
+            # the two clocks tick apart: a span too short for the CPU
+            # clock's tick may read a hair over its duration
+            assert 0 <= args["cpu"] <= ev["dur"] + 1e-3, ev
+            assert ("faults" in args and "preempted" in args) == \
+                (ev["name"] in counted), ev
+            assert ("gc_n" in args) == (ev["name"] in ("round", "submit"))
+        waits = [ev for ev in spans if ev["name"] == "device_wait"]
+        assert waits and all("parent" in ev["args"] for ev in waits)
 
     def test_streams_are_bit_identical_with_a_session_in_mid_flight(
             self, tmp_path, fresh_session):
@@ -1218,7 +1265,7 @@ class TestRingAndCompileEvents:
         assert col.chrome_trace()["metadata"]["dropped_events"] == 6
 
     def test_spans_record_parent_and_round(self):
-        col = TraceCollector()
+        col = TraceCollector(cpu_clock=lambda: 0.0)
         col.round_no = 7
         col.span_begin("round")
         col.begin_step(3, "verify")
@@ -1231,16 +1278,18 @@ class TestRingAndCompileEvents:
         col.span_end()
         col.span_end()
         got = {ev["name"]: ev["args"] for ev in col.events}
-        assert got["grow"] == {"rid": 5, "round": 7, "parent": "prefill"}
+        assert got["grow"] == {"rid": 5, "round": 7, "parent": "prefill",
+                               "cpu": 0.0}
         assert got["model"]["parent"] == "verify"
         assert got["verify"]["parent"] == "round"
         assert got["journal"]["parent"] == "round"
-        assert got["round"] == {"round": 7}
+        # (the outermost span also carries the collector's pauses)
+        assert set(got["round"]) == {"round", "cpu", "gc", "gc_n"}
         # a bare engine's collector has no round and no parent on top
         bare = TraceCollector()
         bare.span_begin("spec_round")
         bare.span_end()
-        assert "args" not in bare.events[0]
+        assert not {"round", "parent"} & set(bare.events[0]["args"])
 
     def test_a_compile_is_an_instant_on_the_round_it_fell_in(self):
         import jax

@@ -1,7 +1,10 @@
 """Offline trace summarizer: load a Chrome-trace JSON written by
 ``TraceCollector.save_chrome_trace`` (inference/telemetry.py),
 validate the ``trace_events`` structure, and print the serving story
-— span durations by phase, gauge tracks, per-request lifecycles and
+— span durations by phase (wall, and where the spans carry it the
+opening thread's CPU time, the wait that is their difference, page
+faults, preemptions and the collector's pauses), gauge tracks,
+per-request lifecycles and
 per-tenant TTFT / TPOT / queue-wait percentiles — without needing the
 engine, the model, or a live process. Sibling of
 tools/recovery_check.py (the snapshot doctor); this is the timeline
@@ -63,9 +66,15 @@ except ImportError:      # run as a script: tools/ is sys.path[0]
 # always did)
 _PHASES = ("admission", "prefill", "model", "bookkeeping")
 _ROUND = ("round", "spec_round", "draft_roll", "embed", "verify", "step",
-          "grow", "sample_verify", "device_wait", "journal", "snapshot")
+          "grow", "sample_verify", "device_wait", "journal", "snapshot",
+          "gc")
 _SUBMIT = ("submit", "submit.journal", "submit.embed", "submit.hash",
            "submit.admit")
+# what a span may carry beside its duration (``TraceCollector``: ``cpu``,
+# seconds of the opening thread's CPU time, on every span; the rest on
+# ``round`` / ``submit`` / ``submit.embed``): a trace written before
+# these existed has none, and its lines end where they always did
+_COUNTS = ("faults", "faults_major", "preempted", "yields", "gc", "gc_n")
 _MODEL = ("mla", "mla.project", "mla.attend", "mla.out",
           "conv", "conv.project", "conv.mix", "conv.out",
           "moe", "moe.route", "moe.experts")     # inside the model phase
@@ -130,12 +139,21 @@ def _rollup(evs):
     [samples, sum, min, max, last]}}, instant tallies, replay-flagged
     span count, children {name: total duration of the spans that name
     it as their parent} — a span's self time is its total less its
-    children's)."""
+    children's; host {name: {"cpu": CPU time of the spans that carry
+    it, "wall": their duration (wait is the difference), "child_cpu":
+    CPU time of the spans that name it as their parent, and the sum of
+    each of ``_COUNTS`` the name's spans carry}}, all times in
+    microseconds)."""
     spans = {}
     counters = {}
     insts = {}
     replayed = 0
     children = {}
+    host = {}
+
+    def add(name, key, value):
+        h = host.setdefault(name, {})
+        h[key] = h.get(key, 0) + value
     for ev in evs:
         ph = ev.get("ph")
         if ph == "X":
@@ -149,6 +167,15 @@ def _rollup(evs):
             if args.get("parent") is not None:
                 children[args["parent"]] = \
                     children.get(args["parent"], 0.0) + d
+            if "cpu" in args:
+                add(name, "cpu", float(args["cpu"]) * 1e6)
+                add(name, "wall", d)
+                if args.get("parent") is not None:
+                    add(args["parent"], "child_cpu",
+                        float(args["cpu"]) * 1e6)
+            for key in _COUNTS:
+                if key in args:
+                    add(name, key, args[key] * (1e6 if key == "gc" else 1))
         elif ph == "C":
             track = counters.setdefault(ev["name"], {})
             for k, v in (ev.get("args") or {}).items():
@@ -159,7 +186,24 @@ def _rollup(evs):
                             max(st[3], v), v]
         elif ph == "i":
             insts[ev["name"]] = insts.get(ev["name"], 0) + 1
-    return spans, counters, insts, replayed, children
+    return spans, counters, insts, replayed, children, host
+
+
+def _host_columns(h: dict, n: int) -> str:
+    """The rest of a span's line: CPU, wait and self CPU where its spans
+    carry ``cpu``, then the counters and the collector's pauses."""
+    out = ""
+    if "cpu" in h:
+        out += (f", cpu {_fmt_s(h['cpu'])}, wait "
+                f"{_fmt_s(h['wall'] - h['cpu'])}, self cpu "
+                f"{_fmt_s(h['cpu'] - h.get('child_cpu', 0.0))}")
+    if "faults" in h:
+        out += (f"; faults {h['faults']} ({h['faults'] / n:.1f} a span, "
+                f"{h['faults_major']} major), preempted {h['preempted']}, "
+                f"yields {h['yields']}")
+    if "gc_n" in h:
+        out += f"; gc {_fmt_s(h['gc'])} in {h['gc_n']} collection(s)"
+    return out
 
 
 def _expert_lines(meta) -> list:
@@ -232,7 +276,7 @@ def summarize(trace: dict, tenant: str = None,
               show_requests: bool = False) -> str:
     evs = trace["traceEvents"]
     lines = []
-    spans, counters, insts, replayed, children = _rollup(evs)
+    spans, counters, insts, replayed, children, host = _rollup(evs)
     lines.append(f"timeline: {len(evs)} event(s), "
                  f"{sum(n for _, n, _ in spans.values())} span(s)"
                  + (f" ({replayed} replay-flagged)" if replayed
@@ -253,7 +297,8 @@ def summarize(trace: dict, tenant: str = None,
             lines.append(f"    {name}: {n} x, total {_fmt_s(tot)}, "
                          f"mean {_fmt_s(tot / n)}, max {_fmt_s(mx)}"
                          + (f", self {_fmt_s(tot - children[name])}"
-                            if name in children else ""))
+                            if name in children else "")
+                         + _host_columns(host.get(name, {}), n))
         if title == "submit spans":
             lines.extend(_submit_hash_lines(evs, trace.get("metadata")))
     if counters:
@@ -369,16 +414,30 @@ def machine_report(trace: dict) -> dict:
     instant/counter tallies and the collector metadata summary — the
     same facts ``summarize`` renders (same ``_rollup`` pass), as
     data."""
-    spans, counters, insts, replayed, children = \
+    spans, counters, insts, replayed, children, host = \
         _rollup(trace["traceEvents"])
     meta = trace.get("metadata")
+
+    def host_fields(name):
+        h = host.get(name, {})
+        out = {k: h[k] for k in _COUNTS if k in h and k != "gc"}
+        if "gc" in h:
+            out["gc_s"] = round(h["gc"] / 1e6, 6)
+        if "cpu" in h:
+            out.update(
+                cpu_s=round(h["cpu"] / 1e6, 6),
+                wait_s=round((h["wall"] - h["cpu"]) / 1e6, 6),
+                self_cpu_s=round(
+                    (h["cpu"] - h.get("child_cpu", 0.0)) / 1e6, 6))
+        return out
     out = {
         "events": len(trace["traceEvents"]),
-        "spans": {name: {"count": n,
-                         "total_s": round(tot / 1e6, 6),
-                         "max_s": round(mx / 1e6, 6),
-                         "self_s": round(
-                             (tot - children.get(name, 0.0)) / 1e6, 6)}
+        "spans": {name: dict(
+                      count=n, total_s=round(tot / 1e6, 6),
+                      max_s=round(mx / 1e6, 6),
+                      self_s=round(
+                          (tot - children.get(name, 0.0)) / 1e6, 6),
+                      **host_fields(name))
                   for name, (tot, n, mx) in sorted(spans.items())},
         "replayed_spans": replayed,
         "instants": dict(sorted(insts.items())),
