@@ -491,6 +491,8 @@ def try_call(layer, inputs, kwargs):
 #                          forward raises
 #   rebind(donated)        after the program ran: the owner takes the
 #                          new arrays (the old ones are deleted)
+#   kwargs                 the keyword arguments of the per-op call
+#                          (read by a core with a pure forward, below)
 
 CORE = "core"                    # the layer's forward does not capture
 TRACE_FAILED = "trace_failed"    # this shape's first trace raised
@@ -553,12 +555,88 @@ class _StateExec(_LayerExec):
         return outs, aux, ()
 
 
+# A core that is no ``nn.Layer`` joins by offering a PURE forward
+# (inference/decoder.py ``DecoderCore``): nothing the layer rule could
+# find out by tracing, the core says itself. ``kwargs`` below are the
+# per-op call's own (``state.kwargs``):
+#   step_operands(*inputs, **kwargs)    -> (donated, plain): the core's
+#                          own arrays for this call (its device-side
+#                          counters, its weights), on the host
+#   step_forward(donated, plain, *inputs, **kwargs) -> (result, donated)
+#                          traced whole inside ``state.lend``: host
+#                          effects none, results as outputs
+#   step_rebind(donated, result, *inputs, **kwargs) -> what the per-op
+#                          call returns, on the host after the run
+
+
+def _pure_core(layer) -> bool:
+    from ..nn import Layer
+    return not isinstance(layer, Layer) and \
+        callable(getattr(layer, "step_forward", None))
+
+
+class _PureExec:
+    """The compiled forward of one (core, input signature, state key)
+    of a core with a pure forward. Holds the core weakly and no array
+    (jax keeps ``fwd``, and with it this object, alive)."""
+
+    def __init__(self, core):
+        self._core_ref = weakref.ref(core)
+        self.state = None            # lent for the duration of one call
+        self.program = jax.jit(self.fwd, donate_argnums=(0, 2))
+
+    def fwd(self, donated, plain, own, own_plain, in_leaves):
+        # named as ``_StateExec.fwd`` is, for the same readers
+        core = self._core_ref()
+        with self.state.lend(donated, plain) as (kwargs, collect):
+            result, own = core.step_forward(own, own_plain, *in_leaves,
+                                            **kwargs)
+            return result, own, collect()
+
+
+def _call_pure(core, inputs, state):
+    """``call_with_state`` for a core with a pure forward (protocol
+    above): the same state, key, reasons and programs per core."""
+    from ..flags import flags_version
+    if _state.active or not enabled() or not _trace_clean():
+        return None, CORE
+    entry = _cache.setdefault(core, {"execs": {}})
+    if entry.get("all") is _UNSAFE:
+        return None, CORE
+    in_leaves, in_tree, _ = _flatten(list(inputs))
+    sig = ("pure", repr(in_tree), state.key, flags_version()) + tuple(
+        (tuple(a.shape), str(a.dtype)) for a in in_leaves)
+    exec_ = entry["execs"].get(sig)
+    if exec_ is _UNSAFE:
+        return None, TRACE_FAILED
+    if exec_ is None:
+        exec_ = entry["execs"][sig] = _PureExec(core)
+    own, own_plain = core.step_operands(*inputs, **state.kwargs)
+    exec_.state, _state.active = state, True
+    try:
+        result, own, returned = exec_.program(
+            *state.arrays(), own, own_plain, tuple(in_leaves))
+    except Exception:
+        # the trace raised: nothing was donated, the call runs per op
+        import os
+        if os.environ.get("PADDLE_TPU_LAYER_JIT_DEBUG"):
+            raise
+        entry["execs"][sig] = _UNSAFE
+        return None, TRACE_FAILED
+    finally:
+        exec_.state, _state.active = None, False
+    state.rebind(returned)
+    return core.step_rebind(own, result, *inputs, **state.kwargs), None
+
+
 def state_programs(layer) -> int:
     """Programs ``call_with_state`` holds for ``layer`` so far."""
     from ..nn import Layer
-    entry = _cache.get(layer) if isinstance(layer, Layer) else None
+    entry = _cache.get(layer) \
+        if isinstance(layer, Layer) or _pure_core(layer) else None
     return 0 if entry is None else sum(
-        isinstance(e, _StateExec) for e in entry["execs"].values())
+        isinstance(e, (_StateExec, _PureExec))
+        for e in entry["execs"].values())
 
 
 def call_with_state(layer, inputs, state):
@@ -572,7 +650,11 @@ def call_with_state(layer, inputs, state):
     ``TRACE_FAILED`` for a signature whose first trace raised: that
     signature stays per-op, others still capture. The state is whole
     either way: a failed trace donated nothing, and a program that ran
-    has its outputs rebound before anything else happens."""
+    has its outputs rebound before anything else happens. A core that
+    offers a pure forward takes its own entry into the same protocol
+    (``_call_pure``)."""
+    if _pure_core(layer):
+        return _call_pure(layer, inputs, state)
     from ..nn import Layer
     if not isinstance(layer, Layer) or _state.active or not enabled() \
             or not _trace_clean():

@@ -528,11 +528,12 @@ def decoder_block(cfg: DecoderConfig, layer: int, p: dict, x, positions,
     ``conv.out``). ``mla`` records a span
     ``mla`` a call with ``mla.project`` (down-projections, norms, RoPE,
     absorption), ``mla.attend`` (append and launch) and ``mla.out`` (on a
-    dense layer the SwiGLU rides in its program). ``counters`` (a dict holding ``acc``)
-    takes the expert layer's device-side row counts; ``route_tap`` (a
-    list) is handed (layer, view, positions, chosen experts) of every
-    expert layer call, the arrays on the device. Returns the rows after
-    the layer."""
+    dense layer the SwiGLU rides in its program); spans are opened on a
+    ``collector`` only, which the step program never passes.
+    ``counters`` (a dict holding ``acc``) takes the expert layer's
+    device-side row counts; ``route_tap`` (a list) is handed (layer,
+    view, positions, chosen experts) of every expert layer call. Returns
+    the rows after the layer."""
     kind = cfg.layer_types[layer]
     if view.window != cfg.window_of(layer):
         raise ValueError(
@@ -666,10 +667,28 @@ def _draw_layer(cfg: DecoderConfig, conv: bool, moe: bool, key) -> dict:
     return p
 
 
+def _draw_params(cfg: DecoderConfig, seed: int) -> List[dict]:
+    """Every layer's parameters, drawn on the device from ``seed``."""
+    keys = jax.random.split(jax.random.PRNGKey(int(seed)), cfg.num_layers)
+    draw = jax.jit(_draw_layer, static_argnums=(0, 1, 2))
+    return [draw(cfg, cfg.layer_types[i] == CONV, cfg.is_moe(i), keys[i])
+            for i in range(cfg.num_layers)]
+
+
+def _kind(caches) -> str:
+    """The expert counters a call books under: ``decode`` (one row a
+    slot) or ``mixed`` (a packed step or a prompt chunk)."""
+    return "decode" if isinstance(caches[0], PagedLayerCache) else "mixed"
+
+
 class DecoderCore:
     """The decoder stack as the engines drive it. Not an ``nn.Layer``:
     its parameters are plain device arrays in per-layer dicts
-    (``self.params``), drawn from ``seed`` by ``jax.random``."""
+    (``self.params``), drawn from ``seed`` by ``jax.random``. Called, it
+    runs per op (the specification, and CPU tier-1's path); it also
+    offers its forward PURE (``step_operands`` / ``step_forward`` /
+    ``step_rebind``), which ``paged_cache.model_call`` runs as one step
+    program on the kernel path."""
 
     # take the packed mixed step whenever the scheduler may: the block
     # needs every row's position, which the ragged layout carries
@@ -698,12 +717,7 @@ class DecoderCore:
         self.num_layers = cfg.num_layers
         self.layer_windows = tuple(cfg.window_of(i)
                                    for i in range(cfg.num_layers))
-        keys = jax.random.split(jax.random.PRNGKey(int(seed)),
-                                cfg.num_layers)
-        draw = jax.jit(_draw_layer, static_argnums=(0, 1, 2))
-        self.params: List[dict] = [
-            draw(cfg, cfg.layer_types[i] == CONV, cfg.is_moe(i), keys[i])
-            for i in range(cfg.num_layers)]
+        self.params: List[dict] = _draw_params(cfg, seed)
         self.collector = None      # PagedServingEngine keeps it current
         # a list here receives every expert layer call's (layer, view,
         # positions, chosen experts): the benchmark's probe compares
@@ -725,6 +739,20 @@ class DecoderCore:
     # -- the protocol -------------------------------------------------
     def __call__(self, src, attn_mask=None, caches=None, time_step=None,
                  **kwargs):
+        x, t, positions = self._inputs(src, caches, time_step)
+        kind = _kind(caches)
+        self._count(kind, x)
+        for i in range(self.num_layers):
+            x = decoder_block(
+                self.config, i, self.params[i], x, positions, caches[i],
+                t, counters=self._counters[kind].get(i),
+                collector=self.collector, route_tap=self.route_tap)
+        return Tensor(x), list(caches)
+
+    @staticmethod
+    def _inputs(src, caches, time_step):
+        """(rows [B, L, d] float32, start positions t [B], every row's
+        position [B, L]) of a call."""
         if caches is None or time_step is None or \
                 not getattr(caches[0], "is_paged", False):
             raise ValueError("DecoderCore serves through paged cache "
@@ -735,17 +763,46 @@ class DecoderCore:
         t = time_step.data if isinstance(time_step, Tensor) \
             else jnp.asarray(time_step, jnp.int32)
         t = jnp.broadcast_to(t.reshape(-1).astype(jnp.int32), (b,))
-        positions = caches[0].positions(t, l)
-        kind = "decode" if isinstance(caches[0], PagedLayerCache) \
-            else "mixed"
+        return x, t, caches[0].positions(t, l)
+
+    def _count(self, kind: str, x) -> None:
         if self._moe_layers:
             self._calls[kind] += 1
-            self._rows[kind] += b * l
+            self._rows[kind] += int(x.shape[0]) * int(x.shape[1])
+
+    # -- the step program (``layer_jit``'s pure-forward protocol) -----
+    # The same blocks, traced whole by ``paged_cache.model_call``: the
+    # weights are plain operands, the expert counters of the call's kind
+    # donated ones, and what the per-op call does on the host (the
+    # ``moe`` / ``mla`` / ``conv`` spans, ``_calls`` / ``_rows``, the
+    # routing tap) is either left out or done by ``step_rebind``.
+    def step_operands(self, src, caches, time_step):
+        counters = self._counters[_kind(caches)]
+        return {i: c["acc"] for i, c in counters.items()}, self.params
+
+    def step_forward(self, acc, params, src, caches, time_step):
+        """The forward with no host effect: returns ((rows, positions,
+        each expert layer's chosen experts), the counters)."""
+        x, t, positions = self._inputs(src, caches, time_step)
+        counters = {i: {"acc": a} for i, a in acc.items()}
+        tap = []
         for i in range(self.num_layers):
-            x = decoder_block(
-                self.config, i, self.params[i], x, positions, caches[i],
-                t, counters=self._counters[kind].get(i),
-                collector=self.collector, route_tap=self.route_tap)
+            x = decoder_block(self.config, i, params[i], x, positions,
+                              caches[i], t, counters=counters.get(i),
+                              route_tap=tap)
+        return ((x, positions, tuple(entry[3] for entry in tap)),
+                {i: c["acc"] for i, c in counters.items()})
+
+    def step_rebind(self, acc, result, src, caches, time_step):
+        kind = _kind(caches)
+        for i, a in acc.items():
+            self._counters[kind][i]["acc"] = a
+        self._count(kind, src)
+        x, positions, chosen = result
+        if self.route_tap is not None:
+            self.route_tap.extend(
+                (i, caches[i], positions, idx)
+                for i, idx in zip(self._moe_layers, chosen))
         return Tensor(x), list(caches)
 
     # -- accounting ---------------------------------------------------

@@ -620,14 +620,20 @@ class _StateMixer:
         """``u`` [B, L, d] (a jax array, the call's rows in the view's
         own row order) through this layer's short convolution with
         ``taps`` [d, rows + 1]: returns y [B, L, d] float32 and leaves
-        each written slot's last rows in the store."""
+        each written slot's last rows in the store. Inside a step
+        program the store and the call's index lists are the ones
+        ``_LentStep`` lent to the trace."""
         c = self._cache
         if self._si is None:
             raise ValueError(f"layer {self._layer} is a K/V layer: it "
                              f"has no rows in the state store")
         if not _trace_clean():
-            raise RuntimeError("the state store is not an operand of "
-                               "a step program")
+            if c._lent_mix is None:
+                raise RuntimeError("the state store is not an operand of "
+                                   "a step program")
+            y, c.state[self._si] = _conv_mix(c.state[self._si], u, taps,
+                                             *c._lent_mix)
+            return y
         plan = self._plan_state(int(u.shape[0]), int(u.shape[1]))
         if self._si == 0:
             for k, v in plan.stats.items():
@@ -743,16 +749,24 @@ class _LentStep:
     batch block table of a uniform call or the packed layout's routing
     and descriptors, as plain operands. ``key`` is what the kernel
     launch is keyed by and nothing more: the static ``q_lens`` of a
-    packed call, ``(B, L)`` of a uniform one (and the layers' windows,
-    which no operand's shape shows). ``writes`` is what one run moves
-    (pages, rows; every layer's view appends once), known from the
-    call's shapes: a run that hits a program compiled for ANOTHER
-    cache of the same geometry (a restored engine, a second engine on
-    the model) traces nothing."""
+    packed call, ``(B, L)`` of a uniform one (and what no operand's
+    shape shows: the layers' windows, and which layers hold a state
+    store instead of K/V). ``writes`` is what one run moves (pages,
+    rows; every layer's view appends once), known from the call's
+    shapes: a run that hits a program compiled for ANOTHER cache of
+    the same geometry (a restored engine, a second engine on the
+    model) traces nothing.
+
+    A cache with a STATE STORE lends it too: every state layer's array
+    donated beside the pools, the call's ``_StatePlan`` (built here,
+    once a call) and the mask of the slots whose rows start from zeros
+    (``kept``; zeroed inside the program) as plain operands. What the
+    plan counts is booked by ``ran``, after a run: a call that falls
+    back to per op books its own, and zeroes the slots itself."""
 
     def __init__(self, x, views, t):
         view = views[0]
-        self.views, self.t = views, t
+        self.kwargs = {"caches": views, "time_step": t}
         self.cache = c = view._cache
         if isinstance(view, PagedRaggedView):
             self.holder, rows = view._layout, view._layout.q_lens
@@ -761,42 +775,76 @@ class _LentStep:
             c.bt_tensor()           # built, so that there is one to lend
             self.holder, rows = c, tuple(x.shape[:2])
             self.names = ("_bt_cached",)
-        self.key = (type(view).__name__, rows, c.layer_windows)
+        self.key = (type(view).__name__, rows, c.layer_windows,
+                    c.layer_state)
         self.writes = len(c.kv_layers) * np.array(
             view._moves(*x.shape[:2]), np.int64)
+        self.plan = self.kept = None
+        if c.state_layers:
+            self.plan = view._plan_state(*(int(n) for n in x.shape[:2]))
+            self.kept = np.ones(c.max_seqs, bool)
+            self.kept[sorted(c._state_fresh)] = False
 
     def _pools(self):
         c = self.cache
-        return (tuple(p.data for p in c.pools),
-                tuple(s.data for s in c.scales) if c.quantized else None)
+        out = (tuple(p.data for p in c.pools),
+               tuple(s.data for s in c.scales) if c.quantized else None)
+        return out if self.plan is None else out + (tuple(c.state),)
 
     def rebind(self, donated):
         c = self.cache
         c.pools[:] = [Tensor(p) for p in donated[0]]
         if c.quantized:
             c.scales[:] = [Tensor(s) for s in donated[1]]
+        if self.plan is not None:
+            c.state[:] = donated[2]
 
     def arrays(self):
-        return self._pools(), (unwrap(self.t),) + tuple(
+        plain = (unwrap(self.kwargs["time_step"]),) + tuple(
             unwrap(getattr(self.holder, n)) for n in self.names)
+        if self.plan is not None:
+            plain += (self.plan.pred, self.plan.keep,
+                      jnp.asarray(self.kept))
+        return self._pools(), plain
+
+    def ran(self) -> None:
+        """Book one run of the program on the host: the pool writes
+        (``pool_write``) and, with a state store, what the plan counts
+        (``slot_state``) and the slots it zeroed."""
+        c = self.cache
+        c._written += self.writes
+        if self.plan is not None:
+            for k, v in self.plan.stats.items():
+                c._state_seen[k] += v
+            fresh = np.flatnonzero(~self.kept).tolist()
+            c._state_seen["slots_reset"] += len(fresh)
+            c._state_fresh.difference_update(fresh)
 
     @contextlib.contextmanager
     def lend(self, donated, plain):
         c, h = self.cache, self.holder
         pools, scales = list(c.pools), c.quantized and list(c.scales)
+        states = list(c.state)
         held = [getattr(h, n) for n in self.names]
         try:
             self.rebind(donated)
-            for n, was, a in zip(self.names, held, plain[1:]):
-                setattr(h, n, Tensor(a) if isinstance(was, Tensor) else a)
-            yield ({"caches": self.views, "time_step": Tensor(plain[0])},
+            n = len(self.names) + 1
+            for name, was, a in zip(self.names, held, plain[1:n]):
+                setattr(h, name, Tensor(a) if isinstance(was, Tensor) else a)
+            if self.plan is not None:
+                pred, keep, kept = plain[n:]
+                c.state[:] = _state_clear(tuple(c.state), kept)
+                c._lent_mix = (pred, keep)
+            yield (dict(self.kwargs, time_step=Tensor(plain[0])),
                    self._pools)
         finally:
             c.pools[:] = pools
             if c.quantized:
                 c.scales[:] = scales
-            for n, was in zip(self.names, held):
-                setattr(h, n, was)
+            c.state[:] = states
+            c._lent_mix = None
+            for name, was in zip(self.names, held):
+                setattr(h, name, was)
 
 
 def model_call(model, x, views, t, collector=None):
@@ -806,9 +854,11 @@ def model_call(model, x, views, t, collector=None):
     is that same forward traced whole (``layer_jit.call_with_state``):
     all layers, each layer's page-form K/V append and its
     paged-attention launch in one XLA program, the pools donated
-    through it (``_LentStep``). Who gets it is what the code can
-    observe: the kernel path live (the predicate ``_attend`` asks),
-    one shard, and a core whose forward captures whole. Every other
+    through it (``_LentStep``; a state store rides along the same way).
+    Who gets it is what the code can observe: the kernel path live (the
+    predicate ``_attend`` asks), one shard, and a core whose forward
+    captures whole: an ``nn.Layer`` by the layer-jit's rule, or a core
+    that offers a pure forward (``DecoderCore``). Every other
     call, and a shape whose first trace failed, runs per op as before,
     and the ``step_program`` gauge says why (one sample a call:
     ``captured``, ``programs`` compiled so far, and on a 0 the
@@ -822,7 +872,7 @@ def model_call(model, x, views, t, collector=None):
         state = _LentStep(x, views, t)
         out, why = _layer_jit.call_with_state(model, (x,), state)
         if why is None:
-            state.cache._written += state.writes
+            state.ran()
     if out is None:
         out = model(x, caches=views, time_step=t)
     if collector is not None:
@@ -1254,13 +1304,18 @@ class _RaggedLayout:
     def positions(self):
         """int32 [1, total_rows] on the device: every packed row's
         absolute position (uploaded once a layout, on first use — a
-        model without a position encoding never asks)."""
+        model without a position encoding never asks). Inside a step
+        program they come from the lent ``kv_lens`` and the static
+        ``q_lens`` (a sequence's rows end at its kv length), never from
+        this layout's upload, which other layouts of the same
+        ``q_lens`` would run as a constant."""
+        if not _trace_clean():
+            q = np.asarray(self.q_lens)
+            seq = np.repeat(np.arange(q.shape[0]), q)
+            back = np.repeat(np.cumsum(q), q) - np.arange(seq.shape[0])
+            return (unwrap(self.kv_lens)[seq] - back)[None] \
+                .astype(jnp.int32)
         if self._pos is None:
-            if not _trace_clean():
-                # a constant of THIS layout in a program other layouts
-                # of the same q_lens run
-                raise RuntimeError("the packed rows' positions are not "
-                                   "an operand of the step program")
             self._pos = jnp.asarray(self.pos_np[None])
         return self._pos
 
@@ -1685,6 +1740,9 @@ class PagedKVCache:
             for i in self.state_layers]
         self._state_fresh: set = set()
         self._state_seen = dict.fromkeys(_STATE_COUNTS + ("slots_reset",), 0)
+        # the call's index lists (pred, keep) while a step program's
+        # trace holds the store (``_LentStep.lend``), else None
+        self._lent_mix = None
         # all entries at the trash block until allocated
         self.block_tables = np.zeros(
             (self.max_seqs, self.max_blocks_per_seq), np.int32)

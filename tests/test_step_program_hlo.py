@@ -155,3 +155,127 @@ def test_the_reading_finds_a_closed_over_weight(one_chip):
     hlo = jax.jit(closed).lower(jax.ShapeDtypeStruct(
         (8, 1024), jnp.float32, sharding=one_chip)).compile().as_text()
     assert [size for _, size in _constants_above(hlo, 1 << 20)] == [4 << 20]
+
+
+# ---- the config-driven block's step program ----------------------------
+# ``DecoderCore`` offers its forward pure (``layer_jit._PureExec``): the
+# same reading at three decoder cells' shapes, weights and pools abstract,
+# the state store real (a few MB). A cell's state store is written back
+# by a gather, whose result the compiler lays out once more (a transpose
+# of the store's shape from the gather, one a conv layer, as the per-op
+# mix program has it); nothing copies the lent store itself.
+
+DECODER_CELLS = {
+    "busy-chat": ("lfm2-24b-a2b-1chip", "serve_conv"),
+    "mixed-queue": ("trinity-large-ep8", "serve_arch"),
+    "long-decode": ("joyai-llm-flash-1chip", "serve_latent"),
+}
+
+
+def _decoder_program(monkeypatch, one_chip, cell, kind, tmp_path):
+    """(compiled HLO, cache, config) of the step program a decoder cell's
+    ``kind`` call (decode-only, or a packed step: a whole prompt chunk
+    beside every other slot's decode row) runs, weights and pools
+    abstract."""
+    import functools
+    import json
+    import os
+    from paddle_tpu.inference import decoder
+    name, job = DECODER_CELLS[cell]
+    job = importlib.import_module(f"benchmark.jobs.{job}")
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs",
+                           name + ".json")) as f:
+        config = json.load(f)
+    cfg = decoder.DecoderConfig.from_spec(
+        job.server_spec(config, 3, str(tmp_path)))
+    draw = decoder._draw_params
+    monkeypatch.setattr(decoder, "_draw_params", lambda c, seed: (
+        jax.eval_shape(functools.partial(draw, c, seed))))
+    monkeypatch.setattr(
+        paddle, "zeros", lambda shape, dtype=None: _abstract(shape, dtype))
+    monkeypatch.setattr(device, "use_pallas_kernels", lambda: True)
+    for kernel in ("paged_attention", "grouped_gemm"):
+        monkeypatch.setattr(importlib.import_module(
+            f"paddle_tpu.ops.pallas.{kernel}"), "on_tpu", lambda: True)
+    core = decoder.DecoderCore(cfg, 3)
+    e = config["engine"]
+    slots = e["max_batch"]
+    cache = pc.PagedKVCache.for_model(
+        core, e["block_size"], e["num_blocks"], slots,
+        max_blocks_per_seq=e["max_blocks_per_seq"], dtype=e["kv_dtype"])
+    if kind == "decode":
+        for slot in range(slots):
+            cache.ensure(slot, 200 + slot)
+        views, rows = cache.views, (slots, 1)
+    else:
+        chunk = e["prefill_token_budget"]
+        cache.ensure(0, chunk, write_from=0)
+        for slot in range(1, slots):
+            cache.ensure(slot, 200 + slot)
+        mask = np.zeros(slots, bool)
+        mask[0] = True
+        cache.set_decode_mask(mask)
+        lens = np.array([0] + [199 + s for s in range(1, slots)])
+        views = cache.ragged_views([("prefill", 0, 0, chunk, 0),
+                                    ("decode", lens, 1)])
+        rows = (1, chunk + slots)
+    x = _abstract(rows + (cfg.hidden_size,), jnp.float32)
+    exec_ = layer_jit._PureExec(core)
+    exec_.state = state = pc._LentStep(
+        x, views, Tensor(np.zeros(rows[0], np.int32)))
+    operands = state.arrays() + core.step_operands(x, **state.kwargs) + (
+        (x.data,),)
+    # at the chip's precision: this suite's "highest" asks the grouped
+    # GEMM for float32 passes over bfloat16, which Mosaic refuses
+    with jax.default_matmul_precision("default"):
+        lowered = exec_.program.lower(*jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                           sharding=one_chip), operands))
+        hlo = lowered.compile().as_text()
+    assert all(isinstance(p.data, jax.ShapeDtypeStruct)
+               for p in cache.pools)
+    assert cache._lent_mix is None
+    return hlo, cache, cfg
+
+
+def _parameters_of(hlo, shape, dtype="bf16"):
+    """The entry computation's parameters of ``shape``."""
+    entry = hlo[hlo.index("\nENTRY "):]
+    entry = entry[:entry.index("\n}\n")]
+    return {int(m.group(1)) for m in re.finditer(
+        r"= %s\[%s\]\S* parameter\((\d+)\)"
+        % (dtype, ",".join(map(str, shape))), entry)}
+
+
+@pytest.mark.parametrize("cell, kind", [
+    ("busy-chat", "decode"), ("busy-chat", "mixed"),
+    ("mixed-queue", "decode"), ("long-decode", "decode")])
+def test_decoder_step_program_moves_no_pool_or_state_and_holds_no_weight(
+        monkeypatch, one_chip, tmp_path, cell, kind):
+    hlo, cache, cfg = _decoder_program(monkeypatch, one_chip, cell, kind,
+                                       tmp_path)
+    pool = tuple(cache.pools[0].data.shape)
+    assert not _pool_sized_moves(hlo, pool), _pool_sized_moves(hlo, pool)
+    assert not _constants_above(hlo, 1 << 20), _constants_above(hlo, 1 << 20)
+    attn = len(cache.kv_layers)
+    experts = sum(cfg.is_moe(i) for i in range(cfg.num_layers))
+    assert hlo.count('custom_call_target="tpu_custom_call"') == \
+        attn + 2 * experts
+    # the launches keep the names their readers find them by
+    assert len(re.findall(r"%fwd\S* = \S+ custom-call\(", hlo)) == attn
+    assert len(re.findall(r"%gmm\S* = \S+ custom-call\(", hlo)) == \
+        2 * experts
+    head = hlo.splitlines()[0]
+    aliased = {int(p) for _, p in re.findall(r"\{(\d+)\}: \((\d+), \{\}",
+                                             head)}
+    lent = _parameters_of(hlo, pool)
+    assert len(lent) == attn and lent <= aliased, (lent, head[:400])
+    if cache.state_layers:
+        shape = tuple(cache.state[0].shape)
+        moves = _pool_sized_moves(hlo, shape)
+        assert len(moves) == len(cache.state_layers), moves
+        assert all("transpose(%gather" in m for m in moves), moves
+        stores = _parameters_of(hlo, shape)
+        assert len(stores) == len(cache.state_layers)
+        assert stores <= aliased, (stores, head[:400])

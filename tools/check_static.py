@@ -250,6 +250,9 @@ SNAPSHOT_ATTR_ALLOW: Dict[str, Dict[str, str]] = {
         "_written": "pages / rows moved since the last pool_write gauge "
                     "sample (PR 29): observational, reset by every "
                     "take_write_stats()",
+        "_lent_mix": "a step program's traced index lists, bound only "
+                     "while its trace runs (_LentStep.lend), None at "
+                     "every snapshot boundary",
     },
     "PagedServingEngine": {
         "model": "weights are the caller's problem (restore arg)",
